@@ -10,8 +10,12 @@ and each worker:
      (the PaddleShuffler/ShuffleData role — data_set.cc:2573),
   3. trains DeepFM on its post-shuffle partition and reports AUC.
 
-On a real multi-host pod the same script runs once per host with the
-env provided by your scheduler; only the endpoints change.
+This is a CPU EMULATION of a multi-host job: the spawned workers are
+pinned to ``JAX_PLATFORMS=cpu``, because a chip belongs to one process at
+a time and N local processes cannot share one host's chips. On a real
+multi-host pod the same worker runs once per host (one process driving
+all of the host's chips) with the env provided by your scheduler; only
+the endpoints change.
 
     python examples/train_multihost.py [--workers 2] [--rows 4000]
 """

@@ -25,9 +25,3 @@ __version__ = "0.1.0"
 
 from paddlebox_tpu import config as config
 from paddlebox_tpu.config import FLAGS as FLAGS
-
-# older jax lines lack jax.shard_map (it lives in jax.experimental);
-# publish the translating shim before any subpackage builds a mesh step
-from paddlebox_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()

@@ -164,8 +164,7 @@ class Flags:
     # False (default) = exact f64 host finalize — BasicAucCalculator::compute
     # semantics (metrics.cc:288-304). True = reduce the AUC bucket tables to
     # scalars ON DEVICE in f32 (~1e-5 AUC drift) and fetch ~8 floats instead
-    # of pulling [2, nbins] to host each pass — an optimization for
-    # tunneled/remote devices where the bucket pull is dead weight.
+    # of pulling [2, nbins] to host each pass.
     auc_device_reduce: bool = False
 
     # --- async pass epilogue (ps/epilogue; docs/PERFORMANCE.md) ---
@@ -190,8 +189,7 @@ class Flags:
 
     # --- pass-boundary scatter (ps/table.scatter_logical_rows) ---
     # fixed chunk size for the begin_pass delta scatter: one compiled
-    # executable per table geometry instead of one per delta size (the
-    # per-size compile measured ~20 s on TPU — BENCH_SHAPES tiered row)
+    # executable per table geometry instead of one per delta size
     scatter_chunk_rows: int = 1 << 14
     # warm the chunk-scatter executable in a background thread at tiered
     # table construction, so the first pass boundary doesn't pay the
@@ -224,14 +222,6 @@ class Flags:
     # twice. False restores the staged whole-pass quantization
     # (winsorize + one walk, at the full-pass f32 host cost).
     q8_streaming_front: bool = True
-
-    # --- XLA persistent compilation cache (utils/compile_cache) ---
-    # "" = auto (<tmp>/paddlebox_tpu_xla_cache, honoring
-    # JAX_COMPILATION_CACHE_DIR); "off" disables. Enabled by
-    # Trainer/ShardedTrainer/launcher init so cold processes (elastic
-    # replacement ranks included) deserialize compiles instead of
-    # re-running XLA at the first pass boundary.
-    compilation_cache_dir: str = ""
 
     # --- telemetry (obs/ TelemetryHub; docs/OBSERVABILITY.md) ---
     # path → attach a JSONL event sink (one structured record per pass)

@@ -11,7 +11,11 @@ TPU-native redesign: on TPU one *process per host* drives all local chips
   multi-host, hands them to ``jax.distributed.initialize`` via
   ``init_runtime_env()`` called from the worker;
 - can spawn N local worker processes to emulate a multi-host job on one
-  machine (tests / CPU-mesh dev), each seeing a disjoint rank;
+  machine (tests / CPU-mesh dev), each seeing a disjoint rank — ONLY as
+  an explicit CPU emulation (``JAX_PLATFORMS=cpu`` in the environment
+  the workers inherit): a chip belongs to one process at a time, so N
+  local workers on a host with chips would fight over them and all but
+  one fail or hang. ``nproc > 1`` without it is refused;
 - integrates ElasticManager: on a worker death (or scale event) it stops
   the survivors and restarts everyone from the latest published
   checkpoint pointer.
@@ -96,6 +100,12 @@ def launch_local(cmd: Sequence[str], cfg: LaunchConfig) -> int:
     """Run ``cmd`` as cfg.nproc rank-stamped local processes; restart the
     gang (from the latest checkpoint pointer) on failure when elastic is
     enabled. Returns the final exit code (0 = all ranks clean)."""
+    if cfg.nproc > 1 and os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        raise ValueError(
+            f"nproc={cfg.nproc}: one process per host drives all local "
+            "chips, and a chip belongs to one process at a time. Several "
+            "local workers are a CPU emulation of a multi-host job — "
+            "say so with JAX_PLATFORMS=cpu.")
     manager: Optional[ElasticManager] = None
     if cfg.elastic_endpoint or cfg.elastic_root:
         if cfg.elastic_endpoint:
@@ -154,7 +164,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         prog="python -m paddlebox_tpu.distributed.launch",
         description="PaddleBox-TPU job launcher")
     ap.add_argument("--nproc", type=int, default=1,
-                    help="local worker processes (emulated hosts)")
+                    help="local worker processes (emulated hosts; >1 "
+                         "needs JAX_PLATFORMS=cpu)")
     ap.add_argument("--coordinator", default="127.0.0.1:8476")
     ap.add_argument("--job-id", default="default")
     ap.add_argument("--elastic-root", default=None,

@@ -344,8 +344,8 @@ def cross_norm_fits(embed_dim: int) -> bool:
 
 
 def _cross_norm_kernel(a_ref, b_ref, m_ref, s_ref, o_ref, *, d: int):
-    av = a_ref[:, 0, :]                               # [tb, d_pad]
-    bv = b_ref[:, 0, :]
+    av = a_ref[...]                                   # [tb, d_pad]
+    bv = b_ref[...]
     had = av * bv
     # d_pad tail columns are zero, so the dot product over the padded
     # lane dim is exact
@@ -357,7 +357,7 @@ def _cross_norm_kernel(a_ref, b_ref, m_ref, s_ref, o_ref, *, d: int):
          jnp.zeros((av.shape[0], pad), jnp.float32)], axis=-1)
     # normalization applied in the SAME residency (mean/scale pads are
     # zero, so the pad columns stay exactly zero)
-    o_ref[:, 0, :] = (feats - m_ref[...]) * s_ref[...]
+    o_ref[...] = (feats - m_ref[...]) * s_ref[...]
 
 
 def _cross_norm_forward(x: jax.Array, mean: jax.Array, scale: jax.Array,
@@ -369,30 +369,37 @@ def _cross_norm_forward(x: jax.Array, mean: jax.Array, scale: jax.Array,
     b_pad = _round_up(max(b, 1), tb)
     d_pad, w_pad = _round_up(d, 128), _round_up(w_out, 128)
 
+    # FIELD-MAJOR operands: the field axis leads and is squeezed out of
+    # every block, so each block's last two dims are (tb, lanes) tiles
+    # — a (tb, 1, lanes) block over a [B, n, lanes] array puts a
+    # 1-of-n slice in the sublane dim, which Mosaic refuses for n > 1
     pairs = x.reshape(b, n, 2, d).astype(jnp.float32)
-    ap = jnp.zeros((b_pad, n, d_pad), jnp.float32)
-    ap = ap.at[:b, :, :d].set(pairs[:, :, 0])
-    bp = jnp.zeros((b_pad, n, d_pad), jnp.float32)
-    bp = bp.at[:b, :, :d].set(pairs[:, :, 1])
-    mp = jnp.zeros((n, w_pad), jnp.float32)
-    mp = mp.at[:, :w_out].set(mean.reshape(n, w_out).astype(jnp.float32))
-    sp = jnp.zeros((n, w_pad), jnp.float32)
-    sp = sp.at[:, :w_out].set(scale.reshape(n, w_out).astype(jnp.float32))
+    ap = jnp.zeros((n, b_pad, d_pad), jnp.float32)
+    ap = ap.at[:, :b, :d].set(pairs[:, :, 0].swapaxes(0, 1))
+    bp = jnp.zeros((n, b_pad, d_pad), jnp.float32)
+    bp = bp.at[:, :b, :d].set(pairs[:, :, 1].swapaxes(0, 1))
+    mp = jnp.zeros((n, 1, w_pad), jnp.float32)
+    mp = mp.at[:, 0, :w_out].set(
+        mean.reshape(n, w_out).astype(jnp.float32))
+    sp = jnp.zeros((n, 1, w_pad), jnp.float32)
+    sp = sp.at[:, 0, :w_out].set(
+        scale.reshape(n, w_out).astype(jnp.float32))
 
     out = pl.pallas_call(
         functools.partial(_cross_norm_kernel, d=d),
         grid=(b_pad // tb, n),
         in_specs=[
-            pl.BlockSpec((tb, 1, d_pad), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tb, 1, d_pad), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, w_pad), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, w_pad), lambda i, j: (j, 0)),
+            pl.BlockSpec((None, tb, d_pad), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((None, tb, d_pad), lambda i, j: (j, i, 0)),
+            pl.BlockSpec((None, 1, w_pad), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, w_pad), lambda i, j: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((tb, 1, w_pad), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, n, w_pad), jnp.float32),
+        out_specs=pl.BlockSpec((None, tb, w_pad), lambda i, j: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, b_pad, w_pad), jnp.float32),
         interpret=_interpret(),
     )(ap, bp, mp, sp)
-    return out[:b, :, :w_out].reshape(b, n * w_out).astype(x.dtype)
+    return out[:, :b, :w_out].swapaxes(0, 1).reshape(
+        b, n * w_out).astype(x.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

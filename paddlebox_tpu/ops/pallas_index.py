@@ -38,21 +38,22 @@ set and either ``lookup`` finds every key in either layout. Rows depend
 only on first-seen allocation order, which both share — parity gates
 target rows/new-mask, never bucket bytes.
 
-Mosaic status: random-access single-element HBM loads are not yet a
+Mosaic status: random-access single-element HBM loads are not a
 Mosaic primitive (same constraint that demoted the per-row DMA
 gather — see ops/pallas_kernels.py status), so on a REAL TPU backend
 ``insert``/``lookup`` route to the XLA formulation, which is still
 fully device-resident (one fused while_loop program, no host round
-trip). The Pallas kernels run under interpret mode everywhere tier-1
-runs and are the shape the Mosaic version keeps.
+trip) and is booked as what it is: ``impl="xla"`` (``device_impl``).
+The Pallas kernels run under interpret mode everywhere tier-1 runs.
 
 Overflow contract: a key that probes ``_MAX_PROBE`` buckets without
 placing, or a batch whose new keys exceed remaining row capacity, makes
 the WHOLE call return overflow — the functional bucket updates are
 simply not committed, and the caller (``DeviceKeyIndex`` → the
 ``use_pallas_index`` seam in ps/table.py / ps/sharded.py) degrades
-LOUDLY to the host index with both decisions booked in
-``pbox_kernel_dispatch_total{kernel="index.*",impl}``.
+LOUDLY to the host index with every decision booked in
+``pbox_kernel_dispatch_total{kernel="index.*",impl}`` under the
+formulation that actually ran (pallas | xla | host).
 """
 
 from __future__ import annotations
@@ -80,7 +81,8 @@ _BK = 256          # keys per Pallas grid block
 
 def book_index_dispatch(op: str, impl: str) -> None:
     """Book one index-seam dispatch decision (op ∈ {assign, lookup},
-    impl ∈ {pallas, host}) — the loud half of the fallback contract."""
+    impl ∈ {pallas, xla, host}) — the loud half of the fallback
+    contract."""
     _book_dispatch(f"index.{op}", impl)
 
 
@@ -202,19 +204,19 @@ def _insert_kernel(meta_ref, kh_ref, kl_ref, bh_in, bl_in, br_in,
         def step(st):
             off, row, new, done = st
             b = ((h + off.astype(jnp.uint32)) & mask).astype(jnp.int32)
-            r = pl.load(br_ref, (b,))
+            r = br_ref[b]
             is_empty = r == _EMPTY
-            is_match = ~is_empty & (pl.load(bh_ref, (b,)) == kh) \
-                & (pl.load(bl_ref, (b,)) == kl)
+            is_match = ~is_empty & (bh_ref[b] == kh) \
+                & (bl_ref[b] == kl)
             cur = cur_ref[0]
 
             @pl.when(is_empty)
             def _():
                 # sequential grid ⇒ read-check-write is race-free: the
                 # atomic-free claim + per-block cursor of ISSUE 19
-                pl.store(bh_ref, (b,), kh)
-                pl.store(bl_ref, (b,), kl)
-                pl.store(br_ref, (b,), cur)
+                bh_ref[b] = kh
+                bl_ref[b] = kl
+                br_ref[b] = cur
                 cur_ref[0] = cur + 1
 
             row = jnp.where(is_empty, cur, jnp.where(is_match, r, row))
@@ -326,10 +328,10 @@ def _lookup_kernel(meta_ref, kh_ref, kl_ref, bh_ref, bl_ref, br_ref,
         def step(st):
             off, row, done = st
             b = ((h + off.astype(jnp.uint32)) & mask).astype(jnp.int32)
-            r = pl.load(br_ref, (b,))
+            r = br_ref[b]
             is_empty = r == _EMPTY
-            is_match = ~is_empty & (pl.load(bh_ref, (b,)) == kh) \
-                & (pl.load(bl_ref, (b,)) == kl)
+            is_match = ~is_empty & (bh_ref[b] == kh) \
+                & (bl_ref[b] == kl)
             return (off + 1, jnp.where(is_match, r, row),
                     done | is_empty | is_match)
 
@@ -479,8 +481,13 @@ def _pad_to_block(a: np.ndarray) -> np.ndarray:
 def default_use_pallas() -> bool:
     """Kernel choice for the device path: Pallas under interpret mode,
     the XLA while_loop formulation on a real TPU (see module docstring —
-    Mosaic has no random-access HBM load yet; both are device-resident)."""
+    Mosaic has no random-access HBM load; both are device-resident)."""
     return _interpret()
+
+
+def device_impl() -> str:
+    """The dispatch-counter name of what ``DeviceKeyIndex`` runs here."""
+    return "pallas" if default_use_pallas() else "xla"
 
 
 class DeviceKeyIndex:

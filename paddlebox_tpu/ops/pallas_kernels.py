@@ -3,8 +3,8 @@
 Reference hot kernels being replaced (SURVEY.md §2.1-2.2, §2.4):
 - ``PullCopy``/``CopyForPull`` gather (fleet/box_wrapper.cu:75,945) and the
   HeterPS hashtable ``get`` → here ``gather_rows``: a scalar-prefetch row
-  gather where the Pallas pipeline double-buffers one row-block DMA per grid
-  step (HBM→VMEM), overlapping fetches across steps.
+  gather where the Pallas pipeline double-buffers eight aligned (8, D)
+  tile DMAs per grid step (HBM→VMEM), overlapping fetches across steps.
 - ``PushMergeCopy`` scatter (box_wrapper.cu:417) + in-kernel optimizer write
   (heter_ps/optimizer.cuh.h) → ``scatter_rows``: aliased in-place row
   scatter (the optimizer math itself stays in jnp where XLA fuses it against
@@ -32,8 +32,16 @@ The suite's CTR op family half (``fused_rank_attention``,
 the sibling ``ops/pallas_ctr.py``, sharing this module's interpret/
 padding/dispatch-booking helpers and the same MXU one-hot recipe.
 
-Status / measured verdict (post ISSUE 12; one TPU chip, DeepFM/criteo
-bench, AoS table [8M+1, 16] f32, 213k rows/batch):
+Mosaic status on the current installation (jax 0.9.0 / libtpu 0.0.34,
+TPU v5e — chip_smoke.py kernels phase): ``gather_rows``,
+``fused_pool_cvm_forward``, ``segment_gather_mxu``/``segment_sum_mxu``
+compile and agree with the XLA compositions. Every block's last two
+dims must be (8, 128)-aligned or span the array: ``scatter_rows`` and
+the DMA references below keep (1, D) blocks and are interpret-only.
+
+Measured verdict of an EARLIER installation (post ISSUE 12; one TPU
+chip, DeepFM/criteo bench, AoS table [8M+1, 16] f32, 213k rows/batch) —
+rationale, not today's rates:
 - XLA's native gather/scatter lowers to PER-ELEMENT access: scatter
   [213k, 16] rows = 26 ms (~7.6 ns/element), gather = 8 ms. The hints
   (unique_indices / indices_are_sorted / mode) change nothing.
@@ -73,7 +81,10 @@ from paddlebox_tpu.config import FLAGS
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode unless this process's devices are TPU chips, as
+    ``jax.devices()[0].platform`` reports them — the one backend test
+    every kernel seam derives from."""
+    return jax.devices()[0].platform != "tpu"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -117,35 +128,53 @@ def _require_interpret(name: str) -> None:
 # Row gather (pull_sparse hot path)
 # ---------------------------------------------------------------------------
 
+#: rows per gather grid step — one sublane tile. Mosaic block shapes
+#: must be (8, 128)-aligned in their last two dims (a (1, D) row block
+#: is refused), so the gather moves whole 8-row tiles.
+_GR = 8
+
+
 def gather_rows(table: jax.Array, rows: jax.Array) -> jax.Array:
     """table [C, D], rows [U] int32 → [U, D].
 
-    One grid step per row; the row index is scalar-prefetched so the
-    pipeline issues the HBM→VMEM DMA for step i+1 while step i copies out.
+    ``_GR`` rows per grid step: the row indices are scalar-prefetched,
+    each of the step's ``_GR`` input specs maps to the aligned
+    ``(_GR, D)`` tile holding one requested row (the pipeline issues
+    the HBM→VMEM tile DMAs for step i+1 while step i copies out), and
+    the kernel picks the row's sublane out of its tile.
     Out-of-bounds pad rows (> C-1, the OOB-pad contract of
     table._build_index / device_unique.dedup_rows) clamp to the sentinel
     row C-1, matching XLA's clamped-gather semantics.
     """
     c, d = table.shape
     u = rows.shape[0]
+    u_pad = _round_up(max(u, 1), _GR)
+    rows_p = jnp.zeros((u_pad,), jnp.int32).at[:u].set(
+        jnp.minimum(rows.astype(jnp.int32), c - 1))
 
-    def kernel(rows_ref, tbl_ref, out_ref):
-        del rows_ref
-        out_ref[...] = tbl_ref[...]
+    def kernel(rows_ref, *refs):
+        tiles, out_ref = refs[:_GR], refs[_GR]
+        base = pl.program_id(0) * _GR
+        for j in range(_GR):
+            sub = rows_ref[base + j] % _GR
+            out_ref[pl.ds(j, 1), :] = tiles[j][pl.ds(sub, 1), :]
+
+    def tile_of(j):
+        return lambda i, rows_ref: (rows_ref[i * _GR + j] // _GR, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(u,),
-        in_specs=[pl.BlockSpec(
-            (1, d), lambda i, rows_ref: (jnp.minimum(rows_ref[i], c - 1), 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, rows_ref: (i, 0)),
+        grid=(u_pad // _GR,),
+        in_specs=[pl.BlockSpec((_GR, d), tile_of(j)) for j in range(_GR)],
+        out_specs=pl.BlockSpec((_GR, d), lambda i, rows_ref: (i, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((u, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((u_pad, d), table.dtype),
         interpret=_interpret(),
-    )(rows, table)
+    )(rows_p, *([table] * _GR))
+    return out[:u]
 
 
 # ---------------------------------------------------------------------------

@@ -13,8 +13,9 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 
 DATA_AXIS = "dp"
@@ -27,6 +28,24 @@ def make_mesh(n_devices: Optional[int] = None,
     if n_devices is not None:
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis_name,))
+
+
+def stacked_zeros(n: int, shape: Sequence[int], dtype) -> jax.Array:
+    """``[n, *shape]`` zeros born SHARDED over the leading axis of
+    ``make_mesh(n)``: slice s is allocated on device s and nowhere else
+    (the per-shard table state and AUC tables of the mesh trainers —
+    broadcast-and-copy on the default device would materialize all n
+    slices on device 0 first). A pod's processes each initialize
+    locally instead; ``train/multihost.globalize_state`` re-stages that
+    state onto the global mesh."""
+    full = (n,) + tuple(shape)
+    if jax.process_count() > 1:
+        return jnp.zeros(full, dtype)
+    if n > len(jax.devices()):
+        raise ValueError(
+            f"{n} shards need {n} devices, found {len(jax.devices())}")
+    return jnp.zeros(full, dtype, device=NamedSharding(
+        make_mesh(n), PartitionSpec(DATA_AXIS)))
 
 
 def data_axis_size(mesh: Mesh, axis_name: str = DATA_AXIS) -> int:
@@ -66,7 +85,6 @@ def hierarchical_allreduce(x: jax.Array, ici_axis: str = ICI_AXIS,
     dense sync ladder — ncclReduceScatter → ``BoxWrapper::SyncDense``
     (inter-node) → ncclAllGather (boxps_worker.cc:1217-1234) — so the
     slow DCN hop carries only 1/n_ici of the bytes."""
-    import jax.numpy as jnp
     n = jax.lax.axis_size(ici_axis)
     flat = x.reshape(-1)
     pad = (-flat.size) % n

@@ -22,17 +22,10 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map as _shard_map_mod  # jax >= 0.8
 
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_mod(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def _one_hot(idx: jax.Array, n: int) -> jax.Array:

@@ -46,6 +46,7 @@ import numpy as np
 
 from paddlebox_tpu.config import FLAGS
 from paddlebox_tpu.data.batch import SlotBatch
+from paddlebox_tpu.parallel.mesh import stacked_zeros
 from paddlebox_tpu.ps.sgd import SparseSGDConfig
 from paddlebox_tpu.ps.table import (FIELD_COL, FIELDS, NUM_FIXED, HostKV,
                                     TableState, field_assign, field_slice,
@@ -256,13 +257,14 @@ class ShardedEmbeddingTable:
                 self._dev_indexes[s] = None
             return rows_s
         if FLAGS.use_pallas_index:
-            from paddlebox_tpu.ops.pallas_index import book_index_dispatch
+            from paddlebox_tpu.ops.pallas_index import (
+                book_index_dispatch, device_impl)
             op = "assign" if assign else "lookup"
             rows_s = self._shard_rows_device(s, keys_s, assign)
             if rows_s is not None:
                 if assign:
                     self._touched[s][rows_s] = True
-                book_index_dispatch(op, "pallas")
+                book_index_dispatch(op, device_impl())
                 return rows_s
             book_index_dispatch(op, "host")
         if assign:
@@ -274,12 +276,11 @@ class ShardedEmbeddingTable:
         return rows_s
 
     def _make_stacked_state(self, single: TableState, n: int) -> TableState:
-        """Subclass hook: build the stacked [N, L, 128] device state —
-        the multihost table stages it SHARDED over the global mesh
-        instead of materializing N windows on one device."""
-        return single.with_packed(
-            jnp.broadcast_to(single.packed[None],
-                             (n,) + single.packed.shape).copy())
+        """Subclass hook: build the stacked [N, L, 128] zero state, each
+        shard born on its own device (parallel.mesh.stacked_zeros) —
+        the multihost table stages it over the global mesh instead."""
+        return single.with_packed(stacked_zeros(
+            n, single.packed.shape, single.packed.dtype))
 
     # ------------------------------------------------------------------
     def prepare_global_eval(self, batches: List[SlotBatch],

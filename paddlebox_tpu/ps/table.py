@@ -37,6 +37,7 @@ from paddlebox_tpu.data.batch import SlotBatch
 from paddlebox_tpu.ops.pallas_kernels import _book_dispatch, gather_rows
 from paddlebox_tpu.ps.sgd import (RowState, SparseSGDConfig,
                                   opt_ext_width, sparse_update)
+from paddlebox_tpu.utils.compile_cache import enable_compilation_cache
 from paddlebox_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -538,6 +539,10 @@ from paddlebox_tpu.ps.kv import make_kv as HostKV  # noqa: N813
 
 def init_table_state(capacity: int, mf_dim: int,
                      dtype=jnp.float32, ext: int = 0) -> TableState:
+    # every entry (trainers, serving, bench, chip_smoke) builds a table
+    # before its first compile — the one shared point that turns the
+    # persistent compilation cache on
+    enable_compilation_cache()
     feat = NUM_FIXED + mf_dim + ext
     _, _, n_lines = pack_geometry(capacity, feat)
     return TableState(jnp.zeros((n_lines, 128), dtype), capacity, feat,
@@ -623,9 +628,9 @@ def scatter_logical_rows(state: TableState, shard_idx,
 
     The scatter runs in FIXED-SIZE chunks (``FLAGS.scatter_chunk_rows``)
     so XLA compiles ONE executable per table geometry instead of one per
-    delta size — the per-pass-boundary scatter compile measured ~20 s on
-    TPU (docs/BENCH_SHAPES.md tiered row, round 4) and delta sizes vary
-    every pass. Chunk pads are out-of-bounds line ids (dropped on
+    delta size — delta sizes vary every pass and each new size would
+    pay a fresh compile at the pass boundary. Chunk pads are
+    out-of-bounds line ids (dropped on
     device); values ship exact-size and are zero-padded on device, so no
     pad bytes ride the wire. The input state stays VALID (unchanged
     semantics for callers that keep references, e.g. trainers that
@@ -698,16 +703,11 @@ def aot_warmup_scatter(shape, dtype, sharded: bool, rpl: int, fp: int,
     which could nondeterministically OOM a box whose HBM was already
     committed to the live table + staging). The AOT executable does NOT
     land in jit's dispatch cache, so the warmup's value rides the
-    PERSISTENT cache: the real begin_pass deserializes (~0.1-1 s)
-    instead of paying the ~20 s scatter compile — which is why the
-    on-disk cache is enabled HERE, before lowering (tables construct
-    before Trainer init, and jax decides cache put at compile
-    initiation; without this the warmup compiled into the void and
-    still reported ok). Returns compile seconds (telemetry)."""
+    PERSISTENT cache (enabled at table construction,
+    ``init_table_state``): the real begin_pass deserializes instead of
+    paying the scatter compile. Returns compile seconds (telemetry)."""
     import time as _time
     from paddlebox_tpu.config import FLAGS as _F
-    from paddlebox_tpu.utils.compile_cache import enable_compilation_cache
-    enable_compilation_cache()
     c = int(chunk or _F.scatter_chunk_rows)
     fn = _scatter_chunk_fn(sharded, rpl, fp, feat)
     sds = jax.ShapeDtypeStruct
@@ -1131,7 +1131,8 @@ class EmbeddingTable:
         Returns None (after degrading ``dev``) whenever the result
         cannot be trusted bit-for-bit — the caller redoes the call on
         the host path, which is always authoritative."""
-        from paddlebox_tpu.ops.pallas_index import book_index_dispatch
+        from paddlebox_tpu.ops.pallas_index import (book_index_dispatch,
+                                                    device_impl)
         t0 = time.perf_counter()
         pre_rows = dev.next_row
         out = dev.assign_raw(keys)
@@ -1161,7 +1162,7 @@ class EmbeddingTable:
         self.last_assign_seconds = {
             "index_host": time.perf_counter() - t1,
             "index_device": t_dev}
-        book_index_dispatch("assign", "pallas")
+        book_index_dispatch("assign", device_impl())
         return (rows_u.astype(np.int32, copy=False),
                 inv.astype(np.int64, copy=False))
 

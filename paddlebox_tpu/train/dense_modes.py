@@ -125,19 +125,13 @@ class KStepParamSync:
         else:
             from jax.sharding import PartitionSpec as P
 
-            try:
-                from jax import shard_map as _shard_map
-                shard_map = _shard_map
-            except ImportError:  # older jax
-                from jax.experimental.shard_map import shard_map
-
             def _avg(params):
                 def body(p):
                     return jax.tree.map(
                         lambda x: jax.lax.pmean(x, axis), p)
                 spec = jax.tree.map(lambda _: P(axis), params)
-                return shard_map(body, mesh=mesh, in_specs=(spec,),
-                                 out_specs=spec)(params)
+                return jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                                     out_specs=spec)(params)
             self._avg = jax.jit(_avg)
 
     def maybe_sync(self, params: Any) -> Tuple[Any, bool]:

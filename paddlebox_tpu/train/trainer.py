@@ -63,9 +63,6 @@ class Trainer:
         (path-substring) → lr against ``lr_map_base``; implemented by
         chaining a per-leaf update scaler after ``tx``
         (box_wrapper.cc:1303-1335, boxps_worker.cc:199-204)."""
-        from paddlebox_tpu.utils.compile_cache import \
-            enable_compilation_cache
-        enable_compilation_cache()
         self.model = model
         self.table = table
         self.desc = desc

@@ -436,6 +436,12 @@ def _run_tiered_lifecycle_leg(workdir: str, seed: int,
 # leg 3: subprocess kill round-trips of scripts/onlinelearn.py
 # ---------------------------------------------------------------------------
 
+def _child_env() -> dict:
+    """The daemon children of this CPU gate run on the CPU whatever the
+    parent holds: a chip belongs to one process at a time."""
+    return dict(os.environ, JAX_PLATFORMS="cpu")
+
+
 def _daemon_cmd(workdir: str, data_dir: str, seed: int) -> list:
     return [sys.executable,
             os.path.join(REPO, "scripts", "onlinelearn.py"),
@@ -522,6 +528,7 @@ def _run_kill_leg(workdir: str, seed: int, signame: str,
     # (a) unkilled oracle
     oracle_dir = os.path.join(workdir, "oracle")
     r = subprocess.run(_daemon_cmd(oracle_dir, data_dir, seed),
+                       env=_child_env(),
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     oracle_step, oracle_digest = _final_digest(oracle_dir, seed)
@@ -536,6 +543,7 @@ def _run_kill_leg(workdir: str, seed: int, signame: str,
         victim_dir = os.path.join(workdir, f"victim{attempt}")
         jsonl = os.path.join(victim_dir, "telemetry.jsonl")
         proc = subprocess.Popen(_daemon_cmd(victim_dir, data_dir, seed),
+                                env=_child_env(),
                                 stdout=subprocess.PIPE,
                                 stderr=subprocess.DEVNULL, text=True)
         try:
@@ -602,6 +610,7 @@ def _run_kill_leg(workdir: str, seed: int, signame: str,
     jsonl = os.path.join(victim_dir, "telemetry.jsonl")
     resumes0 = _count_events(jsonl, "cursor_resume")
     proc = subprocess.Popen(_daemon_cmd(victim_dir, data_dir, seed),
+                            env=_child_env(),
                             stdout=subprocess.PIPE,
                             stderr=subprocess.PIPE, text=True)
     saw_online = False

@@ -16,7 +16,7 @@ Usage:
 Probe sets:
     1  step components: table gather/push, dedup, expand, seqpool
        fwd/bwd, slot-wire decode, dense fwd+bwd, hot-tier gathers
-       (the original harness — VERDICT item 1)
+       (the original harness)
     2  grad-merge ordering, gather extract form, push variants (the
        levers left after the slot-wire decode fix)
     3  merge form/dtype, packed-line expand, dedup sort form (the

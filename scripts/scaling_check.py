@@ -1,59 +1,30 @@
 #!/usr/bin/env python
-"""Multichip scaling + chunked-parity gate (ISSUE 11).
+"""Chunked-exchange parity gate (ISSUE 11), wired into tier-1 by
+``tests/test_scaling_check.py``.
 
-Two checks, wired into tier-1 by ``tests/test_scaling_check.py``:
+**Chunked parity end-to-end through train_pass**: on the in-process
+CPU mesh, ``FLAGS.a2a_chunks=2`` reproduces the ``a2a_chunks=1``
+model digest (params + packed table + AUC) BIT-FOR-BIT, and the
+digest is deterministic across two seeded runs — the fused
+computation-collective schedule (train/sharded) changes the
+exchange's shape, never its math.
 
-1. **Chunked parity end-to-end through train_pass**: on the in-process
-   CPU mesh, ``FLAGS.a2a_chunks=2`` reproduces the ``a2a_chunks=1``
-   model digest (params + packed table + AUC) BIT-FOR-BIT, and the
-   digest is deterministic across two seeded runs — the fused
-   computation-collective schedule (train/sharded) changes the
-   exchange's shape, never its math.
-2. **Multichip trajectory rows**: drive ``BENCH_MODE=multichip``
-   (bench.py — one subprocess per chip count) at a tiny workload into a
-   temp trajectory and assert the ``sharded.n{N}.{shape}.*`` rows land
-   well-formed and pass ``perf_gate`` over them.
+Graceful skip (exit 0 with a SKIP note): fewer than 2 visible devices.
 
-Graceful skips (exit 0 with a SKIP note): fewer than 2 visible devices
-for parity, or subprocess/device failure for the bench rows — CI boxes
-without the virtual-device backend must not fail tier-1 for missing
-hardware.
-
-``--record --source rXX`` additionally appends the measured multichip
-rows to the committed BENCH_trajectory.json under the given source, so
-they gate future rounds via ``perf_gate.py --check --ignore-live``.
+Scaling itself is a chip measurement: ``BENCH_MODE=sharded python
+bench.py`` over all chips of a host, never virtual CPU devices.
 """
 
 from __future__ import annotations
 
 import argparse
-import hashlib
-import json
 import os
-import re
-import subprocess
 import sys
 import tempfile
 from typing import List, Optional, Tuple
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-
-#: well-formed multichip gate keys (perf_gate keys on the metric name;
-#: the optional ``.c{chunks}`` segment keeps chunked-schedule ladders
-#: on their own gate history — BENCH_A2A_CHUNKS)
-KEY_RE = re.compile(
-    r"^sharded\.n\d+\.[a-z0-9_]+(\.c\d+)?\.(ex_per_sec_per_chip"
-    r"|scaling_efficiency)$")
-
-
-def _load_perf_gate():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(REPO, "scripts", "perf_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _digest(trainer) -> str:
@@ -126,113 +97,14 @@ def parity_check(rows_per_file: int = 500,
     return True
 
 
-def bench_rows_check(ns: str = "1,2", bs: int = 128, gbatches: int = 2,
-                     passes: int = 2, timeout_s: float = 480.0,
-                     shape: str = "uniform"
-                     ) -> Tuple[str, List[dict]]:
-    """Run the multichip bench into a temp trajectory; validate keys.
-    Returns ("ok"|"skip"|"fail", rows)."""
-    pg = _load_perf_gate()
-    with tempfile.TemporaryDirectory(prefix="pbox_scaling_") as td:
-        traj = os.path.join(td, "traj.json")
-        env = dict(os.environ)
-        env.update(BENCH_MODE="multichip", BENCH_SHAPE=shape,
-                   BENCH_MULTICHIP_NS=ns, BENCH_MULTICHIP_BS=str(bs),
-                   BENCH_MULTICHIP_BATCHES=str(gbatches),
-                   BENCH_MULTICHIP_PASSES=str(passes),
-                   BENCH_MULTICHIP_TIMEOUT=str(timeout_s / 2),
-                   BENCH_TRAJECTORY=traj, BENCH_TELEMETRY_JSONL="0")
-        try:
-            cp = subprocess.run(
-                [sys.executable, os.path.join(REPO, "bench.py")],
-                env=env, capture_output=True, text=True,
-                timeout=timeout_s)
-        except (subprocess.TimeoutExpired, OSError) as e:
-            print(f"scaling_check: SKIP bench rows — subprocess "
-                  f"unavailable ({e})")
-            return "skip", []
-        data = pg.load_trajectory(traj) if os.path.exists(traj) else None
-        if cp.returncode != 0 or not data or not data["rows"]:
-            print("scaling_check: SKIP bench rows — multichip bench "
-                  f"produced no rows (rc={cp.returncode}): "
-                  f"{cp.stderr[-400:]}")
-            return "skip", []
-        rows = data["rows"]
-        n_list = [int(x) for x in ns.split(",")]
-        want_keys = {f"sharded.n{n}.{shape}.{m}" for n in n_list
-                     for m in ("ex_per_sec_per_chip",
-                               "scaling_efficiency")}
-        got_keys = {r["metric"] for r in rows}
-        bad = [k for k in got_keys if not KEY_RE.match(k)]
-        if bad:
-            print(f"scaling_check: FAIL — malformed metric keys {bad}",
-                  file=sys.stderr)
-            return "fail", rows
-        missing = want_keys - got_keys
-        if missing:
-            print(f"scaling_check: FAIL — missing rows {sorted(missing)}",
-                  file=sys.stderr)
-            return "fail", rows
-        failures, _ = pg.check_rows(rows)
-        if failures:
-            print("\n".join(failures), file=sys.stderr)
-            return "fail", rows
-        eff = {r["metric"]: r["value"] for r in rows
-               if r["metric"].endswith("scaling_efficiency")}
-        print(f"scaling_check: multichip rows OK — {sorted(got_keys)}; "
-              f"efficiency {eff}")
-        return "ok", rows
-
-
 def main(argv: Optional[List[str]] = None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--skip-parity", action="store_true")
-    ap.add_argument("--skip-bench", action="store_true")
-    ap.add_argument("--ns", default="1,2",
-                    help="chip counts for the bench rows (default 1,2)")
-    ap.add_argument("--bs", type=int, default=128)
-    ap.add_argument("--batches", type=int, default=2,
-                    help="global batches per pass per child")
-    ap.add_argument("--timeout", type=float, default=480.0)
-    ap.add_argument("--shape", default="uniform")
-    ap.add_argument("--record", action="store_true",
-                    help="append the measured rows to the committed "
-                    "trajectory under --source")
-    ap.add_argument("--source", default=None,
-                    help="trajectory source tag for --record")
-    ap.add_argument("--trajectory", default=None)
-    args = ap.parse_args(argv)
-    rc = 0
-    if not args.skip_parity:
-        ok = parity_check()
-        if ok is False:
-            rc = 1
-    if not args.skip_bench:
-        status, rows = bench_rows_check(ns=args.ns, bs=args.bs,
-                                        gbatches=args.batches,
-                                        timeout_s=args.timeout,
-                                        shape=args.shape)
-        if status == "fail":
-            rc = 1
-        if args.record and status == "ok":
-            if not args.source:
-                print("--record needs --source", file=sys.stderr)
-                return 2
-            pg = _load_perf_gate()
-            path = args.trajectory or pg.default_trajectory_path()
-            for r in rows:
-                r = dict(r)
-                r["source"] = args.source
-                r.pop("recorded_at", None)
-                pg.append_row(r, path)
-            print(f"scaling_check: recorded {len(rows)} rows -> {path} "
-                  f"(source {args.source})")
-    return rc
+    argparse.ArgumentParser(
+        description=__doc__.splitlines()[0]).parse_args(argv)
+    return 1 if parity_check() is False else 0
 
 
 if __name__ == "__main__":
     # a standalone run needs the virtual CPU mesh BEFORE jax imports
-    # (same trick as tests/conftest.py)
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if "xla_force_host_platform_device_count" not in \
             os.environ.get("XLA_FLAGS", ""):

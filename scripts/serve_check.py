@@ -484,7 +484,6 @@ def _run_corrupt_tip_leg(workdir: str, seed: int) -> dict:
 _PUBLISHER = r"""
 import os, sys, time
 sys.path.insert(0, {repo!r})
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 import jax
 from paddlebox_tpu.artifacts import ArtifactStore
@@ -536,8 +535,11 @@ def _run_sigkill_leg(workdir: str, seed: int) -> dict:
     desc = DataFeedDesc.criteo(batch_size=16)
     root = os.path.join(workdir, "registry_kill")
     os.makedirs(root, exist_ok=True)
+    # the publisher child runs on the CPU whatever this process holds:
+    # a chip belongs to one process at a time
     proc = subprocess.Popen(
-        [sys.executable, "-c", _PUBLISHER.format(repo=REPO), root])
+        [sys.executable, "-c", _PUBLISHER.format(repo=REPO), root],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     deadline = time.time() + 120
     base_aid = None
     while time.time() < deadline:

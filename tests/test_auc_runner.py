@@ -114,8 +114,7 @@ def test_slot_importance_detects_informative_slot():
     _assert_slot_importance(tr, recs, desc)
 
 
-@pytest.mark.slow  # seed-broken (no jax.shard_map) until the
-# jax_compat shim; recovered, but the 8-dev virtual-CPU mesh run is
+@pytest.mark.slow  # the 8-dev virtual-CPU mesh run is
 # heavy (~20 s) — out of the tier-1 wall budget, runs in the slow tier
 def test_slot_importance_on_mesh_trainer():
     """AucRunner composes with the MESH trainer unchanged (it is

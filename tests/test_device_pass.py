@@ -798,7 +798,7 @@ def test_compact_wire_non_trivial_segments():
 def test_u12_locals_wire_roundtrip_and_selection():
     """u12 byte-pair wire (ops/bitpack): exact roundtrip, and
     _encode_locals picks it exactly when locals fit 12 bits (the
-    thousand-slot wire diet — VERDICT r4 item 7)."""
+    thousand-slot wire diet)."""
     import jax.numpy as jnp
 
     from paddlebox_tpu.ops.bitpack import pack_u12, unpack_u12

@@ -134,8 +134,7 @@ def test_trainer_lr_map_freezes_param(ctr_dataset):
     assert moved > 0
 
 
-@pytest.mark.slow  # seed-broken (no jax.shard_map) until the
-# jax_compat shim; recovered, but heavy on the virtual-CPU mesh —
+@pytest.mark.slow  # heavy on the virtual-CPU mesh —
 # out of the tier-1 wall budget, runs in the slow tier
 @pytest.mark.parametrize("zero1", [False, True])
 def test_sharded_trainer_lr_map(ctr_dataset, zero1):

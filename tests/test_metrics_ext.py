@@ -251,8 +251,7 @@ def test_registry_skips_metric_missing_side_channel():
     assert reg.get_metric_msg("m")["ins_num"] == 0
 
 
-@pytest.mark.slow  # seed-broken (no jax.shard_map) until the
-# jax_compat shim; recovered, but heavy on the virtual-CPU mesh —
+@pytest.mark.slow  # heavy on the virtual-CPU mesh —
 # out of the tier-1 wall budget, runs in the slow tier
 def test_registry_on_sharded_trainer():
     """Metric variants accumulate on the MESH trainer: the per-device-row

@@ -80,13 +80,6 @@ def test_mmf_sharded_routing_and_slot_field(mesh, criteo_files):
         assert np.isin(valid, table.class_slots[c]).all()
 
 
-_LEGACY_JAX = tuple(int(v) for v in
-                    jax.__version__.split(".")[:2]) < (0, 6)
-
-
-@pytest.mark.skipif(_LEGACY_JAX, reason=(
-    "single-chip parity drifts on the legacy jax.experimental.shard_map "
-    "line (pre-existing seed failure; passes on jax >= 0.6)"))
 def test_mmf_sharded_e2e_learns_and_matches_single_chip(
         mesh, criteo_files):
     """8-dev mesh multi-mf training with 3 dim classes learns the same
@@ -133,8 +126,7 @@ def test_mmf_sharded_e2e_learns_and_matches_single_chip(
     assert (vals[:, 0] > 0).all()  # show counters accumulated
 
 
-@pytest.mark.slow  # seed-broken (no jax.shard_map) until the
-# jax_compat shim; recovered, but heavy on the virtual-CPU mesh —
+@pytest.mark.slow  # heavy on the virtual-CPU mesh —
 # out of the tier-1 wall budget, runs in the slow tier
 def test_mmf_sharded_save_load_roundtrip(mesh, criteo_files, tmp_path):
     ds, desc = _ds(criteo_files)

@@ -268,7 +268,7 @@ def _real_criteo_fixture(path, rows=384, seed=7):
 
 def test_real_criteo_fixture_end_to_end(tmp_path):
     """Real-format quirks parse through DataFeedDesc.criteo → columnar →
-    one resident train step (VERDICT r4 item 9)."""
+    one resident train step."""
     import optax
 
     from paddlebox_tpu.models import DeepFM

@@ -482,10 +482,6 @@ def test_kernel_microbench_smoke(tmp_path, monkeypatch):
     assert pg.check(str(traj), ignore_live=True) == 0
 
 
-@pytest.mark.skipif(
-    tuple(int(v) for v in jax.__version__.split(".")[:2]) < (0, 6),
-    reason=("pallas DMA interpret mode needs a newer jax API "
-            "(pre-existing seed failure; passes on jax >= 0.6)"))
 def test_dma_kernels_interpret_semantics():
     """gather_rows_dma / scatter_rows_dma (interpret mode off-TPU):
     OOB rows clamp to the sentinel; scatter is in-place on unique rows."""

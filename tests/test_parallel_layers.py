@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from paddlebox_tpu.parallel.layers import (
     column_parallel_linear, pipeline_run, row_parallel_linear,
@@ -28,7 +27,7 @@ def test_vocab_parallel_embedding(mesh):
     w = rng.normal(size=(vocab, dim)).astype(np.float32)
     ids = rng.integers(0, vocab, size=(4, 7)).astype(np.int32)
 
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(vocab_parallel_embedding, axis="mp"),
         mesh=mesh, in_specs=(P(), P("mp", None)), out_specs=P())
     got = f(jnp.asarray(ids), jnp.asarray(w))
@@ -50,7 +49,7 @@ def test_column_then_row_parallel_mlp(mesh):
         h = jax.nn.relu(h)
         return row_parallel_linear(h, w2, b2)
 
-    f = shard_map(block, mesh=mesh,
+    f = jax.shard_map(block, mesh=mesh,
                   in_specs=(P(), P(None, "mp"), P("mp"), P("mp", None), P()),
                   out_specs=P())
     got = f(*map(jnp.asarray, (x, w1, b1, w2, b2)))
@@ -62,10 +61,10 @@ def test_column_parallel_gather_output(mesh):
     rng = np.random.default_rng(2)
     x = rng.normal(size=(4, 10)).astype(np.float32)
     w = rng.normal(size=(10, 24)).astype(np.float32)
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(column_parallel_linear, gather_output=True),
         mesh=mesh, in_specs=(P(), P(None, "mp")), out_specs=P(),
-        check_rep=False)  # all_gather replication isn't statically inferred
+        check_vma=False)  # all_gather replication isn't statically inferred
     got = f(jnp.asarray(x), jnp.asarray(w))
     np.testing.assert_allclose(np.asarray(got), x @ w, rtol=1e-4,
                                atol=1e-5)
@@ -87,7 +86,7 @@ def test_pipeline_matches_sequential():
         out = pipeline_run(stage, ws_sharded[0], x_micros, axis="pp")
         return jax.lax.psum(out, "pp")  # only last stage is nonzero
 
-    f = shard_map(run, mesh=mesh, in_specs=(P(), P("pp", None, None)),
+    f = jax.shard_map(run, mesh=mesh, in_specs=(P(), P("pp", None, None)),
                   out_specs=P())
     got = f(jnp.asarray(x), jnp.asarray(ws))
 
@@ -97,13 +96,6 @@ def test_pipeline_matches_sequential():
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
 
 
-_LEGACY_JAX = tuple(int(v) for v in
-                    jax.__version__.split(".")[:2]) < (0, 6)
-
-
-@pytest.mark.skipif(_LEGACY_JAX, reason=(
-    "fails on the legacy jax.experimental.shard_map line (pre-existing "
-    "seed failure; passes on jax >= 0.6)"))
 def test_hierarchical_allreduce_matches_flat_psum():
     """2-level [dcn, ici] allreduce (reduce-scatter → DCN sum →
     all-gather; boxps_worker.cc:1217-1234 ladder) must equal a flat psum
@@ -136,9 +128,6 @@ def test_hierarchical_allreduce_matches_flat_psum():
     np.testing.assert_allclose(np.asarray(h)[0], x.sum(axis=0), rtol=1e-4)
 
 
-@pytest.mark.skipif(_LEGACY_JAX, reason=(
-    "fails on the legacy jax.experimental.shard_map line (pre-existing "
-    "seed failure; passes on jax >= 0.6)"))
 def test_pipeline_training_matches_sequential():
     """The pipeline must TRAIN, not just infer: several optimizer steps
     through pipeline_train_step must track sequential training of the
@@ -173,7 +162,7 @@ def test_pipeline_training_matches_sequential():
             up, o2 = tx.update(g, o_local, w_local[0])
             return loss, (optax.apply_updates(w_local[0], up)[None], o2)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("pp", None, None), P("pp")),
             out_specs=(P(), (P("pp", None, None), P("pp"))))(

@@ -1,7 +1,5 @@
 """scripts/scaling_check.py tier-1 wiring (ISSUE 11): chunked parity
-end-to-end through train_pass on the in-process CPU mesh, and the
-multichip bench rows landing well-formed in a trajectory (graceful skip
-when subprocess devices are unavailable)."""
+end-to-end through train_pass on the in-process CPU mesh."""
 
 import importlib.util
 import os
@@ -25,20 +23,6 @@ def sc():
                                                "scaling_check.py"))
 
 
-def test_key_regex_wellformed(sc):
-    ok = ("sharded.n1.uniform.ex_per_sec_per_chip",
-          "sharded.n8.zipf.scaling_efficiency",
-          # chunked-schedule ladders gate under their own keys
-          "sharded.n4.uniform.c2.ex_per_sec_per_chip")
-    bad = ("sharded.uniform.ex_per_sec_per_chip",
-           "sharded.n2.uniform.examples",
-           "deepfm_ctr_examples_per_sec_per_chip")
-    for k in ok:
-        assert sc.KEY_RE.match(k), k
-    for k in bad:
-        assert not sc.KEY_RE.match(k), k
-
-
 def test_chunked_parity_through_train_pass(sc):
     """a2a_chunks=2 == a2a_chunks=1 digest, bit for bit, ×2 seeded
     runs — on this process's 8-device mesh (conftest)."""
@@ -46,21 +30,3 @@ def test_chunked_parity_through_train_pass(sc):
     if ok is None:
         pytest.skip("no multi-device mesh in this process")
     assert ok is True
-
-
-def test_multichip_rows_land_in_trajectory(sc):
-    """BENCH_MODE=multichip subprocesses (1 and 2 virtual devices, tiny
-    workload) emit well-formed sharded.n{N}.{shape}.* rows that pass
-    the perf gate; SKIP (not fail) when the subprocess backend is
-    unavailable."""
-    status, rows = sc.bench_rows_check(ns="1,2", bs=128, gbatches=2,
-                                       passes=2, timeout_s=300.0)
-    if status == "skip":
-        pytest.skip("multichip bench subprocesses unavailable")
-    assert status == "ok"
-    metrics = {r["metric"] for r in rows}
-    assert "sharded.n2.uniform.scaling_efficiency" in metrics
-    for r in rows:
-        assert sc.KEY_RE.match(r["metric"])
-        assert isinstance(r["value"], (int, float))
-        assert r.get("n_chips") in (1, 2)

@@ -349,7 +349,7 @@ MH_TIERED_ELASTIC_WORKER = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_pod_topology_elastic_recovery(tmp_path):
-    """Elastic recovery of the POD topology (VERDICT r4 item 4): a
+    """Elastic recovery of the POD topology: a
     2-process global-mesh gang over MultihostTieredShardedTable dies
     mid-run WITHOUT saving its in-flight pass; the restarted gang's
     ranks rebuild their OWNED shards' host tiers from their per-process

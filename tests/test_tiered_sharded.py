@@ -690,7 +690,7 @@ def test_tiered_guards(mesh):
 
 def test_tiered_preloader_overlapped_plan_build(mesh, tmp_path):
     """PassPreloader(build_fn=trainer.build_resident_pass) over a tiered
-    table (VERDICT r4 item 3, preload_into_memory box_wrapper.h:1142):
+    table (preload_into_memory box_wrapper.h:1142):
     pass k+1's ROUTING PLAN builds during pass k (plan_scope pending
     rows), its host values stage overlapped, and begin_pass scatters the
     staged values into the plan-baked rows instead of keeping zeros —
