@@ -1,7 +1,8 @@
 """Anomaly flight recorder — the always-on black box.
 
-A bounded in-memory ring of the most recent telemetry (events, spans,
-critical-path blocks) plus a trigger registry. When an anomaly fires —
+A bounded in-memory ring of the most recent events and critical-path
+blocks, the process's one ring of spans (``obs.trace.recent_spans()``,
+always on) plus a trigger registry. When an anomaly fires —
 a NaN rollback, a refused serving reload, a ``PipelineHangError``, a
 watchdog escalation, an SLO breach from the alert engine, or an
 explicit ``hub.dump_blackbox(reason)`` — the recorder atomically
@@ -12,9 +13,12 @@ the run/pass identity, via the same write-tmp → fsync → ``os.replace``
 discipline as the artifact layer (``utils.fsio.atomic_write_json``).
 
 Hot-loop contract (same as ``trace.py``): with no recorder installed,
-``trigger()`` is one module-global read; the ring itself only receives
+``trigger()`` is one module-global read; the event ring only receives
 records while it is registered as a hub sink, which only happens when
 ``FLAGS.flightrec_dir`` is set — default-off runs stay bit-identical.
+Spans are not copied here: the bundle takes them from ``obs.trace``'s
+ring when it is written, so they are there whether or not any sink was
+attached when they ran.
 Per-trigger debounce collapses anomaly storms into one bundle per
 window, and a retention cap bounds the on-disk footprint.
 """
@@ -51,9 +55,9 @@ KEEP_CRITICAL_PATH = 16
 class FlightRecorder:
     """Ring buffer + trigger registry + atomic bundle publisher.
 
-    Registers on the hub as a dual (event + span) sink; ``emit`` /
-    ``span_full`` appends are lock-light (one deque append under the
-    GIL — no explicit lock on the record path)."""
+    Registers on the hub as an event sink; ``emit`` appends are
+    lock-light (one deque append under the GIL — no explicit lock on
+    the record path)."""
 
     def __init__(self, out_dir: str, ring_events: int = 512,
                  debounce_sec: float = 60.0, keep: int = 16) -> None:
@@ -81,16 +85,6 @@ class FlightRecorder:
         if cp:
             self._cp.append({"pass_seq": event.get("pass_seq"),
                              "seq": event.get("seq"), **cp})
-
-    def span_full(self, rec: Dict) -> None:
-        """Rich span-sink surface (obs/trace fan-out)."""
-        self._ring.append({"rec": "span", **rec})
-
-    def span(self, name: str, start_s: float, dur_s: float,
-             attrs: Optional[Dict] = None) -> None:
-        """Plain span-sink surface (hub.span fan-out)."""
-        self._ring.append({"rec": "span", "name": name, "t0": start_s,
-                           "dur": dur_s, **(attrs or {})})
 
     def close(self) -> None:
         pass
@@ -148,9 +142,13 @@ class FlightRecorder:
     def _publish(self, seq: int, name: str, reason: str,
                  ctx: Dict) -> str:
         from paddlebox_tpu.config import FLAGS
+        from paddlebox_tpu.obs import trace
         from paddlebox_tpu.obs.hub import get_hub
         from paddlebox_tpu.utils.fsio import atomic_write_json
         hub = get_hub()
+        # the newest spans of the process's one span ring, bounded like
+        # the events (they are there with no sink attached, too)
+        spans = trace.recent_spans()[-self._ring.maxlen:]
         bundle = {
             "schema": BUNDLE_SCHEMA,
             "trigger": name,
@@ -159,7 +157,9 @@ class FlightRecorder:
             "ts": time.time(),
             "run": hub.run_id,
             "health": hub.health(),        # run/pass ids + uptime
-            "ring": [dict(r) for r in list(self._ring)],
+            "ring": ([dict(r) for r in list(self._ring)]
+                     + [{"rec": "span", **trace.span_dict(r)}
+                        for r in spans]),
             "instruments": hub.snapshot(),
             "critical_path": list(self._cp),
             "flags": {k: _jsonable(v) for k, v in
@@ -238,7 +238,7 @@ def install_recorder(rec: Optional[FlightRecorder],
     if rec is None:
         _configured_dir = None
     elif attach:
-        hub.add_sink(rec, kind="both")
+        hub.add_sink(rec, kind="event")
     return rec
 
 

@@ -7,7 +7,8 @@ with pluggable sinks:
 
 - **event sinks** (``JsonlSink``...) get one structured record per
   pass/alert — the machine-readable PrintSyncTimer;
-- **span sinks** (``ChromeSpanSink``) get completed timed spans;
+- **span sinks** (``obs.trace.ChromeLaneTraceSink``) get completed
+  causal spans from ``obs.trace.span`` (``span_full(rec)``);
 - **Prometheus**: ``snapshot_prom()`` renders every instrument (plus the
   legacy ``STATS`` registry, bridged as ``pbox_stat`` gauges) in text
   exposition format; ``start_prom_http`` serves it from a background
@@ -25,11 +26,10 @@ JSONL sink, ``FLAGS.telemetry_prom_port>=0`` starts the HTTP endpoint
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 from paddlebox_tpu.obs.instruments import (Counter, Gauge, Histogram,
                                            Instrument, iter_prom_lines)
@@ -106,15 +106,15 @@ class TelemetryHub:
 
     def add_sink(self, sink, kind: Optional[str] = None) -> None:
         """Attach an event sink (has ``emit(dict)``), a span sink (has
-        ``span(name, start, dur, attrs)`` or the rich
-        ``span_full(rec)``), or BOTH — a dual-capability sink registers
-        in both lists (the pre-fix behavior silently filed it as
-        span-only, dropping its events). ``kind`` overrides the
-        auto-classification: "event", "span", or "both"."""
+        ``span_full(rec)``, what ``obs.trace.span`` fans out), or BOTH —
+        a dual-capability sink registers in both lists (the pre-fix
+        behavior silently filed it as span-only, dropping its events).
+        ``kind`` overrides the auto-classification: "event", "span", or
+        "both"."""
         if kind not in (None, "event", "span", "both"):
             raise ValueError(f"unknown sink kind: {kind!r}")
-        as_span = (hasattr(sink, "span") or hasattr(sink, "span_full")
-                   if kind is None else kind in ("span", "both"))
+        as_span = (hasattr(sink, "span_full") if kind is None
+                   else kind in ("span", "both"))
         as_event = (hasattr(sink, "emit") if kind is None
                     else kind in ("event", "both"))
         if kind is not None:
@@ -122,12 +122,12 @@ class TelemetryHub:
             # for a capability it lacks would fail at first emit
             if kind in ("event", "both") and not hasattr(sink, "emit"):
                 raise TypeError(f"sink {sink!r} has no emit()")
-            if kind in ("span", "both") and not (
-                    hasattr(sink, "span") or hasattr(sink, "span_full")):
-                raise TypeError(f"sink {sink!r} has no span()/span_full()")
+            if kind in ("span", "both") and not hasattr(sink,
+                                                        "span_full"):
+                raise TypeError(f"sink {sink!r} has no span_full()")
         if not (as_span or as_event):
             raise TypeError(
-                f"sink {sink!r} exposes neither emit() nor span()")
+                f"sink {sink!r} exposes neither emit() nor span_full()")
         with self._lock:
             if as_span:
                 self._span_sinks.append(sink)
@@ -192,26 +192,6 @@ class TelemetryHub:
                     self._sink_fails.pop(id(s), None)
             except Exception:
                 self._sink_error(s, "emit")
-
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs) -> Iterator[None]:
-        """Run-scoped timed span → span sinks (no-op without any)."""
-        sinks = self._span_sinks
-        if not sinks:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter() - t0
-            for s in sinks:
-                try:
-                    s.span(name, t0, dur, attrs)
-                    if self._sink_fails:
-                        self._sink_fails.pop(id(s), None)
-                except Exception:
-                    self._sink_error(s, "span")
 
     def _sink_error(self, sink, surface: str) -> None:
         """Sink fault isolation: a raising sink never reaches the

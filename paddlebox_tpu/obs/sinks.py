@@ -5,9 +5,8 @@ watchdog alerts, warmup outcomes...); span sinks receive completed
 timed spans. ``JsonlSink`` is the structured-log backend (one JSON
 object per line, flushed per event — events fire at pass granularity,
 not per batch, so durability beats buffering); ``MemorySink`` backs
-tests; ``ChromeSpanSink`` adapts the existing
-``utils.profiler.ChromeTraceWriter`` so hub spans land in the same
-chrome://tracing timeline as StageTimers stages.
+tests. The one span sink, ``ChromeLaneTraceSink``, lives with the spans
+in ``obs/trace.py``.
 """
 
 from __future__ import annotations
@@ -15,7 +14,7 @@ from __future__ import annotations
 import json
 import os
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 
 class JsonlSink:
@@ -82,28 +81,6 @@ class MemorySink:
     def emit(self, event: Dict) -> None:
         with self._lock:
             self.events.append(event)
-
-    def close(self) -> None:
-        pass
-
-
-class ChromeSpanSink:
-    """Span sink → ChromeTraceWriter: hub spans render as X events on
-    the same host-orchestration timeline as StageTimers stages. Pass an
-    explicit writer, or None to follow whatever writer is installed via
-    ``utils.profiler.set_chrome_trace`` at span time."""
-
-    def __init__(self, writer=None) -> None:
-        self._writer = writer
-
-    def span(self, name: str, start_s: float, dur_s: float,
-             attrs: Optional[Dict] = None) -> None:
-        w = self._writer
-        if w is None:
-            from paddlebox_tpu.utils.profiler import chrome_trace
-            w = chrome_trace()
-        if w is not None:
-            w.complete(name, start_s, dur_s, **(attrs or {}))
 
     def close(self) -> None:
         pass

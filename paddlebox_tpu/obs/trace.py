@@ -6,9 +6,10 @@ epilogue lane drains k-1's write-back plus eviction and SSD demotion —
 but the PR 1 telemetry still saw it as main-thread stage timers plus
 counters. This module adds the missing CAUSAL view:
 
-**Spans.** ``span(name, ...)`` times a region and emits a record
-carrying ``(trace_id=run, pass_seq, span_id, parent_id, lane)`` to the
-hub's span sinks. ``lane`` names the EXECUTING context — the catalog:
+**Spans.** ``span(name, ...)`` times a region and keeps a record
+carrying ``(pass_seq, span_id, parent_id, lane)`` (the hub's span sinks
+get it too, with ``trace_id=run``). ``lane`` names the EXECUTING
+context — the catalog:
 
     main            the training/driver thread
     preload.worker  the depth-N PassPreloader worker (build + stage)
@@ -25,10 +26,25 @@ consumer opens its span with ``link_from=that_id`` — the Chrome sink
 renders the link as a flow arrow from the source span's end to the
 linked span's start, across lane rows.
 
-**Inert-when-off.** Every entry point guards on the same contract as
-the hub (``hub.active`` + a span sink attached): with no sinks the
-span() context manager is two attribute reads and yields a shared null
-handle — default-off tracing costs nothing measurable per pass.
+**Always in memory, on the profiler's clock.** Every ``span()``
+records, sink or no sink: on exit it appends one ``SpanRecord`` tuple
+(``time.perf_counter_ns`` start and duration) to a process-wide bounded
+ring (``recent_spans()``), and for its whole extent it holds a
+``jax.profiler.TraceAnnotation`` of the same name. With no profiler
+session the annotation is a flag test in C++; inside one
+(``utils.profiler.trace()``, ``jax.profiler.start_trace``) the span
+lands in the xplane's host plane on the device trace's own clock, on
+the thread that ran it, with ``lane`` and ``pass_seq`` as its stats.
+"Off" (no sink attached) means no sink call, no export, no counter and
+no event payload; the ring and the annotation are what is left. That is
+affordable because spans sit at pass and file granularity only — a span
+inside a per-batch loop does not belong here.
+
+**Names on the device.** The ``SCOPE_*`` constants are the catalog of
+``jax.named_scope`` names the train step puts on its device ops
+(``pbox.pull``, ``pbox.push``, ...); ``obs/xplane.py`` reduces a
+profiler trace by them. The step and the reducer share the constants so
+a refactor cannot drift them apart.
 
 **Chrome rendering.** ``ChromeLaneTraceSink`` writes spans into a
 ``utils.profiler.ChromeTraceWriter`` with one STABLE tid row per lane
@@ -53,11 +69,11 @@ flow-link semantics.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
-from collections import OrderedDict
-from typing import Dict, Iterator, Optional
+from typing import Dict, Iterator, List, NamedTuple, Optional
 
 from paddlebox_tpu.obs.hub import get_hub
 from paddlebox_tpu.utils.logging import get_logger
@@ -83,9 +99,54 @@ LANE_DEVICE = "device.a2a"
 #: timed probe re-run, so a trace shows Pallas vs XLA cost side by side
 LANE_KERNELS = "device.kernels"
 
-_TLS = threading.local()   # .lane: str, .stack: List[int] (open span ids)
+#: the device scope catalog: ``jax.named_scope`` names on the train
+#: step's ops (train/step.py, train/device_pass.py, train/sharded.py).
+#: Metadata only — no operation, number or shape depends on them. The
+#: backward ops of a scope carry ``transpose(jvp(pbox.<name>))``;
+#: obs/xplane folds them into ``pbox.<name>.bwd``.
+SCOPE_DECODE = "pbox.decode"        # wire unpack + slicing the staged pass
+SCOPE_DEDUP = "pbox.dedup"          # in-trace dedup of the compact wire
+SCOPE_PULL = "pbox.pull"            # row gather, pull values, expand
+SCOPE_POOL_CVM = "pbox.pool_cvm"    # fused_seqpool_cvm
+SCOPE_DENSE = "pbox.dense"          # model.apply
+SCOPE_LOSS = "pbox.loss"
+SCOPE_PUSH = "pbox.push"            # merge, scatter, in-table optimizer
+SCOPE_DENSE_OPT = "pbox.dense_opt"  # tx.update + apply_updates
+SCOPE_AUC = "pbox.auc"
+SCOPE_A2A_PULL = "pbox.a2a_pull"    # sharded step: the pull all_to_all
+SCOPE_A2A_PUSH = "pbox.a2a_push"    # sharded step: the grad all_to_all
+#: scopes of the single-chip step (every one is in its lowered text)
+STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_POOL_CVM,
+               SCOPE_DENSE, SCOPE_LOSS, SCOPE_PUSH, SCOPE_DENSE_OPT,
+               SCOPE_AUC)
+#: the sharded step adds the exchange
+SHARDED_SCOPES = (SCOPE_A2A_PULL, SCOPE_A2A_PUSH)
+
+#: spans kept in memory (about 13 a resident pass: hundreds of passes)
+RING_SPANS = 8192
+
+
+class SpanRecord(NamedTuple):
+    """One completed span as the ring keeps it."""
+    name: str
+    lane: str
+    pass_seq: Optional[int]
+    span_id: int
+    parent_id: int
+    link_from: int
+    t0_ns: int      # time.perf_counter_ns() at entry
+    dur_ns: int
+    attrs: Optional[Dict]
+
+
+_RING: "collections.deque[SpanRecord]" = collections.deque(
+    maxlen=RING_SPANS)
+
+# .lane: str, .stack: List[(span id, pass_seq)] of the open spans
+_TLS = threading.local()
 _ID_LOCK = threading.Lock()
 _NEXT_ID = 1
+_NEXT_PASS = 1
 
 
 def _new_span_id() -> int:
@@ -96,10 +157,23 @@ def _new_span_id() -> int:
     return sid
 
 
+def next_pass_seq() -> int:
+    """The process's next pass identifier: whoever makes a pass (the
+    preloader, ``ResidentPass.build``) draws one, the pass carries it
+    (``rp.pass_seq``) and every span of that pass on every lane gets it.
+    """
+    global _NEXT_PASS
+    with _ID_LOCK:
+        seq = _NEXT_PASS
+        _NEXT_PASS += 1
+    return seq
+
+
 def tracing_active() -> bool:
-    """True iff spans would actually be recorded: the hub is active AND
-    at least one span sink is attached (the inert-when-off guard every
-    span call site shares)."""
+    """True iff spans reach a SINK: the hub is active AND at least one
+    span sink is attached. (The ring and the profiler annotation get
+    every span regardless; probes that re-run work only to time it
+    guard on this.)"""
     hub = get_hub()
     return hub.active and bool(hub._span_sinks)
 
@@ -141,16 +215,19 @@ class SpanHandle:
     (stash ``span_id`` on the object crossing threads and pass it as the
     consumer span's ``link_from``)."""
 
-    __slots__ = ("span_id", "lane", "name")
+    __slots__ = ("span_id", "lane", "name", "pass_seq", "attrs")
 
-    def __init__(self, span_id: int, lane: str, name: str) -> None:
+    def __init__(self, span_id: int, lane: str, name: str,
+                 pass_seq: Optional[int] = None,
+                 attrs: Optional[Dict] = None) -> None:
         self.span_id = span_id
         self.lane = lane
         self.name = name
-
-
-#: shared null handle: the no-sink fast path allocates nothing
-NULL_SPAN = SpanHandle(0, "", "")
+        #: ``pass_seq`` and ``attrs`` may be set inside the span, for
+        #: what is known only at its end (the pass a wait popped); the
+        #: ring's record reads both at exit
+        self.pass_seq = pass_seq
+        self.attrs = {} if attrs is None else attrs
 
 
 def current_span_id() -> int:
@@ -158,63 +235,97 @@ def current_span_id() -> int:
     producer-side id for a cross-thread link created mid-span (e.g.
     end_pass links its submit span to the epilogue job it enqueues)."""
     stack = getattr(_TLS, "stack", None)
-    return stack[-1] if stack else 0
+    return stack[-1][0] if stack else 0
+
+
+def recent_spans() -> List[SpanRecord]:
+    """A copy of the in-memory ring, oldest first (completion order:
+    a child precedes its parent)."""
+    return list(_RING)
+
+
+_ANNOTATION = None
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported at the first span so
+    that importing this package stays free of jax."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 @contextlib.contextmanager
 def span(name: str, pass_seq: Optional[int] = None,
          lane: Optional[str] = None, link_from: int = 0,
          **attrs) -> Iterator[SpanHandle]:
-    """Timed causal span → the hub's span sinks. Inert without sinks
-    (yields ``NULL_SPAN``). ``link_from`` names a producer span on
-    another thread; rich sinks render it as a flow arrow. Attrs ride the
-    record (small, JSON-able values only)."""
-    hub = get_hub()
-    sinks = hub._span_sinks
-    if not (hub.active and sinks):
-        yield NULL_SPAN
-        return
+    """Timed causal span → the ring, the profiler's trace, and the hub's
+    span sinks when any is attached. A span with no ``pass_seq`` of its
+    own takes its parent's. ``link_from`` names a producer span on
+    another thread; rich sinks render it as a flow arrow. Attrs ride
+    the record (small, JSON-able values only); the handle's ``attrs``
+    dict may be filled inside the span for values known only at its end.
+    """
     ln = lane or current_lane()
     stack = getattr(_TLS, "stack", None)
     if stack is None:
         stack = _TLS.stack = []
-    parent = stack[-1] if stack else 0
+    parent, parent_seq = stack[-1] if stack else (0, None)
+    if pass_seq is None:
+        pass_seq = parent_seq
     sid = _new_span_id()
-    handle = SpanHandle(sid, ln, name)
-    stack.append(sid)
-    t0 = time.perf_counter()
-    try:
-        yield handle
-    finally:
-        dur = time.perf_counter() - t0
-        stack.pop()
-        rec = {"name": name, "span_id": sid, "parent_id": parent,
-               "lane": ln, "trace_id": hub.run_id, "t0": t0, "dur": dur,
-               "link_from": link_from}
-        if pass_seq is not None:
-            rec["pass_seq"] = pass_seq
-        if attrs:
-            rec["attrs"] = attrs
-        for s in sinks:
-            try:
-                full = getattr(s, "span_full", None)
-                if full is not None:
-                    full(rec)
-                else:
-                    plain = dict(attrs)
-                    plain["lane"] = ln
-                    if pass_seq is not None:
-                        plain["pass_seq"] = pass_seq
-                    s.span(name, t0, dur, plain)
-            except Exception:
-                log.warning("trace span sink failed", exc_info=True)
-        if parent == 0:
-            # lane occupancy counts TOP-LEVEL spans only (children are
-            # contained in their parent's wall — counting both would
-            # double-book the lane)
-            hub.counter("pbox_lane_busy_seconds_total",
-                        "seconds each pipeline lane spent in top-level "
-                        "spans").inc(dur, lane=ln)
+    handle = SpanHandle(sid, ln, name, pass_seq, attrs)
+    note = {"lane": ln} if pass_seq is None else {
+        "lane": ln, "pass_seq": pass_seq}
+    stack.append((sid, pass_seq))
+    with _annotation_cls()(name, **note):
+        t0 = time.perf_counter_ns()
+        try:
+            yield handle
+        finally:
+            rec = SpanRecord(name, ln, handle.pass_seq, sid, parent,
+                             link_from, t0, time.perf_counter_ns() - t0,
+                             attrs or None)
+            stack.pop()
+            _RING.append(rec)
+            hub = get_hub()
+            if hub.active and hub._span_sinks:
+                _to_sinks(hub, rec)
+
+
+def span_dict(rec: SpanRecord) -> Dict:
+    """A ring record as the sinks and the flight recorder's bundle
+    carry it: seconds on the ``perf_counter`` clock (what
+    ``ChromeTraceWriter`` takes), optional fields left out."""
+    out = {"name": rec.name, "span_id": rec.span_id,
+           "parent_id": rec.parent_id, "lane": rec.lane,
+           "t0": rec.t0_ns / 1e9, "dur": rec.dur_ns / 1e9,
+           "link_from": rec.link_from}
+    if rec.pass_seq is not None:
+        out["pass_seq"] = rec.pass_seq
+    if rec.attrs:
+        out["attrs"] = rec.attrs
+    return out
+
+
+def _to_sinks(hub, rec: SpanRecord) -> None:
+    """The sink fan-out of one completed span."""
+    full = span_dict(rec)
+    full["trace_id"] = hub.run_id
+    for s in hub._span_sinks:
+        try:
+            s.span_full(full)
+        except Exception:
+            log.warning("trace span sink failed", exc_info=True)
+    if rec.parent_id == 0:
+        # lane occupancy counts TOP-LEVEL spans only (children are
+        # contained in their parent's wall — counting both would
+        # double-book the lane)
+        hub.counter("pbox_lane_busy_seconds_total",
+                    "seconds each pipeline lane spent in top-level "
+                    "spans").inc(full["dur"], lane=rec.lane)
 
 
 # ---- Chrome sink: per-lane rows + flow arrows --------------------------
@@ -222,9 +333,8 @@ class ChromeLaneTraceSink:
     """Span sink rendering causal spans as PER-LANE tid rows with flow
     arrows for cross-thread links in a chrome://tracing JSON.
 
-    Unlike the PR 1 ``ChromeSpanSink`` (which keys rows off the raw OS
-    thread id), rows here are the LANE catalog: one stable tid per lane
-    name, labeled via thread-name metadata, ordered by first
+    Rows are the LANE catalog, not OS thread ids: one stable tid per
+    lane name, labeled via thread-name metadata, ordered by first
     appearance. A span whose ``link_from`` names an already-rendered
     span gets a flow ("s" at the source span's end, "f" at this span's
     start) so the build→consume hand-off draws as an arrow across
@@ -240,7 +350,8 @@ class ChromeLaneTraceSink:
         self._writer = writer
         self._lock = threading.Lock()
         self._lane_tids: Dict[str, int] = {}
-        self._done: "OrderedDict[int, tuple]" = OrderedDict()
+        self._done: "collections.OrderedDict[int, tuple]" = \
+            collections.OrderedDict()
 
     def _resolve(self):
         w = self._writer
@@ -286,15 +397,6 @@ class ChromeLaneTraceSink:
             w.flow(link, "s", min(src_end, t0), src_tid,
                    name=rec["name"])
             w.flow(link, "f", t0, tid, name=rec["name"])
-
-    def span(self, name: str, start_s: float, dur_s: float,
-             attrs: Optional[Dict] = None) -> None:
-        """Plain hub spans (TelemetryHub.span) land on the emitting
-        thread's lane row too — same timeline, no links."""
-        self.span_full({"name": name, "span_id": 0, "parent_id": 0,
-                        "lane": current_lane(), "t0": start_s,
-                        "dur": dur_s, "attrs": attrs or {},
-                        "link_from": 0})
 
     def close(self) -> None:
         pass
@@ -364,7 +466,8 @@ def critical_path_block(train_sec: float,
 
 
 def reset() -> None:
-    """Test hook: drop pending parts (span ids keep counting — they
-    only need process-uniqueness)."""
+    """Test hook: drop pending parts and the span ring (span and pass
+    ids keep counting — they only need process-uniqueness)."""
     with _PARTS_LOCK:
         _PENDING_PARTS.clear()
+    _RING.clear()

@@ -34,6 +34,7 @@ import numpy as np
 
 from paddlebox_tpu.config import FLAGS
 from paddlebox_tpu.data.dataset import Dataset
+from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.bitpack import (pack_delta, pack_delta_auto,
                                        pack_u12, pack_u16m, pack_u18,
                                        pack_u24, unpack_delta16,
@@ -73,6 +74,11 @@ def poll_preload_abort() -> None:
     if preemption.stop_pending():
         raise PreloadBuildAborted(
             f"pass build aborted ({preemption.stop_reason()})")
+
+
+def _num_keys(per_batch) -> int:
+    """Keys of a pass's per-batch views (the build spans' ``keys``)."""
+    return int(sum(len(b[0]) for b in per_batch))
 
 
 class ResidentPass:
@@ -127,6 +133,9 @@ class ResidentPass:
         # build_streamed — the preloader mirrors them into
         # pbox_preload_build_seconds_total{stage=...}
         self.build_stats: Optional[Dict[str, float]] = None
+        # the pass's identifier on every span of every lane
+        # (obs/trace.next_pass_seq), given by whoever makes the pass
+        self.pass_seq: Optional[int] = None
 
     @property
     def num_batches(self) -> int:
@@ -142,7 +151,8 @@ class ResidentPass:
 
     @classmethod
     def build(cls, dataset: Dataset, table,
-              floats_dtype=np.float32) -> "ResidentPass":
+              floats_dtype=np.float32,
+              pass_seq: Optional[int] = None) -> "ResidentPass":
         """Pack a dataset's batches; assigns table rows for every key and
         dedups per batch (the FeedPass key registration +
         DedupKeysAndFillIdx steps, both done by the native index).
@@ -161,8 +171,11 @@ class ResidentPass:
         dedup, u_pad, k_max = cls._dedup_phase(per_batch, table)
         host = cls._pack_chunk(per_batch, dedup, u_pad, k_max, trivial,
                                table.capacity)
-        return cls(host[0], host[1], floats, host[2], host[3], nrec,
-                   qmeta=qmeta, side=side)
+        rp = cls(host[0], host[1], floats, host[2], host[3], nrec,
+                 qmeta=qmeta, side=side)
+        rp.pass_seq = (trace.next_pass_seq() if pass_seq is None
+                       else pass_seq)
+        return rp
 
     @classmethod
     def build_streamed(cls, dataset: Dataset, table,
@@ -196,8 +209,10 @@ class ResidentPass:
         docs/PERFORMANCE.md telemetry)."""
         stats: Dict[str, float] = {}
         t0 = time.perf_counter()
-        per_batch, floats, qmeta, trivial, nrec, side = cls._front(
-            dataset, floats_dtype)
+        with trace.span("build.front") as sp:
+            per_batch, floats, qmeta, trivial, nrec, side = cls._front(
+                dataset, floats_dtype)
+            sp.attrs["keys"] = _num_keys(per_batch)
         stats["front"] = time.perf_counter() - t0
         floats_t = jax.device_put(floats)
         qm = jax.device_put(np.zeros((2, 0), np.float32)
@@ -233,8 +248,9 @@ class ResidentPass:
                         "wire")
         poll_preload_abort()
         t0 = time.perf_counter()
-        dedup, u_pad, k_max = cls._dedup_phase(per_batch, table, threads,
-                                               stats=stats)
+        with trace.span("build.dedup", keys=_num_keys(per_batch)):
+            dedup, u_pad, k_max = cls._dedup_phase(
+                per_batch, table, threads, stats=stats)
         t_dedup = time.perf_counter() - t0
         # the index stage (key→row assignment inside the dedup phase,
         # host kv or device probe table) reports separately so the
@@ -247,84 +263,91 @@ class ResidentPass:
         # choice _encode_uniq/_encode_gidx make on the whole pass, so
         # per-chunk encodes are mutually consistent and byte-identical
         # to upload()
-        ufmt = cls._choose_uniq_fmt(dedup, u_pad, table.capacity)
-        gfmt = cls._choose_gidx_fmt(per_batch, dedup, k_max)
-        nb = len(per_batch)
-        step = FLAGS.preload_pack_chunk_batches
-        step = nb if step <= 0 else min(step, nb)
-        t_pack = t_h2d = 0.0
-        uniq_parts: List[tuple] = []
-        gidx_parts: List[tuple] = []
-        host_parts: List[tuple] = []
-        with ThreadPoolExecutor(max_workers=threads) as pool:
-            futs = [pool.submit(cls._pack_chunk, per_batch[a:a + step],
-                                dedup[a:a + step], u_pad, k_max,
-                                trivial, table.capacity)
-                    for a in range(0, nb, step)]
-            for f in futs:
-                t0 = time.perf_counter()
-                uniq_c, gidx_c, meta_c, segs_c = f.result()
-                t_pack += time.perf_counter() - t0
-                poll_preload_abort()
-                # host encode is pack work; only the device_put
-                # dispatch books as h2d (the stage split exists so a
-                # starved pipeline names its slow stage correctly)
-                t0 = time.perf_counter()
-                ue = cls._encode_uniq_fmt(ufmt, uniq_c, meta_c)
-                ge = cls._encode_gidx_fmt(gfmt, gidx_c)
-                t_pack += time.perf_counter() - t0
-                t0 = time.perf_counter()
-                up = tuple(jax.device_put(a) for a in ue)
-                gp = tuple(jax.device_put(a) for a in ge)
-                issued.extend(up)
-                issued.extend(gp)
-                uniq_parts.append(up)
-                gidx_parts.append(gp)
-                host_parts.append((uniq_c, gidx_c, meta_c, segs_c))
-                t_h2d += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        if len(host_parts) == 1:
-            uniq, gidx, meta, segs = host_parts[0]
-            uniq_t, gidx_t = uniq_parts[0], gidx_parts[0]
-            t_pack += time.perf_counter() - t0
-        else:
-            uniq = np.concatenate([p[0] for p in host_parts])
-            gidx = np.concatenate([p[1] for p in host_parts])
-            meta = np.concatenate([p[2] for p in host_parts])
-            segs = (None if trivial else
-                    np.concatenate([p[3] for p in host_parts]))
-            t_pack += time.perf_counter() - t0
-            # stitch the staged chunks device-side: one concatenate per
-            # wire leaf, dispatched against the in-flight transfers
-            # (device work → the h2d stage, like the puts it chases)
+        # host pack with each chunk's transfer dispatched as it is
+        # encoded (the pack and h2d stage seconds interleave inside)
+        with trace.span("build.pack"):
+            ufmt = cls._choose_uniq_fmt(dedup, u_pad, table.capacity)
+            gfmt = cls._choose_gidx_fmt(per_batch, dedup, k_max)
+            nb = len(per_batch)
+            step = FLAGS.preload_pack_chunk_batches
+            step = nb if step <= 0 else min(step, nb)
+            t_pack = t_h2d = 0.0
+            uniq_parts: List[tuple] = []
+            gidx_parts: List[tuple] = []
+            host_parts: List[tuple] = []
+            with ThreadPoolExecutor(max_workers=threads) as pool:
+                futs = [pool.submit(cls._pack_chunk,
+                                    per_batch[a:a + step],
+                                    dedup[a:a + step], u_pad, k_max,
+                                    trivial, table.capacity)
+                        for a in range(0, nb, step)]
+                for f in futs:
+                    t0 = time.perf_counter()
+                    uniq_c, gidx_c, meta_c, segs_c = f.result()
+                    t_pack += time.perf_counter() - t0
+                    poll_preload_abort()
+                    # host encode is pack work; only the device_put
+                    # dispatch books as h2d (the stage split exists so
+                    # a starved pipeline names its slow stage correctly)
+                    t0 = time.perf_counter()
+                    ue = cls._encode_uniq_fmt(ufmt, uniq_c, meta_c)
+                    ge = cls._encode_gidx_fmt(gfmt, gidx_c)
+                    t_pack += time.perf_counter() - t0
+                    t0 = time.perf_counter()
+                    up = tuple(jax.device_put(a) for a in ue)
+                    gp = tuple(jax.device_put(a) for a in ge)
+                    issued.extend(up)
+                    issued.extend(gp)
+                    uniq_parts.append(up)
+                    gidx_parts.append(gp)
+                    host_parts.append((uniq_c, gidx_c, meta_c, segs_c))
+                    t_h2d += time.perf_counter() - t0
             t0 = time.perf_counter()
-            uniq_t = tuple(jnp.concatenate([p[j] for p in uniq_parts],
-                                           axis=0)
-                           for j in range(len(uniq_parts[0])))
-            gidx_t = tuple(jnp.concatenate([p[j] for p in gidx_parts],
-                                           axis=0)
-                           for j in range(len(gidx_parts[0])))
-            t_h2d += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        segs_enc = (None if segs is None else
-                    cls._encode_segs_or_fallback(segs, meta, floats))
-        t_pack += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        segs_t = ((jax.device_put(np.zeros((1, 1), np.int32)),)
-                  if segs_enc is None else
-                  tuple(jax.device_put(a) for a in segs_enc))
-        rp = cls(uniq, gidx, floats, meta, segs, nrec, qmeta=qmeta,
-                 side=side)
-        rp.dev = (uniq_t, gidx_t, floats_t, jax.device_put(meta),
-                  segs_t, qm)
-        issued.extend(jax.tree.leaves(rp.dev))
-        if block:
-            jax.block_until_ready(list(jax.tree.leaves(rp.dev)))
-        # block=False: transfers are ISSUED (device_put is eager/async)
-        # and the consuming execution will wait on them — the caller's
-        # thread is free to start the NEXT pass's host build while this
-        # pass's bytes are still on the wire (PassPreloader does this,
-        # overlapping host build k+2 with transfer k+1 and training k)
+            if len(host_parts) == 1:
+                uniq, gidx, meta, segs = host_parts[0]
+                uniq_t, gidx_t = uniq_parts[0], gidx_parts[0]
+                t_pack += time.perf_counter() - t0
+            else:
+                uniq = np.concatenate([p[0] for p in host_parts])
+                gidx = np.concatenate([p[1] for p in host_parts])
+                meta = np.concatenate([p[2] for p in host_parts])
+                segs = (None if trivial else
+                        np.concatenate([p[3] for p in host_parts]))
+                t_pack += time.perf_counter() - t0
+                # stitch the staged chunks device-side: one concatenate
+                # per wire leaf, dispatched against the in-flight
+                # transfers (device work → the h2d stage, like the puts
+                # it chases)
+                t0 = time.perf_counter()
+                uniq_t = tuple(
+                    jnp.concatenate([p[j] for p in uniq_parts], axis=0)
+                    for j in range(len(uniq_parts[0])))
+                gidx_t = tuple(
+                    jnp.concatenate([p[j] for p in gidx_parts], axis=0)
+                    for j in range(len(gidx_parts[0])))
+                t_h2d += time.perf_counter() - t0
+            t0 = time.perf_counter()
+            segs_enc = (None if segs is None else
+                        cls._encode_segs_or_fallback(segs, meta, floats))
+            t_pack += time.perf_counter() - t0
+        with trace.span("build.upload"):
+            t0 = time.perf_counter()
+            segs_t = ((jax.device_put(np.zeros((1, 1), np.int32)),)
+                      if segs_enc is None else
+                      tuple(jax.device_put(a) for a in segs_enc))
+            rp = cls(uniq, gidx, floats, meta, segs, nrec, qmeta=qmeta,
+                     side=side)
+            rp.dev = (uniq_t, gidx_t, floats_t, jax.device_put(meta),
+                      segs_t, qm)
+            issued.extend(jax.tree.leaves(rp.dev))
+            if block:
+                jax.block_until_ready(list(jax.tree.leaves(rp.dev)))
+            # block=False: transfers are ISSUED (device_put is
+            # eager/async) and the consuming execution will wait on
+            # them — the caller's thread is free to start the NEXT
+            # pass's host build while this pass's bytes are still on
+            # the wire (PassPreloader does this, overlapping host build
+            # k+2 with transfer k+1 and training k)
         stats["h2d"] = t_h2d + (time.perf_counter() - t0)
         stats["pack"] = t_pack
         return rp
@@ -421,79 +444,82 @@ class ResidentPass:
         meta = np.zeros((nb, 4), np.int32)
         segs = None if trivial else np.empty((nb, k_max), np.int32)
         t0 = time.perf_counter()
-        bulk = FLAGS.bulk_pass_assign
-        if bulk:
-            # whole-pass bulk assign: ONE host_lock round-trip for the
-            # pass instead of nb (assign_slotted walks keys in order,
-            # so allocation is identical to the per-batch loop)
-            keys_all = np.concatenate([k for k, *_ in per_batch])
-            slots_all = np.concatenate([s for _, s, *_ in per_batch])
-            with table.host_lock:
-                r_all, l_all = table.index.assign_slotted(
-                    keys_all, slots_all.astype(np.uint16, copy=False))
-                table.slot_host[r_all] = slots_all
-            if (l_all < 0).any():
-                return None
-            bounds = np.cumsum([0] + [len(k) for k, *_ in per_batch])
-        for i, (keys, slot_of_key, _, pad_seg, seg_arr) in \
-                enumerate(per_batch):
-            nk = len(keys)
+        with trace.span("build.dedup", keys=_num_keys(per_batch)):
+            bulk = FLAGS.bulk_pass_assign
             if bulk:
-                a = bounds[i]
-                r, l = r_all[a:a + nk], l_all[a:a + nk]
-            else:
-                su = slot_of_key.astype(np.uint16, copy=False)
+                # whole-pass bulk assign: ONE host_lock round-trip for the
+                # pass instead of nb (assign_slotted walks keys in order,
+                # so allocation is identical to the per-batch loop)
+                keys_all = np.concatenate([k for k, *_ in per_batch])
+                slots_all = np.concatenate([s for _, s, *_ in per_batch])
                 with table.host_lock:
-                    r, l = table.index.assign_slotted(keys, su)
-                    table.slot_host[r] = slot_of_key
-                if (l < 0).any():
+                    r_all, l_all = table.index.assign_slotted(
+                        keys_all, slots_all.astype(np.uint16, copy=False))
+                    table.slot_host[r_all] = slots_all
+                if (l_all < 0).any():
                     return None
-            locs[i, :nk] = l
-            rows_g[i, :nk] = r
-            meta[i] = (nk, pad_seg, 0, 0)
-            if segs is not None:
-                segs[i, :nk] = seg_arr
-                segs[i, nk:] = pad_seg
+                bounds = np.cumsum([0] + [len(k) for k, *_ in per_batch])
+            for i, (keys, slot_of_key, _, pad_seg, seg_arr) in \
+                    enumerate(per_batch):
+                nk = len(keys)
+                if bulk:
+                    a = bounds[i]
+                    r, l = r_all[a:a + nk], l_all[a:a + nk]
+                else:
+                    su = slot_of_key.astype(np.uint16, copy=False)
+                    with table.host_lock:
+                        r, l = table.index.assign_slotted(keys, su)
+                        table.slot_host[r] = slot_of_key
+                    if (l < 0).any():
+                        return None
+                locs[i, :nk] = l
+                rows_g[i, :nk] = r
+                meta[i] = (nk, pad_seg, 0, 0)
+                if segs is not None:
+                    segs[i, :nk] = seg_arr
+                    segs[i, nk:] = pad_seg
         if stats is not None:  # key-assignment stage (the dedup twin)
             stats["dedup"] = time.perf_counter() - t0
         t0 = time.perf_counter()
-        bits = max(int(locs.max()).bit_length(), 1)
-        if bits > 24:
-            return None
-        with table.host_lock:
-            cs_map, cr_map = table.index.arena_export()
-        n_slots = int(table.arena_slots)
-        valid = cs_map < n_slots  # default (slotless) arena excluded
-        stride = int(cr_map[valid].max()) + 1 if valid.any() else 1
-        # bucket the stride (power-of-two ladder) so the chunk map's
-        # shape — and therefore the compiled runner — stays stable as
-        # slots grow new chunks across passes
-        from paddlebox_tpu.ps.table import next_bucket
-        stride = min(next_bucket(8, stride),
-                     (cap >> int(table.arena_chunk_bits)) + 1)
-        cmap = np.zeros((n_slots, stride), np.int32)
-        cmap[cs_map[valid], cr_map[valid]] = \
-            np.nonzero(valid)[0].astype(np.int32)
-        loc_t = tuple(jax.device_put(a)
-                      for a in cls._encode_locals(locs, bits))
-        if segs is None:
-            segs_t = (jax.device_put(np.zeros((1, 1), np.int32)),)
-        else:
-            enc = cls._encode_segs_slotwire(segs, meta, floats.shape[1])
-            segs_t = (tuple(jax.device_put(a) for a in enc)
-                      if enc is not None else
-                      tuple(jax.device_put(a)
-                            for a in cls._encode_gidx(segs)))
-        rp = cls(rows_g, locs, floats, meta, segs, nrec, qmeta=qmeta,
-                 side=side)
-        rp.wire = "compact"
-        rp.chunk_bits = int(table.arena_chunk_bits)
-        rp.dev = (loc_t, (jax.device_put(cmap),), floats_t,
-                  jax.device_put(meta), segs_t, qm)
+        with trace.span("build.pack"):
+            bits = max(int(locs.max()).bit_length(), 1)
+            if bits > 24:
+                return None
+            with table.host_lock:
+                cs_map, cr_map = table.index.arena_export()
+            n_slots = int(table.arena_slots)
+            valid = cs_map < n_slots  # default (slotless) arena excluded
+            stride = int(cr_map[valid].max()) + 1 if valid.any() else 1
+            # bucket the stride (power-of-two ladder) so the chunk map's
+            # shape — and therefore the compiled runner — stays stable as
+            # slots grow new chunks across passes
+            from paddlebox_tpu.ps.table import next_bucket
+            stride = min(next_bucket(8, stride),
+                         (cap >> int(table.arena_chunk_bits)) + 1)
+            cmap = np.zeros((n_slots, stride), np.int32)
+            cmap[cs_map[valid], cr_map[valid]] = \
+                np.nonzero(valid)[0].astype(np.int32)
+            loc_t = tuple(jax.device_put(a)
+                          for a in cls._encode_locals(locs, bits))
+            if segs is None:
+                segs_t = (jax.device_put(np.zeros((1, 1), np.int32)),)
+            else:
+                enc = cls._encode_segs_slotwire(segs, meta, floats.shape[1])
+                segs_t = (tuple(jax.device_put(a) for a in enc)
+                          if enc is not None else
+                          tuple(jax.device_put(a)
+                                for a in cls._encode_gidx(segs)))
+            rp = cls(rows_g, locs, floats, meta, segs, nrec, qmeta=qmeta,
+                     side=side)
+            rp.wire = "compact"
+            rp.chunk_bits = int(table.arena_chunk_bits)
+            rp.dev = (loc_t, (jax.device_put(cmap),), floats_t,
+                      jax.device_put(meta), segs_t, qm)
         if stats is not None:  # encode + transfer dispatch
             stats["pack"] = time.perf_counter() - t0
         if block:
-            jax.block_until_ready(list(jax.tree.leaves(rp.dev)))
+            with trace.span("build.upload"):
+                jax.block_until_ready(list(jax.tree.leaves(rp.dev)))
         return rp
 
     @staticmethod
@@ -968,10 +994,12 @@ class ResidentPass:
         regardless of when a checkpoint landed relative to the preload.
         Duplicate-tolerant boolean scatter after dropping the OOB pad
         ids (save paths only read rows the index owns)."""
-        rows = self.uniq.ravel()
-        rows = rows[rows <= table.capacity]
-        with table.host_lock:
-            table._touched[rows] = True
+        with trace.span("pass.mark_trained", pass_seq=self.pass_seq,
+                        rows=int(self.uniq.size)):
+            rows = self.uniq.ravel()
+            rows = rows[rows <= table.capacity]
+            with table.host_lock:
+                table._touched[rows] = True
 
 
 class _BatchView:
@@ -1062,70 +1090,75 @@ class ResidentPassRunner:
         if self.wire == "compact":
             return self._make_view_compact(uniq_t, gidx_t[0], floats,
                                            meta, segs, qmeta)
-        if len(uniq_t) == 3:
-            # u16-delta wire (ops/bitpack.unpack_delta16); the pad
-            # region is derived (fill_oob_pads pattern: distinct, > cap)
-            u_pad = uniq_t[0].shape[0]
-            upos = jnp.arange(u_pad, dtype=jnp.int32)
-            uniq = jnp.where(upos < meta[2],
-                             unpack_delta16(*uniq_t, base=meta[3]),
-                             self.capacity + 1 + upos)
-        elif len(uniq_t) == 2:
-            uniq = unpack_u24(*uniq_t)
-        else:
-            uniq = uniq_t[0]
-        gidx = (unpack_u18(*gidx_t) if len(gidx_t) == 2 else gidx_t[0])
-        k = gidx.shape[0]
-        num_keys, pad_seg = meta[0], meta[1]
-        pos = jnp.arange(k, dtype=jnp.int32)
-        if self.trivial:
-            segments = jnp.where(pos < num_keys, pos, pad_seg)
-        else:
-            segments = self._decode_segs(segs, meta, k_pad=k)
-        key_valid = (pos < num_keys).astype(jnp.float32)
-        if floats.dtype == jnp.uint8:  # q8 wire (quantize_floats)
-            dense, label, show, clk = dequantize_floats(floats, qmeta)
-        else:
-            dense, label, show, clk = unpack_floats(floats)
+        with jax.named_scope(trace.SCOPE_DECODE):
+            if len(uniq_t) == 3:
+                # u16-delta wire (ops/bitpack.unpack_delta16); the pad
+                # region is derived (fill_oob_pads pattern: distinct,
+                # > cap)
+                u_pad = uniq_t[0].shape[0]
+                upos = jnp.arange(u_pad, dtype=jnp.int32)
+                uniq = jnp.where(upos < meta[2],
+                                 unpack_delta16(*uniq_t, base=meta[3]),
+                                 self.capacity + 1 + upos)
+            elif len(uniq_t) == 2:
+                uniq = unpack_u24(*uniq_t)
+            else:
+                uniq = uniq_t[0]
+            gidx = (unpack_u18(*gidx_t) if len(gidx_t) == 2
+                    else gidx_t[0])
+            k = gidx.shape[0]
+            num_keys, pad_seg = meta[0], meta[1]
+            pos = jnp.arange(k, dtype=jnp.int32)
+            if self.trivial:
+                segments = jnp.where(pos < num_keys, pos, pad_seg)
+            else:
+                segments = self._decode_segs(segs, meta, k_pad=k)
+            key_valid = (pos < num_keys).astype(jnp.float32)
+            dense, label, show, clk = self._decode_floats(floats, qmeta)
         return _BatchView(
             uniq, gidx, key_valid, segments,
             dense=dense, label=label, show=show, clk=clk,
             segments_trivial=self.trivial)
+
+    @staticmethod
+    def _decode_floats(floats, qmeta):
+        if floats.dtype == jnp.uint8:  # q8 wire (quantize_floats)
+            return dequantize_floats(floats, qmeta)
+        return unpack_floats(floats)
 
     def _make_view_compact(self, loc_t, cmap, floats, meta, segs,
                            qmeta) -> _BatchView:
         """Decode the compact wire: slot-local rows → global rows via the
         arena chunk map, then in-trace dedup (DedupKeysAndFillIdx on the
         chip — ops/device_unique.py)."""
-        if len(loc_t) == 2:
-            k = loc_t[0].shape[-1]
-            m = 8 * loc_t[1].shape[-1] // k
-            local = unpack_u16m(loc_t[0], loc_t[1], m)
-        elif loc_t[0].dtype == jnp.uint8:   # u12 byte-pair wire
-            local = unpack_u12(loc_t[0])
-        else:
-            local = loc_t[0].astype(jnp.int32)
-        k = local.shape[-1]
-        num_keys, pad_seg = meta[0], meta[1]
-        pos = jnp.arange(k, dtype=jnp.int32)
-        s = self.num_slots
-        if self.trivial:
-            segments = jnp.where(pos < num_keys, pos, pad_seg)
-            slot = pos % s
-        else:
-            segments = self._decode_segs(segs, meta, k_pad=k)
-            slot = segments % s
-        cb = self.chunk_bits
-        stride = cmap.shape[1]
-        chunk = cmap.reshape(-1)[slot * stride + (local >> cb)]
-        rows = (chunk << cb) | (local & ((1 << cb) - 1))
-        rows = jnp.where(pos < num_keys, rows, self.capacity)
-        uniq, gidx = dedup_rows(rows, self.capacity)
-        key_valid = (pos < num_keys).astype(jnp.float32)
-        if floats.dtype == jnp.uint8:
-            dense, label, show, clk = dequantize_floats(floats, qmeta)
-        else:
-            dense, label, show, clk = unpack_floats(floats)
+        with jax.named_scope(trace.SCOPE_DECODE):
+            if len(loc_t) == 2:
+                k = loc_t[0].shape[-1]
+                m = 8 * loc_t[1].shape[-1] // k
+                local = unpack_u16m(loc_t[0], loc_t[1], m)
+            elif loc_t[0].dtype == jnp.uint8:   # u12 byte-pair wire
+                local = unpack_u12(loc_t[0])
+            else:
+                local = loc_t[0].astype(jnp.int32)
+            k = local.shape[-1]
+            num_keys, pad_seg = meta[0], meta[1]
+            pos = jnp.arange(k, dtype=jnp.int32)
+            s = self.num_slots
+            if self.trivial:
+                segments = jnp.where(pos < num_keys, pos, pad_seg)
+                slot = pos % s
+            else:
+                segments = self._decode_segs(segs, meta, k_pad=k)
+                slot = segments % s
+            cb = self.chunk_bits
+            stride = cmap.shape[1]
+            chunk = cmap.reshape(-1)[slot * stride + (local >> cb)]
+            rows = (chunk << cb) | (local & ((1 << cb) - 1))
+            rows = jnp.where(pos < num_keys, rows, self.capacity)
+            key_valid = (pos < num_keys).astype(jnp.float32)
+            dense, label, show, clk = self._decode_floats(floats, qmeta)
+        with jax.named_scope(trace.SCOPE_DEDUP):
+            uniq, gidx = dedup_rows(rows, self.capacity)
         return _BatchView(
             uniq, gidx, key_valid, segments,
             dense=dense, label=label, show=show, clk=clk,
@@ -1140,16 +1173,18 @@ class ResidentPassRunner:
                     state, rng, preds = carry
                     # compact wire: gidx slot carries the PASS-global
                     # arena chunk map, not per-batch data — don't index
-                    gi = (gidx_t if self.wire == "compact"
-                          else tuple(a[i] for a in gidx_t))
-                    # one shared index: the packed pair's leading
-                    # dims are equal; the modulo only serves the
-                    # [1, 1] dummy of the trivial layout
-                    si = i % segs_p[0].shape[0]
-                    sg = tuple(a[si] for a in segs_p)
-                    view = self._make_view(
-                        tuple(a[i] for a in uniq_t), gi, floats_p[i],
-                        meta_p[i], sg, qmeta)
+                    # (slicing the staged pass is the decode's)
+                    with jax.named_scope(trace.SCOPE_DECODE):
+                        gi = (gidx_t if self.wire == "compact"
+                              else tuple(a[i] for a in gidx_t))
+                        # one shared index: the packed pair's leading
+                        # dims are equal; the modulo only serves the
+                        # [1, 1] dummy of the trivial layout
+                        si = i % segs_p[0].shape[0]
+                        sg = tuple(a[si] for a in segs_p)
+                        ui = tuple(a[i] for a in uniq_t)
+                        fi, mi = floats_p[i], meta_p[i]
+                    view = self._make_view(ui, gi, fi, mi, sg, qmeta)
                     # 1-based like Trainer.train_pass's fold of the
                     # pre-incremented global_step
                     rng_i = jax.random.fold_in(rng, state.step + 1)
@@ -1176,18 +1211,22 @@ class ResidentPassRunner:
         """Run every batch of the staged pass → (state, preds or None);
         ``collect_preds`` returns [nb, B] per-batch device predictions
         (the post-pass metric registry feed)."""
-        rp.upload()
+        with trace.span("pass.upload", pass_seq=rp.pass_seq,
+                        staged=rp.dev is not None):
+            rp.upload()
         nb = rp.num_batches
         c = chunk if chunk is not None else (self.chunk or nb)
         i = 0
         chunks = []
-        while i < nb:
-            n = min(c, nb - i)
-            state, preds = self._run(n, collect_preds)(
-                state, *rp.dev, jnp.asarray(i, jnp.int32), rng)
-            if collect_preds:
-                chunks.append(preds)
-            i += n
+        with trace.span("pass.dispatch", pass_seq=rp.pass_seq,
+                        chunks=-(-nb // c)):
+            while i < nb:
+                n = min(c, nb - i)
+                state, preds = self._run(n, collect_preds)(
+                    state, *rp.dev, jnp.asarray(i, jnp.int32), rng)
+                if collect_preds:
+                    chunks.append(preds)
+                i += n
         if not collect_preds:
             return state, None
         return state, (chunks[0] if len(chunks) == 1
@@ -1287,7 +1326,8 @@ class PassPreloader:
             # alongside the open pass's compute (see
             # ResidentPass.upload); a lazy upload would instead
             # serialize into that pass's first step
-            rp.upload(materialize=True)
+            with trace.span("build.upload"):
+                rp.upload(materialize=True)
             return rp
         # build+upload overlapped; transfers stay IN FLIGHT
         # (block=False) so this thread can start the next pass's host
@@ -1298,7 +1338,6 @@ class PassPreloader:
             block=self._block)
 
     def _run(self) -> None:
-        from paddlebox_tpu.obs import trace
         from paddlebox_tpu.resilience import preemption
         # lets the builders' stage polls see THIS preloader's stop()
         # (poll_preload_abort) so an in-flight build aborts promptly
@@ -1332,15 +1371,17 @@ class PassPreloader:
                 # the pass trace's build span on the preload.worker
                 # lane; its id rides the pass so the main-thread
                 # consume span can link back (the build→consume flow
-                # arrow — obs/trace, docs/OBSERVABILITY.md §Tracing)
-                with trace.span("pass.build",
-                                pass_seq=self.builds + 1) as _sp:
+                # arrow — obs/trace, docs/OBSERVABILITY.md §Tracing),
+                # and the pass carries the identifier drawn here onto
+                # every later span of it
+                seq = trace.next_pass_seq()
+                with trace.span("pass.build", pass_seq=seq) as _sp:
                     rp = self._build(ds)
-                if _sp.span_id:
-                    try:
-                        rp._trace_span_id = _sp.span_id
-                    except AttributeError:
-                        pass  # slotted pass objects skip the link
+                try:
+                    rp.pass_seq = seq
+                    rp._trace_span_id = _sp.span_id
+                except AttributeError:
+                    pass  # slotted pass objects skip the link
                 self._note_built(rp, time.perf_counter() - t0)
             except PreloadBuildAborted as e:
                 log.warning("pass preload pipeline stopped: %s", e)
@@ -1455,7 +1496,9 @@ class PassPreloader:
             return None
         t0 = time.perf_counter()
         err = None
-        with self._cv:
+        # the blocked part, always spanned (also when nothing blocked:
+        # the boundary's readers want a wait for every pass)
+        with trace.span("pass.wait") as sp, self._cv:
             wait_with_deadline(
                 self._cv,
                 done=lambda: bool(self._q) or self._exhausted
@@ -1482,6 +1525,8 @@ class PassPreloader:
                     self._stopped = True
             depth = len(self._q)
             self._cv.notify_all()  # a build slot just freed
+            sp.attrs["depth"] = depth
+            sp.pass_seq = getattr(rp, "pass_seq", None)
         self.wait_sec_total += waited
         hub = self._hub()
         if hub is not None:
@@ -1492,7 +1537,6 @@ class PassPreloader:
                 # critical-path attribution: the blocked wait is the
                 # consuming pass's build-starvation stall (obs/trace —
                 # rides the next pass event's critical_path block)
-                from paddlebox_tpu.obs import trace
                 trace.note_pass_part("build_wait", waited)
             hub.gauge("pbox_preload_queue_depth",
                       "staged passes queued ahead of training"
@@ -1716,7 +1760,6 @@ class PassPipeline:
         # boundary attribution for the upcoming pass event
         # (obs/trace critical_path): the begin-stall pieces the table
         # just measured (~0 in steady state — the point of the pipeline)
-        from paddlebox_tpu.obs import trace
         lp = getattr(self.table, "last_pass_stats", None) or {}
         for stage, key in (("stage_wait", "stage_wait_sec"),
                            ("evict_scatter", "evict_scatter_sec"),
@@ -1740,7 +1783,6 @@ class PassPipeline:
             self.trainer.sync_table()
         t0 = time.perf_counter()
         n = self.table.end_pass()
-        from paddlebox_tpu.obs import trace
         trace.note_pass_part("end_submit", time.perf_counter() - t0)
         eps = getattr(self.table, "endpass_stats", None)
         if eps is not None:

@@ -24,6 +24,7 @@ import optax
 
 from paddlebox_tpu.config import FLAGS
 from paddlebox_tpu.metrics import auc_compute, init_auc_state
+from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops import fused_seqpool_cvm
 from paddlebox_tpu.ps.multi_mf import MultiMfEmbeddingTable
 from paddlebox_tpu.ps.table import (apply_push, expand_pull,
@@ -305,12 +306,15 @@ class MultiMfResidentPass:
         landing between build (prepare marks at build time) and training
         clears the flags and would otherwise drop the pass's updates from
         the next delta (the ResidentPass.mark_trained_rows rationale)."""
-        for c, (iu, _ik) in enumerate(self.class_ints):
-            t = table.tables[c]
-            rows = np.unique(iu[:, :-2])  # last 2 cols = meta
-            rows = rows[(rows >= 0) & (rows < t.capacity)]
-            with t.host_lock:
-                t._touched[rows] = True
+        with trace.span("pass.mark_trained",
+                        rows=int(sum(iu[:, :-2].size
+                                     for iu, _ik in self.class_ints))):
+            for c, (iu, _ik) in enumerate(self.class_ints):
+                t = table.tables[c]
+                rows = np.unique(iu[:, :-2])  # last 2 cols = meta
+                rows = rows[(rows >= 0) & (rows < t.capacity)]
+                with t.host_lock:
+                    t._touched[rows] = True
 
 
 def _mmf_resident_runner(step: MultiMfTrainStep, n_steps: int):

@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddlebox_tpu.data.batch import SlotBatch
 from paddlebox_tpu.metrics import AucState, auc_add_batch, init_auc_state
+from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops import fused_seqpool_cvm
 from paddlebox_tpu.ops.seqpool_cvm import fused_seqpool_cvm_slot_group
 from paddlebox_tpu.parallel.mesh import DATA_AXIS, stacked_zeros
@@ -392,6 +393,23 @@ class ShardedTrainStep:
         return params, opt_state
 
     # ---- per-device block program (runs under shard_map) ----
+    def _push(self, table, g_back, resp_idx, serve_rows, serve_valid,
+              serve_slot, rows_full, rng):
+        """The owner's side of the push, shared by both schedules:
+        merge the routed grads into served rows, scale, update."""
+        n, b = self.n, self.batch_size
+        a = resp_idx.shape[1]
+        g_serve = merge_rows(g_back.reshape(n * a, -1),
+                             resp_idx.reshape(n * a),
+                             num_segments=serve_rows.shape[0])
+        # PushCopy scaling (box_wrapper.cu:368): negate embed grads ×
+        # global batch size (the loss is the global mean)
+        gb = jnp.concatenate(
+            [g_serve[:, :2], g_serve[:, 2:] * (-1.0 * b * n)], axis=1)
+        return apply_push(table, serve_rows, gb, self.sgd_cfg, rng,
+                          rows_full=rows_full, touched=serve_valid > 0,
+                          slot_val=serve_slot)
+
     def _device_step(self, state: ShardedStepState, batch: GlobalBatch,
                      rng: jax.Array, sections: tuple = ()):
         """``sections`` = () runs the monolithic pull → compute → push →
@@ -423,20 +441,26 @@ class ShardedTrainStep:
         show = batch.show[0]
         clk = batch.clk[0]
         a = resp_idx.shape[1]
-        a2 = serve_rows.shape[0]
         d = 3 + table.mf_dim
+        # the pbox.* scope catalog of obs/trace (metadata only): the
+        # single-chip step's names plus the two exchanges
+        scope = jax.named_scope
 
         if not sections:
             # ---- pull: serve my rows, exchange, reassemble ----
             # one AoS gather serves the pull AND the push optimizer state
-            rows_full = gather_full_rows(table, serve_rows)    # [A2, F]
-            serve_vals = pull_values(rows_full, table.mf_dim)  # [A2, D]
-            # lane-packed expand (ps/table.expand_pull): narrow-row
-            # gathers and their autodiff transposes run at line
-            # granularity
-            resp = expand_pull(serve_vals,
-                               resp_idx.reshape(-1)).reshape(n, a, d)
-            recv = jax.lax.all_to_all(resp, DATA_AXIS, 0, 0, tiled=True)
+            with scope(trace.SCOPE_PULL):
+                rows_full = gather_full_rows(table, serve_rows)  # [A2, F]
+                serve_vals = pull_values(rows_full,
+                                         table.mf_dim)         # [A2, D]
+                # lane-packed expand (ps/table.expand_pull): narrow-row
+                # gathers and their autodiff transposes run at line
+                # granularity
+                resp = expand_pull(serve_vals,
+                                   resp_idx.reshape(-1)).reshape(n, a, d)
+            with scope(trace.SCOPE_A2A_PULL):
+                recv = jax.lax.all_to_all(resp, DATA_AXIS, 0, 0,
+                                          tiled=True)
             vals_flat = recv.reshape(n * a, d)
 
             ins_w = (show > 0).astype(jnp.float32)
@@ -444,14 +468,18 @@ class ShardedTrainStep:
             batch_show_clk = jnp.stack([show, clk], axis=1)
 
             def loss_fn(params, vals_flat):
-                values_k = expand_pull(vals_flat, gather_idx)
-                pooled = fused_seqpool_cvm(
-                    values_k, segments, batch_show_clk, b, s,
-                    self.use_cvm, self.cvm_offset)
-                logits = self.model.apply(params, pooled, dense)
-                ls = optax.sigmoid_binary_cross_entropy(logits, label)
-                loss_local = jnp.sum(ls * ins_w) / jnp.maximum(
-                    wsum_global, 1.0)
+                with scope(trace.SCOPE_PULL):
+                    values_k = expand_pull(vals_flat, gather_idx)
+                with scope(trace.SCOPE_POOL_CVM):
+                    pooled = fused_seqpool_cvm(
+                        values_k, segments, batch_show_clk, b, s,
+                        self.use_cvm, self.cvm_offset)
+                with scope(trace.SCOPE_DENSE):
+                    logits = self.model.apply(params, pooled, dense)
+                with scope(trace.SCOPE_LOSS):
+                    ls = optax.sigmoid_binary_cross_entropy(logits, label)
+                    loss_local = jnp.sum(ls * ins_w) / jnp.maximum(
+                        wsum_global, 1.0)
                 return loss_local, logits
 
             (loss_local, logits), (g_params, g_vals_flat) = \
@@ -459,22 +487,18 @@ class ShardedTrainStep:
                                    has_aux=True)(state.params, vals_flat)
 
             # ---- push: route grads back to owners, merge, update ----
-            g_back = jax.lax.all_to_all(
-                g_vals_flat.reshape(n, a, d), DATA_AXIS, 0, 0, tiled=True)
-            g_serve = merge_rows(g_back.reshape(n * a, d),
-                                 resp_idx.reshape(n * a), num_segments=a2)
-            # PushCopy scaling (box_wrapper.cu:368): negate embed grads ×
-            # global batch size (loss above is the global mean)
-            gb = jnp.concatenate(
-                [g_serve[:, :2], g_serve[:, 2:] * (-1.0 * b * n)], axis=1)
-            touched = serve_valid > 0
-            table = apply_push(table, serve_rows, gb,
-                               self.sgd_cfg, jax.random.fold_in(rng, me),
-                               rows_full=rows_full, touched=touched,
-                               slot_val=serve_slot)
+            with scope(trace.SCOPE_A2A_PUSH):
+                g_back = jax.lax.all_to_all(
+                    g_vals_flat.reshape(n, a, d), DATA_AXIS, 0, 0,
+                    tiled=True)
+            with scope(trace.SCOPE_PUSH):
+                table = self._push(table, g_back, resp_idx, serve_rows,
+                                   serve_valid, serve_slot, rows_full,
+                                   jax.random.fold_in(rng, me))
 
             # ---- dense sync ----
-            params, opt_state = self._dense_sync(state, g_params, me)
+            with scope(trace.SCOPE_DENSE_OPT):
+                params, opt_state = self._dense_sync(state, g_params, me)
         else:
             # ---- chunked exchange-compute schedule (ISSUE 11) ----
             # "Optimizing Distributed ML Communication with Fused
@@ -487,16 +511,21 @@ class ShardedTrainStep:
             a_off = section_offsets(a_secs)
             k_off = section_offsets(k_secs)
             s_off = section_offsets(s_secs)
-            rows_full = gather_full_rows(table, serve_rows)    # [A2, F]
-            serve_vals = pull_values(rows_full, table.mf_dim)  # [A2, D]
+            with scope(trace.SCOPE_PULL):
+                rows_full = gather_full_rows(table, serve_rows)  # [A2, F]
+                serve_vals = pull_values(rows_full,
+                                         table.mf_dim)         # [A2, D]
             recvs = []
             for g, ag in enumerate(a_secs):
                 lo = a_off[g]
-                resp_g = expand_pull(
-                    serve_vals,
-                    resp_idx[:, lo:lo + ag].reshape(-1)).reshape(n, ag, d)
-                recv_g = jax.lax.all_to_all(resp_g, DATA_AXIS, 0, 0,
-                                            tiled=True)
+                with scope(trace.SCOPE_PULL):
+                    resp_g = expand_pull(
+                        serve_vals,
+                        resp_idx[:, lo:lo + ag].reshape(-1)
+                    ).reshape(n, ag, d)
+                with scope(trace.SCOPE_A2A_PULL):
+                    recv_g = jax.lax.all_to_all(resp_g, DATA_AXIS, 0, 0,
+                                                tiled=True)
                 recvs.append(recv_g.reshape(n * ag, d))
 
             ins_w = (show > 0).astype(jnp.float32)
@@ -514,17 +543,23 @@ class ShardedTrainStep:
                     seg = segments[k_off[g]:k_off[g] + kg]
                     # global position owner*A + j → chunk-local (ONE
                     # definition, shared with the probe)
-                    local = chunk_local_positions(gi, a, a_off[g], ag)
-                    values_k = expand_pull(recvs[g], local)
-                    parts.append(fused_seqpool_cvm_slot_group(
-                        values_k, seg, batch_show_clk, b, s,
-                        s_off[g], s_off[g] + sg,
-                        self.use_cvm, self.cvm_offset))
-                pooled = jnp.concatenate(parts, axis=1)
-                logits = self.model.apply(params, pooled, dense)
-                ls = optax.sigmoid_binary_cross_entropy(logits, label)
-                loss_local = jnp.sum(ls * ins_w) / jnp.maximum(
-                    wsum_global, 1.0)
+                    with scope(trace.SCOPE_PULL):
+                        local = chunk_local_positions(gi, a, a_off[g],
+                                                      ag)
+                        values_k = expand_pull(recvs[g], local)
+                    with scope(trace.SCOPE_POOL_CVM):
+                        parts.append(fused_seqpool_cvm_slot_group(
+                            values_k, seg, batch_show_clk, b, s,
+                            s_off[g], s_off[g] + sg,
+                            self.use_cvm, self.cvm_offset))
+                with scope(trace.SCOPE_POOL_CVM):
+                    pooled = jnp.concatenate(parts, axis=1)
+                with scope(trace.SCOPE_DENSE):
+                    logits = self.model.apply(params, pooled, dense)
+                with scope(trace.SCOPE_LOSS):
+                    ls = optax.sigmoid_binary_cross_entropy(logits, label)
+                    loss_local = jnp.sum(ls * ins_w) / jnp.maximum(
+                        wsum_global, 1.0)
                 return loss_local, logits
 
             (loss_local, logits), (g_params, g_recvs) = \
@@ -537,25 +572,24 @@ class ShardedTrainStep:
             # dense sync so the exchange overlaps psum/ZeRO-1 (the
             # monolithic path runs them strictly in sequence); merge /
             # apply_push then see exactly the monolithic layout
-            g_vals = jnp.concatenate(
-                [gr.reshape(n, ag, d)
-                 for gr, ag in zip(g_recvs, a_secs)], axis=1)
-            g_back = jax.lax.all_to_all(g_vals, DATA_AXIS, 0, 0,
-                                        tiled=True)
-            params, opt_state = self._dense_sync(state, g_params, me)
-            g_serve = merge_rows(g_back.reshape(n * a, d),
-                                 resp_idx.reshape(n * a), num_segments=a2)
-            gb = jnp.concatenate(
-                [g_serve[:, :2], g_serve[:, 2:] * (-1.0 * b * n)], axis=1)
-            touched = serve_valid > 0
-            table = apply_push(table, serve_rows, gb,
-                               self.sgd_cfg, jax.random.fold_in(rng, me),
-                               rows_full=rows_full, touched=touched,
-                               slot_val=serve_slot)
+            with scope(trace.SCOPE_A2A_PUSH):
+                g_vals = jnp.concatenate(
+                    [gr.reshape(n, ag, d)
+                     for gr, ag in zip(g_recvs, a_secs)], axis=1)
+                g_back = jax.lax.all_to_all(g_vals, DATA_AXIS, 0, 0,
+                                            tiled=True)
+            with scope(trace.SCOPE_DENSE_OPT):
+                params, opt_state = self._dense_sync(state, g_params, me)
+            with scope(trace.SCOPE_PUSH):
+                table = self._push(table, g_back, resp_idx, serve_rows,
+                                   serve_valid, serve_slot, rows_full,
+                                   jax.random.fold_in(rng, me))
 
-        pred = jax.nn.sigmoid(logits)
-        auc = auc_add_batch(auc, pred, label, ins_w)
-        loss = jax.lax.psum(loss_local, DATA_AXIS)
+        with scope(trace.SCOPE_AUC):
+            pred = jax.nn.sigmoid(logits)
+            auc = auc_add_batch(auc, pred, label, ins_w)
+        with scope(trace.SCOPE_LOSS):
+            loss = jax.lax.psum(loss_local, DATA_AXIS)
 
         new_state = ShardedStepState(
             table=table.with_packed(table.packed[None]),
@@ -706,26 +740,31 @@ class ShardedTrainStep:
         """Run every staged global batch of a ShardedResidentPass.
         ``collect_preds`` also returns [nb, N, B] per-batch predictions
         (device-sharded on axis 1) for the post-pass registry replay."""
-        rp.upload()
+        seq = getattr(rp, "pass_seq", None)
+        with trace.span("pass.upload", pass_seq=seq,
+                        staged=rp.dev is not None):
+            rp.upload()
         nb = rp.num_batches
         fmt = getattr(rp, "fmt", None)
         fmt_key = tuple(sorted(fmt.items())) if fmt else None
         c = chunk or nb
         i = 0
         chunks = []
-        while i < nb:
-            n = min(c, nb - i)
-            out = self._resident_runner(
-                n, fmt_key, getattr(rp, "capacity", 0) or 0,
-                collect=collect_preds,
-                sections=getattr(rp, "sections", ()))(
-                state, rp.dev, jnp.asarray(i, jnp.int32), rng)
-            if collect_preds:
-                state, preds = out
-                chunks.append(preds)
-            else:
-                state = out
-            i += n
+        with trace.span("pass.dispatch", pass_seq=seq,
+                        chunks=-(-nb // c)):
+            while i < nb:
+                n = min(c, nb - i)
+                out = self._resident_runner(
+                    n, fmt_key, getattr(rp, "capacity", 0) or 0,
+                    collect=collect_preds,
+                    sections=getattr(rp, "sections", ()))(
+                    state, rp.dev, jnp.asarray(i, jnp.int32), rng)
+                if collect_preds:
+                    state, preds = out
+                    chunks.append(preds)
+                else:
+                    state = out
+                i += n
         if not collect_preds:
             return state, None
         return state, (chunks[0] if len(chunks) == 1
@@ -1223,15 +1262,32 @@ class ShardedTrainer:
         lax.fori_loop inside the shard_map program — per-step host work
         and H2D hops are zero; embedding all_to_all / dense psum happen
         inside the loop body exactly as in the streaming step."""
+        prebuilt = isinstance(pass_or_dataset, ShardedResidentPass)
+        seq = (pass_or_dataset.pass_seq if prebuilt else None) \
+            or trace.next_pass_seq()
+        # the pass boundary under the same span names as
+        # Trainer.train_pass_resident (obs/trace)
+        with trace.span("pass.train", pass_seq=seq) as sp:
+            out, rp = self._train_pass_resident(pass_or_dataset, seq,
+                                                log_prefix)
+            sp.attrs.update(records=rp.num_records,
+                            batches=rp.num_batches)
+        return out
+
+    def _train_pass_resident(self, pass_or_dataset, seq: int,
+                             log_prefix: str):
+        """The body of ``train_pass_resident`` → (result, the pass)."""
         from paddlebox_tpu.metrics import auc_compute
         from paddlebox_tpu.utils import Timer
         from paddlebox_tpu.utils.logging import get_logger
         log = get_logger(__name__)
         timer = Timer()
         timer.start()
-        rp = (pass_or_dataset
-              if isinstance(pass_or_dataset, ShardedResidentPass)
-              else self.build_resident_pass(pass_or_dataset))
+        if isinstance(pass_or_dataset, ShardedResidentPass):
+            rp = pass_or_dataset
+        else:
+            rp = self.build_resident_pass(pass_or_dataset)
+            rp.pass_seq = seq
         want_metrics = len(self.metrics) > 0
         if want_metrics and rp.side is None:
             log.warning(
@@ -1239,39 +1295,41 @@ class ShardedTrainer:
                 "prebuilt ShardedResidentPass predates them; rebuild it "
                 "with build_resident_pass, or use train_pass")
             want_metrics = False
-        rp.upload()
         # consume span: links back to this pass's build span on the
         # preloader lane (obs/trace — the build→consume flow arrow)
-        from paddlebox_tpu.obs import trace
         with trace.span("pass.consume",
                         link_from=getattr(rp, "_trace_span_id", 0)):
             self.state, preds = self.step_fn.run_resident(
                 self.state, rp, self._rng, collect_preds=want_metrics)
-            jax.block_until_ready(self.state.step)
+            with trace.span("pass.device_wait"):
+                jax.block_until_ready(self.state.step)
         rp.mark_trained_rows(self.table)
         if want_metrics:
             self._feed_registry_resident(rp, preds)
         self.global_step += rp.num_batches
         timer.pause()
-        self.table.state = self.state.table
-        res = auc_compute(self._finalize_auc(self.state.auc))
-        out = res.as_dict()
-        out.update(batches=rp.num_batches, elapsed_sec=timer.elapsed_sec(),
-                   examples_per_sec=rp.num_records /
-                   max(timer.elapsed_sec(), 1e-9))
-        log.info("%ssharded resident pass: %d global batches, %.0f ex/s, "
-                 "auc=%.4f", log_prefix, rp.num_batches,
-                 out["examples_per_sec"], res.auc)
-        from paddlebox_tpu.obs.hub import emit_pass_event
-        ev = dict(out, global_step=self.global_step)
-        pr = getattr(self, "_last_exchange_probe", None)
-        if pr is not None:
-            # measured by train/a2a_probe (the sharded bench runs it);
-            # rides the pass event → telemetry_report's "a2a ovl" column
-            ev["exchange_overlap_frac"] = pr["exchange_overlap_frac"]
-        emit_pass_event("train_pass_resident_sharded", ev,
-                        table=self.table, examples=rp.num_records)
-        return out
+        with trace.span("pass.finish"):
+            self.table.state = self.state.table
+            res = auc_compute(self._finalize_auc(self.state.auc))
+            out = res.as_dict()
+            out.update(batches=rp.num_batches,
+                       elapsed_sec=timer.elapsed_sec(),
+                       examples_per_sec=rp.num_records /
+                       max(timer.elapsed_sec(), 1e-9))
+            log.info("%ssharded resident pass: %d global batches, "
+                     "%.0f ex/s, auc=%.4f", log_prefix, rp.num_batches,
+                     out["examples_per_sec"], res.auc)
+            from paddlebox_tpu.obs.hub import emit_pass_event
+            ev = dict(out, global_step=self.global_step)
+            pr = getattr(self, "_last_exchange_probe", None)
+            if pr is not None:
+                # measured by train/a2a_probe (the sharded bench runs
+                # it); rides the pass event → telemetry_report's
+                # "a2a ovl" column
+                ev["exchange_overlap_frac"] = pr["exchange_overlap_frac"]
+            emit_pass_event("train_pass_resident_sharded", ev,
+                            table=self.table, examples=rp.num_records)
+        return out, rp
 
 
 class ShardedResidentPass:
@@ -1294,6 +1352,8 @@ class ShardedResidentPass:
         # monolithic schedule. Set by build(); rides into
         # run_resident's per-schedule executable.
         self.sections: tuple = ()
+        # the pass's identifier on every span of every lane (obs/trace)
+        self.pass_seq: Optional[int] = None
         # host side channels for the post-pass registry replay
         # ({label, show, uid, rank, cmatch} as [nb, N, B], None where a
         # batch lacked the channel) — set by build(); kept OUT of the
@@ -1610,7 +1670,8 @@ class ShardedResidentPass:
         """Per-shard touched flags for this pass's served rows, set AFTER
         training (same delta-save rationale as ResidentPass)."""
         sr = self.arrays["serve_rows"]  # [nb, N, A2]
-        with table.host_lock:
+        with trace.span("pass.mark_trained", pass_seq=self.pass_seq,
+                        rows=int(sr.size)), table.host_lock:
             for s in range(sr.shape[1]):
                 rows = np.unique(sr[:, s])
                 rows = rows[rows < table.capacity]

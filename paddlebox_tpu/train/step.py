@@ -29,6 +29,7 @@ import optax
 
 from paddlebox_tpu.data.batch import SlotBatch
 from paddlebox_tpu.metrics import AucState, auc_add_batch
+from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops import fused_seqpool_cvm
 from paddlebox_tpu.ps.sgd import SparseSGDConfig
 from paddlebox_tpu.ps.table import (PullIndex, TableState, apply_push,
@@ -280,27 +281,40 @@ class TrainStep:
     # ---- the traced step ----
     def _step(self, state: StepState, batch: DeviceBatch,
               rng: jax.Array) -> Tuple[StepState, Dict[str, jax.Array]]:
+        # every op of the step sits under one pbox.* scope of
+        # obs/trace's catalog (metadata only), so a profile names device
+        # time by what the step does and obs/xplane reduces it
+        scope = jax.named_scope
         b, s = self.batch_size, self.num_slots
-        batch_show_clk = jnp.stack([batch.show, batch.clk], axis=1)
-        ins_w = (batch.show > 0).astype(jnp.float32)  # mask tail padding
+        with scope(trace.SCOPE_POOL_CVM):
+            batch_show_clk = jnp.stack([batch.show, batch.clk], axis=1)
+        with scope(trace.SCOPE_LOSS):
+            ins_w = (batch.show > 0).astype(jnp.float32)  # mask padding
 
         # ONE gather serves both the pull values and the push optimizer
         # state (AoS rows — see TableState)
-        rows_full = gather_full_rows(state.table, batch.unique_rows)
-        vals_u = pull_values(rows_full, state.table.mf_dim)
+        with scope(trace.SCOPE_PULL):
+            rows_full = gather_full_rows(state.table, batch.unique_rows)
+            vals_u = pull_values(rows_full, state.table.mf_dim)
 
         pool_segs = getattr(batch, "pool_segments", batch.segments)
 
         def loss_fn(params, vals_u):
-            values_k = expand_pull(vals_u, batch.gather_idx)
-            pooled = fused_seqpool_cvm(
-                values_k, pool_segs, batch_show_clk, b, s,
-                self.use_cvm, self.cvm_offset, 0.0, self.need_filter,
-                0.2, 1.0, 0.96, self.quant_ratio,
-                key_valid=batch.key_valid)
-            logits = self.model.apply(params, pooled, batch.dense)
-            ls = optax.sigmoid_binary_cross_entropy(logits, batch.label)
-            loss = jnp.sum(ls * ins_w) / jnp.maximum(jnp.sum(ins_w), 1.0)
+            with scope(trace.SCOPE_PULL):
+                values_k = expand_pull(vals_u, batch.gather_idx)
+            with scope(trace.SCOPE_POOL_CVM):
+                pooled = fused_seqpool_cvm(
+                    values_k, pool_segs, batch_show_clk, b, s,
+                    self.use_cvm, self.cvm_offset, 0.0, self.need_filter,
+                    0.2, 1.0, 0.96, self.quant_ratio,
+                    key_valid=batch.key_valid)
+            with scope(trace.SCOPE_DENSE):
+                logits = self.model.apply(params, pooled, batch.dense)
+            with scope(trace.SCOPE_LOSS):
+                ls = optax.sigmoid_binary_cross_entropy(logits,
+                                                        batch.label)
+                loss = jnp.sum(ls * ins_w) / jnp.maximum(jnp.sum(ins_w),
+                                                         1.0)
             return loss, logits
 
         (loss, logits), (g_params, g_vals_u) = jax.value_and_grad(
@@ -312,20 +326,23 @@ class TrainStep:
         # PushMergeCopy/DedupKeys contract for free). Embed grads are scaled
         # by -batch_size as in PushCopy (box_wrapper.cu:368-372: the in-table
         # adagrad ADDS ratio*g/g_show, so push carries the negated sum-grad).
-        g_vals_u = jnp.concatenate(
-            [g_vals_u[:, :2], g_vals_u[:, 2:] * (-1.0 * b)], axis=1)
-        # touched derives from the dup-free unique_rows contract inside
-        # apply_push; slot is host metadata (EmbeddingTable.slot_host) —
-        # no segment op spent on either
-        table = apply_push(state.table, batch.unique_rows, g_vals_u,
-                           self.sgd_cfg, rng, rows_full=rows_full)
+        with scope(trace.SCOPE_PUSH):
+            g_vals_u = jnp.concatenate(
+                [g_vals_u[:, :2], g_vals_u[:, 2:] * (-1.0 * b)], axis=1)
+            # touched derives from the dup-free unique_rows contract
+            # inside apply_push; slot is host metadata
+            # (EmbeddingTable.slot_host) — no segment op spent on either
+            table = apply_push(state.table, batch.unique_rows, g_vals_u,
+                               self.sgd_cfg, rng, rows_full=rows_full)
 
-        updates, opt_state = self.tx.update(g_params, state.opt_state,
-                                            state.params)
-        params = optax.apply_updates(state.params, updates)
+        with scope(trace.SCOPE_DENSE_OPT):
+            updates, opt_state = self.tx.update(g_params, state.opt_state,
+                                                state.params)
+            params = optax.apply_updates(state.params, updates)
 
-        pred = jax.nn.sigmoid(logits)
-        auc = auc_add_batch(state.auc, pred, batch.label, ins_w)
+        with scope(trace.SCOPE_AUC):
+            pred = jax.nn.sigmoid(logits)
+            auc = auc_add_batch(state.auc, pred, batch.label, ins_w)
 
         new_state = StepState(table=table, params=params,
                               opt_state=opt_state, auc=auc,

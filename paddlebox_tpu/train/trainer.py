@@ -1086,8 +1086,8 @@ class Trainer:
         mode gives up by design — with a dump configured, fall back to
         the streaming pass (for a prebuilt ResidentPass that is
         impossible, so raise instead of silently writing no dump)."""
-        from paddlebox_tpu.train.device_pass import (ResidentPass,
-                                                     ResidentPassRunner)
+        from paddlebox_tpu.obs import trace
+        from paddlebox_tpu.train.device_pass import ResidentPass
         if self._dump_cfg is not None:
             if isinstance(pass_or_dataset, ResidentPass):
                 raise ValueError(
@@ -1097,6 +1097,25 @@ class Trainer:
             log.warning("dump configured: falling back to streaming "
                         "train_pass for this pass")
             return self.train_pass(pass_or_dataset, log_prefix)
+        prebuilt = isinstance(pass_or_dataset, ResidentPass)
+        seq = (pass_or_dataset.pass_seq if prebuilt else None) \
+            or trace.next_pass_seq()
+        # the pass boundary, spanned where the work happens (obs/trace;
+        # docs/OBSERVABILITY.md §Tracing): pass.train is the parent of
+        # consume (upload, dispatch, device_wait), mark_trained, finish
+        with trace.span("pass.train", pass_seq=seq) as sp:
+            out, rp = self._train_pass_resident(pass_or_dataset, seq,
+                                                log_prefix)
+            sp.attrs.update(records=rp.num_records,
+                            batches=rp.num_batches)
+        return out
+
+    def _train_pass_resident(self, pass_or_dataset, seq: int,
+                             log_prefix: str):
+        """The body of ``train_pass_resident`` → (result, the pass)."""
+        from paddlebox_tpu.obs import trace
+        from paddlebox_tpu.train.device_pass import (ResidentPass,
+                                                     ResidentPassRunner)
         want_metrics = len(self.metrics) > 0
         timer = Timer()
         timer.start()
@@ -1106,7 +1125,8 @@ class Trainer:
             rp = pass_or_dataset
         else:
             with st.stage("build"):
-                rp = ResidentPass.build(pass_or_dataset, self.table)
+                rp = ResidentPass.build(pass_or_dataset, self.table,
+                                        pass_seq=seq)
         trivial = rp.segs is None
         wire = getattr(rp, "wire", "dedup")
         key = (rp.key_capacity, trivial, wire, rp.chunk_bits)
@@ -1121,14 +1141,14 @@ class Trainer:
         # loop is one XLA program; the block is the honest device time).
         # The consume span links back to the pass's build span on the
         # preloader lane (obs/trace — the cross-thread flow arrow)
-        from paddlebox_tpu.obs import trace
         with trace.span("pass.consume",
                         link_from=getattr(rp, "_trace_span_id", 0)), \
                 st.stage("step"):
             self.state, preds = runner.run_pass(
                 self.state, rp, self._rng,
                 collect_preds=want_metrics and rp.side is not None)
-            jax.block_until_ready(self.state.step)
+            with trace.span("pass.device_wait"):
+                jax.block_until_ready(self.state.step)
         rp.mark_trained_rows(self.table)
         if want_metrics:
             if rp.side is None:
@@ -1141,21 +1161,23 @@ class Trainer:
                     self._feed_registry_resident(rp, preds)
         self.global_step += rp.num_batches
         timer.pause()
-        self.sync_table()
-        res = auc_compute(self.state.auc)
-        out = res.as_dict()
-        out.update(batches=rp.num_batches, elapsed_sec=timer.elapsed_sec(),
-                   examples_per_sec=rp.num_records /
-                   max(timer.elapsed_sec(), 1e-9))
-        if FLAGS.check_nan_inf and math.isnan(out.get("auc", 0.0)):
-            raise NanInfError(f"nan metrics after resident pass "
-                              f"at step {self.global_step}")
-        log.info("%sresident pass done: %d batches, %.0f ex/s, auc=%.4f",
-                 log_prefix, rp.num_batches, out["examples_per_sec"],
-                 res.auc)
-        self._emit_pass("train_pass_resident", out, rp.num_records,
-                        stage_timers=True)
-        return out
+        with trace.span("pass.finish"):
+            self.sync_table()
+            res = auc_compute(self.state.auc)
+            out = res.as_dict()
+            out.update(batches=rp.num_batches,
+                       elapsed_sec=timer.elapsed_sec(),
+                       examples_per_sec=rp.num_records /
+                       max(timer.elapsed_sec(), 1e-9))
+            if FLAGS.check_nan_inf and math.isnan(out.get("auc", 0.0)):
+                raise NanInfError(f"nan metrics after resident pass "
+                                  f"at step {self.global_step}")
+            log.info("%sresident pass done: %d batches, %.0f ex/s, "
+                     "auc=%.4f", log_prefix, rp.num_batches,
+                     out["examples_per_sec"], res.auc)
+            self._emit_pass("train_pass_resident", out, rp.num_records,
+                            stage_timers=True)
+        return out, rp
 
     def train_passes_resident(self, datasets: Iterable[Dataset],
                               depth: Optional[int] = None,

@@ -2,6 +2,7 @@
 """Render a run's telemetry JSONL as a per-pass summary table.
 
 Usage: python scripts/telemetry_report.py RUN.jsonl [--events]
+       python scripts/telemetry_report.py --xplane TRACE_DIR
 
 Reads the event stream the TelemetryHub's JsonlSink wrote
 (FLAGS_telemetry_jsonl=..., or bench.py's BENCH_telemetry.jsonl) and
@@ -10,6 +11,12 @@ prints one row per pass: throughput, stage breakdown, queue stalls
 events of the same process), table occupancy and the HBM peak.
 ``--events`` appends the non-pass events (stragglers, scatter warmups)
 at the end. Stdlib only — runs anywhere the JSONL lands.
+
+``--xplane TRACE_DIR`` instead reduces a ``jax.profiler`` trace
+(``utils.profiler.trace()``, ``jax.profiler.start_trace``) by the
+program's own names: device self time by ``pbox.*`` scope and each
+device's idle gaps by the program span the main lane was in
+(``paddlebox_tpu/obs/xplane.py``; this mode needs jax to read the file).
 """
 
 from __future__ import annotations
@@ -482,6 +489,16 @@ def render_report(events: List[dict], show_events: bool = False) -> str:
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    if "--xplane" in argv:
+        at = argv.index("--xplane")
+        if at + 1 >= len(argv):
+            print(__doc__, file=sys.stderr)
+            return 2
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        from paddlebox_tpu.obs import xplane
+        print(xplane.report(argv[at + 1]))
+        return 0
     show_events = "--events" in argv
     paths = [a for a in argv if not a.startswith("--")]
     if not paths:

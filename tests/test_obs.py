@@ -331,8 +331,11 @@ def test_serving_report_column():
 
 def test_add_sink_dual_capability_registers_both(fresh_hub):
     """Regression (ISSUE 10 satellite): a sink exposing BOTH emit and
-    span used to be silently registered span-only — its events were
-    dropped. It must land in both lists; kind= narrows explicitly."""
+    span_full used to be silently registered span-only — its events
+    were dropped. It must land in both lists; kind= narrows explicitly.
+    A span sink is classified by ``span_full`` alone (every span comes
+    through ``obs.trace.span``)."""
+    from paddlebox_tpu.obs import trace
 
     class Dual:
         def __init__(self):
@@ -341,8 +344,8 @@ def test_add_sink_dual_capability_registers_both(fresh_hub):
         def emit(self, ev):
             self.events.append(ev)
 
-        def span(self, name, start, dur, attrs):
-            self.spans.append(name)
+        def span_full(self, rec):
+            self.spans.append(rec["name"])
 
         def close(self):
             pass
@@ -352,7 +355,7 @@ def test_add_sink_dual_capability_registers_both(fresh_hub):
     assert d in fresh_hub.event_sinks()
     assert d in fresh_hub.span_sinks()
     fresh_hub.emit("tick")
-    with fresh_hub.span("s1"):
+    with trace.span("s1"):
         pass
     assert [e["event"] for e in d.events] == ["tick"]
     assert d.spans == ["s1"]
@@ -365,6 +368,15 @@ def test_add_sink_dual_capability_registers_both(fresh_hub):
         fresh_hub.add_sink(Dual(), kind="bogus")
     with pytest.raises(TypeError):
         fresh_hub.add_sink(object())
+
+    class PlainOnly:                      # the removed PR 1 surface
+        def span(self, name, start, dur, attrs):
+            pass
+
+    with pytest.raises(TypeError):
+        fresh_hub.add_sink(PlainOnly())
+    with pytest.raises(TypeError):
+        fresh_hub.add_sink(PlainOnly(), kind="span")
     # close_sinks closes a dual sink exactly once
     closes = []
 
@@ -375,17 +387,6 @@ def test_add_sink_dual_capability_registers_both(fresh_hub):
     fresh_hub.add_sink(CountingDual())
     fresh_hub.close_sinks()
     assert len(closes) == 1
-
-
-def test_chrome_span_sink(fresh_hub):
-    from paddlebox_tpu.obs import ChromeSpanSink
-    from paddlebox_tpu.utils.profiler import ChromeTraceWriter
-    w = ChromeTraceWriter()
-    fresh_hub.add_sink(ChromeSpanSink(w))
-    with fresh_hub.span("stage_x", pass_id=3):
-        pass
-    assert w._events and w._events[0]["name"] == "stage_x"
-    assert w._events[0]["args"] == {"pass_id": 3}
 
 
 # ---- channel gauges ----------------------------------------------------

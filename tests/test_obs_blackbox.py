@@ -51,6 +51,43 @@ def test_bundle_schema_and_ring(fresh_hub, tmp_path):
     assert "passes_total" in b["health"]
 
 
+def test_bundle_carries_spans_from_the_trace_ring(fresh_hub, tmp_path):
+    """The recorder keeps no span ring of its own: the bundle takes the
+    newest spans of obs/trace's ring when it is written — those that ran
+    before the recorder was installed, with no sink attached, too."""
+    from paddlebox_tpu.obs import trace
+    trace.reset()
+    with trace.span("pass.train", pass_seq=3, records=10):
+        with trace.span("pass.mark_trained"):
+            pass
+    rec = FlightRecorder(str(tmp_path), ring_events=4)
+    assert not hasattr(rec, "span_full") and not hasattr(rec, "span")
+    flightrec.install_recorder(rec)
+    assert rec in fresh_hub.event_sinks()
+    assert rec not in fresh_hub.span_sinks()
+    for i in range(6):                   # older spans fall off the cut
+        with trace.span("later", i=i):
+            pass
+    b = json.load(open(flightrec.trigger("manual", reason="spans")))
+    spans = [r for r in b["ring"] if r.get("rec") == "span"]
+    assert [r["attrs"]["i"] for r in spans] == [2, 3, 4, 5]
+    trace.reset()
+    with trace.span("pass.train", pass_seq=3, records=10):
+        with trace.span("pass.mark_trained"):
+            pass
+    rec2 = FlightRecorder(str(tmp_path / "b"), ring_events=64)
+    b = json.load(open(rec2.trigger("manual")))
+    spans = {r["name"]: r for r in b["ring"] if r.get("rec") == "span"}
+    assert set(spans) == {"pass.train", "pass.mark_trained"}
+    assert spans["pass.train"]["pass_seq"] == 3
+    assert spans["pass.train"]["attrs"] == {"records": 10}
+    assert spans["pass.mark_trained"]["parent_id"] == \
+        spans["pass.train"]["span_id"]
+    assert spans["pass.mark_trained"]["pass_seq"] == 3
+    assert spans["pass.train"]["dur"] >= spans["pass.mark_trained"]["dur"]
+    trace.reset()
+
+
 def test_debounce_and_retention(fresh_hub, tmp_path):
     rec = FlightRecorder(str(tmp_path), debounce_sec=600.0, keep=2)
     flightrec.install_recorder(rec)

@@ -31,11 +31,68 @@ def fresh_hub():
 
 # ---- span layer --------------------------------------------------------
 def test_span_inert_without_sinks(fresh_hub):
+    """"Off" means no sink: the ring gets the span, no sink is called
+    and no counter moves."""
     assert not trace.tracing_active()
-    with trace.span("x") as h:
-        assert h is trace.NULL_SPAN
-        assert h.span_id == 0
+    with trace.span("x", k=1) as h:
+        assert h.span_id > 0 and h.lane == trace.LANE_MAIN
+    (rec,) = trace.recent_spans()
+    assert (rec.name, rec.span_id, rec.attrs) == ("x", h.span_id, {"k": 1})
+    assert rec.dur_ns >= 0 and rec.t0_ns > 0
+    assert not fresh_hub.active
     assert fresh_hub.snapshot() == {}  # no instrument was created
+
+
+def test_ring_is_bounded_and_reset_clears_it(fresh_hub):
+    for i in range(trace.RING_SPANS + 10):
+        with trace.span("tick", i=i):
+            pass
+    spans = trace.recent_spans()
+    assert len(spans) == trace.RING_SPANS
+    assert spans[0].attrs == {"i": 10}           # the oldest fell off
+    assert spans[-1].attrs == {"i": trace.RING_SPANS + 9}
+    assert spans is not trace.recent_spans()     # a copy each time
+    trace.reset()
+    assert trace.recent_spans() == []
+
+
+def test_parent_pass_seq_and_link_survive_without_sinks(fresh_hub):
+    """Identity is the ring's own: ids, nesting per thread, the pass a
+    child inherits from its parent, a pass set late on the handle, and
+    the cross-thread link, all with nothing attached."""
+    box = {}
+
+    def producer():
+        trace.set_lane(trace.LANE_PRELOAD)
+        with trace.span("pass.build", pass_seq=7) as hb:
+            with trace.span("build.front"):
+                pass
+        box["sid"] = hb.span_id
+
+    t = threading.Thread(target=producer)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    with trace.span("pass.wait") as hw:
+        hw.pass_seq = 7                  # known only once the pass pops
+    with trace.span("pass.train", pass_seq=7):
+        with trace.span("pass.consume", link_from=box["sid"]):
+            pass
+    with trace.span("elsewhere"):
+        pass
+    by = {r.name: r for r in trace.recent_spans()}
+    assert by["build.front"].parent_id == by["pass.build"].span_id
+    assert by["build.front"].lane == trace.LANE_PRELOAD
+    assert by["pass.consume"].parent_id == by["pass.train"].span_id
+    assert by["pass.consume"].link_from == by["pass.build"].span_id
+    assert by["pass.train"].parent_id == 0
+    for name in ("pass.build", "build.front", "pass.wait", "pass.train",
+                 "pass.consume"):
+        assert by[name].pass_seq == 7, name
+    assert by["elsewhere"].pass_seq is None
+    assert len({r.span_id for r in by.values()}) == len(by)
+    a, b = trace.next_pass_seq(), trace.next_pass_seq()
+    assert b == a + 1
 
 
 def test_span_nesting_and_parent_ids(fresh_hub):
@@ -131,35 +188,6 @@ def test_cross_thread_span_links(fresh_hub):
     flows = [e for e in w._events if e["ph"] in ("s", "f")]
     assert {e["ph"] for e in flows} == {"s", "f"}
     assert all(e["id"] == box["sid"] for e in flows)
-
-
-def test_plain_span_sinks_receive_causal_spans(fresh_hub):
-    """A sink with only the PR 1 span(name, start, dur, attrs) surface
-    still receives causal spans (lane/pass_seq folded into attrs) —
-    the add_sink dual/kind semantics themselves are covered in
-    tests/test_obs.py."""
-
-    class PlainSink:
-        def __init__(self):
-            self.spans = []
-
-        def span(self, name, start, dur, attrs):
-            self.spans.append((name, attrs))
-
-        def close(self):
-            pass
-
-    sink = PlainSink()
-    fresh_hub.add_sink(sink)
-    with fresh_hub.span("stage_y"):
-        pass
-    with trace.span("causal_z", pass_seq=3):
-        pass
-    assert [n for n, _ in sink.spans] == ["stage_y", "causal_z"]
-    attrs = sink.spans[1][1]
-    assert attrs["lane"] == "main" and attrs["pass_seq"] == 3
-    with pytest.raises(TypeError):
-        fresh_hub.add_sink(sink.spans, kind="span")  # list: no span()
 
 
 # ---- critical-path math ------------------------------------------------
