@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 
-def dedup_rows(rows: jax.Array, capacity: int) -> Tuple[jax.Array, jax.Array]:
+def dedup_rows(rows: jax.Array, capacity: int
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Dedup per-key row ids into a compacted unique list.
 
     Args:
@@ -33,14 +34,20 @@ def dedup_rows(rows: jax.Array, capacity: int) -> Tuple[jax.Array, jax.Array]:
       capacity: table row capacity (sentinel row id).
 
     Returns:
-      (unique_rows, gather_idx): int32 [K] unique row list, and int32 [K]
-      mapping each key to its unique position — the (unique_rows,
-      gather_idx) contract of ``PullIndex``. Padding positions (≥ U) hold
+      (unique_rows, gather_idx, num_unique): int32 [K] unique row list,
+      int32 [K] mapping each key to its unique position — the
+      (unique_rows, gather_idx) contract of ``PullIndex`` — and the
+      distinct count U as an int32 scalar on the device. U counts the
+      sentinel entry that pad keys collapse into: ``gather_idx`` points
+      at it, so it lies inside ``[0, U)``. Padding positions (≥ U) hold
       DISTINCT out-of-bounds values > capacity, never pointed at by
-      gather_idx, so that (a) gathers through them clamp to the zero
-      sentinel row and (b) table scatters can promise ``unique_indices``
-      (OOB updates drop) — the difference between a vectorized and a
-      serialized TPU scatter.
+      gather_idx, so that gathers through them clamp to the zero
+      sentinel row and table scatters drop them. (They do NOT let
+      ``apply_push`` promise ``unique_indices``: it scatters LINES, and
+      rows that share a line repeat one.) The unique axis is K wide
+      whatever U is, and a TPU gather or scatter costs per index, pad
+      or real: U is what lets ``gather_full_rows`` / ``apply_push`` stop
+      at the rows the batch touched.
     """
     k = rows.shape[0]
     # ONE sort carrying original positions — replaces the earlier
@@ -59,7 +66,7 @@ def dedup_rows(rows: jax.Array, capacity: int) -> Tuple[jax.Array, jax.Array]:
     # uid slot (commutes); pads prefill with distinct OOB ids
     oob = capacity + 1 + pos
     unique_rows = oob.at[uid_sorted].set(sr)
-    return unique_rows, gather_idx
+    return unique_rows, gather_idx, uid_sorted[-1] + 1
 
 
 def dedup_keys_first_seen(

@@ -122,7 +122,14 @@ class TableState:
     gather/scatter touch 16 strided tiles — measured 2.2x slower than
     one contiguous line per row. The packed layout keeps rows lane-
     contiguous at zero memory waste; gathers fetch whole lines and
-    extract in-register, pushes scatter-ADD masked line deltas.
+    extract in-register, pushes scatter-ADD masked line deltas: one
+    [U, 128] delta line a unique slot, zero outside the row's lane span,
+    so rows that share a line add into disjoint lanes (exact, in any
+    order) and the scatter's line indices REPEAT — it is never a
+    ``unique_indices`` scatter. Its cost is per slot of the unique axis,
+    pad or real, not per line of the table (measured, PERF.md §6 PR 27):
+    a unique axis that knows its distinct count lets the gather and the
+    push stop there (``num_unique`` of gather_full_rows / apply_push).
 
     Why AoS and not per-field SoA: a TPU scatter/gather costs per INDEX,
     not per byte — nine per-field scatters were 9x the price of one
@@ -517,8 +524,9 @@ def fill_oob_pads(unique_rows: np.ndarray, u: int, capacity: int) -> None:
 
     This is the unique-scatter invariant shared by every host index
     builder: pads must never collide with real rows OR each other, so
-    gathers through them clamp to the zero sentinel row, scatters drop
-    them, and apply_push can promise ``unique_indices`` to XLA."""
+    gathers through them clamp to the zero sentinel row and scatters
+    drop them (apply_push promises no ``unique_indices``: it scatters
+    lines, and rows sharing a line repeat one)."""
     n = len(unique_rows) - u
     unique_rows[u:] = capacity + np.arange(1, n + 1, dtype=np.int32)
 
@@ -549,7 +557,55 @@ def init_table_state(capacity: int, mf_dim: int,
                       ext)
 
 
-def gather_full_rows(state: TableState, unique_rows: jax.Array) -> jax.Array:
+#: slots one trip of the counted gather / push loops visits (below)
+PUSH_CHUNK = 8192
+
+
+def push_chunk(u: int) -> int:
+    """Slots a trip of the counted loops visits on a unique axis of
+    ``u`` slots (``gather_full_rows`` / ``apply_push`` given a
+    ``num_unique``): a constant of the shapes, never of the data."""
+    return min(PUSH_CHUNK, u)
+
+
+def push_chunks(u: int, num_unique: Optional[jax.Array]):
+    """Trips the counted loops make over ``[0, num_unique)``: int32
+    ``ceil(num_unique / push_chunk(u))``, computed on the device; with
+    no count, the trips that cover the whole axis (an int)."""
+    c = push_chunk(u)
+    if num_unique is None:
+        return -(-u // c)
+    return (num_unique.astype(jnp.int32) + (c - 1)) // c
+
+
+def _chunk_start(i: jax.Array, c: int, u: int) -> jax.Array:
+    """First slot of trip ``i``. Where ``c`` does not divide ``u`` the
+    last trip is moved back to end at ``u`` and so overlaps the one
+    before it: its slots below ``i * c`` were visited already."""
+    return jnp.minimum(i * c, u - c)
+
+
+def _extract_rows(state: TableState, rows: jax.Array) -> jax.Array:
+    """Line gather + in-register extract of clamped row ids → [n, F]."""
+    rpl, fp, _ = state.geometry
+    n = rows.shape[0]
+    if FLAGS.use_pallas_gather:
+        _book_dispatch("gather_rows", "pallas")
+        lines = gather_rows(state.packed, rows // rpl)
+    else:
+        _book_dispatch("gather_rows", "xla")
+        lines = state.packed[rows // rpl]                 # [n, 128]
+    grouped = lines.reshape(n, rpl, fp)
+    onehot = _lane_onehot(rows % rpl, rpl, lines.dtype)   # [n, rpl]
+    # elementwise mask+reduce, NOT einsum (default-precision dot_general
+    # would round through bf16 on TPU); where-select, NOT multiply, so a
+    # NaN row cannot bleed across its storage line (_lane_select)
+    vals = _lane_select(onehot, grouped).sum(axis=1)
+    return vals[:, :state._feat] if fp != state._feat else vals
+
+
+def gather_full_rows(state: TableState, unique_rows: jax.Array,
+                     num_unique: Optional[jax.Array] = None) -> jax.Array:
     """ONE line-gather of complete feature rows → [U, 8+mf_dim].
 
     Each logical row lives lane-contiguous inside one 128-wide storage
@@ -561,23 +617,34 @@ def gather_full_rows(state: TableState, unique_rows: jax.Array) -> jax.Array:
     scripts/profile_keypath2.py, round 5). Pad/OOB ids are clamped to
     the SENTINEL row before the line split so they read its zeros —
     clamping raw line indices instead would let a far-OOB id alias a
-    real row when capacity % rows_per_line == rpl-1."""
-    rpl, fp, _ = state.geometry
-    u = unique_rows.shape[0]
+    real row when capacity % rows_per_line == rpl-1.
+
+    ``num_unique`` (int32 scalar ON THE DEVICE, or None): the caller's
+    promise that every slot at or after it is a pad. A TPU gather costs
+    per index, pad or real, so with a count the gather runs over
+    ``[0, num_unique)`` only, as ``push_chunks`` trips of ``push_chunk``
+    slots (a ``fori_loop`` whose trip count is data), each writing its
+    rows into a zero [U, F] buffer. The slots never visited stay zero,
+    which is what a pad reads from the zero sentinel row: the result is
+    bit-identical to the single gather's. ``None`` is the single gather,
+    for every unique axis that a host already cut to the distinct
+    count's bucket (the dedup wire, ``DeviceBatch``, the sharded
+    steps)."""
     rows = jnp.minimum(unique_rows, state.capacity)
-    if FLAGS.use_pallas_gather:
-        _book_dispatch("gather_rows", "pallas")
-        lines = gather_rows(state.packed, rows // rpl)
-    else:
-        _book_dispatch("gather_rows", "xla")
-        lines = state.packed[rows // rpl]                 # [U, 128]
-    grouped = lines.reshape(u, rpl, fp)
-    onehot = _lane_onehot(rows % rpl, rpl, lines.dtype)   # [U, rpl]
-    # elementwise mask+reduce, NOT einsum (default-precision dot_general
-    # would round through bf16 on TPU); where-select, NOT multiply, so a
-    # NaN row cannot bleed across its storage line (_lane_select)
-    vals = _lane_select(onehot, grouped).sum(axis=1)
-    return vals[:, :state._feat] if fp != state._feat else vals
+    if num_unique is None:
+        return _extract_rows(state, rows)
+    u = rows.shape[0]
+    c = push_chunk(u)
+
+    def body(i, buf):
+        at = _chunk_start(i, c, u)
+        # an overlapping last trip rewrites what the trip before wrote
+        vals = _extract_rows(state, jax.lax.dynamic_slice(rows, (at,), (c,)))
+        return jax.lax.dynamic_update_slice(buf, vals, (at, 0))
+
+    return jax.lax.fori_loop(
+        0, push_chunks(u, num_unique), body,
+        jnp.zeros((u, state._feat), state.packed.dtype))
 
 
 _SCATTER_CHUNK_FNS: Dict[tuple, object] = {}
@@ -887,6 +954,7 @@ def apply_push(
     rows_full: Optional[jax.Array] = None,  # [U_pad, F] from gather_full_rows
     touched: Optional[jax.Array] = None,    # bool [U_pad]; None → derived
     slot_val: Optional[jax.Array] = None,   # f32 [U_pad]; None → keep col
+    num_unique: Optional[jax.Array] = None,  # int32 scalar: pads from here
 ) -> TableState:
     """In-table optimizer on merged grads — dy_mf_update_value
     (optimizer.cuh.h:80) + scatter write-back.
@@ -899,6 +967,25 @@ def apply_push(
     exactly; pad rows are masked to zero delta so in-bounds-aliasing pads
     write nothing. NOTE: ``old + (new − old)`` can differ from ``new`` by
     1 ulp — both train paths share this op, so path-parity is exact.
+
+    ``unique_indices`` is NOT promised to the scatter and cannot be:
+    the indices are LINES, and rows sharing a line repeat one.
+
+    ``num_unique`` (int32 scalar on the device, or None) is
+    ``gather_full_rows``'s promise: every slot at or after it is a pad.
+    A TPU scatter costs per update, dropped or not, so with a count the
+    scatter-add runs over ``[0, num_unique)`` only: ``push_chunks`` trips
+    of ``push_chunk`` slots in a ``fori_loop`` that carries ``packed``
+    (in place). The optimizer mathematics above it stays U-wide, so
+    every row draws the random numbers its position drew before. The
+    slots left out are pads: their delta is masked to zero (``touched``
+    is false past the sentinel) and they are out of bounds or alias the
+    table's last line, so leaving them out adds nothing that was added
+    before but zeros: the table is bit-identical to the single
+    scatter's (adds into a line's disjoint lanes commute exactly).
+    Where ``push_chunk`` does not divide U the last trip overlaps the
+    one before, and its slots already visited are sent out of bounds
+    and dropped, so no delta is added twice.
 
     ``rows_full`` lets the caller reuse the rows gathered for the pull
     (gather_full_rows) instead of re-gathering here. ``touched`` defaults
@@ -915,7 +1002,7 @@ def apply_push(
         # the trailing re-zero).
         touched = unique_rows < state.capacity
     if rows_full is None:
-        rows_full = gather_full_rows(state, unique_rows)
+        rows_full = gather_full_rows(state, unique_rows, num_unique)
     mf_dim = state.mf_dim
     mf_end = NUM_FIXED + mf_dim
     rows = RowState(
@@ -949,7 +1036,27 @@ def apply_push(
             [delta, jnp.zeros((u, fp - state._feat), delta.dtype)], axis=1)
     onehot = _lane_onehot(unique_rows % rpl, rpl, delta.dtype)
     d_lines = _lane_select(onehot, delta[:, None, :]).reshape(u, 128)
-    packed = state.packed.at[unique_rows // rpl].add(d_lines, mode="drop")
+    if num_unique is None:
+        packed = state.packed.at[unique_rows // rpl].add(d_lines,
+                                                        mode="drop")
+    else:
+        c = push_chunk(u)
+        n_lines = state.packed.shape[0]
+
+        def body(i, packed):
+            at = _chunk_start(i, c, u)
+            line = jax.lax.dynamic_slice(unique_rows, (at,), (c,)) // rpl
+            if u % c:
+                # the overlapping last trip: drop what was added already
+                line = jnp.where(
+                    at + jnp.arange(c, dtype=jnp.int32) >= i * c,
+                    line, n_lines)
+            return packed.at[line].add(
+                jax.lax.dynamic_slice(d_lines, (at, 0), (c, 128)),
+                mode="drop")
+
+        packed = jax.lax.fori_loop(0, push_chunks(u, num_unique), body,
+                                   state.packed)
     # keep the sentinel row zero (defense in depth — pad deltas are
     # masked, but eval's miss collapse reads it)
     cap = state.capacity

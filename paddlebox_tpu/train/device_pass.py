@@ -41,6 +41,7 @@ from paddlebox_tpu.ops.bitpack import (pack_delta, pack_delta_auto,
                                        unpack_u12, unpack_u16m,
                                        unpack_u18, unpack_u24)
 from paddlebox_tpu.ops.device_unique import dedup_rows
+from paddlebox_tpu.ps.table import push_chunk, push_chunks
 from paddlebox_tpu.train.step import (dequantize_floats, pack_floats,
                                       quantize_floats, unpack_floats)
 from paddlebox_tpu.utils.logging import get_logger
@@ -136,6 +137,9 @@ class ResidentPass:
         # the pass's identifier on every span of every lane
         # (obs/trace.next_pass_seq), given by whoever makes the pass
         self.pass_seq: Optional[int] = None
+        # trips of the counted push loops, one device scalar a program
+        # the pass ran as (ResidentPassRunner.run_pass / push_slots)
+        self.push_trips: List[jax.Array] = []
 
     @property
     def num_batches(self) -> int:
@@ -1007,8 +1011,12 @@ class _BatchView:
 
     def __init__(self, unique_rows, gather_idx, key_valid, segments,
                  dense, label, show, clk,
-                 segments_trivial=False) -> None:
+                 segments_trivial=False, num_unique=None) -> None:
         self.unique_rows = unique_rows
+        # the distinct count on the device, where the unique axis was
+        # built there at key-count width (compact wire); None where a
+        # host cut the axis to the count's bucket (dedup wire)
+        self.num_unique = num_unique
         self.gather_idx = gather_idx
         self.key_valid = key_valid
         self.segments = segments
@@ -1158,11 +1166,11 @@ class ResidentPassRunner:
             key_valid = (pos < num_keys).astype(jnp.float32)
             dense, label, show, clk = self._decode_floats(floats, qmeta)
         with jax.named_scope(trace.SCOPE_DEDUP):
-            uniq, gidx = dedup_rows(rows, self.capacity)
+            uniq, gidx, num_unique = dedup_rows(rows, self.capacity)
         return _BatchView(
             uniq, gidx, key_valid, segments,
             dense=dense, label=label, show=show, clk=clk,
-            segments_trivial=self.trivial)
+            segments_trivial=self.trivial, num_unique=num_unique)
 
     def _run(self, n_steps: int, collect: bool = False):
         key = (n_steps, collect)
@@ -1170,7 +1178,7 @@ class ResidentPassRunner:
             def run(state, uniq_t, gidx_t, floats_p, meta_p,
                     segs_p, qmeta, start, rng):
                 def body(i, carry):
-                    state, rng, preds = carry
+                    state, rng, preds, pushed = carry
                     # compact wire: gidx slot carries the PASS-global
                     # arena chunk map, not per-batch data — don't index
                     # (slicing the staged pass is the decode's)
@@ -1194,14 +1202,20 @@ class ResidentPassRunner:
                         # metric registry feed (AddAucMonitor role)
                         preds = jax.lax.dynamic_update_index_in_dim(
                             preds, stats["pred"], i - start, 0)
-                    return state, rng, preds
+                    # trips of the step's counted push; an uncounted
+                    # push covers its whole unique axis
+                    pushed = pushed + stats.get(
+                        "push_chunks",
+                        push_chunks(view.unique_rows.shape[0], None))
+                    return state, rng, preds, pushed
 
                 preds0 = (jnp.zeros((n_steps, floats_p.shape[1]),
                                     jnp.float32) if collect
                           else jnp.zeros((), jnp.float32))
-                state, _, preds = jax.lax.fori_loop(
-                    start, start + n_steps, body, (state, rng, preds0))
-                return state, preds
+                state, _, preds, pushed = jax.lax.fori_loop(
+                    start, start + n_steps, body,
+                    (state, rng, preds0, jnp.zeros((), jnp.int32)))
+                return state, preds, pushed
 
             self._jit[key] = jax.jit(run, donate_argnums=(0,))
         return self._jit[key]
@@ -1210,7 +1224,9 @@ class ResidentPassRunner:
                  chunk: Optional[int] = None, collect_preds: bool = False):
         """Run every batch of the staged pass → (state, preds or None);
         ``collect_preds`` returns [nb, B] per-batch device predictions
-        (the post-pass metric registry feed)."""
+        (the post-pass metric registry feed). The trips the counted
+        pushes made stay on the device, on the pass, for
+        ``push_slots``."""
         with trace.span("pass.upload", pass_seq=rp.pass_seq,
                         staged=rp.dev is not None):
             rp.upload()
@@ -1218,12 +1234,14 @@ class ResidentPassRunner:
         c = chunk if chunk is not None else (self.chunk or nb)
         i = 0
         chunks = []
+        rp.push_trips = []
         with trace.span("pass.dispatch", pass_seq=rp.pass_seq,
                         chunks=-(-nb // c)):
             while i < nb:
                 n = min(c, nb - i)
-                state, preds = self._run(n, collect_preds)(
+                state, preds, pushed = self._run(n, collect_preds)(
                     state, *rp.dev, jnp.asarray(i, jnp.int32), rng)
+                rp.push_trips.append(pushed)
                 if collect_preds:
                     chunks.append(preds)
                 i += n
@@ -1231,6 +1249,19 @@ class ResidentPassRunner:
             return state, None
         return state, (chunks[0] if len(chunks) == 1
                        else jnp.concatenate(chunks, axis=0))
+
+    @staticmethod
+    def push_slots(rp: ResidentPass) -> Tuple[int, int]:
+        """→ (slots of the unique axis the pass's gathers and pushes
+        visited, slots of that axis x steps) of a pass that has run: the
+        trips its programs counted x ``push_chunk``, held to the second
+        (a last trip that overlaps counts whole). They differ only where
+        the steps carry their distinct count (``apply_push``'s
+        ``num_unique``: the compact wire). Reading pulls the counters
+        off the device, so call it once the pass is done."""
+        full = rp.num_batches * rp.unique_capacity
+        trips = sum(int(t) for t in rp.push_trips)
+        return min(trips * push_chunk(rp.unique_capacity), full), full
 
 
 class PassPreloader:

@@ -34,7 +34,7 @@ from paddlebox_tpu.ops import fused_seqpool_cvm
 from paddlebox_tpu.ps.sgd import SparseSGDConfig
 from paddlebox_tpu.ps.table import (PullIndex, TableState, apply_push,
                                     expand_pull, gather_full_rows,
-                                    pull_values)
+                                    pull_values, push_chunks)
 
 
 def pack_floats(dense: np.ndarray, label: np.ndarray, show: np.ndarray,
@@ -126,6 +126,12 @@ class DeviceBatch(NamedTuple):
     @property
     def num_keys(self) -> jax.Array:
         return self.ints_u[-2]
+
+    @property
+    def num_unique(self) -> None:
+        """No count: the host cut ``unique_rows`` to the distinct
+        count's bucket already (``gather_full_rows``)."""
+        return None
 
     @property
     def gather_idx(self) -> jax.Array:
@@ -291,10 +297,14 @@ class TrainStep:
         with scope(trace.SCOPE_LOSS):
             ins_w = (batch.show > 0).astype(jnp.float32)  # mask padding
 
+        # a unique axis built on the device is as wide as the key axis
+        # and says where its pads start: gather and push stop there
+        num_unique = batch.num_unique
         # ONE gather serves both the pull values and the push optimizer
         # state (AoS rows — see TableState)
         with scope(trace.SCOPE_PULL):
-            rows_full = gather_full_rows(state.table, batch.unique_rows)
+            rows_full = gather_full_rows(state.table, batch.unique_rows,
+                                         num_unique)
             vals_u = pull_values(rows_full, state.table.mf_dim)
 
         pool_segs = getattr(batch, "pool_segments", batch.segments)
@@ -333,7 +343,8 @@ class TrainStep:
             # inside apply_push; slot is host metadata
             # (EmbeddingTable.slot_host) — no segment op spent on either
             table = apply_push(state.table, batch.unique_rows, g_vals_u,
-                               self.sgd_cfg, rng, rows_full=rows_full)
+                               self.sgd_cfg, rng, rows_full=rows_full,
+                               num_unique=num_unique)
 
         with scope(trace.SCOPE_DENSE_OPT):
             updates, opt_state = self.tx.update(g_params, state.opt_state,
@@ -353,6 +364,10 @@ class TrainStep:
                  # per-instance preds for the dump subsystem; stays on
                  # device unless a DumpWriter fetches it
                  "pred": pred}
+        if num_unique is not None:
+            # the engagement counter: trips the push's loop made
+            stats["push_chunks"] = push_chunks(
+                batch.unique_rows.shape[0], num_unique)
         return new_state, stats
 
     def _forward(self, table: TableState, params: Any,

@@ -1161,11 +1161,16 @@ class Trainer:
                     self._feed_registry_resident(rp, preds)
         self.global_step += rp.num_batches
         timer.pause()
-        with trace.span("pass.finish"):
+        with trace.span("pass.finish") as sp:
             self.sync_table()
             res = auc_compute(self.state.auc)
             out = res.as_dict()
-            out.update(batches=rp.num_batches,
+            # how much of the unique axis the pushes visited: read with
+            # the AUC, after the device is done (no sync of its own)
+            slots, slots_full = runner.push_slots(rp)
+            sp.attrs.update(push_slots=slots, push_slots_full=slots_full)
+            out.update(push_slots=slots, push_slots_full=slots_full,
+                       batches=rp.num_batches,
                        elapsed_sec=timer.elapsed_sec(),
                        examples_per_sec=rp.num_records /
                        max(timer.elapsed_sec(), 1e-9))

@@ -22,9 +22,10 @@ def test_dedup_rows_matches_numpy():
     for trial in range(5):
         rows = rng.integers(0, cap, size=300).astype(np.int32)
         rows[rng.random(300) < 0.1] = cap  # sentinel (invalid keys)
-        uniq, gidx = jax.jit(dedup_rows, static_argnums=1)(
+        uniq, gidx, n = jax.jit(dedup_rows, static_argnums=1)(
             jnp.asarray(rows), cap)
         uniq, gidx = np.asarray(uniq), np.asarray(gidx)
+        assert n.dtype == jnp.int32 and int(n) == len(np.unique(rows))
         # expansion reconstructs every key's row
         np.testing.assert_array_equal(uniq[gidx], rows)
         ref = np.unique(rows)
@@ -37,8 +38,30 @@ def test_dedup_rows_matches_numpy():
 def test_dedup_rows_all_sentinel():
     cap = 64
     rows = jnp.full(16, cap, jnp.int32)
-    uniq, gidx = dedup_rows(rows, cap)
+    uniq, gidx, n = dedup_rows(rows, cap)
     assert int(uniq[0]) == cap and (np.asarray(gidx) == 0).all()
+    assert int(n) == 1      # the sentinel entry alone
+
+
+@pytest.mark.parametrize("pad_keys,dups", [(0, False), (0, True),
+                                           (40, False), (40, True)])
+def test_dedup_rows_count_includes_the_sentinel_entry(pad_keys, dups):
+    """The third return value is ``len(np.unique(rows))``: the sentinel
+    entry that pad keys collapse into counts, since ``gather_idx``
+    points at it; without pad keys there is no such entry."""
+    rng = np.random.default_rng(pad_keys + dups)
+    cap, k = 900, 256
+    real = k - pad_keys
+    rows = (rng.integers(0, 60, real) if dups
+            else rng.choice(cap, real, replace=False))
+    rows = np.concatenate([rows, np.full(pad_keys, cap)]).astype(np.int32)
+    uniq, gidx, n = dedup_rows(jnp.asarray(rows), cap)
+    n = int(n)
+    assert n == len(np.unique(rows))
+    uniq, gidx = np.asarray(uniq), np.asarray(gidx)
+    assert gidx.max() == n - 1                 # every slot below n is used
+    assert (uniq[:n] <= cap).all() and (uniq[n:] > cap).all()
+    assert (uniq[n - 1] == cap) == (pad_keys > 0)
 
 
 @pytest.fixture(scope="module")
@@ -898,3 +921,89 @@ def test_grid_segment_wire_roundtrip_and_selection():
             (jnp.asarray(enc2[0][i]), jnp.asarray(enc2[1][i])),
             jnp.asarray(meta[i])))
         np.testing.assert_array_equal(got, bad[i])
+
+
+# ---- ISSUE 27: the compact wire's steps carry their distinct count ----
+
+def _withhold_count(monkeypatch):
+    from paddlebox_tpu.train import device_pass
+    monkeypatch.setattr(
+        device_pass, "dedup_rows",
+        lambda rows, cap: dedup_rows(rows, cap)[:2] + (None,))
+
+
+def _step_counts(rp, capacity):
+    """Distinct rows of each step of a compact-wire pass as the device
+    counts them: the step's real keys' rows, and the sentinel entry
+    where the key axis has pads."""
+    out = []
+    for i in range(rp.num_batches):
+        nk = int(rp.meta[i, 0])
+        rows = rp.uniq[i, :nk]
+        assert (rows < capacity).all()
+        out.append(len(np.unique(rows)) + (nk < rp.key_capacity))
+    return out
+
+
+@pytest.mark.parametrize("chunk", [512, 1000, 8192],
+                         ids=["trips-divide-K", "ragged-K", "one-trip"])
+def test_compact_pass_with_count_is_bitwise_the_pass_without(
+        criteo_files, monkeypatch, chunk):
+    """A whole compact-wire resident pass whose gathers and pushes stop
+    at each step's distinct count trains what the same pass trains with
+    the count withheld: table, dense parameters and AUC state bit for
+    bit; and ``pass.finish`` says how many slots the pushes visited."""
+    from paddlebox_tpu.obs import trace
+    from paddlebox_tpu.ps import table as tbl
+    monkeypatch.setattr(tbl, "PUSH_CHUNK", chunk)
+    tr_a, ds = _make_arena(criteo_files)
+    tr_b, _ = _make_arena(criteo_files)
+    outs = {}
+    for name, tr in (("count", tr_a), ("none", tr_b)):
+        with monkeypatch.context() as m:
+            if name == "none":
+                _withhold_count(m)
+            for _ in range(2):
+                rp = ResidentPass.build_streamed(ds, tr.table)
+                assert rp.wire == "compact"
+                assert rp.unique_capacity == rp.key_capacity
+                out = tr.train_pass_resident(rp)
+        fin = [r for r in trace.recent_spans()
+               if r.name == "pass.finish"][-1]
+        outs[name] = (out, rp, fin)
+    assert tr_a.table.rows_digest() == tr_b.table.rows_digest()
+    np.testing.assert_array_equal(
+        np.asarray(tr_a.state.table.packed).view(np.uint32),
+        np.asarray(tr_b.state.table.packed).view(np.uint32))
+    for a, b in zip(jax.tree.leaves((tr_a.state.params, tr_a.state.opt_state,
+                                     tr_a.state.auc)),
+                    jax.tree.leaves((tr_b.state.params, tr_b.state.opt_state,
+                                     tr_b.state.auc))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the engagement counter: sum over the steps of ceil(n_i / C) x C
+    out, rp, fin = outs["count"]
+    k = rp.key_capacity
+    c = min(chunk, k)
+    full = rp.num_batches * k
+    want = min(full, sum(-(-n // c) * c
+                         for n in _step_counts(rp, tr_a.table.capacity)))
+    assert fin.attrs["push_slots"] == out["push_slots"] == want
+    assert fin.attrs["push_slots_full"] == out["push_slots_full"] == full
+    assert (want < full) == (c < k)
+    # with the count withheld every trip is made
+    out, _, fin = outs["none"]
+    assert fin.attrs["push_slots"] == fin.attrs["push_slots_full"] == full
+
+
+def test_dedup_wire_reports_every_slot_pushed(criteo_files):
+    """The dedup wire's unique axis is a host-chosen bucket of the
+    distinct count: it carries no count, and says so."""
+    from paddlebox_tpu.obs import trace
+    tr, ds = _make(criteo_files)
+    rp = ResidentPass.build_streamed(ds, tr.table)
+    assert rp.wire == "dedup"
+    out = tr.train_pass_resident(rp)
+    fin = [r for r in trace.recent_spans() if r.name == "pass.finish"][-1]
+    full = rp.num_batches * rp.unique_capacity
+    assert fin.attrs == {"push_slots": full, "push_slots_full": full}
+    assert out["push_slots"] == out["push_slots_full"] == full

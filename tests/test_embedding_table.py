@@ -376,3 +376,134 @@ def test_push_with_nan_neighbor_keeps_healthy_rows_finite():
     assert np.isfinite(out[1]).all(), "healthy touched row went NaN"
     assert np.isnan(out[2]).any(), "NaN row should persist until shrink"
     assert np.isfinite(out[0]).all() and np.isfinite(out[3:]).all()
+
+
+# ---- the counted gather / push (ISSUE 27): a unique axis built on the
+# device is as wide as the key axis; with its distinct count the two
+# table ops stop at the rows the batch touched --------------------------
+
+_COUNTED_CHUNK = 64
+
+
+def _counted_case(name):
+    """-> (capacity, per-key rows [K] with pad keys at ``capacity``)."""
+    rng = np.random.default_rng(abs(hash(name)) % 1000 + len(name))
+    cap = 1003      # (cap + 1) % 8 == 4: the sentinel shares its line
+    k = 256         # four trips of 64
+    if name == "sentinel-only":
+        rows = np.full(k, cap)
+    elif name == "inside-a-trip":           # 100 distinct + the sentinel
+        rows = np.concatenate([rng.choice(cap, 100, replace=False),
+                               np.full(k - 100, cap)])
+    elif name == "exact-multiple":          # 127 distinct + the sentinel
+        rows = np.concatenate([rng.choice(cap, 127, replace=False),
+                               rng.choice(cap, 1), np.full(k - 128, cap)])
+        rows[127] = rows[0]
+    elif name == "all-distinct":            # num_unique == K, no pad key
+        rows = rng.choice(cap, k, replace=False)
+    elif name == "duplicates-no-pad-key":   # num_unique < K, no sentinel
+        rows = rng.choice(cap, 90, replace=False)[rng.integers(0, 90, k)]
+    elif name == "rows-share-lines":        # eight neighbours a line
+        rows = np.concatenate([np.arange(400, 400 + 150),
+                               np.full(k - 150, cap)])
+    elif name == "ragged-K-all-distinct":   # 250 = 3 x 64 + 58
+        rows = rng.choice(cap, 250, replace=False)
+    elif name == "ragged-K-last-trip-overlaps":   # 200 + sentinel -> 4 trips
+        rows = np.concatenate([rng.choice(cap, 200, replace=False),
+                               np.full(50, cap)])
+    elif name == "ragged-K-three-trips":    # stops before the overlap
+        rows = np.concatenate([rng.choice(cap, 150, replace=False),
+                               np.full(100, cap)])
+    else:
+        raise KeyError(name)
+    return cap, rng.permutation(rows).astype(np.int32)
+
+
+@pytest.mark.parametrize("name", [
+    "sentinel-only", "inside-a-trip", "exact-multiple", "all-distinct",
+    "duplicates-no-pad-key", "rows-share-lines", "ragged-K-all-distinct",
+    "ragged-K-last-trip-overlaps", "ragged-K-three-trips"])
+def test_counted_gather_and_push_are_bitwise_the_single_ops(name,
+                                                            monkeypatch):
+    import jax
+    from paddlebox_tpu.ops.device_unique import dedup_rows
+    from paddlebox_tpu.ps import table as tbl
+    monkeypatch.setattr(tbl, "PUSH_CHUNK", _COUNTED_CHUNK)
+    cap, key_rows = _counted_case(name)
+    mf = 4                                   # feat 12 -> 8 rows a line
+    rng = np.random.default_rng(7)
+    logical = rng.normal(size=(cap + 1, 8 + mf)).astype(np.float32)
+    logical[:, 0:2] = np.abs(logical[:, 0:2])            # show, clk
+    logical[:, 5:7] = np.abs(logical[:, 5:7])            # g2sums
+    logical[:, 7] = (rng.random(cap + 1) < 0.5) * mf     # mf_size
+    logical[::7, 4] = -0.0                   # x + 0.0 must not be added
+    logical[cap] = 0.0
+    st = tbl.TableState.from_logical(logical, cap)
+    uniq, gidx, n = dedup_rows(jnp.asarray(key_rows), cap)
+    k = len(key_rows)
+    assert int(n) == len(np.unique(key_rows))
+    assert int(tbl.push_chunks(k, n)) == -(-int(n) // _COUNTED_CHUNK)
+    # random draws reach the table (lazy mf creation): every row must
+    # see the numbers its position drew before
+    cfg = SparseSGDConfig(mf_create_thresholds=0.0, mf_initial_range=0.5)
+    grads = jnp.asarray(rng.normal(size=(k, 3 + mf)).astype(np.float32))
+    key = jax.random.PRNGKey(5)
+
+    @jax.jit
+    def both(st, uniq, n):
+        rows_1 = tbl.gather_full_rows(st, uniq)
+        rows_n = tbl.gather_full_rows(st, uniq, n)
+        new_1 = tbl.apply_push(st, uniq, grads, cfg, key, rows_full=rows_1)
+        new_n = tbl.apply_push(st, uniq, grads, cfg, key, rows_full=rows_n,
+                               num_unique=n)
+        # and the push that gathers for itself
+        new_g = tbl.apply_push(st, uniq, grads, cfg, key, num_unique=n)
+        return rows_1, rows_n, new_1.packed, new_n.packed, new_g.packed
+
+    rows_1, rows_n, p_1, p_n, p_g = map(np.asarray, both(st, uniq, n))
+    as_bits = lambda a: a.view(np.uint32)    # noqa: E731
+    np.testing.assert_array_equal(as_bits(rows_n), as_bits(rows_1))
+    np.testing.assert_array_equal(as_bits(p_n), as_bits(p_1))
+    np.testing.assert_array_equal(as_bits(p_g), as_bits(p_1))
+    assert not np.array_equal(p_1, np.asarray(st.packed)) or int(n) == 1
+    # the sentinel stays zero and real rows really moved
+    assert not np.any(tbl.unpack_host(p_n, cap, 8 + mf)[cap])
+
+
+@pytest.mark.parametrize("counted", [False, True],
+                         ids=["no-count", "count"])
+def test_push_without_a_count_lowers_to_the_single_scatter(counted):
+    """A caller that passes no count compiles what it compiled before:
+    one scatter, no loop. With a count the same one scatter sits in a
+    ``while`` whose trip count is data."""
+    import jax
+    from paddlebox_tpu.ps import table as tbl
+    cap, mf, u = 1003, 4, 256
+    cfg = SparseSGDConfig(mf_create_thresholds=0.0, mf_initial_range=0.0)
+    st = tbl.init_table_state(cap, mf)
+
+    def push(st, uniq, grads, n):
+        rows = tbl.gather_full_rows(st, uniq, n if counted else None)
+        return tbl.apply_push(st, uniq, grads, cfg, jax.random.PRNGKey(0),
+                              rows_full=rows,
+                              num_unique=n if counted else None)
+
+    def default_args(st, uniq, grads, n):
+        rows = tbl.gather_full_rows(st, uniq)
+        return tbl.apply_push(st, uniq, grads, cfg, jax.random.PRNGKey(0),
+                              rows_full=rows)
+
+    args = (st, jnp.zeros(u, jnp.int32), jnp.zeros((u, 3 + mf)),
+            jnp.zeros((), jnp.int32))
+    text = jax.jit(push).lower(*args).as_text()
+    plain = jax.jit(default_args).lower(*args).as_text()
+    ops = lambda t, op: t.count('"stablehlo.%s"(' % op)   # noqa: E731
+    # the line scatter-add of the push; the sentinel's re-zero is the
+    # other. (The text of the uncounted form was compared with the
+    # parent commit's when this landed: equal byte for byte.)
+    assert ops(text, "scatter") == ops(plain, "scatter") == 2
+    assert ops(text, "gather") == ops(plain, "gather") == 1
+    loops = text.count("stablehlo.while") - plain.count("stablehlo.while")
+    assert loops == (2 if counted else 0)    # the gather's and the push's
+    if not counted:
+        assert text == plain.replace("default_args", "push")
