@@ -1,9 +1,10 @@
 """One run of one cell: set-up by parts, the window of whole passes, the
 traced passes, the comparison with the plain reference, the result line.
 
-Nothing here knows a cell by name. The cell's configuration file, traffic
-file, entry kind, reference net, limits and per-layer readers are found by
-the names ``BENCHMARK.json`` gives.
+Nothing here knows a cell by name, or a model family by what it compares.
+The cell's configuration file, traffic file, entry kind, family, reference
+net, limits and per-layer readers are found by the names ``BENCHMARK.json``
+and the configuration give.
 """
 
 from __future__ import annotations
@@ -17,11 +18,9 @@ import shutil
 import sys
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List
 
-import numpy as np
-
-from benchmarks import compare, roofline, tracered, traffic as traffic_mod
+from benchmarks import compare, tracered, traffic as traffic_mod
 from benchmarks.peaks import device_peaks
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -85,23 +84,11 @@ def require_tpu(chips: int):
     return devs
 
 
-def seeded_params(ref_model, config: dict, seed: int):
-    """The dense weights, made on the device in one jitted call."""
-    import jax
-    n_slots = len(config["slot_sizes"])
-    mf, dd = int(config["mf_dim"]), int(config["dense_dim"])
-    args = config["model"]["args"]
-    init = jax.jit(lambda key: ref_model.init(key, n_slots, mf, dd, args))
-    return init(jax.random.PRNGKey(seed % (2 ** 31 - 1)))
-
-
-def sample_keys(cols, count: int, seed: int) -> np.ndarray:
-    """A seeded sample of the pass's distinct keys (all, if fewer)."""
-    uniq = np.unique(cols.keys)
-    if len(uniq) <= count:
-        return uniq
-    rng = np.random.default_rng([int(seed), 7])
-    return np.sort(rng.choice(uniq, size=count, replace=False))
+def family_of(config: dict):
+    """The configuration's family file (``families/<family>.py``); a
+    configuration that names none is of family ``ctr``."""
+    return importlib.import_module(
+        "benchmarks.families." + config.get("family", "ctr"))
 
 
 def run_window(entry, seconds: float, watch: CompileWatch) -> dict:
@@ -166,7 +153,7 @@ def run_traced(entry, n_passes: int) -> dict:
     red["batches"] = batches
     red["passes"] = n_passes
     note("trace", window_s=red["window_s"], busy_s_each=red["busy_s_each"],
-         collective_s=red["collective_s"],
+         scopes=red["scopes"], collective_s=red["collective_s"],
          collective_events=red["collective_events"], gaps=red["gaps"])
     return red
 
@@ -183,44 +170,6 @@ def read_layer_metrics(names: List[str], ctx: dict) -> Dict[str, float]:
         if val is not None:
             out[name] = float(val)
     return out
-
-
-def reference_pass(loaded: dict, ref_model, pool, params, chips: int,
-                   keys: np.ndarray, tower_dtype: Optional[str] = None,
-                   fault: Optional[str] = None) -> dict:
-    """The plain reference over the run's first pass, its rows cut to
-    ``keys``."""
-    import jax
-    from benchmarks.reference import ctr
-    config, traffic = loaded["config"], loaded["traffic"]
-    if tower_dtype is None:
-        tower_dtype = config["tower_dtype"]  # as the configuration states
-    ref = ctr.run_pass(
-        ref_model.forward, config, pool[0],
-        int(traffic["batch_per_chip"]) * chips, params,
-        tower_dtype=tower_dtype, fault=fault)
-    at = np.searchsorted(ref["keys"], np.asarray(keys, np.uint64))
-    if not np.array_equal(ref.pop("keys")[at], keys):
-        raise ValueError("a compared key is not of the reference's pass")
-    ref["rows"] = np.asarray(jax.device_get(ref.pop("table")[at]))
-    ref["params"] = jax.device_get(ref["params"])
-    ref["mu"] = jax.device_get(ref["mu"])
-    return ref
-
-
-def first_pass(entry, pool, traffic: dict, seed: int):
-    """The first pass through the window's own call and feed, and what it
-    trained: -> (compared keys, the program's state at them, seconds)."""
-    t0 = time.perf_counter()
-    entry.train(entry.wait())
-    entry.block()
-    t1 = time.perf_counter()
-    keys = sample_keys(pool[0], int(traffic["check_rows"]), seed)
-    state = entry.read_state(keys)
-    state["loss"] = compare.logloss_from_buckets(
-        state.pop("auc_pos"), state.pop("auc_neg"))
-    return keys, state, {"first_pass_s": t1 - t0,
-                         "read_state_s": time.perf_counter() - t1}
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
@@ -248,15 +197,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         "benchmarks.entries." + traffic["entry"])
     ref_model = importlib.import_module(
         "benchmarks.reference.models." + config["reference"])
+    family = family_of(config)
     parts["python_start_s"] = t - t_start
     lap("imports_native_s")
     note("host", cpu_count=os.cpu_count(),
          threads=threading.active_count(), compile_cache=cache_dir,
          device_kind=devs[0].device_kind, chips=len(devs))
 
-    pool = traffic_mod.make_pool(config, traffic, seed)
+    pool = family.make_pool(config, traffic, seed)
     lap("data_pool_s")
-    params = seeded_params(ref_model, config, seed)
+    params = family.seeded_params(ref_model, config, seed)
     init_params = jax.device_get(params)
     lap("weights_s")
     entry = entry_mod.build(config, traffic, pool, params, chips)
@@ -267,7 +217,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     # what the first pass trained is read here and compared once the
     # window has closed
-    keys, prog_state, secs = first_pass(entry, pool, traffic, seed)
+    what, prog_state, secs = family.first_pass(entry, pool, traffic, seed)
     parts.update(secs)
     t = time.perf_counter()
     for _ in range(int(traffic["warm_passes"]) - 1):
@@ -300,15 +250,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     t_ref = time.perf_counter()
-    ref = reference_pass(loaded, ref_model, pool, init_params, chips, keys)
-    early = compare.early_rows(
-        pool[0], int(traffic["batch_per_chip"]) * chips, keys)
-    numbers = compare.compare(prog_state, ref, init_params,
-                              int(config["mf_dim"]), early)
+    ref = family.reference_pass(loaded, ref_model, pool, init_params, chips,
+                                what)
+    numbers = family.numbers(prog_state, ref, init_params, loaded, pool,
+                             chips, what)
     correct, compared = compare.judge(numbers, loaded["limits"])
     note("reference", seconds=time.perf_counter() - t_ref,
          numbers=numbers,
-         worst_leaves=compare.worst_leaves(prog_state, ref, init_params))
+         **family.diagnostics(prog_state, ref, init_params))
 
     rate = win["records"] / win["seconds"] / chips
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -321,16 +270,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "setup_s": {"value": setup_s, "unit": "s"}}
     else:
         peaks = device_peaks(devs[0].device_kind)
-        work = roofline.step_work(
-            config["slot_sizes"], traffic_mod.slot_vocab(config),
-            int(config["mf_dim"]),
-            int(config["dense_dim"]), int(traffic["batch_per_chip"]),
-            chips, traffic, param_shapes)
+        work = family.work(config, traffic, chips, param_shapes)
         ctx = {"window": win, "trace": red, "chips": chips, "rate": rate,
                "peaks": peaks, "work": work,
-               "keys_per_example": int(sum(config["slot_sizes"])),
-               "flops_per_example":
-                   roofline.dense_flops_per_example(param_shapes)}
+               "keys_per_example": work["keys_per_example"],
+               "flops_per_example": work["flops_per_example"]}
         units = {m["name"]: m["unit"] for m in loaded["bench"]["per_layer"]
                  if workload in m.get("workloads", [workload])}
         vals = read_layer_metrics(list(units), ctx)
@@ -339,7 +283,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
         result["breakdown"] = {
-            "device_ops": red["ops"][:10],
+            "device_ops": tracered.scoped_ops(red),
             "idle_gaps": sorted(([k, v] for k, v in red["gaps"].items()),
                                 key=lambda kv: -kv[1])}
     result["device"] = device

@@ -7,9 +7,10 @@ long, a first pass is short):
         [--program-seeds 21,22,23] [--faults half_batch]
 
 ``--seeds``: the plain reference over the cell's first pass, then in the
-program's place (a) the control, the reference with the net's matmul
-operands in the precision below the one the configuration states, and (b)
-the reference with a fault planted. ``--program-seeds``: the timed entry's
+program's place (a) the control, the reference in the precision below the
+one the configuration states, and (b) the reference with a fault planted;
+the configuration's family file (``families/``) names that precision and
+the faults. ``--program-seeds``: the timed entry's
 own first pass against the reference (the lower readings; ``run.py``'s
 runs give more). Every reading is judged against the cell's limits as a
 run's is, and the process exits 1 where a control or a fault comes out
@@ -24,69 +25,58 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-#: the precision below the one a configuration states for its net
-LOWER = {"float32": "bfloat16", "bfloat16": "float8_e4m3fn",
-         "float16": "float8_e4m3fn"}
-#: planted in the reference; a state left unchanged reads 1 and needs no
-#: run, but costs none either
-FAULTS = ("half_batch", "state_unchanged")
-
 
 def _setting(loaded: dict):
+    from benchmarks import harness
     config = loaded["config"]
     ref_model = importlib.import_module(
         "benchmarks.reference.models." + config["reference"])
-    return config, loaded["traffic"], int(loaded["cell"]["chips"]), ref_model
+    return (config, loaded["traffic"], int(loaded["cell"]["chips"]),
+            ref_model, harness.family_of(config))
 
 
-def stand_in_readings(loaded: dict, seeds, faults=FAULTS):
-    """(seed, run, numbers) of the control and of each planted fault."""
+def stand_in_readings(loaded: dict, seeds, faults=None):
+    """(seed, run, numbers) of the control and of each planted fault (the
+    family's, where none are named)."""
     import jax
-    from benchmarks import compare, harness, traffic as traffic_mod
-    config, traffic, chips, ref_model = _setting(loaded)
-    control = LOWER[config["tower_dtype"]]
-    runs = [("control:" + control, dict(tower_dtype=control))]
-    runs += [("fault:" + f, dict(fault=f)) for f in faults]
+    config, traffic, chips, ref_model, family = _setting(loaded)
+    control = family.control_precision(config)
+    runs = [("control:" + control, dict(precision=control))]
+    runs += [("fault:" + f, dict(fault=f))
+             for f in (family.FAULTS if faults is None else faults)]
     for seed in seeds:
-        pool = traffic_mod.make_pool(config, traffic, seed, count=1)
-        params = jax.device_get(harness.seeded_params(ref_model, config,
-                                                      seed))
-        keys = harness.sample_keys(pool[0], int(traffic["check_rows"]), seed)
-        early = compare.early_rows(
-            pool[0], int(traffic["batch_per_chip"]) * chips, keys)
-        ref = harness.reference_pass(loaded, ref_model, pool, params, chips,
-                                     keys)
+        pool = family.make_pool(config, traffic, seed, count=1)
+        params = jax.device_get(family.seeded_params(ref_model, config,
+                                                     seed))
+        what = family.sample(pool, traffic, seed)
+        ref = family.reference_pass(loaded, ref_model, pool, params, chips,
+                                    what)
         for name, kw in runs:
-            other = harness.reference_pass(loaded, ref_model, pool, params,
-                                           chips, keys, **kw)
-            yield seed, name, compare.compare(other, ref, params,
-                                              int(config["mf_dim"]), early)
+            other = family.reference_pass(loaded, ref_model, pool, params,
+                                          chips, what, **kw)
+            yield seed, name, family.numbers(other, ref, params, loaded,
+                                             pool, chips, what)
 
 
 def program_readings(loaded: dict, seeds):
     """(seed, "program", numbers) of the timed entry's own first pass."""
     import jax
-    from benchmarks import compare, harness, traffic as traffic_mod
-    config, traffic, chips, ref_model = _setting(loaded)
+    config, traffic, chips, ref_model, family = _setting(loaded)
     entry_mod = importlib.import_module(
         "benchmarks.entries." + traffic["entry"])
     for seed in seeds:
-        pool = traffic_mod.make_pool(config, traffic, seed, count=2)
-        params = harness.seeded_params(ref_model, config, seed)
+        pool = family.make_pool(config, traffic, seed, count=2)
+        params = family.seeded_params(ref_model, config, seed)
         init = jax.device_get(params)
         entry = entry_mod.build(config, traffic, pool, params, chips)
-        keys, state, _ = harness.first_pass(entry, pool, traffic, seed)
+        what, state, _ = family.first_pass(entry, pool, traffic, seed)
         entry.close()
         del entry, params
-        ref = harness.reference_pass(loaded, ref_model, pool, init, chips,
-                                     keys)
-        early = compare.early_rows(
-            pool[0], int(traffic["batch_per_chip"]) * chips, keys)
-        numbers = compare.compare(state, ref, init, int(config["mf_dim"]),
-                                  early)
-        numbers["early_rows"] = int(early.sum())
-        numbers["worst_leaves"] = compare.worst_leaves(state, ref, init)
-        yield seed, "program", numbers
+        ref = family.reference_pass(loaded, ref_model, pool, init, chips,
+                                    what)
+        numbers = family.numbers(state, ref, init, loaded, pool, chips, what)
+        yield seed, "program", dict(numbers, **family.diagnostics(
+            state, ref, init))
 
 
 def main() -> None:
@@ -94,7 +84,8 @@ def main() -> None:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", default="")
     ap.add_argument("--program-seeds", default="")
-    ap.add_argument("--faults", default=",".join(FAULTS))
+    ap.add_argument("--faults", default=None,
+                    help="comma-separated; the family's where not given")
     args = ap.parse_args()
     from benchmarks import compare, harness
     loaded = harness.load_cell(args.workload)
@@ -110,19 +101,16 @@ def main() -> None:
                                              ints(args.program_seeds)), True),
                            (stand_in_readings(
                                loaded, ints(args.seeds),
+                               args.faults and
                                [f for f in args.faults.split(",") if f]),
                             False)):
         for seed, run, numbers in readings:
-            leaves = numbers.pop("worst_leaves", None)
-            numbers.pop("early_rows", None)
             correct, table = compare.judge(numbers, loaded["limits"])
             failed = [k for k, (v, lim) in table.items() if not v <= lim]
             bad += correct != want
-            line = {"seed": seed, "run": run, "correct": correct,
-                    "failed": failed, "numbers": numbers}
-            if leaves:
-                line["worst_leaves"] = leaves
-            print(json.dumps(line), flush=True)
+            print(json.dumps({"seed": seed, "run": run, "correct": correct,
+                              "failed": failed, "numbers": numbers}),
+                  flush=True)
     sys.exit(1 if bad else 0)
 
 

@@ -417,9 +417,18 @@ def test_traced_run_reports_layers_and_breakdown(on_cpu, monkeypatch,
     """A ``--trace 1`` run with the recorded trace standing in for the
     profiler (the CPU has no device plane): the per-layer metrics of the
     cell and no others, ``busy_s``/``window_s``, a breakdown of at most
-    ten short names, shares in % and under 100."""
+    ten short names, each under its scope, shares in % and under 100.
+    The recording is older than the program's scopes: its ops are given
+    some here, by their place on the line."""
     with open(os.path.join(HERE, "data", "trace_small.json")) as f:
         rec = json.load(f)
+    given = ["pbox.decode", "pbox.dedup", "pbox.pull", "pbox.pull.bwd",
+             "pbox.pool_cvm", "pbox.dense.bwd", "pbox.push", "other"]
+    for plane in rec["trace"]["planes"]:
+        for line in plane["lines"]:
+            if line["name"] == tracered.OPS_LINE:
+                line["events"] = [ev + [given[i % len(given)]]
+                                  for i, ev in enumerate(line["events"])]
 
     def traced(entry, n_passes):
         red = tracered.reduce(rec["trace"])
@@ -443,6 +452,15 @@ def test_traced_run_reports_layers_and_breakdown(on_cpu, monkeypatch,
     bd = res["breakdown"]
     assert 0 < len(bd["device_ops"]) <= 10
     assert all(len(n) <= 64 for n, _ in bd["device_ops"])
+    assert all(n.split("/")[0] in given for n, _ in bd["device_ops"])
+    ops = dict(tracered.reduce(rec["trace"])["ops"])
+    assert all(ops[n.split("/", 1)[1]] == sec for n, sec in bd["device_ops"])
+    # the four scope readers and the ops without a scope are the step
+    parts = sum(res["metrics"][n]["value"] for n in (
+        "step.decode_dedup_ms", "step.pull_pool_ms", "step.dense_ms",
+        "step.push_ms"))
+    step = res["metrics"]["step.ms_per_batch"]["value"]
+    assert 0.5 * step < parts < step
     assert {n for n, _ in bd["idle_gaps"]} == {"wait", "train", "other"}
 
 
