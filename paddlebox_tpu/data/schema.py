@@ -46,6 +46,15 @@ class DataFeedDesc:
     # static padding ladder for flattened sparse keys per batch
     key_bucket_min: int = 1024
     key_bucket_growth: float = 2.0
+    # a SEQUENCE feed (a language model's): a record is one position of a
+    # sequence of ``seq_len`` tokens (0: not a sequence feed), a batch is
+    # whole sequences, the one sparse slot holds the position's token and
+    # the label is an integer id (the next token), which crosses the
+    # resident wire as int32 (train/device_pass)
+    seq_len: int = 0
+    # the key that opens a document in a packed sequence feed (counted
+    # into the pass's ``documents``), or None
+    bos_key: Optional[int] = None
 
     @property
     def sparse_slots(self) -> List[SlotDef]:

@@ -4,6 +4,7 @@ from paddlebox_tpu.models.wide_deep import WideDeep
 from paddlebox_tpu.models.dcn import DCNv2
 from paddlebox_tpu.models.ads_rank import AdsRank
 from paddlebox_tpu.models.mmoe import MMoE, MMoESingle
+from paddlebox_tpu.models.nemotron_h import NemotronH
 
 MODEL_REGISTRY = {
     "ctr_dnn": CtrDnn,
@@ -15,4 +16,4 @@ MODEL_REGISTRY = {
 }
 
 __all__ = ["CtrDnn", "DeepFM", "WideDeep", "DCNv2", "AdsRank",
-           "MMoE", "MMoESingle", "MODEL_REGISTRY"]
+           "MMoE", "MMoESingle", "NemotronH", "MODEL_REGISTRY"]

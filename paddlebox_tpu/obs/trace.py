@@ -121,6 +121,22 @@ STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_POOL_CVM,
                SCOPE_AUC)
 #: the sharded step adds the exchange
 SHARDED_SCOPES = (SCOPE_A2A_PULL, SCOPE_A2A_PUSH)
+#: a sequence model's step (train/step.SeqTrainStep over
+#: models/nemotron_h.py) in place of pool_cvm / dense / auc. One word
+#: after ``pbox.``: the reducers read a scope's name up to the first
+#: character that is no letter, digit or ``_``
+SCOPE_SSM_PROJ = "pbox.ssm_proj"    # norm, in/out projections, gated norm
+SCOPE_SSM_CONV = "pbox.ssm_conv"    # causal depthwise conv + silu
+SCOPE_SSM_SCAN = "pbox.ssm_scan"    # the chunked state-space scan
+SCOPE_ATTN = "pbox.attn"            # norm, q/k/v/o, blockwise attention
+SCOPE_MOE_ROUTE = "pbox.moe_route"  # norm, router, top-k, weights
+SCOPE_MOE_EXPERTS = "pbox.moe_experts"  # sort, grouped products, combine
+SCOPE_MOE_SHARED = "pbox.moe_shared"    # the shared expert
+SCOPE_HEAD = "pbox.head"            # final norm + the output head
+SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_SSM_PROJ,
+                   SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_ATTN,
+                   SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS, SCOPE_MOE_SHARED,
+                   SCOPE_HEAD, SCOPE_LOSS, SCOPE_PUSH, SCOPE_DENSE_OPT)
 
 #: spans kept in memory (about 13 a resident pass: hundreds of passes)
 RING_SPANS = 8192

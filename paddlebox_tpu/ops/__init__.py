@@ -25,6 +25,8 @@ from paddlebox_tpu.ops.seqpool_variants import (
     fused_seqpool_cvm_with_credit, fused_seqpool_cvm_with_pcoc,
 )
 from paddlebox_tpu.ops.seq_tensor import fused_seq_tensor
+from paddlebox_tpu.ops.ssd import ssd_scan
+from paddlebox_tpu.ops.causal_attention import causal_gqa_attention
 
 __all__ = [
     "fused_seqpool_cvm", "fused_seqpool_cvm_with_conv",
@@ -38,5 +40,5 @@ __all__ = [
     "fused_seqpool_cvm_with_credit", "fused_seqpool_cvm_with_pcoc",
     "fused_seq_tensor", "fused_embed_pool_cvm", "segment_gather_mxu",
     "segment_sum_mxu", "fused_rank_attention", "fused_batch_fc",
-    "fused_cross_norm_hadamard",
+    "fused_cross_norm_hadamard", "ssd_scan", "causal_gqa_attention",
 ]

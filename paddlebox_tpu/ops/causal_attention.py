@@ -1,0 +1,163 @@
+"""Causal grouped-query attention, blockwise, forward and backward.
+
+One chip's whole sequence (``parallel/ring_attention`` is the form whose
+key/value blocks travel a mesh axis; its ``_flash_block`` is the step this
+grew from). The [T, T] scores exist for one pair of blocks at a time: the
+forward pass keeps the output and each row's log-sum-exp, the backward
+pass computes every block pair's probabilities again from them. Blocks
+above the diagonal are never visited: query block i loops over key blocks
+0..i, a loop whose trip count is data, which is why the backward pass is
+written out (``jax.custom_vjp``) and not derived.
+
+The ``G = H / KV`` query heads that share a key/value head are one matrix
+side: a query block is [G * block, D] against its key block [block, D].
+Matrix products take ``mm_dtype`` operands (bfloat16) and accumulate in
+float32; the softmax is float32.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+#: in place of -inf under the mask: exp() of it is 0 and no row is NaN
+_NEG = -1e30
+
+
+def _blocks(q, k, v, block):
+    """q [B,T,KV,G,D] -> [nb,B,KV,G*blk,D]; k, v [B,T,KV,D] ->
+    [nb,B,KV,blk,D]."""
+    bsz, t, kv, g, d = q.shape
+    nb = t // block
+    qb = q.reshape(bsz, nb, block, kv, g, d).transpose(1, 0, 3, 4, 2, 5)
+    qb = qb.reshape(nb, bsz, kv, g * block, d)
+    kb = k.reshape(bsz, nb, block, kv, d).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(bsz, nb, block, kv, d).transpose(1, 0, 3, 2, 4)
+    return qb, kb, vb
+
+
+def _unblock_q(ob, shape, block):
+    bsz, t, kv, g, d = shape
+    nb = t // block
+    ob = ob.reshape(nb, bsz, kv, g, block, d).transpose(1, 0, 4, 2, 3, 5)
+    return ob.reshape(bsz, t, kv, g, d)
+
+
+def _unblock_kv(xb, shape):
+    bsz, t, kv, d = shape
+    return xb.transpose(1, 0, 3, 2, 4).reshape(bsz, t, kv, d)
+
+
+def _scores(qi, kj, i, j, block, scale):
+    """Masked scores of query block i against key block j, float32
+    [B,KV,G*blk,blk], and the mask."""
+    s = jnp.einsum("bkmd,bknd->bkmn", qi, kj,
+                   preferred_element_type=jnp.float32) * scale
+    rows = i * block + jnp.arange(qi.shape[2]) % block
+    cols = j * block + jnp.arange(block)
+    mask = rows[:, None] >= cols[None, :]
+    return jnp.where(mask, s, _NEG), mask
+
+
+def _forward(q, k, v, block, scale, mm_dtype):
+    qb, kb, vb = _blocks(q.astype(mm_dtype), k.astype(mm_dtype),
+                         v.astype(mm_dtype), block)
+    nb, bsz, kv, m, d = qb.shape
+    f32 = jnp.float32
+
+    def q_block(i):
+        qi = qb[i]
+
+        def kv_block(j, carry):
+            top, den, acc = carry
+            s, _ = _scores(qi, kb[j], i, j, block, scale)
+            new_top = jnp.maximum(top, jnp.max(s, -1))
+            p = jnp.exp(s - new_top[..., None])
+            corr = jnp.exp(top - new_top)
+            den = den * corr + jnp.sum(p, -1)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bkmn,bknd->bkmd", p.astype(mm_dtype), vb[j],
+                preferred_element_type=f32)
+            return new_top, den, acc
+
+        top, den, acc = jax.lax.fori_loop(
+            0, i + 1, kv_block,
+            (jnp.full((bsz, kv, m), _NEG, f32), jnp.zeros((bsz, kv, m), f32),
+             jnp.zeros((bsz, kv, m, d), f32)))
+        return acc / den[..., None], top + jnp.log(den)
+
+    ob, lse = jax.lax.map(q_block, jnp.arange(nb))
+    return _unblock_q(ob, q.shape, block), (ob, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attention(q, k, v, block, scale, mm_dtype):
+    return _forward(q, k, v, block, scale, mm_dtype)[0]
+
+
+def _attention_fwd(q, k, v, block, scale, mm_dtype):
+    out, (ob, lse) = _forward(q, k, v, block, scale, mm_dtype)
+    return out, (q, k, v, ob, lse)
+
+
+def _attention_bwd(block, scale, mm_dtype, res, dout):
+    q, k, v, ob, lse = res
+    f32 = jnp.float32
+    qb, kb, vb = _blocks(q.astype(mm_dtype), k.astype(mm_dtype),
+                         v.astype(mm_dtype), block)
+    dob = _blocks(dout, k, v, block)[0]
+    delta = jnp.sum(ob * dob, -1)                          # [nb,B,KV,M]
+    dob = dob.astype(mm_dtype)
+    nb = qb.shape[0]
+
+    def q_block(carry, i):
+        qi, doi, lse_i, delta_i = qb[i], dob[i], lse[i], delta[i]
+
+        def kv_block(j, carry):
+            dq, dk, dv = carry
+            s, mask = _scores(qi, kb[j], i, j, block, scale)
+            p = jnp.where(mask, jnp.exp(s - lse_i[..., None]), 0.0)
+            dp = jnp.einsum("bkmd,bknd->bkmn", doi, vb[j],
+                            preferred_element_type=f32)
+            ds = (p * (dp - delta_i[..., None]) * scale).astype(mm_dtype)
+            dq = dq + jnp.einsum("bkmn,bknd->bkmd", ds, kb[j],
+                                 preferred_element_type=f32)
+            dk = dk.at[j].add(jnp.einsum("bkmn,bkmd->bknd", ds, qi,
+                                         preferred_element_type=f32))
+            dv = dv.at[j].add(jnp.einsum("bkmn,bkmd->bknd",
+                                         p.astype(mm_dtype), doi,
+                                         preferred_element_type=f32))
+            return dq, dk, dv
+
+        dk, dv = carry
+        dq, dk, dv = jax.lax.fori_loop(
+            0, i + 1, kv_block, (jnp.zeros(qi.shape, f32), dk, dv))
+        return (dk, dv), dq
+
+    (dk, dv), dq = jax.lax.scan(
+        q_block, (jnp.zeros(kb.shape, f32), jnp.zeros(vb.shape, f32)),
+        jnp.arange(nb))
+    return (_unblock_q(dq, q.shape, block).astype(q.dtype),
+            _unblock_kv(dk, k.shape).astype(k.dtype),
+            _unblock_kv(dv, v.shape).astype(v.dtype))
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+def causal_gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                         block: int = 512, sm_scale=None,
+                         mm_dtype=jnp.bfloat16) -> jax.Array:
+    """q [B,T,H,D], k, v [B,T,KV,D] with H % KV == 0 (query head h reads
+    key/value head h // (H / KV)) -> [B,T,H,D] float32. The block is the
+    largest divisor of T that ``block`` allows."""
+    bsz, t, h, d = q.shape
+    kv = k.shape[2]
+    scale = float(sm_scale) if sm_scale is not None else d ** -0.5
+    blk = math.gcd(t, block)
+    out = _attention(q.reshape(bsz, t, kv, h // kv, d), k, v, blk, scale,
+                     mm_dtype)
+    return out.reshape(bsz, t, h, d)

@@ -5,8 +5,8 @@ from paddlebox_tpu.parallel.layers import (
     pipeline_train_step,
 )
 from paddlebox_tpu.parallel.moe import (
-    moe_forward_local, moe_forward_sharded, naive_gating, top1_gating,
-    top2_gating,
+    moe_forward_local, moe_forward_sharded, naive_gating, route_top_k,
+    routed_experts, top1_gating, top2_gating,
 )
 from paddlebox_tpu.parallel.ring_attention import (
     make_context_parallel_attention, reference_attention, ring_attention,
@@ -18,7 +18,7 @@ __all__ = [
     "column_parallel_linear", "row_parallel_linear", "pipeline_run",
     "pipeline_train_step",
     "moe_forward_local", "moe_forward_sharded", "naive_gating",
-    "top1_gating", "top2_gating",
+    "top1_gating", "top2_gating", "route_top_k", "routed_experts",
     "make_context_parallel_attention", "reference_attention",
     "ring_attention", "ulysses_attention",
 ]

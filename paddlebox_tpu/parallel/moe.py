@@ -12,11 +12,24 @@ single ``jax.lax.all_to_all`` over the ``ep`` mesh axis inside
 ``shard_map`` (replaces the reference's NCCL Global_Scatter/Gather ops).
 Shapes are fully static: capacity drops overflow tokens exactly like the
 reference's capacity gates.
+
+``routed_experts`` is the other kind of expert layer: sigmoid top-k
+routing that DROPS NOTHING, over a layer that is told which experts it
+holds (one chip's share of an expert-parallel deployment), sorted
+token-choices and grouped matrix products over the experts held.
+
+Callers (ROADMAP D5 reads this): ``route_top_k`` and ``routed_experts``
+run in the benchmark (``models/nemotron_h.py``'s ``E`` layers, cell
+``nemotron3-nano-30b-a3b.train-packed-8k``). The capacity gates
+(``top1_gating``, ``top2_gating``, ``naive_gating``),
+``moe_forward_local`` and ``moe_forward_sharded`` (the expert
+``all_to_all``) are test-only: no model of the benchmark calls them.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -174,3 +187,120 @@ def moe_forward_sharded(mesh: Any, axis: str,
         in_specs=(P(axis), P(), P(axis)),
         out_specs=(P(axis), P()),
     )
+
+
+# ---- dropless routing over the experts held here ---------------------------
+
+#: rows of the laid-out axis a chunk computes, and rows of it that belong
+#: to one expert; fewer where the tokens are fewer
+EXPERT_CHUNK = 8192
+EXPERT_BLOCK = 512
+
+
+def route_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
+                top_k: int, scale: float
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid top-k routing (DeepSeek-V3 / Nemotron-H style), float32 at
+    the highest matmul precision: scores ``sigmoid(x W_r)``, the
+    ``top_k`` experts by score + ``bias`` (a correction that only
+    chooses, and gets no gradient), weights = the chosen scores over
+    their sum, times ``scale``. x [N, D] -> (experts [N, k] int32,
+    weights [N, k])."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, w / jnp.sum(w, -1, keepdims=True) * scale
+
+
+def routed_experts(x: jax.Array, idx: jax.Array, w: jax.Array,
+                   up: jax.Array, down: jax.Array,
+                   held: Tuple[int, int], mm_dtype=jnp.bfloat16
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """What the experts ``held = (lo, hi)`` give the tokens routed to
+    them: ``sum_k w[n, k] * down_e(relu(up_e(x[n]))^2)`` over the choices
+    k whose expert ``e = idx[n, k]`` is held; choices of experts that
+    live elsewhere add nothing here. x [N, D]; idx, w [N, k] from
+    ``route_top_k`` over ALL experts; up [hi-lo, D, F], down [hi-lo, F,
+    D]. Returns (y [N, D] float32, {"choices": token-choices that fell
+    on held experts, "load": [hi-lo] of them an expert}).
+
+    No choice is dropped, whatever the imbalance. The token-choices are
+    sorted by expert (held first) and every expert's run of rows is laid
+    out from a multiple of ``EXPERT_BLOCK`` rows, so that a block of rows
+    belongs to ONE expert: the grouped product is then a batched product
+    of [block, D] row blocks with their experts' matrices (gathered a
+    block), exact in its operations but for each expert's last, partly
+    empty block. Shapes are static: the laid-out axis has ``N * min(k,
+    hi-lo) + (hi-lo) * block`` rows, the most that can fall on the held
+    experts, cut into chunks of ``min(N, EXPERT_CHUNK)`` rows (a block
+    is the largest divisor of a chunk that ``EXPERT_BLOCK`` allows);
+    a chunk that starts past the last row in use is skipped
+    (``lax.cond``) and a chunk's work is recomputed in the backward
+    pass, so time and memory follow the choices that are there and not
+    the bound. (A skipped chunk still passes zeros through the scan's
+    backward pass; PERF.md section 7.)"""
+    n, d = x.shape
+    k = idx.shape[1]
+    lo, hi = held
+    n_held = hi - lo
+    bound = n * min(k, n_held)
+    rows = min(n, EXPERT_CHUNK)
+    blk = math.gcd(rows, EXPERT_BLOCK)
+    n_chunks = -(-(bound + n_held * blk) // rows)
+    r_pad = n_chunks * rows
+
+    flat_e = idx.reshape(-1)
+    is_held = (flat_e >= lo) & (flat_e < hi)
+    group = jnp.where(is_held, flat_e - lo, n_held)
+    order = jnp.argsort(group, stable=True)[:bound]
+    load = jnp.sum(group[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                   dtype=jnp.int32)                            # [n_held]
+    # where each expert's run starts: packed (in ``order``), and laid out
+    # from a multiple of ``blk``
+    starts = jnp.cumsum(load) - load
+    laid = -(-load // blk) * blk
+    laid_ends = jnp.cumsum(laid)
+    e_sorted = group[order]
+    live = e_sorted < n_held
+    e_safe = jnp.minimum(e_sorted, n_held - 1)
+    dest = jnp.where(
+        live, jnp.arange(bound, dtype=jnp.int32) - starts[e_safe]
+        + (laid_ends - laid)[e_safe], r_pad)                   # dead: dropped
+    tok = jnp.zeros((r_pad,), jnp.int32).at[dest].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    w_laid = jnp.zeros((r_pad,), w.dtype).at[dest].set(
+        w.reshape(-1)[order], mode="drop")
+    in_use = laid_ends[-1]
+    expert_of_block = jnp.minimum(
+        jnp.searchsorted(laid_ends, jnp.arange(r_pad // blk) * blk,
+                         side="right"), n_held - 1)
+    xm, upm, downm = (x.astype(mm_dtype), up.astype(mm_dtype),
+                      down.astype(mm_dtype))
+    f32 = jnp.float32
+
+    def one_chunk(y, ci):
+        c0 = ci * rows
+
+        # recomputed in the backward pass: the scan then keeps nothing of
+        # a chunk but its number
+        @jax.checkpoint
+        def work(y):
+            t = jax.lax.dynamic_slice(tok, (c0,), (rows,))
+            wt = jax.lax.dynamic_slice(w_laid, (c0,), (rows,))
+            e = jax.lax.dynamic_slice(expert_of_block, (c0 // blk,),
+                                      (rows // blk,))
+            xb = xm[t].reshape(rows // blk, blk, d)
+            hid = jnp.einsum("bmd,bdf->bmf", xb, upm[e],
+                             preferred_element_type=f32)
+            hid = jnp.square(jax.nn.relu(hid)).astype(mm_dtype)
+            out = jnp.einsum("bmf,bfd->bmd", hid, downm[e],
+                             preferred_element_type=f32)
+            # a row that holds no choice has weight 0
+            return y.at[t].add(out.reshape(rows, d) * wt[:, None])
+
+        return jax.lax.cond(c0 < in_use, work, lambda y: y, y), None
+
+    y, _ = jax.lax.scan(one_chunk, jnp.zeros((n, d), f32),
+                        jnp.arange(n_chunks, dtype=jnp.int32))
+    return y, {"choices": jnp.sum(load), "load": load}

@@ -20,6 +20,15 @@ nonetheless first-class here, TPU-native by construction:
 Both run under ``jax.shard_map`` over a mesh axis and are exercised on
 the 8-device CPU mesh in tests (tests/test_ring_attention.py) against a
 single-device reference attention.
+
+Callers (ROADMAP D5 reads this): every function of this module is
+test-only: no model of the benchmark calls ``ring_attention``,
+``ulysses_attention``, ``make_context_parallel_attention`` or
+``reference_attention``. The one-chip form that the benchmark runs
+(cell ``nemotron3-nano-30b-a3b.train-packed-8k``) is
+``ops/causal_attention.causal_gqa_attention``: ``_flash_block``'s online
+softmax with a causal block loop, grouped-query heads and a backward
+pass that recomputes the scores.
 """
 
 from __future__ import annotations

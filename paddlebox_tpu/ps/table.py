@@ -47,12 +47,13 @@ NUM_FIXED = 8  # scalar columns before the embedx block
 
 
 def _f_pad(feat: int) -> int:
-    """Smallest divisor of 128 ≥ feat — the padded logical row width so
-    rows pack evenly into 128-lane storage lines."""
+    """The padded logical row width: the smallest divisor of 128 ≥ feat,
+    so that rows pack evenly into 128-lane storage lines; a row wider
+    than one line takes whole lines (the next multiple of 128)."""
     for d in (1, 2, 4, 8, 16, 32, 64, 128):
         if d >= feat:
             return d
-    raise ValueError(f"feature width {feat} > 128 unsupported")
+    return -(-feat // 128) * 128
 
 
 def _lane_onehot(sub: jax.Array, rpl: int, dtype) -> jax.Array:
@@ -79,11 +80,24 @@ def _lane_select(mask: jax.Array, values: jax.Array) -> jax.Array:
 
 def pack_geometry(capacity: int, feat: int):
     """(rows_per_line, f_pad, n_lines) for a [capacity+1, feat] logical
-    table stored as [n_lines, 128] lane-aligned lines."""
+    table stored as [n_lines, 128] lane-aligned lines. A WIDE row (feat >
+    128, a token's vector) is ``f_pad // 128`` whole consecutive lines:
+    rows_per_line is then 1 and row r starts at line ``r * (f_pad //
+    128)``."""
     fp = _f_pad(feat)
+    if fp > 128:
+        return 1, fp, (capacity + 1) * (fp // 128)
     rpl = 128 // fp
     n_lines = (capacity + 1 + rpl - 1) // rpl
     return rpl, fp, n_lines
+
+
+def _row_lines(rows: jax.Array, fp: int) -> jax.Array:
+    """Line ids [n * lpr] of wide rows ``rows`` [n] (``lpr = fp // 128``
+    consecutive lines a row), row-major."""
+    lpr = fp // 128
+    return (rows[:, None] * lpr
+            + jnp.arange(lpr, dtype=rows.dtype)[None, :]).reshape(-1)
 
 
 def unpack_host(packed: np.ndarray, capacity: int, feat: int) -> np.ndarray:
@@ -91,7 +105,7 @@ def unpack_host(packed: np.ndarray, capacity: int, feat: int) -> np.ndarray:
     copy only for the final column slice)."""
     rpl, fp, n_lines = pack_geometry(capacity, feat)
     lead = packed.shape[:-2]
-    flat = packed.reshape(*lead, n_lines * rpl, fp)
+    flat = packed.reshape(*lead, n_lines * 128 // fp, fp)
     return flat[..., :capacity + 1, :feat]
 
 
@@ -99,7 +113,7 @@ def pack_host(logical: np.ndarray, capacity: int, feat: int) -> np.ndarray:
     """Logical [..., C+1, F] → packed [..., L, 128] (numpy)."""
     rpl, fp, n_lines = pack_geometry(capacity, feat)
     lead = logical.shape[:-2]
-    out = np.zeros((*lead, n_lines * rpl, fp), logical.dtype)
+    out = np.zeros((*lead, n_lines * 128 // fp, fp), logical.dtype)
     out[..., :capacity + 1, :feat] = logical
     return out.reshape(*lead, n_lines, 128)
 
@@ -176,7 +190,7 @@ class TableState:
         ``packed`` directly)."""
         rpl, fp, n_lines = self.geometry
         lead = self.packed.shape[:-2]
-        flat = self.packed.reshape(*lead, n_lines * rpl, fp)
+        flat = self.packed.reshape(*lead, n_lines * 128 // fp, fp)
         return flat[..., :self._capacity + 1, :self._feat]
 
     @property
@@ -450,7 +464,12 @@ def dispatch_packed_row_gather(state: "TableState", shard: Optional[int],
     if fn is None:
         cols = jnp.arange(feat, dtype=jnp.int32)
 
-        if sharded:
+        if fp > 128:   # wide rows: whole lines, then the row's width
+            def run(packed, *args):
+                idx = args[-1]
+                src = packed[args[0]] if sharded else packed
+                return src[_row_lines(idx, fp)].reshape(-1, fp)[:, :feat]
+        elif sharded:
             def run(packed, s, idx):
                 lines = packed[s, idx // rpl]            # [K, 128]
                 off = (idx % rpl * fp)[:, None] + cols[None, :]
@@ -589,6 +608,11 @@ def _extract_rows(state: TableState, rows: jax.Array) -> jax.Array:
     """Line gather + in-register extract of clamped row ids → [n, F]."""
     rpl, fp, _ = state.geometry
     n = rows.shape[0]
+    if fp > 128:
+        # a wide row is whole lines: nothing to extract in-register
+        _book_dispatch("gather_rows", "xla")
+        lines = state.packed[_row_lines(rows, fp)]
+        return lines.reshape(n, fp)[:, :state._feat]
     if FLAGS.use_pallas_gather:
         _book_dispatch("gather_rows", "pallas")
         lines = gather_rows(state.packed, rows // rpl)
@@ -662,7 +686,19 @@ def _scatter_chunk_fn(sharded: bool, rpl: int, fp: int, feat: int):
         return fn
     cols_off = jnp.arange(feat, dtype=jnp.int32)
 
-    if sharded:
+    if fp > 128:   # wide rows: whole lines, the pad columns zero as ever
+        lpr = fp // 128
+
+        def run(packed, *args):
+            rows_c, vals_c = args[-2:]
+            lines = _row_lines(jnp.minimum(rows_c, packed.shape[-2] // lpr),
+                               fp)
+            vals = jnp.pad(vals_c, ((0, 0), (0, fp - feat))).reshape(-1, 128)
+            if sharded:
+                return packed.at[jnp.repeat(args[0], lpr), lines].set(
+                    vals, mode="drop")
+            return packed.at[lines].set(vals, mode="drop")
+    elif sharded:
         def run(packed, shard_c, rows_c, vals_c):
             lines = rows_c // rpl
             cols = (rows_c % rpl * fp)[:, None] + cols_off[None, :]
@@ -945,6 +981,47 @@ def push_stats(gather_idx: jax.Array, key_valid: jax.Array,
     return touched, slot_val
 
 
+def _push_wide_lines(packed: jax.Array, unique_rows: jax.Array,
+                     delta: jax.Array, fp: int,
+                     num_unique: Optional[jax.Array]) -> jax.Array:
+    """``apply_push``'s scatter-add for WIDE rows (a row is ``fp // 128``
+    whole lines, so no two slots share a line and there is no lane
+    select): ``delta`` [U, fp] added at the rows' lines, the slots at or
+    after ``num_unique`` left out by the same counted loop; then the
+    sentinel row's lines are set to zero, as the narrow path does. Pad
+    ids lie past the table and are dropped (clamped first, so that a far
+    id times the lines a row cannot wrap)."""
+    u = delta.shape[0]
+    lpr = fp // 128
+    n_rows = packed.shape[0] // lpr
+    d_lines = delta.reshape(u * lpr, 128)
+    rows = jnp.minimum(unique_rows, n_rows)
+    cap = n_rows - 1
+
+    def zero_sentinel(packed):
+        return packed.at[cap * lpr:(cap + 1) * lpr].set(0.0)
+
+    if num_unique is None:
+        return zero_sentinel(
+            packed.at[_row_lines(rows, fp)].add(d_lines, mode="drop"))
+    c = push_chunk(u)
+
+    def body(i, packed):
+        at = _chunk_start(i, c, u)
+        rows_c = jax.lax.dynamic_slice(rows, (at,), (c,))
+        if u % c:
+            # the overlapping last trip: drop what was added already
+            rows_c = jnp.where(
+                at + jnp.arange(c, dtype=jnp.int32) >= i * c, rows_c,
+                n_rows)
+        return packed.at[_row_lines(rows_c, fp)].add(
+            jax.lax.dynamic_slice(d_lines, (at * lpr, 0), (c * lpr, 128)),
+            mode="drop")
+
+    return zero_sentinel(
+        jax.lax.fori_loop(0, push_chunks(u, num_unique), body, packed))
+
+
 def apply_push(
     state: TableState,
     unique_rows: jax.Array,   # int32 [U_pad]
@@ -1034,6 +1111,9 @@ def apply_push(
     if fp != state._feat:
         delta = jnp.concatenate(
             [delta, jnp.zeros((u, fp - state._feat), delta.dtype)], axis=1)
+    if fp > 128:
+        return state.with_packed(_push_wide_lines(
+            state.packed, unique_rows, delta, fp, num_unique))
     onehot = _lane_onehot(unique_rows % rpl, rpl, delta.dtype)
     d_lines = _lane_select(onehot, delta[:, None, :]).reshape(u, 128)
     if num_unique is None:

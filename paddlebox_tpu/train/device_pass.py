@@ -140,6 +140,10 @@ class ResidentPass:
         # trips of the counted push loops, one device scalar a program
         # the pass ran as (ResidentPassRunner.run_pass / push_slots)
         self.push_trips: List[jax.Array] = []
+        # [steps, len(step.step_scalars)] float32 a program the pass ran
+        # as, where the step hands out per-step scalars (a sequence
+        # step's losses); on the device until the trainer reads them
+        self.step_scalars: List[jax.Array] = []
 
     @property
     def num_batches(self) -> int:
@@ -710,6 +714,8 @@ class ResidentPass:
                 col.keys[a:b], col.key_slot[a:b].astype(np.int16),
                 k_max, pad_seg,
                 None if trivial else segs_global[a:b]))
+        if getattr(desc, "seq_len", 0):
+            return cls._front_sequences(desc, col, per_batch, trivial, nb)
         # float block: pack the whole pass, zero-pad the tail batch
         floats_full = pack_floats(col.dense, col.label, col.show, col.clk)
         d3 = floats_full.shape[1]
@@ -727,6 +733,28 @@ class ResidentPass:
                 "rank": col.rank, "cmatch": col.cmatch,
                 "batch_size": bs, "num_records": r}
         return front + (side,)
+
+    @staticmethod
+    def _front_sequences(desc, col, per_batch, trivial: bool, nb: int):
+        """The rest of the front for a SEQUENCE feed (``desc.seq_len``):
+        a record is one position, its one key the token there and its
+        label the id of the next; a batch is whole sequences. In place of
+        the float block the pass carries the labels as int32 [nb, B, 1]
+        (no dense values; show is 1 at every real position, and a tail
+        batch's pad positions carry label -1)."""
+        bs, r = desc.batch_size, col.num_records
+        if bs % desc.seq_len or not trivial:
+            raise ValueError(
+                f"a sequence feed takes one key a record and batches of "
+                f"whole sequences: batch {bs}, seq_len {desc.seq_len}, "
+                f"one-key layout {trivial}")
+        labels = np.full(nb * bs, -1, np.int32)
+        labels[:r] = col.label
+        side = {"num_records": r, "batch_size": bs,
+                "documents": (None if desc.bos_key is None else
+                              int((col.keys == desc.bos_key).sum()))}
+        return (per_batch, labels.reshape(nb, bs, 1), None, trivial, r,
+                side)
 
     @staticmethod
     def _encode_floats(floats: np.ndarray, floats_dtype):
@@ -1132,6 +1160,11 @@ class ResidentPassRunner:
     def _decode_floats(floats, qmeta):
         if floats.dtype == jnp.uint8:  # q8 wire (quantize_floats)
             return dequantize_floats(floats, qmeta)
+        if floats.dtype == jnp.int32:  # a sequence pass: labels are ids
+            label = floats[:, 0]
+            show = (label >= 0).astype(jnp.float32)
+            return (jnp.zeros((floats.shape[0], 0), jnp.float32), label,
+                    show, jnp.zeros_like(show))
         return unpack_floats(floats)
 
     def _make_view_compact(self, loc_t, cmap, floats, meta, segs,
@@ -1174,11 +1207,15 @@ class ResidentPassRunner:
 
     def _run(self, n_steps: int, collect: bool = False):
         key = (n_steps, collect)
+        # scalars the step hands out for every step of the pass (a
+        # sequence step's loss and expert loads); none for a click step,
+        # whose program is then what it was
+        scalars = getattr(self.step, "step_scalars", ())
         if key not in self._jit:
             def run(state, uniq_t, gidx_t, floats_p, meta_p,
                     segs_p, qmeta, start, rng):
                 def body(i, carry):
-                    state, rng, preds, pushed = carry
+                    state, rng, preds, pushed = carry[:4]
                     # compact wire: gidx slot carries the PASS-global
                     # arena chunk map, not per-batch data — don't index
                     # (slicing the staged pass is the decode's)
@@ -1207,15 +1244,24 @@ class ResidentPassRunner:
                     pushed = pushed + stats.get(
                         "push_chunks",
                         push_chunks(view.unique_rows.shape[0], None))
-                    return state, rng, preds, pushed
+                    if not scalars:
+                        return state, rng, preds, pushed
+                    row = jnp.stack([stats[k].astype(jnp.float32)
+                                     for k in scalars])
+                    return (state, rng, preds, pushed,
+                            jax.lax.dynamic_update_index_in_dim(
+                                carry[4], row, i - start, 0))
 
                 preds0 = (jnp.zeros((n_steps, floats_p.shape[1]),
                                     jnp.float32) if collect
                           else jnp.zeros((), jnp.float32))
-                state, _, preds, pushed = jax.lax.fori_loop(
-                    start, start + n_steps, body,
-                    (state, rng, preds0, jnp.zeros((), jnp.int32)))
-                return state, preds, pushed
+                init = (state, rng, preds0, jnp.zeros((), jnp.int32))
+                if scalars:
+                    init += (jnp.zeros((n_steps, len(scalars)),
+                                       jnp.float32),)
+                state, _, preds, pushed, *per_step = jax.lax.fori_loop(
+                    start, start + n_steps, body, init)
+                return (state, preds, pushed, *per_step)
 
             self._jit[key] = jax.jit(run, donate_argnums=(0,))
         return self._jit[key]
@@ -1235,13 +1281,16 @@ class ResidentPassRunner:
         i = 0
         chunks = []
         rp.push_trips = []
+        rp.step_scalars = []
         with trace.span("pass.dispatch", pass_seq=rp.pass_seq,
                         chunks=-(-nb // c)):
             while i < nb:
                 n = min(c, nb - i)
-                state, preds, pushed = self._run(n, collect_preds)(
+                state, preds, pushed, *per_step = self._run(
+                    n, collect_preds)(
                     state, *rp.dev, jnp.asarray(i, jnp.int32), rng)
                 rp.push_trips.append(pushed)
+                rp.step_scalars.extend(per_step)
                 if collect_preds:
                     chunks.append(preds)
                 i += n

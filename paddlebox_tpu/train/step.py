@@ -393,3 +393,101 @@ class TrainStep:
     def __call__(self, state: StepState, batch: DeviceBatch,
                  rng: jax.Array) -> Tuple[StepState, Dict[str, jax.Array]]:
         return self._jit(state, batch, rng)
+
+
+class SeqTrainStep:
+    """The fused train step of a SEQUENCE model (``model.sequence_model``:
+    ``loss(params, vectors [S, T, D], labels [S, T], valid [S, T]) ->
+    (loss, {name: scalar})``, the names those of ``model.step_scalars``),
+    with ``TrainStep``'s state and call: pull -> net -> per-position loss
+    -> push -> dense update, one traced function.
+
+    What differs from the click step: a record is one position of a
+    sequence and its one key is the token there. The slot is pulled
+    UNPOOLED: position t of sequence s reads the vector (``embedx_w``) of
+    its token's row, [S, T, mf_dim], no ``fused_seqpool_cvm`` and no CVM
+    columns. The label is an integer id a position (the next token) and
+    the loss a mean cross-entropy over the step's positions; there is no
+    AUC. The push carries, a row, its occurrences (show) and the summed
+    vector gradient times -positions, as the click step's does
+    (``apply_push`` and the in-row rule are shared).
+
+    It runs inside the resident pass program only
+    (``ResidentPassRunner`` calls ``_step``): there is no streaming form.
+    ``step_scalars`` names the stats that ``ResidentPassRunner`` hands
+    out for EVERY step of a pass: the loss (a pass-long mean loss is the
+    wrong number to hold against a reference, PERF.md PR 28) and then
+    whatever the model names."""
+
+    def __init__(self, model, tx: optax.GradientTransformation,
+                 sgd_cfg: SparseSGDConfig, batch_size: int,
+                 seq_len: int) -> None:
+        if seq_len <= 0 or batch_size % seq_len:
+            raise ValueError(f"a step is whole sequences: batch "
+                             f"{batch_size}, seq_len {seq_len}")
+        self.model = model
+        self.tx = tx
+        self.sgd_cfg = sgd_cfg
+        self.batch_size = batch_size
+        self.seq_len = seq_len
+        self.num_slots = 1
+        self.step_scalars = ("loss",) + tuple(
+            getattr(model, "step_scalars", ()))
+
+    def init_params(self, mf_dim: int, dense_dim: int) -> Any:
+        del mf_dim, dense_dim   # the model knows its own widths
+        return self.model.init(jax.random.PRNGKey(0))
+
+    init_state = TrainStep.init_state
+
+    def _step(self, state: StepState, batch,
+              rng: jax.Array) -> Tuple[StepState, Dict[str, jax.Array]]:
+        scope = jax.named_scope
+        b, t = self.batch_size, self.seq_len
+        if batch.gather_idx.shape[0] != b:
+            raise ValueError("a sequence step takes one key a position: "
+                             f"{batch.gather_idx.shape[0]} keys, {b} "
+                             f"positions")
+        mf = state.table.mf_dim
+        num_unique = batch.num_unique
+        with scope(trace.SCOPE_PULL):
+            rows_full = gather_full_rows(state.table, batch.unique_rows,
+                                         num_unique)
+            vecs_u = pull_values(rows_full, mf)[:, 3:]           # [U, mf]
+        with scope(trace.SCOPE_LOSS):
+            valid = (batch.show > 0).reshape(b // t, t)
+            labels = jnp.maximum(batch.label.astype(jnp.int32),
+                                 0).reshape(b // t, t)
+
+        def loss_fn(params, vecs_u):
+            with scope(trace.SCOPE_PULL):
+                emb = vecs_u[batch.gather_idx].reshape(b // t, t, mf)
+            return self.model.loss(params, emb, labels, valid)
+
+        (loss, scalars), (g_params, g_vecs_u) = jax.value_and_grad(
+            loss_fn, argnums=(0, 1), has_aux=True)(state.params, vecs_u)
+
+        with scope(trace.SCOPE_PUSH):
+            u = vecs_u.shape[0]
+            g_show = jax.ops.segment_sum(batch.show * batch.key_valid,
+                                         batch.gather_idx, num_segments=u)
+            zero = jnp.zeros((u, 2), jnp.float32)   # no click, no embed_w
+            g_vals_u = jnp.concatenate(
+                [g_show[:, None], zero, g_vecs_u * (-1.0 * b)], axis=1)
+            table = apply_push(state.table, batch.unique_rows, g_vals_u,
+                               self.sgd_cfg, rng, rows_full=rows_full,
+                               num_unique=num_unique)
+
+        with scope(trace.SCOPE_DENSE_OPT):
+            updates, opt_state = self.tx.update(g_params, state.opt_state,
+                                                state.params)
+            params = optax.apply_updates(state.params, updates)
+
+        new_state = StepState(table=table, params=params,
+                              opt_state=opt_state, auc=state.auc,
+                              step=state.step + 1)
+        stats = dict(scalars, loss=loss)
+        if num_unique is not None:
+            stats["push_chunks"] = push_chunks(
+                batch.unique_rows.shape[0], num_unique)
+        return new_state, stats

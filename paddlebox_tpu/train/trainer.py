@@ -32,8 +32,8 @@ from paddlebox_tpu.data.dataset import Dataset, InMemoryDataset
 from paddlebox_tpu.metrics import (AucResult, MetricRegistry, auc_compute,
                                    init_auc_state)
 from paddlebox_tpu.ps.table import EmbeddingTable, PullIndex
-from paddlebox_tpu.train.step import (DeviceBatch, StepState, TrainStep,
-                                      make_device_batch)
+from paddlebox_tpu.train.step import (DeviceBatch, SeqTrainStep, StepState,
+                                      TrainStep, make_device_batch)
 from paddlebox_tpu.utils import Channel, Timer
 from paddlebox_tpu.utils.logging import get_logger
 
@@ -76,9 +76,19 @@ class Trainer:
                 table.mf_dim, desc.dense_dim, use_cvm=use_cvm)
             scales = build_lr_scales(params, lr_map, lr_map_base)
             self.tx = optax.chain(self.tx, lr_map_transform(scales))
-        self.step_fn = TrainStep(
-            model, self.tx, table.cfg, desc.batch_size,
-            len(desc.sparse_slots), use_cvm=use_cvm, rng_seed=seed)
+        if getattr(model, "sequence_model", False):
+            # not (pooled, dense) -> logit: the slot pulled unpooled, an
+            # id label and a loss a position (train/step.SeqTrainStep);
+            # trained through train_pass_resident
+            if lr_map or not getattr(desc, "seq_len", 0):
+                raise ValueError("a sequence model takes a sequence feed "
+                                 "(desc.seq_len) and no lr_map")
+            self.step_fn = SeqTrainStep(model, self.tx, table.cfg,
+                                        desc.batch_size, desc.seq_len)
+        else:
+            self.step_fn = TrainStep(
+                model, self.tx, table.cfg, desc.batch_size,
+                len(desc.sparse_slots), use_cvm=use_cvm, rng_seed=seed)
         if params is None:
             params = self.step_fn.init_params(table.mf_dim, desc.dense_dim)
         self.state = self.step_fn.init_state(table.state, params,
@@ -1161,6 +1171,8 @@ class Trainer:
                     self._feed_registry_resident(rp, preds)
         self.global_step += rp.num_batches
         timer.pause()
+        if getattr(self.step_fn, "step_scalars", ()):
+            return self._finish_sequence_pass(rp, runner, timer), rp
         with trace.span("pass.finish") as sp:
             self.sync_table()
             res = auc_compute(self.state.auc)
@@ -1183,6 +1195,45 @@ class Trainer:
             self._emit_pass("train_pass_resident", out, rp.num_records,
                             stage_timers=True)
         return out, rp
+
+    def _finish_sequence_pass(self, rp, runner, timer) -> Dict[str, Any]:
+        """``pass.finish`` of a sequence model's pass: no AUC; the
+        per-step scalars the pass program handed out come off the device
+        here, once: each step's loss, and what the model names
+        (``model.step_scalars``: name -> ``sum`` or ``mean`` over the
+        pass's steps), which go on the span and into the result under
+        the model's own names."""
+        from paddlebox_tpu.obs import trace
+        with trace.span("pass.finish") as sp:
+            self.sync_table()
+            per_step = np.concatenate(
+                [np.asarray(jax.device_get(a)) for a in rp.step_scalars])
+            col = {k: per_step[:, i].astype(np.float64)
+                   for i, k in enumerate(self.step_fn.step_scalars)}
+            slots, slots_full = runner.push_slots(rp)
+            counters = dict(
+                push_slots=slots, push_slots_full=slots_full,
+                tokens=rp.num_records,
+                documents=(rp.side or {}).get("documents"))
+            for k, fold in getattr(self.step_fn.model, "step_scalars",
+                                   {}).items():
+                counters[k] = float(getattr(np, fold)(col[k]))
+            sp.attrs.update(counters)
+            out = dict(counters, loss=float(col["loss"].mean()),
+                       losses=col["loss"].tolist(),
+                       batches=rp.num_batches,
+                       elapsed_sec=timer.elapsed_sec(),
+                       examples_per_sec=rp.num_records /
+                       max(timer.elapsed_sec(), 1e-9))
+            if FLAGS.check_nan_inf and math.isnan(out["loss"]):
+                raise NanInfError(f"nan loss after resident pass at step "
+                                  f"{self.global_step}")
+            log.info("resident pass done: %d steps, %.0f tokens/s, "
+                     "loss=%.4f", rp.num_batches, out["examples_per_sec"],
+                     out["loss"])
+            self._emit_pass("train_pass_resident", out, rp.num_records,
+                            stage_timers=True)
+        return out
 
     def train_passes_resident(self, datasets: Iterable[Dataset],
                               depth: Optional[int] = None,

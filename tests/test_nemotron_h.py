@@ -1,0 +1,421 @@
+"""The hybrid language-model path (models/nemotron_h.py, ops/ssd.py,
+ops/causal_attention.py, parallel/moe.routed_experts, wide table rows,
+train/step.SeqTrainStep through Trainer + PassPreloader) against the plain
+reference the benchmark keeps (benchmarks/reference/models/nemotron_h.py,
+benchmarks/reference/lm.py), at tiny widths on the CPU in float32."""
+
+import collections
+import dataclasses
+import hashlib
+import itertools
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.reference import lm as ref_lm  # noqa: E402
+from benchmarks.reference.models import nemotron_h as ref  # noqa: E402
+from paddlebox_tpu.models import NemotronH  # noqa: E402
+from paddlebox_tpu.obs import trace  # noqa: E402
+from paddlebox_tpu.ops.ssd import ssd_scan  # noqa: E402
+from paddlebox_tpu.parallel.moe import route_top_k, routed_experts  # noqa: E402
+
+CFG = dict(
+    hybrid_override_pattern="MEMEM*EME", hidden_size=64, vocab_size=96,
+    layer_norm_epsilon=1e-5, mamba_num_heads=8, mamba_head_dim=8,
+    n_groups=2, ssm_state_size=16, conv_kernel=4, chunk_size=8,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    moe_intermediate_size=48, moe_shared_expert_intermediate_size=96,
+    router_outputs=16, n_routed_experts=4, first_expert_held=0,
+    num_experts_per_tok=3, routed_scaling_factor=2.5, time_step_min=0.001,
+    time_step_max=0.1, time_step_floor=1e-4)
+
+
+def rel(a, b):
+    return float(jnp.linalg.norm(jnp.asarray(a) - jnp.asarray(b))
+                 / jnp.maximum(jnp.linalg.norm(jnp.asarray(b)), 1e-30))
+
+
+def program(cfg, **kw):
+    return NemotronH(cfg, compute_dtype=jnp.float32, **kw)
+
+
+@pytest.fixture(autouse=True)
+def program_flags_restored():
+    """``entries/common.program_flags`` sets process-wide flags; the
+    worker's later test files must find them as they were."""
+    from paddlebox_tpu.config import FLAGS
+    saved = dataclasses.asdict(FLAGS)
+    yield
+    for name, value in saved.items():
+        setattr(FLAGS, name, value)
+
+
+@pytest.fixture
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+#: a float32 comparison needs float32 products on the CPU too
+f32 = pytest.mark.usefixtures("highest_precision")
+
+
+@f32
+# ---- layers and the whole stack: forward, loss, gradients ------------------
+
+@pytest.mark.parametrize("pattern,t", [
+    ("M", 16), ("M", 20), ("*", 16), ("*", 20), ("E", 20),
+    ("MEMEM*EME", 20)])
+def test_stack_matches_the_reference(pattern, t):
+    cfg = dict(CFG, hybrid_override_pattern=pattern)
+    params = ref.init(jax.random.PRNGKey(3), cfg)
+    emb = jax.random.normal(jax.random.PRNGKey(1), (2, t, 64)) * 0.02
+    labels = jax.random.randint(jax.random.PRNGKey(2), (2, t), 0, 96)
+    model = program(cfg)
+    want, (gp_r, ge_r) = jax.value_and_grad(
+        lambda p, e: ref.loss(p, e, labels, cfg), argnums=(0, 1))(params, emb)
+    (got, scalars), (gp, ge) = jax.value_and_grad(
+        lambda p, e: model.loss(p, e, labels, jnp.ones((2, t), bool)),
+        argnums=(0, 1), has_aux=True)(params, emb)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    assert rel(model.logits(params, emb),
+               ref.forward(params, emb, cfg)) < 1e-5
+    assert rel(ge, ge_r) < 1e-4
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(gp_r)[0],
+                            jax.tree.leaves(gp)):
+        assert rel(b, a) < 2e-4, jax.tree_util.keystr(path)
+    assert set(scalars) == set(model.step_scalars)
+    _, loads = model.hidden(params, emb)
+    assert loads.shape == (pattern.count("E"), 4)
+    assert float(scalars["moe_choices_held"]) == float(jnp.sum(loads))
+
+
+@f32
+@pytest.mark.parametrize("t", [16, 24, 21, 5],
+                         ids=["2-chunks", "3-chunks", "ragged", "short"])
+def test_chunked_scan_matches_the_step_by_step_recurrence(t):
+    ks = jax.random.split(jax.random.PRNGKey(t), 5)
+    b_, g, k, p, n = 2, 2, 4, 8, 16
+    x = jax.random.normal(ks[0], (b_, t, g * k, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b_, t, g * k)))
+    a = -jnp.exp(jax.random.normal(ks[2], (g * k,)))
+    b = jax.random.normal(ks[3], (b_, t, g, n))
+    c = jax.random.normal(ks[4], (b_, t, g, n))
+
+    def chunked(x, dt, a, b, c):
+        return ssd_scan(x, dt, a, b, c, chunk=8, mm_dtype=jnp.float32)
+
+    def stepwise(x, dt, a, b, c):
+        y, _ = ref.recurrence(x.reshape(b_, t, g, k, p),
+                              dt.reshape(b_, t, g, k), a.reshape(g, k), b, c)
+        return y.reshape(b_, t, g * k, p)
+
+    assert rel(chunked(x, dt, a, b, c), stepwise(x, dt, a, b, c)) < 1e-5
+    w = jax.random.normal(jax.random.PRNGKey(9), (b_, t, g * k, p))
+    grads = [jax.grad(lambda *v, f=f: jnp.sum(f(*v) * w),
+                      argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+             for f in (chunked, stepwise)]
+    for got, want in zip(*grads):
+        assert rel(got, want) < 1e-4
+
+
+# ---- the expert layer: shares add up, nothing is dropped -------------------
+
+def _expert_layer(key, experts=16):
+    cfg = dict(CFG, hybrid_override_pattern="E", n_routed_experts=experts)
+    lay = ref.init(key, cfg)["layers"][0]
+    u = jax.random.normal(jax.random.fold_in(key, 7), (2, 12, 64))
+    return cfg, lay, u
+
+
+@f32
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Four chips hold four of the sixteen experts each; what they give,
+    with the shared expert (which every chip computes alike) counted
+    once, is the whole layer as the reference computes it uncut."""
+    cfg, lay, u = _expert_layer(jax.random.PRNGKey(5))
+    z = ref.dims(cfg)
+    want = ref.moe(lay, u, z, None, (0, 16))
+    flat = u.reshape(-1, 64)
+    idx, w = route_top_k(flat, lay["router"], lay["router_bias"], 3, 2.5)
+    shared = ref.relu2_mlp(u, lay["shared_up"], lay["shared_down"],
+                           None).reshape(-1, 64)
+    total, choices = shared, 0
+    for lo in range(0, 16, 4):
+        part, stats = routed_experts(
+            flat, idx, w, lay["up"][lo:lo + 4], lay["down"][lo:lo + 4],
+            (lo, lo + 4), mm_dtype=jnp.float32)
+        # one share alone is the reference given the same share
+        alone = ref.moe({**lay, "up": lay["up"][lo:lo + 4],
+                         "down": lay["down"][lo:lo + 4]}, u, z, None,
+                        (lo, lo + 4)).reshape(-1, 64) - shared
+        assert rel(part, alone) < 1e-5
+        total = total + part
+        choices += int(stats["choices"])
+    assert choices == flat.shape[0] * 3       # every choice fell somewhere
+    assert rel(total, want.reshape(-1, 64)) < 1e-5
+
+
+@f32
+@pytest.mark.parametrize("n", [24, 14, 7],
+                         ids=["blocks-of-8", "blocks-of-2", "blocks-of-1"])
+def test_routing_drops_nothing_when_every_token_picks_the_same_experts(n):
+    cfg, lay, u = _expert_layer(jax.random.PRNGKey(6), experts=4)
+    flat = u.reshape(-1, 64)[:n]
+    # the correction bias makes experts 0, 1, 2 every token's choice
+    bias = jnp.zeros(16).at[:3].set(10.0)
+    idx, w = route_top_k(flat, lay["router"], bias, 3, 2.5)
+    assert set(np.asarray(idx).reshape(-1)) == {0, 1, 2}
+    y, stats = routed_experts(flat, idx, w, lay["up"], lay["down"], (0, 4),
+                              mm_dtype=jnp.float32)
+    assert int(stats["choices"]) == 3 * n
+    assert list(np.asarray(stats["load"])) == [n, n, n, 0]
+    want = sum(jnp.sum(jnp.where(idx == e, w, 0.0), -1)[:, None]
+               * ref.relu2_mlp(flat[None], lay["up"][e], lay["down"][e],
+                               None)[0] for e in range(3))
+    assert rel(y, want) < 1e-5
+    # the capacity gates of this module drop here; this layer may not
+    kept = ref.drop_over_capacity(idx, w, ref.dims(cfg), 1.0)
+    assert float(jnp.sum(kept > 0)) < 3 * n
+
+
+# ---- table rows wider than one line -----------------------------------------
+
+def test_wide_rows_pulled_pushed_and_read_back():
+    """A table of 300-wide rows (three lines a row): gather, counted
+    gather, the in-row rule's push and the host's row read and write
+    equal the same on the logical table."""
+    from paddlebox_tpu.ps.sgd import SparseSGDConfig
+    from paddlebox_tpu.ps.table import (NUM_FIXED, TableState, apply_push,
+                                        dispatch_packed_row_gather,
+                                        gather_full_rows, pack_geometry,
+                                        scatter_logical_rows)
+    cap, mf = 50, 292
+    feat = NUM_FIXED + mf
+    assert pack_geometry(cap, feat) == (1, 384, 3 * (cap + 1))
+    rng = np.random.default_rng(0)
+    logical = rng.standard_normal((cap + 1, feat)).astype(np.float32)
+    logical[:, 7] = 1.0
+    logical[:, 5:7] = np.abs(logical[:, 5:7])
+    logical[cap] = 0
+    st = TableState.from_logical(logical)
+    assert np.array_equal(np.asarray(st.data), logical)
+    rows = np.array([3, 7, 49, 0, cap, cap + 5, cap + 9], np.int32)
+    want = logical[np.minimum(rows, cap)]
+    assert np.array_equal(np.asarray(gather_full_rows(st, jnp.asarray(rows))),
+                          want)
+    counted = np.asarray(gather_full_rows(st, jnp.asarray(rows),
+                                          jnp.asarray(4, jnp.int32)))
+    assert np.array_equal(counted[:4], want[:4]) and not counted[4:].any()
+    out, k = dispatch_packed_row_gather(st, None, np.array([5, 9], np.int32))
+    assert np.array_equal(np.asarray(out)[:k], logical[[5, 9]])
+
+    cfg = SparseSGDConfig(mf_create_thresholds=0.0, mf_initial_range=0.0,
+                          mf_learning_rate=0.05, mf_initial_g2sum=3.0)
+    g = rng.standard_normal((7, 3 + mf)).astype(np.float32)
+    g[:, 0], g[:, 1], g[:, 2] = 2.0, 0.0, 0.0
+    real = rows[:4]
+    exp = logical.copy()
+    scaled = g[:4, 3:] / 2.0
+    ratio = 0.05 * np.sqrt(3.0 / (3.0 + logical[real, 6]))
+    exp[real, NUM_FIXED:] = np.clip(
+        logical[real, NUM_FIXED:] + scaled * ratio[:, None], -10, 10)
+    exp[real, 6] += np.mean(scaled * scaled, axis=1)
+    exp[real, 0] += 2.0
+    exp[real, 2] += 0.1 * 2.0
+    pushed = [np.asarray(apply_push(st, jnp.asarray(rows), jnp.asarray(g),
+                                    cfg, jax.random.PRNGKey(0),
+                                    num_unique=nu).data)
+              for nu in (None, jnp.asarray(4, jnp.int32))]
+    assert np.array_equal(pushed[0], pushed[1])      # the counted loop
+    np.testing.assert_allclose(pushed[0], exp, rtol=2e-6, atol=2e-6)
+    st2 = scatter_logical_rows(st, None, np.array([1, 2], np.int32),
+                               np.ones((2, feat), np.float32))
+    d = np.asarray(st2.data)
+    assert (d[[1, 2]] == 1).all() and np.array_equal(d[3:], logical[3:])
+
+
+# ---- one pass through Trainer + PassPreloader -------------------------------
+
+def _toy_cell():
+    from benchmarks.tests.test_family_lm import toy_cell
+    cell = toy_cell()
+    cell["config"]["matmul_dtype"] = "float32"
+    return cell
+
+
+def _trainer(cell, pool, params):
+    import optax
+    from benchmarks.entries import common, resident_seq
+    from paddlebox_tpu.ps import EmbeddingTable
+    from paddlebox_tpu.train import PassPreloader, Trainer
+    common.program_flags()
+    config, traffic = cell["config"], cell["traffic"]
+    desc = resident_seq.feed_desc(traffic)
+    table = EmbeddingTable(mf_dim=64, capacity=96,
+                           cfg=common.sparse_cfg(config),
+                           unique_bucket_min=48, arena_slots=1)
+    resident_seq.load_vocabulary(table, params["embedding"])
+    tx = optax.adam(config["dense_optimizer"]["learning_rate"])
+    tr = Trainer(program(config), table, desc, tx=tx)
+    tr.state = tr.state._replace(params=params["net"],
+                                 opt_state=tx.init(params["net"]))
+    pre = PassPreloader(itertools.cycle(resident_seq.datasets(desc, pool)),
+                        table, depth=2)
+    pre.start_next()
+    return tr, table, pre
+
+
+@f32
+def test_one_pass_through_the_trainer_equals_the_reference_step_by_step():
+    from benchmarks.families import lm
+    from paddlebox_tpu.ps.table import NUM_FIXED
+    cell = _toy_cell()
+    config, traffic = cell["config"], cell["traffic"]
+    pool = lm.make_pool(config, traffic, 5)
+    params = lm.seeded_params(ref, config, 5)
+    host = jax.device_get(params)
+    tr, table, pre = _trainer(cell, pool, params)
+    try:
+        rp = pre.wait()
+        assert rp.wire == "compact" and rp.floats.dtype == np.int32
+        out = tr.train_pass_resident(rp)
+    finally:
+        pre.drain()
+    want = ref_lm.run_pass(ref, config, pool[0].inputs, pool[0].labels, 2,
+                           host["net"], host["embedding"], precision=None)
+    # every step's loss, out of the pass program
+    assert len(out["losses"]) == 4
+    np.testing.assert_allclose(out["losses"], want["loss_steps"], rtol=2e-5)
+    assert out["tokens"] == 192
+    assert out["documents"] == int((pool[0].inputs == 0).sum())
+    assert out["moe_choices_held"] > 0
+    assert out["moe_expert_load_max"] >= out["moe_expert_load_mean"] > 0
+    fin = [s for s in trace.recent_spans() if s.name == "pass.finish"][-1]
+    for k in ("tokens", "documents", "moe_choices_held",
+              "moe_expert_load_max", "moe_expert_load_mean"):
+        assert fin.attrs[k] == out[k], k
+    # the dense weights after four Adam steps
+    for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(want["params"])[0],
+            jax.tree.leaves(tr.state.params)):
+        assert rel(b, a) < 1e-4, jax.tree_util.keystr(path)
+    # every row of the table: counts exactly, vectors and Adagrad sums
+    rows = table.index.lookup(np.arange(96, dtype=np.uint64))
+    got = np.asarray(tr.state.table.data)[rows]
+    ref_rows = np.asarray(want["table"])
+    assert np.array_equal(got[:, 0], ref_rows[:, 0])           # show
+    moved = ref_rows[:, 0] > 0
+    assert moved.sum() > 10
+    d_got = got[:, NUM_FIXED:] - host["embedding"]
+    d_ref = ref_rows[:, NUM_FIXED:] - host["embedding"]
+    assert rel(d_got[moved], d_ref[moved]) < 1e-3
+    assert not d_got[~moved].any()
+    assert rel(got[:, 6], ref_rows[:, 6]) < 1e-3
+
+
+def test_sequence_pass_program_carries_every_scope():
+    from benchmarks.families import lm
+    from paddlebox_tpu.train.device_pass import ResidentPassRunner
+    cell = _toy_cell()
+    pool = lm.make_pool(cell["config"], cell["traffic"], 6, count=1)
+    params = lm.seeded_params(ref, cell["config"], 6)
+    tr, table, pre = _trainer(cell, pool, params)
+    try:
+        rp = pre.wait()
+    finally:
+        pre.drain()
+    runner = ResidentPassRunner(tr.step_fn, table.capacity, True,
+                                wire=rp.wire, num_slots=1,
+                                chunk_bits=rp.chunk_bits)
+    text = runner._run(rp.num_batches).lower(
+        tr.state, *rp.dev, jnp.asarray(0, jnp.int32),
+        tr._rng).as_text(debug_info=True)
+    missing = {s for s in trace.SEQ_STEP_SCOPES
+               if not re.search(re.escape(s) + r"(?![A-Za-z0-9_])", text)}
+    assert not missing, missing
+    # a layer is one jax.checkpoint: in its backward pass the forward ops
+    # computed again and the backward ops carry the scope behind a
+    # ``checkpoint`` part, so the reducers count them under the scope
+    # itself, not under ``.bwd``
+    for s in (trace.SCOPE_SSM_SCAN, trace.SCOPE_MOE_EXPERTS,
+              trace.SCOPE_ATTN):
+        assert f"checkpoint/{s}/" in text, s
+
+
+# ---- the click models' pass program is what it was --------------------------
+
+#: DeepFM's resident pass program (the shapes below) as lowered at the
+#: parent commit b97e57f, before the sequence path was added: shared code
+#: adapts on what the input shows, and what a click model shows has not
+#: changed. The structure (counts of the ops that cost) holds under any
+#: jax; the sha256 of the whole text only under the jax it was recorded
+#: with, since a new jax may print the same program differently.
+DEEPFM_PASS_JAX = "0.9.0"
+DEEPFM_PASS_TEXT = {
+    "compact": "896e41fcb4bfa9d2d2faf5945e38c85225b50dd03c0efe5a4b18e7d306a7e461",
+    "dedup": "cae3296a77ec634fb2bc73ac369cea222bd2fbe1c2bf72af8c9c2f49ad7d8081",
+}
+DEEPFM_PASS_OPS = {
+    "compact": {"while": 5, "gather": 8, "scatter": 12, "sort": 1,
+                "dot_general": 11},
+    "dedup": {"while": 3, "gather": 8, "scatter": 8, "dot_general": 11},
+}
+
+
+def deepfm_pass_text(arena: bool) -> str:
+    """The lowered text of a small DeepFM ``train_pass_resident`` program
+    (also run by hand on the parent commit to record the pins)."""
+    import optax
+    from paddlebox_tpu.data import DataFeedDesc, InMemoryDataset, SlotDef
+    from paddlebox_tpu.data.columnar import ColumnarRecords
+    from paddlebox_tpu.models import DeepFM
+    from paddlebox_tpu.ps import EmbeddingTable, SparseSGDConfig
+    from paddlebox_tpu.train import Trainer
+    from paddlebox_tpu.train.device_pass import (ResidentPass,
+                                                 ResidentPassRunner)
+    s, bs, r = 4, 64, 256
+    slots = [SlotDef("label", "float", 1), SlotDef("dense", "float", 3)]
+    slots += [SlotDef(f"C{i}", "uint64") for i in range(s)]
+    desc = DataFeedDesc(slots=slots, batch_size=bs, label_slot="label",
+                        key_bucket_min=bs * s)
+    rng = np.random.default_rng(0)
+    ds = InMemoryDataset(desc)
+    ds.columnar = ColumnarRecords(
+        keys=(rng.integers(0, 500, (r, s)) + 1000 * np.arange(s)
+              ).reshape(-1).astype(np.uint64),
+        key_slot=np.tile(np.arange(s, dtype=np.int32), r),
+        offsets=np.arange(r + 1, dtype=np.int64) * s,
+        dense=rng.standard_normal((r, 3)).astype(np.float32),
+        label=(rng.random(r) < 0.3).astype(np.float32),
+        show=np.ones(r, np.float32),
+        clk=np.zeros(r, np.float32))
+    table = EmbeddingTable(mf_dim=10, capacity=1 << 16, cfg=SparseSGDConfig(),
+                           unique_bucket_min=256,
+                           arena_slots=s if arena else None)
+    tr = Trainer(DeepFM(hidden=(32, 16)), table, desc, tx=optax.adam(1e-3))
+    rp = ResidentPass.build_streamed(ds, table, floats_dtype="q8")
+    assert rp.wire == ("compact" if arena else "dedup")
+    runner = ResidentPassRunner(tr.step_fn, table.capacity, rp.segs is None,
+                                wire=rp.wire, num_slots=s,
+                                chunk_bits=rp.chunk_bits)
+    return runner._run(rp.num_batches).lower(
+        tr.state, *rp.dev, jnp.asarray(0, jnp.int32), tr._rng).as_text()
+
+
+@pytest.mark.parametrize("wire", ["compact", "dedup"])
+def test_deepfm_pass_program_lowers_to_the_parents_text(wire):
+    text = deepfm_pass_text(wire == "compact")
+    ops = collections.Counter(re.findall(
+        r"stablehlo\.(while|gather|scatter|sort|dot_general)\b", text))
+    assert dict(ops) == DEEPFM_PASS_OPS[wire]
+    if jax.__version__ == DEEPFM_PASS_JAX:
+        assert hashlib.sha256(text.encode()).hexdigest() == \
+            DEEPFM_PASS_TEXT[wire]
