@@ -54,7 +54,8 @@ class NemotronH:
     #: the scalars ``loss`` hands out a step beside the loss, and how a
     #: pass folds each over its steps (``SeqTrainStep`` and ``Trainer``
     #: pass them through by these names and know nothing of them)
-    step_scalars = {"moe_choices_held": "sum", "moe_expert_load_max": "mean",
+    step_scalars = {"moe_choices_held": "sum", "moe_rows_computed": "sum",
+                    "moe_expert_load_max": "mean",
                     "moe_expert_load_mean": "mean"}
 
     def __init__(self, config: Dict[str, Any],
@@ -194,7 +195,7 @@ class NemotronH:
             return x + self._mm(o.reshape(s, t, self.qh * self.hd),
                                 lay["o"])
 
-    def _moe(self, lay, x) -> Tuple[jax.Array, jax.Array]:
+    def _moe(self, lay, x) -> Tuple[jax.Array, jax.Array, jax.Array]:
         s, t, d = x.shape
         with _scope(trace.SCOPE_MOE_ROUTE):
             u = self._norm(x, lay["norm"]).reshape(s * t, d)
@@ -206,14 +207,15 @@ class NemotronH:
         with _scope(trace.SCOPE_MOE_SHARED):
             hid = jnp.square(jax.nn.relu(self._mm(u, lay["shared_up"])))
             y = y + self._mm(hid, lay["shared_down"])
-            return x + y.reshape(s, t, d), stats["load"]
+            return x + y.reshape(s, t, d), stats["load"], stats["rows"]
 
     # ---- the stack, the head and the loss ----
     def hidden(self, params, emb: jax.Array):
         """Token vectors [S, T, hidden] -> (the last layer's output, the
         token-choices each held expert took in each ``E`` layer, int32
-        [E layers, held])."""
-        x, loads = emb, []
+        [E layers, held], the rows each ``E`` layer's loops computed,
+        int32 [E layers])."""
+        x, loads, rows = emb, [], []
         for kind, lay in zip(self.pattern, params["layers"]):
             if kind == "M":
                 # sequences are independent in a mixer over time: one at
@@ -225,14 +227,17 @@ class NemotronH:
             elif kind == "*":
                 x = jax.checkpoint(self._attention)(lay, x)
             else:
-                x, load = jax.checkpoint(self._moe)(lay, x)
+                x, load, computed = jax.checkpoint(self._moe)(lay, x)
                 loads.append(load)
+                rows.append(computed)
         n_held = self.held[1] - self.held[0]
-        return x, (jnp.stack(loads) if loads
-                   else jnp.zeros((0, n_held), jnp.int32))
+        if not loads:
+            return (x, jnp.zeros((0, n_held), jnp.int32),
+                    jnp.zeros((0,), jnp.int32))
+        return x, jnp.stack(loads), jnp.stack(rows)
 
     def logits(self, params, emb: jax.Array) -> jax.Array:
-        x, _ = self.hidden(params, emb)
+        x, _, _ = self.hidden(params, emb)
         with _scope(trace.SCOPE_HEAD):
             return self._mm(self._norm(x, params["final_norm"]),
                             params["head"])
@@ -242,7 +247,7 @@ class NemotronH:
         """Mean cross-entropy of ``labels`` [S, T] over the positions
         ``valid`` marks -> (loss, the step's ``step_scalars``). The
         logits exist for ``HEAD_ROWS`` positions at a time."""
-        x, loads = self.hidden(params, emb)
+        x, loads, computed = self.hidden(params, emb)
         n = labels.size
         rows = math.gcd(n, HEAD_ROWS)
         x = x.reshape(n // rows, rows, self.d)
@@ -264,12 +269,15 @@ class NemotronH:
                                 (x, lab, ok))
         with _scope(trace.SCOPE_LOSS):
             return (total / jnp.maximum(jnp.sum(ok), 1.0),
-                    self._load_scalars(loads))
+                    self._load_scalars(loads, computed))
 
     @staticmethod
-    def _load_scalars(loads: jax.Array) -> Dict[str, jax.Array]:
+    def _load_scalars(loads: jax.Array,
+                      computed: jax.Array) -> Dict[str, jax.Array]:
         """Of the token-choices each held expert took in each ``E`` layer
-        [E layers, held]: their sum, and the layer under most load this
+        [E layers, held]: their sum, beside the sum of the rows the
+        layers' loops ``computed`` for them (the choices and each run's
+        padding to whole blocks), and the layer under most load this
         step: its busiest held expert's choices and its mean."""
         loads = loads.astype(jnp.float32)
         if loads.shape[0]:
@@ -278,4 +286,5 @@ class NemotronH:
         else:
             top = mean = jnp.zeros((), jnp.float32)
         return {"moe_choices_held": jnp.sum(loads),
+                "moe_rows_computed": jnp.sum(computed.astype(jnp.float32)),
                 "moe_expert_load_max": top, "moe_expert_load_mean": mean}

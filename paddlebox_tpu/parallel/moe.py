@@ -15,8 +15,11 @@ reference's capacity gates.
 
 ``routed_experts`` is the other kind of expert layer: sigmoid top-k
 routing that DROPS NOTHING, over a layer that is told which experts it
-holds (one chip's share of an expert-parallel deployment), sorted
-token-choices and grouped matrix products over the experts held.
+holds (one chip's share of an expert-parallel deployment): one sort of
+the token-choices, then a grouped matrix product that is one loop an
+expert over the blocks of rows that hold its choices, the trip count
+read from the data, forward and (written by hand, ``jax.custom_vjp``)
+backward.
 
 Callers (ROADMAP D5 reads this): ``route_top_k`` and ``routed_experts``
 run in the benchmark (``models/nemotron_h.py``'s ``E`` layers, cell
@@ -191,9 +194,8 @@ def moe_forward_sharded(mesh: Any, axis: str,
 
 # ---- dropless routing over the experts held here ---------------------------
 
-#: rows of the laid-out axis a chunk computes, and rows of it that belong
-#: to one expert; fewer where the tokens are fewer
-EXPERT_CHUNK = 8192
+#: rows of an expert's run that one trip of the loops computes; the
+#: largest divisor of the token count that this allows
 EXPERT_BLOCK = 512
 
 
@@ -221,86 +223,138 @@ def routed_experts(x: jax.Array, idx: jax.Array, w: jax.Array,
     them: ``sum_k w[n, k] * down_e(relu(up_e(x[n]))^2)`` over the choices
     k whose expert ``e = idx[n, k]`` is held; choices of experts that
     live elsewhere add nothing here. x [N, D]; idx, w [N, k] from
-    ``route_top_k`` over ALL experts; up [hi-lo, D, F], down [hi-lo, F,
-    D]. Returns (y [N, D] float32, {"choices": token-choices that fell
-    on held experts, "load": [hi-lo] of them an expert}).
+    ``route_top_k`` over ALL experts (a token's k experts are distinct);
+    up [hi-lo, D, F], down [hi-lo, F, D]. Returns (y [N, D] float32,
+    {"choices": token-choices that fell on held experts, "load": [hi-lo]
+    of them an expert, "rows": the rows the loops computed}).
 
-    No choice is dropped, whatever the imbalance. The token-choices are
-    sorted by expert (held first) and every expert's run of rows is laid
-    out from a multiple of ``EXPERT_BLOCK`` rows, so that a block of rows
-    belongs to ONE expert: the grouped product is then a batched product
-    of [block, D] row blocks with their experts' matrices (gathered a
-    block), exact in its operations but for each expert's last, partly
-    empty block. Shapes are static: the laid-out axis has ``N * min(k,
-    hi-lo) + (hi-lo) * block`` rows, the most that can fall on the held
-    experts, cut into chunks of ``min(N, EXPERT_CHUNK)`` rows (a block
-    is the largest divisor of a chunk that ``EXPERT_BLOCK`` allows);
-    a chunk that starts past the last row in use is skipped
-    (``lax.cond``) and a chunk's work is recomputed in the backward
-    pass, so time and memory follow the choices that are there and not
-    the bound. (A skipped chunk still passes zeros through the scan's
-    backward pass; PERF.md section 7.)"""
-    n, d = x.shape
-    k = idx.shape[1]
+    No choice is dropped, whatever the imbalance. The layout is plain
+    JAX: ONE sort of the choices by (expert, token) packs every held
+    expert's tokens into a run (``tok``; ``starts``, ``load`` say where
+    and how long), and ``wt`` [N, hi-lo] holds what weight a token gave
+    each held expert (0 where it did not choose it), so ``w``'s gradient
+    flows through ``wt``. The grouped product over the runs
+    (``_grouped_product``) is then one loop an expert whose trip count is
+    read from the data: ``ceil(load_e / block)`` blocks of ``block`` rows
+    (the largest divisor of N that ``EXPERT_BLOCK`` allows), each a
+    gather of its tokens' rows, two products with the expert's matrices
+    read in place, and a scatter-add; the backward pass is written by
+    hand as the same loop (reverse mode cannot differentiate a trip count
+    that is data). Nothing is sized by, or walks, the ``N * k`` bound but
+    the sort and its integers: time follows the choices that are there,
+    and an expert without a choice runs no trip."""
+    n, k = x.shape[0], idx.shape[1]
     lo, hi = held
     n_held = hi - lo
-    bound = n * min(k, n_held)
-    rows = min(n, EXPERT_CHUNK)
-    blk = math.gcd(rows, EXPERT_BLOCK)
-    n_chunks = -(-(bound + n_held * blk) // rows)
-    r_pad = n_chunks * rows
+    blk = math.gcd(n, EXPERT_BLOCK)
+    if (n_held + 1) * n * k >= 2 ** 31:
+        raise ValueError(f"{n} tokens x {k} choices x {n_held} experts "
+                         f"held do not fit the int32 sort key")
 
-    flat_e = idx.reshape(-1)
-    is_held = (flat_e >= lo) & (flat_e < hi)
-    group = jnp.where(is_held, flat_e - lo, n_held)
-    order = jnp.argsort(group, stable=True)[:bound]
-    load = jnp.sum(group[:, None] == jnp.arange(n_held)[None, :], axis=0,
-                   dtype=jnp.int32)                            # [n_held]
-    # where each expert's run starts: packed (in ``order``), and laid out
-    # from a multiple of ``blk``
+    mine = idx[:, :, None] == jnp.arange(lo, hi, dtype=idx.dtype)
+    wt = jnp.sum(jnp.where(mine, w[:, :, None], 0), axis=1)   # [N, n_held]
+    load = jnp.sum(mine, axis=(0, 1), dtype=jnp.int32)         # [n_held]
     starts = jnp.cumsum(load) - load
-    laid = -(-load // blk) * blk
-    laid_ends = jnp.cumsum(laid)
-    e_sorted = group[order]
-    live = e_sorted < n_held
-    e_safe = jnp.minimum(e_sorted, n_held - 1)
-    dest = jnp.where(
-        live, jnp.arange(bound, dtype=jnp.int32) - starts[e_safe]
-        + (laid_ends - laid)[e_safe], r_pad)                   # dead: dropped
-    tok = jnp.zeros((r_pad,), jnp.int32).at[dest].set(
-        (order // k).astype(jnp.int32), mode="drop")
-    w_laid = jnp.zeros((r_pad,), w.dtype).at[dest].set(
-        w.reshape(-1)[order], mode="drop")
-    in_use = laid_ends[-1]
-    expert_of_block = jnp.minimum(
-        jnp.searchsorted(laid_ends, jnp.arange(r_pad // blk) * blk,
-                         side="right"), n_held - 1)
+    # held choices first, by expert, a run's tokens ascending; the key is
+    # unique, so one operand sorts and nothing is gathered
+    flat_e = idx.reshape(-1)
+    group = jnp.where((flat_e >= lo) & (flat_e < hi), flat_e - lo, n_held)
+    key = group * (n * k) + jnp.arange(n * k, dtype=jnp.int32)
+    tok = (jnp.sort(key) % (n * k)) // k
+    # a run's last block reads up to ``blk`` entries past the run
+    tok = jnp.concatenate([tok, jnp.zeros((blk,), jnp.int32)])
+
+    y = _grouped_product(x, wt, up, down, tok, starts, load, blk, mm_dtype)
+    return y, {"choices": jnp.sum(load), "load": load,
+               "rows": jnp.sum(-(-load // blk)) * blk}
+
+
+def _block_rows(tok, starts, load, e: int, i, blk: int):
+    """Block ``i`` of expert ``e``'s run: its rows' tokens, and which of
+    the rows hold a choice (the run's last block may be partly past it:
+    those rows read another run's tokens and weigh 0)."""
+    t = jax.lax.dynamic_slice(tok, (starts[e] + i * blk,), (blk,))
+    return t, i * blk + jnp.arange(blk, dtype=jnp.int32) < load[e]
+
+
+def _dot(a, b, axis_a: int, axis_b: int):
+    """``a`` and ``b`` contracted over one axis each, float32 out."""
+    return jax.lax.dot_general(a, b, (((axis_a,), (axis_b,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _grouped_product(x, wt, up, down, tok, starts, load, blk, mm_dtype):
+    """``y[n] = sum_e wt[n, e] * down_e(relu(up_e(x[n]))^2)`` over the
+    tokens of each expert's run (``routed_experts`` says what ``tok``,
+    ``starts`` and ``load`` are). Products take ``mm_dtype`` operands and
+    accumulate in float32; ``relu^2`` is float32."""
     xm, upm, downm = (x.astype(mm_dtype), up.astype(mm_dtype),
                       down.astype(mm_dtype))
+    y = jnp.zeros(x.shape, jnp.float32)
+    for e in range(up.shape[0]):
+
+        def one_block(i, y, e=e):
+            t, live = _block_rows(tok, starts, load, e, i, blk)
+            hid = _dot(xm[t], upm[e], 1, 0)
+            act = jnp.square(jax.nn.relu(hid)).astype(mm_dtype)
+            out = _dot(act, downm[e], 1, 0)
+            wb = jnp.where(live, wt[:, e][t], 0)
+            return y.at[t].add(out * wb[:, None])
+
+        y = jax.lax.fori_loop(0, -(-load[e] // blk), one_block, y)
+    return y
+
+
+def _grouped_product_fwd(x, wt, up, down, tok, starts, load, blk, mm_dtype):
+    y = _grouped_product(x, wt, up, down, tok, starts, load, blk, mm_dtype)
+    return y, (x, wt, up, down, tok, starts, load)
+
+
+def _grouped_product_bwd(blk, mm_dtype, res, dy):
+    """Nothing of a block is kept from the forward pass: a trip gathers
+    its rows again and computes the hidden activation once more, then the
+    cotangents of both products (cotangents are ``mm_dtype`` operands as
+    the activations were). An expert's weight gradients accumulate in
+    float32 through its loop and are written once."""
+    x, wt, up, down, tok, starts, load = res
     f32 = jnp.float32
+    xm, upm, downm = (x.astype(mm_dtype), up.astype(mm_dtype),
+                      down.astype(mm_dtype))
+    dx = jnp.zeros(x.shape, f32)
+    dwt, dup, ddown = [], [], []
+    for e in range(up.shape[0]):
 
-    def one_chunk(y, ci):
-        c0 = ci * rows
+        def one_block(i, carry, e=e):
+            dx, dwt_e, dup_e, ddown_e = carry
+            t, live = _block_rows(tok, starts, load, e, i, blk)
+            wb = jnp.where(live, wt[:, e][t], 0)
+            xb = xm[t]
+            hid = jax.nn.relu(_dot(xb, upm[e], 1, 0))
+            act = jnp.square(hid).astype(mm_dtype)
+            dyb = dy[t]
+            # d out / d act, before the row's weight: the weight's own
+            # gradient is sum(out * dy) = sum(act * (dy down^T))
+            dact = _dot(dyb.astype(mm_dtype), downm[e], 1, 1)
+            dwt_e = dwt_e.at[t].add(
+                jnp.where(live, jnp.sum(act.astype(f32) * dact, -1), 0))
+            ddown_e = ddown_e + _dot(
+                act, (dyb * wb[:, None]).astype(mm_dtype), 0, 0)
+            dhid = (dact * wb[:, None] * 2 * hid).astype(mm_dtype)
+            dx = dx.at[t].add(_dot(dhid, upm[e], 1, 1))
+            dup_e = dup_e + _dot(xb, dhid, 0, 0)
+            return dx, dwt_e, dup_e, ddown_e
 
-        # recomputed in the backward pass: the scan then keeps nothing of
-        # a chunk but its number
-        @jax.checkpoint
-        def work(y):
-            t = jax.lax.dynamic_slice(tok, (c0,), (rows,))
-            wt = jax.lax.dynamic_slice(w_laid, (c0,), (rows,))
-            e = jax.lax.dynamic_slice(expert_of_block, (c0 // blk,),
-                                      (rows // blk,))
-            xb = xm[t].reshape(rows // blk, blk, d)
-            hid = jnp.einsum("bmd,bdf->bmf", xb, upm[e],
-                             preferred_element_type=f32)
-            hid = jnp.square(jax.nn.relu(hid)).astype(mm_dtype)
-            out = jnp.einsum("bmf,bfd->bmd", hid, downm[e],
-                             preferred_element_type=f32)
-            # a row that holds no choice has weight 0
-            return y.at[t].add(out.reshape(rows, d) * wt[:, None])
+        dx, dwt_e, dup_e, ddown_e = jax.lax.fori_loop(
+            0, -(-load[e] // blk), one_block,
+            (dx, jnp.zeros(x.shape[:1], f32), jnp.zeros(up.shape[1:], f32),
+             jnp.zeros(down.shape[1:], f32)))
+        dwt.append(dwt_e)
+        dup.append(dup_e)
+        ddown.append(ddown_e)
+    return (dx.astype(x.dtype), jnp.stack(dwt, 1).astype(wt.dtype),
+            jnp.stack(dup).astype(up.dtype),
+            jnp.stack(ddown).astype(down.dtype), None, None, None)
 
-        return jax.lax.cond(c0 < in_use, work, lambda y: y, y), None
 
-    y, _ = jax.lax.scan(one_chunk, jnp.zeros((n, d), f32),
-                        jnp.arange(n_chunks, dtype=jnp.int32))
-    return y, {"choices": jnp.sum(load), "load": load}
+_grouped_product.defvjp(_grouped_product_fwd, _grouped_product_bwd)

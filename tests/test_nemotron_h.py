@@ -8,6 +8,7 @@ import collections
 import dataclasses
 import hashlib
 import itertools
+import math
 import os
 import re
 import sys
@@ -92,9 +93,12 @@ def test_stack_matches_the_reference(pattern, t):
                             jax.tree.leaves(gp)):
         assert rel(b, a) < 2e-4, jax.tree_util.keystr(path)
     assert set(scalars) == set(model.step_scalars)
-    _, loads = model.hidden(params, emb)
+    _, loads, _ = model.hidden(params, emb)
     assert loads.shape == (pattern.count("E"), 4)
     assert float(scalars["moe_choices_held"]) == float(jnp.sum(loads))
+    blk = math.gcd(2 * t, 512)
+    assert float(scalars["moe_rows_computed"]) == float(
+        jnp.sum(-(-loads // blk) * blk))
 
 
 @f32
@@ -184,6 +188,138 @@ def test_routing_drops_nothing_when_every_token_picks_the_same_experts(n):
     # the capacity gates of this module drop here; this layer may not
     kept = ref.drop_over_capacity(idx, w, ref.dims(cfg), 1.0)
     assert float(jnp.sum(kept > 0)) < 3 * n
+
+
+# ---- the expert layer's hand-written backward pass, and what it computes -----
+
+def _held_reference(x, idx, w, up, down, held, precision):
+    """The dense reference of one share: every held expert computed for
+    every token, weighted by what the token's choices gave it."""
+    y = 0.0
+    for j, e in enumerate(range(*held)):
+        w_e = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
+        y = y + w_e[:, None] * ref.relu2_mlp(x[None], up[j], down[j],
+                                             precision)[0]
+    return y
+
+
+def _expert_case(case):
+    """(x, idx, w, up, down, held, block) of a named routing."""
+    n, held, same = {"routed-evenly": (24, (4, 8), False),
+                     "blocks-of-8": (24, (0, 4), True),
+                     "blocks-of-2": (14, (0, 4), True),
+                     "blocks-of-1": (7, (0, 4), True),
+                     "no-choice-held": (24, (12, 16), True),
+                     "bfloat16-operands": (24, (0, 4), True)}[case]
+    _, lay, u = _expert_layer(jax.random.PRNGKey(8), experts=16)
+    flat = u.reshape(-1, 64)[:n]
+    # the correction bias makes experts 0, 1, 2 every token's choice
+    bias = jnp.zeros(16).at[:3].set(10.0) if same else lay["router_bias"]
+    idx, w = route_top_k(flat, lay["router"], bias, 3, 2.5)
+    lo, hi = held
+    return (flat, idx, w, lay["up"][lo:hi], lay["down"][lo:hi], held,
+            {24: 8, 14: 2, 7: 1}[n])
+
+
+EXPERT_CASES = ["routed-evenly", "blocks-of-8", "blocks-of-2", "blocks-of-1",
+                "no-choice-held", "bfloat16-operands"]
+
+
+@f32
+@pytest.mark.parametrize("case", EXPERT_CASES)
+def test_routed_experts_gradients_equal_the_dense_references(case):
+    x, idx, w, up, down, held, _ = _expert_case(case)
+    bf16 = case == "bfloat16-operands"
+    cot = jax.random.normal(jax.random.PRNGKey(11), x.shape)
+
+    def ours(x, w, up, down):
+        y, _ = routed_experts(x, idx, w, up, down, held,
+                              mm_dtype=jnp.bfloat16 if bf16 else jnp.float32)
+        return jnp.sum(y * cot)
+
+    def dense(x, w, up, down):
+        return jnp.sum(_held_reference(x, idx, w, up, down, held,
+                                       "bfloat16" if bf16 else None) * cot)
+
+    got = jax.grad(ours, argnums=(0, 1, 2, 3))(x, w, up, down)
+    want = jax.grad(dense, argnums=(0, 1, 2, 3))(x, w, up, down)
+    if case == "no-choice-held":
+        # zero trips: nothing is computed, not even a rounding
+        y, stats = routed_experts(x, idx, w, up, down, held,
+                                  mm_dtype=jnp.float32)
+        assert int(stats["choices"]) == 0 and not np.asarray(y).any()
+        for g, r in zip(got, want):
+            assert not np.asarray(g).any() and not np.asarray(r).any()
+        return
+    # float32: the same arithmetic in another order. bfloat16 operands:
+    # ``down``'s gradient takes the reference's very roundings; for the
+    # other three the reference rounds the weighted cotangent, the loop
+    # rounds the cotangent and weighs the product: one rounding apart
+    # (read 0.0014-0.0032; plain float32 lies 0.0034-0.0042 from either)
+    for name, g, r in zip(("x", "w", "up", "down"), got, want):
+        assert float(jnp.linalg.norm(r)) > 0, name
+        limit = 1e-4 if not bf16 else 1e-5 if name == "down" else 1e-2
+        assert rel(g, r) < limit, name
+
+
+@pytest.mark.parametrize("case", EXPERT_CASES)
+def test_rows_computed_follow_the_choices(case):
+    x, idx, w, up, down, held, blk = _expert_case(case)
+    _, stats = routed_experts(x, idx, w, up, down, held, mm_dtype=jnp.float32)
+    load = np.asarray(stats["load"])
+    want = np.asarray(jnp.sum((idx[:, :, None] == jnp.arange(*held)),
+                              axis=(0, 1)))
+    assert list(load) == list(want)
+    assert int(stats["choices"]) == load.sum()
+    assert int(stats["rows"]) == sum(-(-int(c) // blk) * blk for c in load)
+    if case == "no-choice-held":
+        assert int(stats["rows"]) == 0
+    elif case != "routed-evenly":
+        assert list(load) == [x.shape[0]] * 3 + [0]
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_the_gradient_program_walks_the_blocks_in_use_not_the_bound():
+    """At 1,024 tokens, 8 experts held of 128: the program of the gradient
+    has loops whose trip count is data and no scan over chunks of the
+    bound, builds no stack of a weight matrix a block, and sizes no float
+    value by the 6 x N choices that could fall here."""
+    n, d, f, k, n_held = 1024, 64, 48, 6, 8
+    ks = jax.random.split(jax.random.PRNGKey(12), 5)
+    x = jax.random.normal(ks[0], (n, d))
+    idx, w = route_top_k(x, jax.random.normal(ks[1], (d, 128)),
+                         jnp.zeros(128), k, 2.5)
+    up = jax.random.normal(ks[2], (n_held, d, f))
+    down = jax.random.normal(ks[3], (n_held, f, d))
+
+    def total(x, w, up, down):
+        y, _ = routed_experts(x, idx, w, up, down, (0, n_held))
+        return jnp.sum(y)
+
+    jaxpr = jax.make_jaxpr(jax.grad(total, argnums=(0, 1, 2, 3)))(
+        x, w, up, down)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    names = collections.Counter(e.primitive.name for e in eqns)
+    assert names["while"] == 2 * n_held and not names["scan"]
+    assert not names["cond"]
+    for eqn in eqns:
+        for var in eqn.outvars:
+            shape, dtype = var.aval.shape, var.aval.dtype
+            if len(shape) == 3 and shape[1:] in ((d, f), (f, d)):
+                # the held stack, or one expert's matrix sliced out of it
+                assert shape[0] in (1, n_held), (eqn.primitive.name, shape)
+            if shape and jnp.issubdtype(dtype, jnp.inexact):
+                assert shape[0] < k * n, (eqn.primitive.name, shape)
 
 
 # ---- table rows wider than one line -----------------------------------------
@@ -299,7 +435,8 @@ def test_one_pass_through_the_trainer_equals_the_reference_step_by_step():
     assert out["moe_choices_held"] > 0
     assert out["moe_expert_load_max"] >= out["moe_expert_load_mean"] > 0
     fin = [s for s in trace.recent_spans() if s.name == "pass.finish"][-1]
-    for k in ("tokens", "documents", "moe_choices_held",
+    assert out["moe_rows_computed"] >= out["moe_choices_held"]
+    for k in ("tokens", "documents", "moe_choices_held", "moe_rows_computed",
               "moe_expert_load_max", "moe_expert_load_mean"):
         assert fin.attrs[k] == out[k], k
     # the dense weights after four Adam steps
