@@ -2,11 +2,11 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
 ONE process that owns the chip drives the repo's main paths once, through
-the entry points a user calls, at the full width of the model every
-recorded number uses: DeepFM(512,256,128), 26 sparse slots + 13 dense,
-mf_dim 8, batch 8192, an 8M-row HBM table, adam(1e-3). Depth is cut (a
-few batches per pass, a few passes per phase); weights are random, data
-comes from a seed. Phases:
+the entry points a user calls, at a full DeepFM width:
+DeepFM(512,256,128), 26 sparse slots + 13 dense, mf_dim 8, batch 8192,
+an 8M-row HBM table, adam(1e-3) (the benchmark's own widths are in
+benchmarks/configs/). Depth is cut (a few batches per pass, a few
+passes per phase); weights are random, data comes from a seed. Phases:
 
   resident   InMemoryDataset → PassPreloader → Trainer.train_pass_resident
   streaming  Trainer.train_pass
@@ -48,8 +48,9 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class Widths:
-    """The bench's resident cell (bench.py SHAPES["uniform"] + main) and
-    its on-chip AdsRank-PV widths. Only tests pass anything else."""
+    """Full DeepFM width for the resident, streaming, serve, sharded and
+    tiered phases, and the AdsRank-PV widths of the kernels phase. Only
+    tests pass anything else."""
 
     hidden: Tuple[int, ...] = (512, 256, 128)
     mf_dim: int = 8
@@ -197,10 +198,90 @@ def criteo_dataset(workdir: str, name: str, rows: int, w: Widths,
     return ds, desc
 
 
+def build_records(num_records: int, num_slots: int = 26,
+                  vocab_per_slot: int = 100_000, seed: int = 0,
+                  avg_keys_per_slot: float = 1.0,
+                  key_dist: str = "uniform"):
+    """Seeded criteo-shaped records, built columnar-fast. Slot ``s``
+    draws its ids from ``[s * vocab_per_slot, (s + 1) * vocab_per_slot)``.
+
+    ``avg_keys_per_slot > 1`` gives RAGGED slots: per-(record, slot) key
+    counts ~ 1 + Poisson(avg-1), the feed-log shape that takes the
+    segment stream and the non-trivial seqpool path.
+
+    ``key_dist="zipf"`` draws a slot's ids from a bounded Zipf (s=1.2)
+    instead of uniformly: a few ids dominate every batch."""
+    from paddlebox_tpu.data.record import SlotRecord
+    rng = np.random.default_rng(seed)
+
+    def draw_keys(size):
+        if key_dist == "zipf":
+            w = 1.0 / np.arange(1, vocab_per_slot + 1,
+                                dtype=np.float64) ** 1.2
+            return rng.choice(vocab_per_slot, size=size, p=w / w.sum())
+        return rng.integers(0, vocab_per_slot, size=size)
+
+    dense_all = rng.normal(size=(num_records, 13)).astype(np.float32)
+    labels = (rng.random(num_records) < 0.25).astype(np.float32)
+    slot_base = (np.arange(num_slots) * vocab_per_slot).astype(np.uint64)
+    if avg_keys_per_slot <= 1.0:
+        keys_all = draw_keys((num_records, num_slots))
+        keys_all = (keys_all + slot_base).astype(np.uint64)
+        offsets = np.arange(num_slots + 1, dtype=np.int32)
+        return [
+            SlotRecord(keys=keys_all[i], slot_offsets=offsets,
+                       dense=dense_all[i], label=float(labels[i]),
+                       show=1.0, clk=float(labels[i]))
+            for i in range(num_records)
+        ]
+    counts = 1 + rng.poisson(avg_keys_per_slot - 1.0,
+                             size=(num_records, num_slots))
+    offs = np.zeros((num_records, num_slots + 1), np.int32)
+    np.cumsum(counts, axis=1, out=offs[:, 1:])
+    total = offs[:, -1]
+    flat = draw_keys(int(total.sum()))
+    flat_base = np.repeat(
+        np.tile(slot_base, num_records),
+        counts.reshape(-1))
+    flat = (flat + flat_base).astype(np.uint64)
+    starts = np.concatenate([[0], np.cumsum(total)[:-1]])
+    return [
+        SlotRecord(keys=flat[starts[i]:starts[i] + total[i]],
+                   slot_offsets=offs[i],
+                   dense=dense_all[i], label=float(labels[i]),
+                   show=1.0, clk=float(labels[i]))
+        for i in range(num_records)
+    ]
+
+
+def build_pv_records(n_pvs: int, num_slots: int, vocab_per_slot: int,
+                     dense_dim: int, seed: int = 0):
+    """Seeded search pages for the PV rank-attention lane: 2-4 ads per
+    PV with shuffled 1-based ranks and valid cmatch, so every batch
+    carries a dense rank_offset matrix (data/pv.build_rank_offset)."""
+    from paddlebox_tpu.data.record import SlotRecord
+    rng = np.random.default_rng(seed)
+    recs = []
+    for sid in range(n_pvs):
+        n_ads = int(rng.integers(2, 5))
+        ranks = rng.permutation(n_ads) + 1
+        for a in range(n_ads):
+            keys = (rng.integers(0, vocab_per_slot, num_slots)
+                    + np.arange(num_slots) * vocab_per_slot).astype(
+                        np.uint64)
+            label = float(rng.random() < 0.25)
+            recs.append(SlotRecord(
+                keys=keys,
+                slot_offsets=np.arange(num_slots + 1, dtype=np.int32),
+                dense=rng.normal(size=dense_dim).astype(np.float32),
+                label=label, show=1.0, clk=label, search_id=sid,
+                rank=int(ranks[a]), cmatch=222))
+    return recs
+
+
 def ragged_dataset(rows: int, w: Widths, seed: int):
     """Multi-key slots (the feed-log shape): the only layout whose
     pooling is not a reshape, i.e. where the seqpool kernel runs."""
-    from bench import build_records
     from paddlebox_tpu.data import DataFeedDesc, InMemoryDataset, SlotDef
     slots = [SlotDef("label", "float", 1), SlotDef("dense", "float", 13)]
     slots += [SlotDef(f"C{i}", "uint64") for i in range(1, 27)]
@@ -581,14 +662,13 @@ def check_state_close(flag: str, off, on) -> None:
 
 
 class PvJob:
-    """The AdsRank-PV job of bench.measure_pv: PV-merged batches with a
-    rank_offset matrix through rank_attention + the slot_fc batch_fc
-    tower + the cross_norm block, over a pull→train→push loop."""
+    """The AdsRank-PV job: PV-merged batches with a rank_offset matrix
+    through rank_attention + the slot_fc batch_fc tower + the
+    cross_norm block, over a pull→train→push loop."""
 
     def __init__(self, w: Widths) -> None:
         import jax
         import jax.numpy as jnp
-        from bench import build_pv_records
         from paddlebox_tpu.data import DataFeedDesc, SlotDef
         from paddlebox_tpu.data.pv import PvBatchBuilder
         from paddlebox_tpu.models import AdsRank
@@ -760,7 +840,7 @@ def phase_kernels(watch: CompileWatch, ds_uniform, desc_uniform,
         print(f"[kernels] {flag}: state digest bit-identical to flag-off",
               flush=True)
 
-    # the CTR family over one AdsRank-PV pass at bench's on-chip widths
+    # the CTR family over one AdsRank-PV pass at the Widths' pv_* sizes
     job = PvJob(w)
     off = dict(use_pallas_rank_attention=False, use_pallas_batch_fc=False,
                use_pallas_cross_norm=False)

@@ -31,16 +31,8 @@ class Flags:
     """Process-wide tunables. Defaults mirror the reference's flag defaults
     where a counterpart exists (cited per field)."""
 
-    # --- sparse pull/push (reference: FLAGS_enable_pullpush_dedup_keys,
-    # box_wrapper_impl.h:20) ---
-    enable_pullpush_dedup_keys: bool = True
-    # zero-pad embedding outputs for zero-length slots
-    # (reference: FLAGS_enable_pull_box_padding_zero, pull_box_sparse_op.h:25)
-    padding_zeros: bool = True
-
     # --- data pipeline (reference: platform/flags.cc:946-975) ---
     record_pool_max_size: int = 2_000_000
-    shuffle_thread_num: int = 8
     read_thread_num: int = 8
     channel_capacity: int = 65536
     # native C++ file→columnar parse fast path (data/parser.py,
@@ -49,9 +41,6 @@ class Flags:
 
     # --- trainer (reference: boxps_worker.cc) ---
     check_nan_inf: bool = False
-    enable_gc: bool = True
-    sync_dense_every_steps: int = 1  # K-step dense sync (boxps_worker.cc:1317)
-    enable_sharding_stage: int = 0   # FLAGS_padbox_enable_sharding_stage
 
     # --- embedding store ---
     # Default per-shard row capacity; tables are statically sized for XLA.
@@ -76,8 +65,6 @@ class Flags:
     host_demote_watermark: float = 0.92
     # demotion drains RAM occupancy down to this fraction
     host_demote_target: float = 0.8
-    # embedx (mf) lazy-creation threshold semantics (optimizer.cuh.h:105)
-    mf_create_threshold: float = 0.0
     # feature shrink: drop rows whose decayed show falls below this
     shrink_delete_threshold: float = 0.0
     show_click_decay_rate: float = 0.98
@@ -182,8 +169,8 @@ class Flags:
     # write-back lands (clean rows only — release + accounting, no D2H)
     # so steady-state begin_pass pays only for genuinely-new rows; the
     # inline eviction in begin_pass remains as the emergency path
-    # (reported as evict_emergency_sec vs evict_async_sec in the bench's
-    # begin_stall_breakdown). False = eviction stays fully inline at
+    # (reported as evict_emergency_sec vs evict_async_sec in the table's
+    # last_pass_stats). False = eviction stays fully inline at
     # begin_pass (the pre-pipeline behavior).
     async_capacity_evict: bool = True
 

@@ -9,7 +9,7 @@ ads (``rank_attention`` op, operators/rank_attention_op.*) plus slot-wise
 half; paddlebox_tpu/data/pv.py builds the batches.
 
 The optional towers exercise the full device-side CTR op family
-(ISSUE 13 — the PV bench lane runs with all three on):
+(ISSUE 13 — chip_smoke's kernels phase runs with all three on):
 
 - ``slot_fc``: a per-slot ``batch_fc`` projection over the pooled
   embeddings (the reference's slot-wise tower, batch_fc_op default

@@ -10,9 +10,9 @@ another machine is never loaded.
 
 ``load_native()`` returns None when the toolchain is missing (tests that
 ask for the Python index by name still run); the chip entry points
-(chip_smoke.py, bench.py) call ``require_native()``, which raises — the
-native index is ~50x faster on the per-batch key→row hot path and a
-silent fallback would hide that.
+(chip_smoke.py, benchmarks/run.py) call ``require_native()``, which
+raises — the native index is ~50x faster on the per-batch key→row hot
+path and a silent fallback would hide that.
 """
 
 from __future__ import annotations

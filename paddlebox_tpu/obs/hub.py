@@ -21,7 +21,7 @@ nothing measurable per step.
 
 Enable via flags: ``FLAGS.telemetry_jsonl=/path/run.jsonl`` attaches a
 JSONL sink, ``FLAGS.telemetry_prom_port>=0`` starts the HTTP endpoint
-(``configure_from_flags`` is called by Trainer init and bench.py).
+(``configure_from_flags`` is called by Trainer init).
 """
 
 from __future__ import annotations
@@ -522,8 +522,8 @@ def reset_hub() -> TelemetryHub:
 
 def configure_from_flags() -> TelemetryHub:
     """Attach flag-selected sinks to the global hub (idempotent; called
-    by Trainer init and bench.py so ``FLAGS_telemetry_jsonl=...`` in the
-    environment is all a run needs)."""
+    by Trainer init so ``FLAGS_telemetry_jsonl=...`` in the environment
+    is all a run needs)."""
     global _configured_jsonl
     from paddlebox_tpu.config import FLAGS
     hub = _HUB
@@ -559,7 +559,7 @@ def emit_pass_event(kind: str, metrics: Dict, stage_timers=None,
     ev: Dict = {"kind": kind}
     for k in ("batches", "elapsed_sec", "examples_per_sec", "auc",
               "last_loss", "global_step", "pass_seq",
-              "exchange_overlap_frac", "actual_ctr", "predicted_ctr"):
+              "actual_ctr", "predicted_ctr"):
         if k in metrics:
             ev[k] = metrics[k]
     if examples is not None:

@@ -87,17 +87,6 @@ LANE_PRELOAD = "preload.worker"
 LANE_EPILOGUE = "epilogue.lane"
 LANE_SSD = "ssd.compact"
 LANE_READER = "stream.reader"
-#: device-side exchange/compute attribution (ISSUE 11): the sharded
-#: step's chunked embedding all_to_alls and their interleaved pooling,
-#: measured by the decomposed probe (train/a2a_probe) — spans are
-#: ``a2a.pull.<k>`` / ``pool.<k>`` / ``a2a.push`` on this row
-LANE_DEVICE = "device.a2a"
-#: per-kernel device attribution (ISSUE 12): the embed-pool-CVM kernel
-#: family measured by the kernel microbench
-#: (scripts/profile_keypath.py --set kernels) — spans are
-#: ``kernel.{gather,pool_cvm,fused}[. _xla]`` on this row, one per
-#: timed probe re-run, so a trace shows Pallas vs XLA cost side by side
-LANE_KERNELS = "device.kernels"
 
 #: the device scope catalog: ``jax.named_scope`` names on the train
 #: step's ops (train/step.py, train/device_pass.py, train/sharded.py).
@@ -421,12 +410,9 @@ class ChromeLaneTraceSink:
 # ---- per-pass critical-path attribution --------------------------------
 #: boundary stage keys the drivers report (note_pass_part); "train" is
 #: implicit (the pass event's elapsed_sec). Order = report/docs order.
-#: ``exchange_wait`` is the sharded step's measured NON-overlapped
-#: embedding-exchange seconds per pass (train/a2a_probe): the part of
-#: the pull/push all_to_all the schedule could not hide behind compute.
 BOUNDARY_STAGES = ("build_wait", "stage_wait", "fence_wait",
                    "ssd_promote", "evict_emergency", "evict_scatter",
-                   "exchange_wait", "end_submit")
+                   "end_submit")
 
 _PARTS_LOCK = threading.Lock()
 _PENDING_PARTS: Dict[str, float] = {}

@@ -60,11 +60,9 @@ rationale, not today's rates:
   nondecreasing ids), and ``fused_pool_cvm_forward`` (pool + CVM in
   one VMEM residency). The expand gather (``vals_u[gather_idx]``,
   UNSORTED ids) stays on XLA's clamped gather — the one-hot form is
-  O(K·U·D) there and per-row DMA is ruled out above. Per-shape numbers:
-  ``scripts/profile_keypath.py --set kernels`` →
-  ``kernel.{gather,pool_cvm,fused}.{shape}.{backend}`` trajectory rows,
-  gated by ``scripts/perf_gate.py`` (docs/PERFORMANCE.md §Device
-  kernels).
+  O(K·U·D) there and per-row DMA is ruled out above. Whether any of
+  them beats its XLA composition is not measured on this installation
+  (PERF.md section 7).
 """
 
 from __future__ import annotations
@@ -98,7 +96,7 @@ def _book_dispatch(kernel: str, impl: str) -> None:
     branch runs once per compiled executable), so the counter counts
     compiled-program dispatches, not per-batch executions — enough to
     prove which implementation a run's programs actually contain
-    (docs/OBSERVABILITY.md §Device kernels). Inert without an active
+    (docs/OBSERVABILITY.md, instrument catalog). Inert without an active
     hub."""
     try:
         from paddlebox_tpu.obs.hub import get_hub

@@ -101,10 +101,8 @@ def plan_sections(idx: "ShardedPullIndex") -> Tuple:
 
 
 def section_offsets(sections) -> List[int]:
-    """Start offset of each contiguous section (exclusive-prefix sum).
-    Shared by every consumer of a grouped plan's static layout — the
-    chunked device step and the exchange probe must slice the SAME
-    positions (train/sharded._device_step, train/a2a_probe)."""
+    """Start offset of each contiguous section (exclusive-prefix sum)
+    of a grouped plan's static layout (train/sharded._device_step)."""
     off, t = [], 0
     for x in sections:
         off.append(t)

@@ -73,7 +73,7 @@ def _lane_select(mask: jax.Array, values: jax.Array) -> jax.Array:
     to bleed into every healthy row sharing its 128-lane storage line
     (and, through the scatter-add transpose, into their updates) —
     ``where`` keeps a NaN confined to its own lane span, which is what
-    lets telemetry localize a NaN to ONE key (round-5 advisor finding).
+    lets telemetry localize a NaN to ONE key.
     Exact f32 either way (select, no arithmetic)."""
     return jnp.where(mask.astype(bool)[:, :, None], values, 0)
 
@@ -637,8 +637,7 @@ def gather_full_rows(state: TableState, unique_rows: jax.Array,
     mask + sum over the rows-per-line axis extracts the row's slice
     in-register. The earlier take_along_axis extract lowered to a SECOND
     per-index gather and cost as much as the line fetch itself — the
-    mask extract is pure VPU work (measured: 23.3 → 12.9 ms at U=491k,
-    scripts/profile_keypath2.py, round 5). Pad/OOB ids are clamped to
+    mask extract is pure VPU work. Pad/OOB ids are clamped to
     the SENTINEL row before the line split so they read its zeros —
     clamping raw line indices instead would let a far-OOB id alias a
     real row when capacity % rows_per_line == rpl-1.
@@ -891,15 +890,13 @@ def pull_rows(state: TableState, unique_rows: jax.Array) -> jax.Array:
 def expand_pull(values_u: jax.Array, gather_idx: jax.Array) -> jax.Array:
     """[U, D] unique values → [K, D] per-key-occurrence values.
 
-    LANE-PACKED formulation (round 5): the naive ``values_u[gather_idx]``
+    LANE-PACKED formulation: the naive ``values_u[gather_idx]``
     row gather — and, worse, its autodiff transpose (the per-unique grad
     merge) — pay XLA's per-index cost on narrow strided rows. Packing
     the unique values into 128-lane lines (8 rows/line at D ≤ 16) makes
     the forward a line fetch + one-hot VPU extract and the TRANSPOSE a
     line-granular scatter-add of masked deltas (the apply_push trick,
-    derived by autodiff for free). Measured at the ragged bench shape
-    (K=557k, U=491k): fwd 18.1 → 11.0 ms, transpose 39.4 → 13.3 ms
-    (scripts/profile_keypath3.py, exact f32 both ways). Falls back to
+    derived by autodiff for free). Exact f32 both ways. Falls back to
     the plain gather when the shapes don't line-align."""
     u, d = values_u.shape
     fp = _f_pad(d) if d <= 128 else 0
@@ -930,7 +927,7 @@ def merge_rows(values: jax.Array, idx: jax.Array,
     """segment_sum of narrow rows in LANE-PACKED form: [M, D] values
     summed by ``idx`` into [num_segments, D]. A scatter-add into a
     [num, D<16] accumulator is random-access RMW on strided narrow rows
-    (~3x slower than line-granular — DESIGN_NOTES §4i); this packs each
+    (docs/DESIGN_NOTES.md §4a); this packs each
     contribution into its row's lane span of a 128-lane line delta and
     scatter-adds whole lines (disjoint-lane adds commute exactly, the
     apply_push trick). Exact f32; falls back to jax.ops.segment_sum when
@@ -938,9 +935,8 @@ def merge_rows(values: jax.Array, idx: jax.Array,
     m, d = values.shape
     fp = _f_pad(d) if d <= 128 else 0
     rpl = 128 // fp if fp else 0
-    # the line form wins in the RMW-bound regime (large accumulators):
-    # measured 13.3 vs 39.4 ms into 491k segments but ~13 vs 12.6 into
-    # 106k — below the crossover the plain scatter-add is already fast
+    # the line form wins in the RMW-bound regime (large accumulators);
+    # into a small accumulator the plain scatter-add is already fast
     # and the [M, 128] delta materialization is pure overhead
     if not fp or num_segments % rpl or num_segments <= (1 << 17):
         return jax.ops.segment_sum(values, idx, num_segments=num_segments)

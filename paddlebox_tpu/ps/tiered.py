@@ -101,8 +101,8 @@ queued stage's working set, and plan-pending rows (the capacity-union
 contract above). Rows dirtied after the end_pass snapshot are skipped
 and fall to the EMERGENCY inline path in begin_pass (the pre-pipeline
 eviction, with its fence + dirty write-back), reported separately as
-``evict_emergency_sec`` vs ``evict_async_sec`` in the bench's
-``begin_stall_breakdown``. ``FLAGS.async_capacity_evict=False``
+``evict_emergency_sec`` vs ``evict_async_sec`` in
+``last_pass_stats``. ``FLAGS.async_capacity_evict=False``
 restores fully-inline eviction.
 """
 
@@ -787,8 +787,7 @@ class TieredShardedEmbeddingTable(ShardedEmbeddingTable):
         self.wait_stage_done()
         # critical-path stall spent WAITING on the stage (host fetch +
         # any SSD promote it triggered) — near zero when the stage
-        # overlapped the previous pass's training (bench begin_stall
-        # breakdown; docs/STORAGE.md)
+        # overlapped the previous pass's training (docs/STORAGE.md)
         self._last_stage_wait_sec = time.perf_counter() - t0
         st = self._stage
         if st is None:
@@ -882,7 +881,7 @@ class TieredShardedEmbeddingTable(ShardedEmbeddingTable):
         finally:
             self.host_lock.release()
         self.in_pass = True
-        # begin_stall breakdown (bench tiered mode): stage wait on the
+        # begin_stall breakdown: stage wait on the
         # critical path, evict+scatter time, and the SSD promote
         # seconds this pass's staging incurred (with its critical-path
         # share — overlapped promotes show promote_sec > 0 with

@@ -792,8 +792,8 @@ class ResidentPass:
         runs outside the lock), then the per-batch sort/rank splits fan
         out over a thread pool (numpy releases the GIL). The old path
         acquired host_lock once PER BATCH with the index assign inside
-        — nb serialized lock round-trips on the preloader thread,
-        measured as the dominant prologue stall (BENCH_r05). New-row
+        — nb serialized lock round-trips on the preloader thread, the
+        dominant prologue stall. New-row
         allocation order is first-seen over the pass, matching a serial
         batch walk of the native (first-occurrence) index row for row.
 
@@ -1086,8 +1086,8 @@ class ResidentPassRunner:
         (u18 lows are uint16; the GRID leaf is the only 2-D uint8).
         Both count wires decode with the scatter+cumsum identity —
         out[p] = #{cells whose cumulative count <= p} == the
-        searchsorted(cum, arange, "right") this replaced, measured 14x
-        faster (56 → 3.9 ms at K=557k, scripts/profile_keypath.py)."""
+        searchsorted(cum, arange, "right") this replaced: a binary
+        search per output slot against one scatter and one prefix sum."""
 
         def cum_decode(counts_flat, k):
             # empty cells stack duplicate boundary marks, hence .add;
@@ -1321,7 +1321,7 @@ class PassPreloader:
     (FLAGS.preload_depth, default 2). Pass k+2's build starts the
     moment k+1's finishes — no join-per-consume, so a slow build no
     longer serializes into the next pass boundary (the depth-1
-    alternating-stall pattern of BENCH_r05).
+    alternating-stall pattern).
 
     With the tiered tables' ASYNC EPILOGUE (ps/epilogue,
     FLAGS.async_end_pass) the steady-state pipeline is FOUR-deep: pass
@@ -1376,8 +1376,7 @@ class PassPreloader:
         depth = FLAGS.preload_depth if depth is None else depth
         # depth=0 → MANUAL mode: the worker builds one pass per
         # start_next() credit instead of free-running (the depth-1
-        # era's strict kick-per-pass protocol; bench's no-overlap
-        # control uses it)
+        # era's strict kick-per-pass protocol)
         self._manual = depth == 0
         self._credits = 0
         self.depth = max(1, depth)

@@ -1320,14 +1320,8 @@ class ShardedTrainer:
                      "%.0f ex/s, auc=%.4f", log_prefix, rp.num_batches,
                      out["examples_per_sec"], res.auc)
             from paddlebox_tpu.obs.hub import emit_pass_event
-            ev = dict(out, global_step=self.global_step)
-            pr = getattr(self, "_last_exchange_probe", None)
-            if pr is not None:
-                # measured by train/a2a_probe (the sharded bench runs
-                # it); rides the pass event → telemetry_report's
-                # "a2a ovl" column
-                ev["exchange_overlap_frac"] = pr["exchange_overlap_frac"]
-            emit_pass_event("train_pass_resident_sharded", ev,
+            emit_pass_event("train_pass_resident_sharded",
+                            dict(out, global_step=self.global_step),
                             table=self.table, examples=rp.num_records)
         return out, rp
 

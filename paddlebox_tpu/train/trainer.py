@@ -927,7 +927,7 @@ class Trainer:
             replayed = int(getattr(dataset, "files_replayed", 0)) - rep0
             # files CONSUMED, not dispatched: a window file quarantined
             # during this pass never trained, so it must not inflate
-            # the throughput totals (bench stream mode divides by them)
+            # the throughput totals
             # or desync pbox_stream_files_total from files_completed
             quarantined = {p for p, _ in
                            getattr(dataset, "quarantined_files", [])}

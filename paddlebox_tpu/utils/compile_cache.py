@@ -19,7 +19,7 @@ Two sources for the directory, no third:
   fill the tree that is copied to the chip machine.
 
 ``enable_compilation_cache()`` is the one switch, called from the one
-point every entry shares (trainers, ServingModel, bench.py,
+point every entry shares (trainers, ServingModel, benchmarks/run.py,
 chip_smoke.py all build a table before their first compile):
 ``ps/table.init_table_state``.
 """

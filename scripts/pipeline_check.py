@@ -24,7 +24,7 @@ PROLOGUE gate (``run_prologue_check``, ISSUE 5): the depth-N preload
 pipeline's twin —
 
 (a) scheduling property: with deterministic sleep-timed builds
-    (bimodal, avg build < train — the BENCH_r05 shape), the depth-N
+    (bimodal, avg build < train), the depth-N
     pipeline's steady-state per-pass wait drops vs depth-1 (the queue
     absorbs the slow builds instead of joining on each), and
 (b) bit-identity: a REAL 4-pass single-chip resident training job run

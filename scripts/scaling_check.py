@@ -11,8 +11,9 @@ exchange's shape, never its math.
 
 Graceful skip (exit 0 with a SKIP note): fewer than 2 visible devices.
 
-Scaling itself is a chip measurement: ``BENCH_MODE=sharded python
-bench.py`` over all chips of a host, never virtual CPU devices.
+Scaling itself is a chip measurement over all chips of a host, never
+virtual CPU devices: the four-chip training cell that ROADMAP.md R1
+asks of ``benchmarks/``.
 """
 
 from __future__ import annotations

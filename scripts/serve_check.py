@@ -15,8 +15,8 @@ Four legs, one seeded scenario (``run_serve_check``):
      version (each query pins one snapshot; its lookup digest must
      equal that version's replay oracle — no torn reads across swaps);
    - query p99 latency ≤ ``SERVE_CHECK_P99_MS`` (default 500 ms — an
-     intentionally generous CI bound; the bench lane tracks the real
-     number) and snapshot staleness ≤ ``SERVE_CHECK_STALENESS_SEC``;
+     intentionally generous CI bound, not a serving latency) and
+     snapshot staleness ≤ ``SERVE_CHECK_STALENESS_SEC``;
    - ``/readyz`` refuses before the first adoption and passes after.
 
 2. **tiered publisher** — a three-tier (host RAM + SSD segments)
@@ -65,8 +65,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-#: CI-generous SLO bounds (env-overridable); the serve bench lane
-#: (BENCH_MODE=serve) tracks the real numbers with a perf gate.
+#: CI-generous SLO bounds (env-overridable); real serving latency waits
+#: for a serving cell in benchmarks/ (ROADMAP.md R2).
 P99_BOUND_MS = float(os.environ.get("SERVE_CHECK_P99_MS", "500"))
 STALENESS_BOUND_SEC = float(
     os.environ.get("SERVE_CHECK_STALENESS_SEC", "30"))

@@ -5,10 +5,10 @@ Usage: python scripts/telemetry_report.py RUN.jsonl [--events]
        python scripts/telemetry_report.py --xplane TRACE_DIR
 
 Reads the event stream the TelemetryHub's JsonlSink wrote
-(FLAGS_telemetry_jsonl=..., or bench.py's BENCH_telemetry.jsonl) and
-prints one row per pass: throughput, stage breakdown, queue stalls
-(diffed from the cumulative channel counters between consecutive pass
-events of the same process), table occupancy and the HBM peak.
+(FLAGS_telemetry_jsonl=...) and prints one row per pass: throughput,
+stage breakdown, queue stalls (diffed from the cumulative channel
+counters between consecutive pass events of the same process), table
+occupancy and the HBM peak.
 ``--events`` appends the non-pass events (stragglers, scatter warmups)
 at the end. Stdlib only — runs anywhere the JSONL lands.
 
@@ -112,20 +112,6 @@ def _bottleneck_cell(cp: Dict) -> str:
     if b == "device":
         return f"device (+{stall:.3f}s stalls)"
     return f"{b} +{stall:.3f}s"
-
-
-def _a2a_cell(ev: Dict) -> str:
-    """Per-pass exchange-overlap fraction (ISSUE 11): how much of the
-    sharded step's embedding all_to_all the chunked schedule hid behind
-    compute (train/a2a_probe, riding the pass event when the sharded
-    bench ran the probe; the critical_path's exchange_wait_sec is the
-    remainder)."""
-    v = ev.get("exchange_overlap_frac")
-    if v is None:
-        cp = ev.get("critical_path") or {}
-        w = cp.get("exchange_wait_sec")
-        return f"wait {float(w):.3f}s" if w is not None else ""
-    return f"{float(v):.0%}"
 
 
 def _begin_stall_cell(lp: Dict) -> str:
@@ -249,7 +235,6 @@ def build_rows(events: List[dict]) -> List[Dict[str, str]]:
             "begin stall": begin_stall or "-",
             "bottleneck": _bottleneck_cell(ev.get("critical_path", {}))
             or "-",
-            "a2a ovl": _a2a_cell(ev) or "-",
             "hbm peak": _fmt_bytes(hbm.get("peak_bytes_in_use", 0)),
         })
         if any_serving:

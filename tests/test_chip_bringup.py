@@ -1,7 +1,8 @@
-"""Tier-1 (CPU) gates for the chip bring-up (ISSUE 21): the entry points
-that measure refuse to run without a chip, the compile cache lands where
-the contract says, peaks come from one table, the native library is
-never a foreign binary, and the dispatch counter books what ran."""
+"""Tier-1 (CPU) gates for the chip bring-up (ISSUE 21): chip_smoke
+refuses to run without a chip, its seeded record generators give the
+traffic they name, the compile cache lands where the contract says,
+peaks come from one table, the native library is never a foreign
+binary, and the dispatch counter books what ran."""
 
 import importlib.util
 import json
@@ -38,15 +39,6 @@ def test_chip_smoke_refuses_cpu():
     # the platform it found is printed; the JSON verdict is not
     assert "platform=cpu" in r.stdout
     assert '"ok"' not in r.stdout
-
-
-def test_bench_refuses_cpu():
-    r = _run([sys.executable, os.path.join(REPO, "bench.py")],
-             env={"JAX_PLATFORMS": "cpu", "BENCH_TRAJECTORY": "0"},
-             timeout=120)
-    assert r.returncode != 0
-    assert "'cpu'" in r.stderr, r.stderr[-500:]
-    assert '"metric"' not in r.stdout
 
 
 # ---- (b) compile-cache placement ---------------------------------------
@@ -108,21 +100,19 @@ def test_cache_stays_off_on_cpu():
 
 def test_peak_table_raises_on_unknown_device_kind():
     spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    v5e = bench.device_peaks("TPU v5 lite")
-    assert v5e["bf16_flops_per_sec"] == 197e12 and v5e["source"]
+        "peaks", os.path.join(REPO, "benchmarks", "peaks.py"))
+    peaks = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(peaks)
+    v5e = peaks.device_peaks("TPU v5 lite")
+    assert v5e["bf16_flops_per_s"] == 197e12 and v5e["source"]
     with pytest.raises(KeyError, match="TPU v9"):
-        bench.device_peaks("TPU v9")
-    assert "BENCH_PEAK_TFLOPS" not in open(
-        os.path.join(REPO, "bench.py")).read()
+        peaks.device_peaks("TPU v9")
 
 
 # ---- (d) native library: strict on the chip path, never foreign ---------
 
 def test_strict_native_loader_raises_when_build_fails():
-    """CXX=false: require_native() (chip_smoke / bench) raises, while
+    """CXX=false: require_native() (chip_smoke / benchmarks) raises, while
     make_kv's python index — which tests ask for by name — still works.
     The failed build leaves the real artifact untouched."""
     code = """
@@ -259,6 +249,55 @@ def test_mesh_state_born_sharded_and_second_pass_compiles_nothing(
         jax.monitoring.unregister_event_duration_listener(on_compile)
     assert first > 0
     assert compiles[first:] == [], compiles[first:]
+
+
+# ---- chip_smoke's seeded record generators ------------------------------
+
+@pytest.mark.parametrize("shape", ["uniform", "ragged", "zipf"])
+def test_smoke_records(shape, monkeypatch):
+    """build_records: every key inside its slot's own vocabulary range,
+    at least one key a slot, ragged counts with the asked mean, a Zipf
+    draw whose commonest id dominates its slot; build_pv_records pages
+    of 2-4 ads with ranks 1..n."""
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke as cs
+    slots, vocab, n, avg = 6, 500, 400, 3.0
+    recs = cs.build_records(
+        n, num_slots=slots, vocab_per_slot=vocab, seed=3,
+        avg_keys_per_slot=avg if shape == "ragged" else 1.0,
+        key_dist="zipf" if shape == "zipf" else "uniform")
+    assert len(recs) == n
+    counts = np.stack([np.diff(r.slot_offsets) for r in recs])
+    assert counts.shape == (n, slots) and counts.min() >= 1
+    by_slot = [[] for _ in range(slots)]
+    for r in recs:
+        assert r.keys.dtype == np.uint64
+        assert len(r.keys) == r.slot_offsets[-1]
+        for s in range(slots):
+            by_slot[s].append(r.keys[r.slot_offsets[s]:
+                                     r.slot_offsets[s + 1]])
+    for s, chunks in enumerate(by_slot):
+        keys = np.concatenate(chunks).astype(np.int64)
+        assert keys.min() >= s * vocab and keys.max() < (s + 1) * vocab
+        top_share = np.bincount(keys - s * vocab).max() / len(keys)
+        if shape == "zipf":
+            assert top_share > 0.05, top_share
+        else:
+            assert top_share < 0.05, top_share
+    if shape == "ragged":
+        assert counts.mean() == pytest.approx(avg, rel=0.10)
+        assert counts.max() > 1
+    else:
+        assert (counts == 1).all()
+    pv = cs.build_pv_records(20, slots, vocab, dense_dim=4, seed=3)
+    pages = {}
+    for r in pv:
+        assert len(r.keys) == slots and r.cmatch == 222
+        pages.setdefault(r.search_id, []).append(r.rank)
+    assert sorted(pages) == list(range(20))
+    for ranks in pages.values():
+        assert 2 <= len(ranks) <= 4
+        assert sorted(ranks) == list(range(1, len(ranks) + 1))
 
 
 # ---- chip_smoke's phases stay runnable ---------------------------------

@@ -60,10 +60,13 @@ def test_rank_attention_forward_parity(param_2d, all_invalid):
 
 @pytest.mark.parametrize("param_2d", [False, True])
 @pytest.mark.parametrize("enable_input_bp", [False, True])
-def test_rank_attention_grads_bitwise(param_2d, enable_input_bp):
+def test_rank_attention_grads_match_composition(param_2d, enable_input_bp):
     """Same upstream cotangent ⇒ the fused custom_vjp's grads match the
-    XLA composition's autodiff EXACTLY (the backward einsums/scatter
-    are the same ops); dX is exactly zero without enable_input_bp."""
+    XLA composition's autodiff to rtol 1e-6, atol 1e-6, not bit for bit:
+    the two backward passes are the same einsums and scatter, but XLA:CPU
+    fuses the composition's backward einsum and the custom_vjp's
+    differently (1e-6 drift here; PR 21 read 1.5e-5 between them on the
+    chip). dX is exactly zero without enable_input_bp."""
     x, ro, param = _rank_case(seed=3)
     if param_2d:
         param = param.reshape(MR * MR * x.shape[1], -1)
@@ -79,8 +82,10 @@ def test_rank_attention_grads_bitwise(param_2d, enable_input_bp):
 
     gx0, gp0 = grads(False)
     gx1, gp1 = grads(True)
-    np.testing.assert_array_equal(np.asarray(gx1), np.asarray(gx0))
-    np.testing.assert_array_equal(np.asarray(gp1), np.asarray(gp0))
+    np.testing.assert_allclose(np.asarray(gx1), np.asarray(gx0),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(gp1), np.asarray(gp0),
+                               rtol=1e-6, atol=1e-6)
     assert np.asarray(gp1).shape == param.shape  # cotangent keeps layout
     if not enable_input_bp:
         np.testing.assert_array_equal(np.asarray(gx1), 0.0)

@@ -16,9 +16,6 @@ logical state:
   returns the identical lines bitwise, so the digest must match EXACTLY.
 """
 
-import json
-import os
-
 import jax
 import numpy as np
 import optax
@@ -31,10 +28,6 @@ from paddlebox_tpu.models import DeepFM
 from paddlebox_tpu.ps import EmbeddingTable, SparseSGDConfig
 from paddlebox_tpu.train import Trainer
 from paddlebox_tpu.train.checkpoint import state_digest
-
-REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                         os.pardir))
-
 
 @pytest.fixture(scope="module")
 def criteo_files(tmp_path_factory):
@@ -424,30 +417,3 @@ def test_index_abort_polled_build_rolls_back(criteo_files):
         tr0, ds0 = _trainer_uniform(criteo_files)
         tr0.train_passes_resident([ds0], depth=0)
     assert state_digest(tr) == state_digest(tr0)
-
-
-def test_committed_kernel_trajectory_gates():
-    """The interpret-mode CPU kernel round is recorded (satellite:
-    kernel.* rows live in BENCH_trajectory.json) and the perf gate
-    passes over it."""
-    import importlib.util
-    path = os.path.join(REPO_ROOT, "BENCH_trajectory.json")
-    with open(path) as fh:
-        data = json.load(fh)
-    metrics = {r["metric"] for r in data["rows"]}
-    for probe in ("gather", "pool_cvm", "fused",
-                  # the ISSUE 13 CTR family round (KERNELS_r02)
-                  "rank_attention", "batch_fc", "cross_norm",
-                  # the ISSUE 19 device key-index round (KERNELS_r03)
-                  "index.insert", "index.lookup", "index.dedup"):
-        assert any(m.startswith(f"kernel.{probe}.") and m.endswith(".cpu")
-                   for m in metrics), f"no recorded kernel.{probe}.* row"
-    # the PV rank-attention bench lane's rows (BENCH_MODE=pv) are
-    # folded and gated alongside the kernel rounds
-    assert "adsrank_pv_examples_per_sec_per_chip" in metrics
-    assert "adsrank_pv_examples_per_sec_per_chip_pallas" in metrics
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(REPO_ROOT, "scripts", "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-    assert pg.check(path, ignore_live=True) == 0

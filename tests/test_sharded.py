@@ -821,44 +821,3 @@ def test_a2a_grouped_plan_layout():
         # sentinel (resp pad), so in-section pad keys read zeros
         assert (p.resp_idx[:, :, a_lo[g + 1] - 1]
                 == p.serve_capacity - 1).all()
-
-
-def test_a2a_probe_reports_and_spans(mesh, chunk_parity_ds):
-    """train/a2a_probe: per-chunk a2a/pool seconds with the right
-    arity, a sane overlap fraction, the exchange_wait critical-path
-    part, and a2a.pull.*/a2a.push spans on the device.a2a lane when a
-    trace sink is attached."""
-    from paddlebox_tpu.obs import trace
-    from paddlebox_tpu.obs.hub import get_hub
-    from paddlebox_tpu.obs.trace import ChromeLaneTraceSink
-    from paddlebox_tpu.train.a2a_probe import probe_exchange
-    from paddlebox_tpu.utils.profiler import ChromeTraceWriter
-    ds, desc = chunk_parity_ds
-    tr = _chunk_trainer(mesh, desc, 2)
-    tr.train_pass(ds)
-    w = ChromeTraceWriter()
-    sink = ChromeLaneTraceSink(w)
-    hub = get_hub()
-    hub.add_sink(sink)
-    try:
-        trace.reset()
-        pr = probe_exchange(tr, dataset=ds, reps=1)
-    finally:
-        hub.remove_sink(sink)
-    assert pr["a2a_chunks"] == 2
-    assert len(pr["a2a_pull_sec"]) == 2 and len(pr["pool_sec"]) == 2
-    assert all(t > 0 for t in pr["a2a_pull_sec"] + pr["pool_sec"])
-    assert 0.0 <= pr["exchange_overlap_frac"] <= 1.0
-    assert pr["exchange_wait_sec"] >= 0.0
-    # the wait part rides the next pass event's critical_path — unless
-    # the measured wait was exactly 0 (CPU timing noise can make the
-    # monolithic step read slower than chunked by more than the whole
-    # exchange; note_pass_part skips zero parts by design)
-    parts = trace.consume_pass_parts()
-    assert "exchange_wait" in parts or pr["exchange_wait_sec"] == 0.0
-    names = {e.get("name") for e in w._events}
-    assert {"a2a.pull.0", "a2a.pull.1", "pool.0", "pool.1",
-            "a2a.push"} <= names
-    lanes = {e.get("args", {}).get("lane") for e in w._events
-             if e.get("name", "").startswith("a2a.")}
-    assert lanes == {trace.LANE_DEVICE}

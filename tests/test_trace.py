@@ -3,7 +3,9 @@ lane sink's tid rows + flow arrows, the critical-path block math, and
 the cross-thread span contract over a REAL depth-2 tiered pipeline job
 (ISSUE 10 acceptance surface)."""
 
+import importlib.util
 import json
+import os
 import threading
 
 import jax
@@ -379,17 +381,20 @@ def test_depth2_tiered_job_emits_linked_lane_spans(mesh, tmp_path):
                                     "evict_scatter", "end_submit")
 
 
-def test_jsonl_report_renders_bottleneck_column(tmp_path, fresh_hub):
-    """telemetry_report renders the per-pass bottleneck column and the
-    whole-run critical-path summary from synthetic events."""
-    import importlib.util
-    import os
+def _telemetry_report():
     spec = importlib.util.spec_from_file_location(
         "telemetry_report",
         os.path.join(os.path.dirname(__file__), os.pardir, "scripts",
                      "telemetry_report.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+def test_jsonl_report_renders_bottleneck_column(tmp_path, fresh_hub):
+    """telemetry_report renders the per-pass bottleneck column and the
+    whole-run critical-path summary from synthetic events."""
+    mod = _telemetry_report()
     events = []
     for i in range(8):
         # pass 2: the build stall (0.74s) exceeds its train (0.5s) —
@@ -408,3 +413,30 @@ def test_jsonl_report_renders_bottleneck_column(tmp_path, fresh_hub):
     assert "bottleneck" in report
     assert "7/8 passes device-bound" in report
     assert "pass 2 build_wait-bound: +0.740s" in report
+
+
+def test_critical_path_smoke_end_to_end():
+    """Deterministic synthetic pass parts → block math → report
+    verdicts, no trainers involved."""
+    tr = _telemetry_report()
+    # 4 device-bound passes, one fence-bound straggler
+    events = []
+    specs = [(1.0, {"build_wait": 0.05}), (1.0, {}),
+             (0.8, {"fence_wait": 1.2}), (1.0, {"stage_wait": 0.02}),
+             (1.0, {"evict_emergency": 0.4})]
+    for i, (train, parts) in enumerate(specs):
+        blk = trace.critical_path_block(train, parts)
+        assert blk["wall_sec"] == pytest.approx(
+            train + sum(parts.values()))
+        events.append({"event": "pass", "ts": i, "seq": i, "proc": 0,
+                       "kind": "train_pass_resident",
+                       "pass_seq": i + 1, "batches": 1, "examples": 10,
+                       "elapsed_sec": train,
+                       "examples_per_sec": 10 / train,
+                       "critical_path": blk})
+    line = tr.critical_path_summary(events)
+    assert "4/5 passes device-bound" in line
+    assert "pass 3 fence_wait-bound: +1.200s" in line
+    report = tr.render_report(events)
+    assert "bottleneck" in report
+    assert "fence_wait +1.200s" in report
