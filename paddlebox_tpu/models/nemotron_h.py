@@ -33,6 +33,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.causal_attention import causal_gqa_attention
@@ -160,7 +161,13 @@ class NemotronH:
         s, t, _ = x.shape
         h, p, g, n, di = self.h, self.p, self.g, self.n, self.di
         with _scope(trace.SCOPE_SSM_PROJ):
-            proj = self._mm(self._norm(x, lay["norm"]), lay["in_proj"])
+            # a step's channels side by side in memory, as the scan's
+            # kernels take them: left to itself XLA lays the forward
+            # pass's conv out with time in the lanes and copies into and
+            # out of the kernels (8 ms a step; PERF.md section 6, PR 32)
+            proj = with_layout_constraint(
+                self._mm(self._norm(x, lay["norm"]), lay["in_proj"]),
+                Layout(major_to_minor=(0, 1, 2)))
             gate, xbc, dt = jnp.split(proj, [di, di + self.conv_dim], -1)
         with _scope(trace.SCOPE_SSM_CONV):
             # causal depthwise conv: position t reads t-k+1 .. t
@@ -174,8 +181,8 @@ class NemotronH:
             y = ssd_scan(xs, jax.nn.softplus(dt + lay["dt_bias"]),
                          -jnp.exp(lay["A_log"]), b.reshape(s, t, g, n),
                          c.reshape(s, t, g, n), chunk=self.chunk,
-                         mm_dtype=self.dtype)
-            y = (y + lay["D"][:, None] * xs).reshape(s, t, di)
+                         mm_dtype=self.dtype, skip=lay["D"])
+            y = y.reshape(s, t, di)
         with _scope(trace.SCOPE_SSM_PROJ):
             y = (y * jax.nn.silu(gate)).reshape(s, t, g, di // g)
             y = y * jax.lax.rsqrt(

@@ -63,6 +63,14 @@ rationale, not today's rates:
   O(K·U·D) there and per-row DMA is ruled out above. Whether any of
   them beats its XLA composition is not measured on this installation
   (PERF.md section 7).
+
+The first Pallas kernels with a benchmarked caller are not in this file:
+``ops/ssd.py`` (PR 32), the forward and the hand-written backward sweep
+of the Mamba layers' chunked scan, which cell 2 of the benchmark
+(``nemotron3-nano-30b-a3b.train-packed-8k``) runs 24 times a step and
+judges by ``kernels.ssm_scan_roofline``. They take this module's
+``_interpret`` and ``_book_dispatch`` and have no flag: the shapes choose
+between them and their XLA composition (PERF.md section 6, PR 32).
 """
 
 from __future__ import annotations

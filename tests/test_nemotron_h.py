@@ -487,6 +487,97 @@ def test_sequence_pass_program_carries_every_scope():
         assert f"checkpoint/{s}/" in text, s
 
 
+# ---- the scan's kernels, as a chip is given them ------------------------------
+
+#: Mamba widths at which the scan's cells tile (a group of eight heads of
+#: 64, state 128, chunk 128), the rest of the toy model as it is
+TILING = dict(CFG, hybrid_override_pattern="M", mamba_num_heads=16,
+              mamba_head_dim=64, n_groups=2, ssm_state_size=128,
+              chunk_size=128)
+
+
+@pytest.fixture
+def kernels_for_the_chip(monkeypatch):
+    """The Pallas seams lower their Mosaic kernels, not the interpreter
+    (which is what a process without a TPU otherwise gets)."""
+    from paddlebox_tpu.ops import pallas_kernels
+    monkeypatch.setattr(pallas_kernels, "_interpret", lambda: False)
+
+
+def test_every_kernel_of_the_scan_sits_under_its_scope(kernels_for_the_chip):
+    """The program of two Mamba layers' loss and gradient, lowered for a
+    TPU: three custom calls (the forward sweep, the sweep that also writes
+    the states, which the layers' ``jax.checkpoint`` runs in the backward
+    pass, and the backward sweep), each under ``pbox.ssm_scan`` by its own
+    naming, the hand-written backward rule's included (a ``custom_vjp``
+    rule's ops carry what the rule gives them), so that the trace's
+    readers count all three. Three, not six: the sweeps are jitted, and
+    layers of one shape share one trace and one lowered function of each
+    (tracing a kernel is set-up time in every process)."""
+    cfg = dict(TILING, hybrid_override_pattern="MM")
+    params = ref.init(jax.random.PRNGKey(3), cfg)
+    emb = jax.random.normal(jax.random.PRNGKey(1), (2, 256, 64)) * 0.02
+    labels = jnp.zeros((2, 256), jnp.int32)
+    model = NemotronH(cfg)
+    step = jax.jit(jax.value_and_grad(
+        lambda p, e: model.loss(p, e, labels, jnp.ones((2, 256), bool))[0],
+        argnums=(0, 1)))
+    text = step.trace(params, emb).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    stacks = [locs[ref_] for ref_ in re.findall(
+        r"@tpu_custom_call\(.*loc\((#loc\d+)\)$", text, re.M)]
+    assert len(stacks) == 3, stacks
+    for stack in stacks:
+        assert trace.SCOPE_SSM_SCAN in stack.split("/"), stacks
+    # each layer calls the shared functions (its two sequences are one
+    # ``lax.map`` body; a forward call the gradient does not need is dead
+    # code the compiler drops)
+    calls = re.findall(r"call @(_forward|_backward)\w*\(", text)
+    assert calls.count("_backward") == 2 and calls.count("_forward") >= 4
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One chip of a described (not attached) v5e: the TPU's compiler is
+    installed here and refuses what the chip's would."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # pragma: no cover - no libtpu here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_the_scan_kernels_compile_for_a_v5e(what, one_v5e,
+                                             kernels_for_the_chip):
+    """At the cell's shapes (one sequence of 8,192 steps, 64 heads of 64
+    in 8 groups, state 128, chunk 128, float32 in, bfloat16 operands):
+    Mosaic takes the forward and the backward kernel. It proves that they
+    compile, not what they compute (tests/test_ssd_kernel.py) nor how
+    fast (PERF.md)."""
+    t, h, p, g, n = 8192, 64, 64, 8, 128
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_v5e)
+
+    args = (arg(1, t, h, p), arg(1, t, h), arg(h), arg(1, t, g, n),
+            arg(1, t, g, n))
+
+    def scan(*v):
+        return ssd_scan(*v, chunk=128, mm_dtype=jnp.bfloat16)
+
+    f = scan if what == "forward" else jax.grad(
+        lambda *v: jnp.sum(scan(*v)), argnums=(0, 1, 2, 3, 4))
+    compiled = jax.jit(f).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= (
+        1 if what == "forward" else 2)
+
+
 # ---- the click models' pass program is what it was --------------------------
 
 #: DeepFM's resident pass program (the shapes below) as lowered at the
