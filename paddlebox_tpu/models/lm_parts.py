@@ -1,0 +1,87 @@
+"""What the sequence models (``nemotron_h.py``, ``lfm2.py``) share beside
+the ops: the stated matrix product, RMSNorm, the head and the loss a slab
+of positions at a time, and the scalars an expert layer hands a step."""
+
+from __future__ import annotations
+
+import math
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+
+from paddlebox_tpu.obs import trace
+
+_scope = jax.named_scope
+
+#: positions of a block of attention, and positions the logits exist for
+#: at a time: the largest divisors of the sequence (of the step's
+#: positions) that these allow
+ATTN_BLOCK = 512
+HEAD_ROWS = 4096
+
+#: the scalars a model with routed experts hands out a step beside the
+#: loss, and how a pass folds each over its steps (``SeqTrainStep`` and
+#: ``Trainer`` pass them through by these names and know nothing of them)
+MOE_STEP_SCALARS = {"moe_choices_held": "sum", "moe_rows_computed": "sum",
+                    "moe_expert_load_max": "mean",
+                    "moe_expert_load_mean": "mean"}
+
+
+def matmul(x, w, dtype):
+    """The stated matrix product: ``dtype`` operands, float32
+    accumulation and result; contracts x's last axis with w's first."""
+    return jax.lax.dot_general(
+        x.astype(dtype), w.astype(dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, weight, eps: float):
+    return x * jax.lax.rsqrt(
+        jnp.mean(x * x, -1, keepdims=True) + eps) * weight
+
+
+def head_loss(x, norm_weight, head, labels, valid, eps: float, dtype):
+    """Mean cross-entropy of ``labels`` [S, T] over the positions
+    ``valid`` marks, from the last layer's output ``x`` [S, T, hidden]
+    through the final norm and the untied ``head``. The logits exist for
+    ``HEAD_ROWS`` positions at a time."""
+    n = labels.size
+    rows = math.gcd(n, HEAD_ROWS)
+    x = x.reshape(n // rows, rows, x.shape[-1])
+    lab = labels.reshape(n // rows, rows)
+    ok = valid.reshape(n // rows, rows).astype(jnp.float32)
+
+    @jax.checkpoint
+    def some_rows(total, xs):
+        x_r, lab_r, ok_r = xs
+        with _scope(trace.SCOPE_HEAD):
+            z = matmul(rms_norm(x_r, norm_weight, eps), head, dtype)
+        with _scope(trace.SCOPE_LOSS):
+            logp = jax.nn.log_softmax(z, axis=-1)
+            nll = -jnp.take_along_axis(logp, lab_r[:, None], -1)[:, 0]
+            return total + jnp.sum(nll * ok_r), None
+
+    total, _ = jax.lax.scan(some_rows, jnp.zeros((), jnp.float32),
+                            (x, lab, ok))
+    with _scope(trace.SCOPE_LOSS):
+        return total / jnp.maximum(jnp.sum(ok), 1.0)
+
+
+def moe_load_scalars(loads: jax.Array,
+                     computed: jax.Array) -> Dict[str, jax.Array]:
+    """Of the token-choices each held expert took in each expert layer
+    [expert layers, held]: their sum, beside the sum of the rows the
+    layers' loops ``computed`` for them (the choices and each run's
+    padding to whole blocks), and the layer under most load this step:
+    its busiest held expert's choices and its mean."""
+    loads = loads.astype(jnp.float32)
+    if loads.shape[0]:
+        worst = loads[jnp.argmax(jnp.max(loads, axis=1))]
+        top, mean = jnp.max(worst), jnp.mean(worst)
+    else:
+        top = mean = jnp.zeros((), jnp.float32)
+    return {"moe_choices_held": jnp.sum(loads),
+            "moe_rows_computed": jnp.sum(computed.astype(jnp.float32)),
+            "moe_expert_load_max": top, "moe_expert_load_mean": mean}

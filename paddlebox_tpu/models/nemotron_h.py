@@ -35,29 +35,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from paddlebox_tpu.models.lm_parts import (ATTN_BLOCK, MOE_STEP_SCALARS,
+                                           head_loss, matmul,
+                                           moe_load_scalars, rms_norm)
 from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.causal_attention import causal_gqa_attention
+from paddlebox_tpu.ops.short_conv import causal_depthwise_conv
 from paddlebox_tpu.ops.ssd import ssd_scan
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts
 
 _scope = jax.named_scope
 
-#: positions of a block of attention, and positions the logits exist for
-#: at a time: the largest divisors of the sequence (of the step's
-#: positions) that these allow
-ATTN_BLOCK = 512
-HEAD_ROWS = 4096
-
 
 class NemotronH:
     #: ``Trainer`` builds ``SeqTrainStep`` for such a model
     sequence_model = True
-    #: the scalars ``loss`` hands out a step beside the loss, and how a
-    #: pass folds each over its steps (``SeqTrainStep`` and ``Trainer``
-    #: pass them through by these names and know nothing of them)
-    step_scalars = {"moe_choices_held": "sum", "moe_rows_computed": "sum",
-                    "moe_expert_load_max": "mean",
-                    "moe_expert_load_mean": "mean"}
+    #: the scalars ``loss`` hands out a step beside the loss
+    step_scalars = MOE_STEP_SCALARS
 
     def __init__(self, config: Dict[str, Any],
                  compute_dtype=jnp.bfloat16) -> None:
@@ -145,17 +139,10 @@ class NemotronH:
 
     # ---- pieces ----
     def _mm(self, x, w):
-        """The stated matrix product: ``compute_dtype`` operands, float32
-        accumulation and result; contracts x's last axis with w's
-        first."""
-        return jax.lax.dot_general(
-            x.astype(self.dtype), w.astype(self.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        return matmul(x, w, self.dtype)
 
     def _norm(self, x, weight):
-        return x * jax.lax.rsqrt(
-            jnp.mean(x * x, -1, keepdims=True) + self.eps) * weight
+        return rms_norm(x, weight, self.eps)
 
     def _mamba(self, lay, x):
         s, t, _ = x.shape
@@ -170,11 +157,8 @@ class NemotronH:
                 Layout(major_to_minor=(0, 1, 2)))
             gate, xbc, dt = jnp.split(proj, [di, di + self.conv_dim], -1)
         with _scope(trace.SCOPE_SSM_CONV):
-            # causal depthwise conv: position t reads t-k+1 .. t
-            pad = jnp.pad(xbc, ((0, 0), (self.conv_k - 1, 0), (0, 0)))
-            conv = sum(pad[:, j:j + t] * lay["conv_w"][j]
-                       for j in range(self.conv_k))
-            xbc = jax.nn.silu(conv + lay["conv_b"])
+            xbc = jax.nn.silu(causal_depthwise_conv(xbc, lay["conv_w"])
+                              + lay["conv_b"])
         with _scope(trace.SCOPE_SSM_SCAN):
             xs, b, c = jnp.split(xbc, [di, di + g * n], -1)
             xs = xs.reshape(s, t, h, p)
@@ -252,46 +236,8 @@ class NemotronH:
     def loss(self, params, emb: jax.Array, labels: jax.Array,
              valid: jax.Array):
         """Mean cross-entropy of ``labels`` [S, T] over the positions
-        ``valid`` marks -> (loss, the step's ``step_scalars``). The
-        logits exist for ``HEAD_ROWS`` positions at a time."""
+        ``valid`` marks -> (loss, the step's ``step_scalars``)."""
         x, loads, computed = self.hidden(params, emb)
-        n = labels.size
-        rows = math.gcd(n, HEAD_ROWS)
-        x = x.reshape(n // rows, rows, self.d)
-        lab = labels.reshape(n // rows, rows)
-        ok = valid.reshape(n // rows, rows).astype(jnp.float32)
-
-        @jax.checkpoint
-        def some_rows(total, xs):
-            x_r, lab_r, ok_r = xs
-            with _scope(trace.SCOPE_HEAD):
-                z = self._mm(self._norm(x_r, params["final_norm"]),
-                             params["head"])
-            with _scope(trace.SCOPE_LOSS):
-                logp = jax.nn.log_softmax(z, axis=-1)
-                nll = -jnp.take_along_axis(logp, lab_r[:, None], -1)[:, 0]
-                return total + jnp.sum(nll * ok_r), None
-
-        total, _ = jax.lax.scan(some_rows, jnp.zeros((), jnp.float32),
-                                (x, lab, ok))
-        with _scope(trace.SCOPE_LOSS):
-            return (total / jnp.maximum(jnp.sum(ok), 1.0),
-                    self._load_scalars(loads, computed))
-
-    @staticmethod
-    def _load_scalars(loads: jax.Array,
-                      computed: jax.Array) -> Dict[str, jax.Array]:
-        """Of the token-choices each held expert took in each ``E`` layer
-        [E layers, held]: their sum, beside the sum of the rows the
-        layers' loops ``computed`` for them (the choices and each run's
-        padding to whole blocks), and the layer under most load this
-        step: its busiest held expert's choices and its mean."""
-        loads = loads.astype(jnp.float32)
-        if loads.shape[0]:
-            worst = loads[jnp.argmax(jnp.max(loads, axis=1))]
-            top, mean = jnp.max(worst), jnp.mean(worst)
-        else:
-            top = mean = jnp.zeros((), jnp.float32)
-        return {"moe_choices_held": jnp.sum(loads),
-                "moe_rows_computed": jnp.sum(computed.astype(jnp.float32)),
-                "moe_expert_load_max": top, "moe_expert_load_mean": mean}
+        return (head_loss(x, params["final_norm"], params["head"], labels,
+                          valid, self.eps, self.dtype),
+                moe_load_scalars(loads, computed))

@@ -117,7 +117,8 @@ SHARDED_SCOPES = (SCOPE_A2A_PULL, SCOPE_A2A_PUSH)
 SCOPE_SSM_PROJ = "pbox.ssm_proj"    # norm, in/out projections, gated norm
 SCOPE_SSM_CONV = "pbox.ssm_conv"    # causal depthwise conv + silu
 SCOPE_SSM_SCAN = "pbox.ssm_scan"    # the chunked state-space scan
-SCOPE_ATTN = "pbox.attn"            # norm, q/k/v/o, blockwise attention
+SCOPE_ATTN = "pbox.attn"            # norm, q/k/v/o (a model's q/k norms
+#                                     and rotary), blockwise attention
 SCOPE_MOE_ROUTE = "pbox.moe_route"  # norm, router, top-k, weights
 SCOPE_MOE_EXPERTS = "pbox.moe_experts"  # sort, grouped products, combine
 SCOPE_MOE_SHARED = "pbox.moe_shared"    # the shared expert
@@ -126,6 +127,15 @@ SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_SSM_PROJ,
                    SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_ATTN,
                    SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS, SCOPE_MOE_SHARED,
                    SCOPE_HEAD, SCOPE_LOSS, SCOPE_PUSH, SCOPE_DENSE_OPT)
+#: the same step over models/lfm2.py: a gated short convolution in place
+#: of the Mamba-2 mixer, a leading dense feed-forward, no shared expert
+SCOPE_CONV_PROJ = "pbox.conv_proj"  # operator norm, in_proj, out_proj
+SCOPE_CONV_MIX = "pbox.conv_mix"    # the two gates and the depthwise conv
+SCOPE_MLP = "pbox.mlp"              # the dense SwiGLU feed-forward
+CONV_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL,
+                        SCOPE_CONV_PROJ, SCOPE_CONV_MIX, SCOPE_ATTN,
+                        SCOPE_MLP, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
+                        SCOPE_HEAD, SCOPE_LOSS, SCOPE_PUSH, SCOPE_DENSE_OPT)
 
 #: spans kept in memory (about 13 a resident pass: hundreds of passes)
 RING_SPANS = 8192

@@ -13,6 +13,10 @@ The ``G = H / KV`` query heads that share a key/value head are one matrix
 side: a query block is [G * block, D] against its key block [block, D].
 Matrix products take ``mm_dtype`` operands (bfloat16) and accumulate in
 float32; the softmax is float32.
+
+``rotary_embedding`` is the position embedding a model applies to its
+queries and keys before the call (``models/lfm2.py``); a model without
+one (``models/nemotron_h.py``) calls the attention as it is.
 """
 
 from __future__ import annotations
@@ -25,6 +29,18 @@ import jax.numpy as jnp
 
 #: in place of -inf under the mask: exp() of it is 0 and no row is NaN
 _NEG = -1e30
+
+
+def rotary_embedding(x: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half position embedding, float32: x [B, T, heads, D], the
+    pair (x_i, x_{i + D/2}) of position t turned by the angle
+    ``t * theta^(-2i / D)``; a position is the index in the sequence."""
+    t, d = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], -1)[None, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return x * jnp.cos(ang) + jnp.concatenate([-x2, x1], -1) * jnp.sin(ang)
 
 
 def _blocks(q, k, v, block):

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -200,33 +201,40 @@ EXPERT_BLOCK = 512
 
 
 def route_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
-                top_k: int, scale: float
+                top_k: int, scale: float, sum_eps: float = 0.0
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid top-k routing (DeepSeek-V3 / Nemotron-H style), float32 at
-    the highest matmul precision: scores ``sigmoid(x W_r)``, the
-    ``top_k`` experts by score + ``bias`` (a correction that only
+    """Sigmoid top-k routing (DeepSeek-V3 / Nemotron-H / LFM2 style),
+    float32 at the highest matmul precision: scores ``sigmoid(x W_r)``,
+    the ``top_k`` experts by score + ``bias`` (a correction that only
     chooses, and gets no gradient), weights = the chosen scores over
-    their sum, times ``scale``. x [N, D] -> (experts [N, k] int32,
-    weights [N, k])."""
+    their sum (+ ``sum_eps`` where a model's equations add one), times
+    ``scale``. x [N, D] -> (experts [N, k] int32, weights [N, k])."""
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
                                precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
-    return idx, w / jnp.sum(w, -1, keepdims=True) * scale
+    total = jnp.sum(w, -1, keepdims=True)
+    if sum_eps:
+        total = total + sum_eps
+    return idx, w / total * scale
 
 
 def routed_experts(x: jax.Array, idx: jax.Array, w: jax.Array,
                    up: jax.Array, down: jax.Array,
-                   held: Tuple[int, int], mm_dtype=jnp.bfloat16
+                   held: Tuple[int, int], mm_dtype=jnp.bfloat16,
+                   gate: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """What the experts ``held = (lo, hi)`` give the tokens routed to
-    them: ``sum_k w[n, k] * down_e(relu(up_e(x[n]))^2)`` over the choices
-    k whose expert ``e = idx[n, k]`` is held; choices of experts that
-    live elsewhere add nothing here. x [N, D]; idx, w [N, k] from
-    ``route_top_k`` over ALL experts (a token's k experts are distinct);
-    up [hi-lo, D, F], down [hi-lo, F, D]. Returns (y [N, D] float32,
-    {"choices": token-choices that fell on held experts, "load": [hi-lo]
-    of them an expert, "rows": the rows the loops computed}).
+    them: ``sum_k w[n, k] * expert_e(x[n])`` over the choices k whose
+    expert ``e = idx[n, k]`` is held; choices of experts that live
+    elsewhere add nothing here. An expert's weights say what it is:
+    ``down_e(relu(up_e(x))^2)`` with one up-projection,
+    ``down_e(silu(gate_e(x)) * up_e(x))`` where it has a ``gate`` as well.
+    x [N, D]; idx, w [N, k] from ``route_top_k`` over ALL experts (a
+    token's k experts are distinct); up, gate [hi-lo, D, F], down
+    [hi-lo, F, D]. Returns (y [N, D] float32, {"choices": token-choices
+    that fell on held experts, "load": [hi-lo] of them an expert, "rows":
+    the rows the loops computed}).
 
     No choice is dropped, whatever the imbalance. The layout is plain
     JAX: ONE sort of the choices by (expert, token) packs every held
@@ -237,7 +245,7 @@ def routed_experts(x: jax.Array, idx: jax.Array, w: jax.Array,
     (``_grouped_product``) is then one loop an expert whose trip count is
     read from the data: ``ceil(load_e / block)`` blocks of ``block`` rows
     (the largest divisor of N that ``EXPERT_BLOCK`` allows), each a
-    gather of its tokens' rows, two products with the expert's matrices
+    gather of its tokens' rows, the products with the expert's matrices
     read in place, and a scatter-add; the backward pass is written by
     hand as the same loop (reverse mode cannot differentiate a trip count
     that is data). Nothing is sized by, or walks, the ``N * k`` bound but
@@ -264,7 +272,8 @@ def routed_experts(x: jax.Array, idx: jax.Array, w: jax.Array,
     # a run's last block reads up to ``blk`` entries past the run
     tok = jnp.concatenate([tok, jnp.zeros((blk,), jnp.int32)])
 
-    y = _grouped_product(x, wt, up, down, tok, starts, load, blk, mm_dtype)
+    ups = (up,) if gate is None else (gate, up)
+    y = _grouped_product(x, wt, ups, down, tok, starts, load, blk, mm_dtype)
     return y, {"choices": jnp.sum(load), "load": load,
                "rows": jnp.sum(-(-load // blk)) * blk}
 
@@ -283,22 +292,44 @@ def _dot(a, b, axis_a: int, axis_b: int):
                                preferred_element_type=jnp.float32)
 
 
+def _activation(hids):
+    """An expert's hidden activation, float32, from its up-projections'
+    products ``hids``, and what the backward rule keeps of them: one
+    product is ``relu^2``, two are ``silu(gate) * up``."""
+    if len(hids) == 1:
+        hid = jax.nn.relu(hids[0])
+        return jnp.square(hid), hid
+    g, u = hids
+    sig = jax.nn.sigmoid(g)
+    return g * sig * u, (g, u, sig)
+
+
+def _activation_bwd(kept, dact):
+    """The products' cotangents from the activation's ``dact``."""
+    if not isinstance(kept, tuple):
+        return (dact * 2 * kept,)
+    g, u, sig = kept
+    return dact * u * sig * (1 + g * (1 - sig)), dact * g * sig
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _grouped_product(x, wt, up, down, tok, starts, load, blk, mm_dtype):
-    """``y[n] = sum_e wt[n, e] * down_e(relu(up_e(x[n]))^2)`` over the
-    tokens of each expert's run (``routed_experts`` says what ``tok``,
-    ``starts`` and ``load`` are). Products take ``mm_dtype`` operands and
-    accumulate in float32; ``relu^2`` is float32."""
-    xm, upm, downm = (x.astype(mm_dtype), up.astype(mm_dtype),
-                      down.astype(mm_dtype))
+def _grouped_product(x, wt, ups, down, tok, starts, load, blk, mm_dtype):
+    """``y[n] = sum_e wt[n, e] * down_e(act(up_e(x[n]) for up in ups))``
+    over the tokens of each expert's run (``routed_experts`` says what
+    ``tok``, ``starts`` and ``load`` are, ``_activation`` what ``act``
+    is). Products take ``mm_dtype`` operands and accumulate in float32;
+    the activation is float32."""
+    xm = x.astype(mm_dtype)
+    upms = [up.astype(mm_dtype) for up in ups]
+    downm = down.astype(mm_dtype)
     y = jnp.zeros(x.shape, jnp.float32)
-    for e in range(up.shape[0]):
+    for e in range(down.shape[0]):
 
         def one_block(i, y, e=e):
             t, live = _block_rows(tok, starts, load, e, i, blk)
-            hid = _dot(xm[t], upm[e], 1, 0)
-            act = jnp.square(jax.nn.relu(hid)).astype(mm_dtype)
-            out = _dot(act, downm[e], 1, 0)
+            xb = xm[t]
+            act, _ = _activation([_dot(xb, upm[e], 1, 0) for upm in upms])
+            out = _dot(act.astype(mm_dtype), downm[e], 1, 0)
             wb = jnp.where(live, wt[:, e][t], 0)
             return y.at[t].add(out * wb[:, None])
 
@@ -306,32 +337,34 @@ def _grouped_product(x, wt, up, down, tok, starts, load, blk, mm_dtype):
     return y
 
 
-def _grouped_product_fwd(x, wt, up, down, tok, starts, load, blk, mm_dtype):
-    y = _grouped_product(x, wt, up, down, tok, starts, load, blk, mm_dtype)
-    return y, (x, wt, up, down, tok, starts, load)
+def _grouped_product_fwd(x, wt, ups, down, tok, starts, load, blk, mm_dtype):
+    y = _grouped_product(x, wt, ups, down, tok, starts, load, blk, mm_dtype)
+    return y, (x, wt, ups, down, tok, starts, load)
 
 
 def _grouped_product_bwd(blk, mm_dtype, res, dy):
     """Nothing of a block is kept from the forward pass: a trip gathers
     its rows again and computes the hidden activation once more, then the
-    cotangents of both products (cotangents are ``mm_dtype`` operands as
+    cotangents of every product (cotangents are ``mm_dtype`` operands as
     the activations were). An expert's weight gradients accumulate in
     float32 through its loop and are written once."""
-    x, wt, up, down, tok, starts, load = res
+    x, wt, ups, down, tok, starts, load = res
     f32 = jnp.float32
-    xm, upm, downm = (x.astype(mm_dtype), up.astype(mm_dtype),
-                      down.astype(mm_dtype))
+    xm = x.astype(mm_dtype)
+    upms = [up.astype(mm_dtype) for up in ups]
+    downm = down.astype(mm_dtype)
     dx = jnp.zeros(x.shape, f32)
-    dwt, dup, ddown = [], [], []
-    for e in range(up.shape[0]):
+    dwt, dups, ddown = [], [], []
+    for e in range(down.shape[0]):
 
         def one_block(i, carry, e=e):
-            dx, dwt_e, dup_e, ddown_e = carry
+            dx, dwt_e, dups_e, ddown_e = carry
             t, live = _block_rows(tok, starts, load, e, i, blk)
             wb = jnp.where(live, wt[:, e][t], 0)
             xb = xm[t]
-            hid = jax.nn.relu(_dot(xb, upm[e], 1, 0))
-            act = jnp.square(hid).astype(mm_dtype)
+            act, kept = _activation([_dot(xb, upm[e], 1, 0)
+                                     for upm in upms])
+            act = act.astype(mm_dtype)
             dyb = dy[t]
             # d out / d act, before the row's weight: the weight's own
             # gradient is sum(out * dy) = sum(act * (dy down^T))
@@ -340,20 +373,25 @@ def _grouped_product_bwd(blk, mm_dtype, res, dy):
                 jnp.where(live, jnp.sum(act.astype(f32) * dact, -1), 0))
             ddown_e = ddown_e + _dot(
                 act, (dyb * wb[:, None]).astype(mm_dtype), 0, 0)
-            dhid = (dact * wb[:, None] * 2 * hid).astype(mm_dtype)
-            dx = dx.at[t].add(_dot(dhid, upm[e], 1, 1))
-            dup_e = dup_e + _dot(xb, dhid, 0, 0)
-            return dx, dwt_e, dup_e, ddown_e
+            dhids = [dhid.astype(mm_dtype) for dhid in
+                     _activation_bwd(kept, dact * wb[:, None])]
+            dx = dx.at[t].add(functools.reduce(operator.add, [
+                _dot(dhid, upm[e], 1, 1) for dhid, upm in zip(dhids, upms)]))
+            dups_e = [dup_e + _dot(xb, dhid, 0, 0)
+                      for dup_e, dhid in zip(dups_e, dhids)]
+            return dx, dwt_e, dups_e, ddown_e
 
-        dx, dwt_e, dup_e, ddown_e = jax.lax.fori_loop(
+        dx, dwt_e, dups_e, ddown_e = jax.lax.fori_loop(
             0, -(-load[e] // blk), one_block,
-            (dx, jnp.zeros(x.shape[:1], f32), jnp.zeros(up.shape[1:], f32),
+            (dx, jnp.zeros(x.shape[:1], f32),
+             [jnp.zeros(up.shape[1:], f32) for up in ups],
              jnp.zeros(down.shape[1:], f32)))
         dwt.append(dwt_e)
-        dup.append(dup_e)
+        dups.append(dups_e)
         ddown.append(ddown_e)
     return (dx.astype(x.dtype), jnp.stack(dwt, 1).astype(wt.dtype),
-            jnp.stack(dup).astype(up.dtype),
+            tuple(jnp.stack(d).astype(up.dtype)
+                  for d, up in zip(zip(*dups), ups)),
             jnp.stack(ddown).astype(down.dtype), None, None, None)
 
 

@@ -387,7 +387,10 @@ def _toy_cell():
     return cell
 
 
-def _trainer(cell, pool, params):
+def _trainer(cell, pool, params, model=None):
+    """(trainer, table, preloader) of a toy cell (hidden 64, 96 ids) over
+    ``model``, this file's float32 ``NemotronH`` where none is given
+    (tests/test_lfm2.py hands in the other sequence model)."""
     import optax
     from benchmarks.entries import common, resident_seq
     from paddlebox_tpu.ps import EmbeddingTable
@@ -400,7 +403,7 @@ def _trainer(cell, pool, params):
                            unique_bucket_min=48, arena_slots=1)
     resident_seq.load_vocabulary(table, params["embedding"])
     tx = optax.adam(config["dense_optimizer"]["learning_rate"])
-    tr = Trainer(program(config), table, desc, tx=tx)
+    tr = Trainer(model or program(config), table, desc, tx=tx)
     tr.state = tr.state._replace(params=params["net"],
                                  opt_state=tx.init(params["net"]))
     pre = PassPreloader(itertools.cycle(resident_seq.datasets(desc, pool)),
@@ -458,13 +461,14 @@ def test_one_pass_through_the_trainer_equals_the_reference_step_by_step():
     assert rel(got[:, 6], ref_rows[:, 6]) < 1e-3
 
 
-def test_sequence_pass_program_carries_every_scope():
+def _pass_text(cell, ref_model, model, debug_info: bool) -> str:
+    """The lowered text of a toy cell's ``train_pass_resident`` program
+    over ``model`` (tests/test_lfm2.py lowers both sequence models so)."""
     from benchmarks.families import lm
     from paddlebox_tpu.train.device_pass import ResidentPassRunner
-    cell = _toy_cell()
     pool = lm.make_pool(cell["config"], cell["traffic"], 6, count=1)
-    params = lm.seeded_params(ref, cell["config"], 6)
-    tr, table, pre = _trainer(cell, pool, params)
+    params = lm.seeded_params(ref_model, cell["config"], 6)
+    tr, table, pre = _trainer(cell, pool, params, model)
     try:
         rp = pre.wait()
     finally:
@@ -472,9 +476,14 @@ def test_sequence_pass_program_carries_every_scope():
     runner = ResidentPassRunner(tr.step_fn, table.capacity, True,
                                 wire=rp.wire, num_slots=1,
                                 chunk_bits=rp.chunk_bits)
-    text = runner._run(rp.num_batches).lower(
+    return runner._run(rp.num_batches).lower(
         tr.state, *rp.dev, jnp.asarray(0, jnp.int32),
-        tr._rng).as_text(debug_info=True)
+        tr._rng).as_text(debug_info=debug_info)
+
+
+def test_sequence_pass_program_carries_every_scope():
+    cell = _toy_cell()
+    text = _pass_text(cell, ref, program(cell["config"]), True)
     missing = {s for s in trace.SEQ_STEP_SCOPES
                if not re.search(re.escape(s) + r"(?![A-Za-z0-9_])", text)}
     assert not missing, missing
