@@ -179,6 +179,10 @@ def load_native() -> ctypes.CDLL | None:
         lib.kv_dedup_first_seen.argtypes = [ctypes.c_void_p, ctypes.c_int64,
                                             ctypes.c_void_p, ctypes.c_void_p,
                                             ctypes.c_void_p]
+        lib.kv_dedup_slotted_first_seen.restype = ctypes.c_int64
+        lib.kv_dedup_slotted_first_seen.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
         lib.kv_arena_chunk_count.restype = ctypes.c_int32
         lib.kv_arena_chunk_count.argtypes = [ctypes.c_void_p]
         lib.kv_arena_export.restype = ctypes.c_int32

@@ -31,6 +31,12 @@ inline uint64_t mix(uint64_t k) {
   return k ^ (k >> 31);
 }
 
+// hash of a (key, slot) pair: the slot in the key's top bits, so that
+// neighbouring ids of neighbouring slots do not share a bucket
+inline uint64_t pair_hash(uint64_t k, uint16_t s) {
+  return mix(k ^ (static_cast<uint64_t>(s) << 48));
+}
+
 // Optional slot-arena row allocator: rows are carved from fixed-size,
 // chunk-aligned extents owned by one slot each, so a slot's rows cluster
 // into few chunks and a (slot, local) pair addresses any row with
@@ -380,6 +386,7 @@ int64_t kv_assign_slotted(void* p, const uint64_t* in, const uint16_t* slots,
       uint64_t h = mix(in[i + PF]) & kv->mask;
       __builtin_prefetch(&kv->state[h]);
       __builtin_prefetch(&kv->keys[h]);
+      __builtin_prefetch(&kv->rows[h]);
     }
     int32_t s = static_cast<int32_t>(slots[i]);
     int32_t r = kv->assign_one(in[i], s);
@@ -471,6 +478,61 @@ int64_t kv_dedup_first_seen(const uint64_t* in, int64_t n,
       ++u;
     }
     inv_out[i] = pos[h];
+  }
+  return u;
+}
+
+// First-seen dedup of a pass's (key, slot) stream — the front half of the
+// compact-wire build (train/device_pass.py::_compact_tail): the index is
+// then walked with the distinct pairs only. A key seen under two slots
+// is two pairs (the index gives the second its first row and a local of
+// -1, as it does on the whole stream). The call-local table is sized by
+// what it finds, not by 2n: it starts at n/8 cells (a Zipf pass of 6.8M
+// keys holds 0.73M distinct pairs, and a table of 16M cells never fits a
+// cache) and grows fourfold when three quarters full. uniq_out and
+// uslot_out get the distinct pairs in first-occurrence order, inv_out
+// each position's rank among them. Buffers sized n. Returns the count.
+int64_t kv_dedup_slotted_first_seen(const uint64_t* in, const uint16_t* slots,
+                                    int64_t n, uint64_t* uniq_out,
+                                    uint16_t* uslot_out, int32_t* inv_out) {
+  struct Cell {
+    uint64_t key;
+    int32_t pos;  // rank among the distinct pairs, -1 = empty
+    uint16_t slot;
+  };
+  uint64_t cap = 1 << 12;
+  while (cap < static_cast<uint64_t>(n) / 8) cap <<= 1;
+  std::vector<Cell> cells(cap, Cell{0, -1, 0});
+  uint64_t mask = cap - 1;
+  int64_t u = 0;
+  constexpr int64_t PF = 16;
+  for (int64_t i = 0; i < n; ++i) {
+    if (i + PF < n)
+      __builtin_prefetch(&cells[pair_hash(in[i + PF], slots[i + PF]) & mask]);
+    uint64_t k = in[i];
+    uint16_t s = slots[i];
+    uint64_t h = pair_hash(k, s) & mask;
+    while (cells[h].pos >= 0 && (cells[h].key != k || cells[h].slot != s))
+      h = (h + 1) & mask;
+    int32_t p = cells[h].pos;
+    if (p < 0) {
+      p = static_cast<int32_t>(u);
+      cells[h] = Cell{k, p, s};
+      uniq_out[u] = k;
+      uslot_out[u] = s;
+      ++u;
+      if (static_cast<uint64_t>(u) * 4 > cap * 3) {
+        cap <<= 2;
+        mask = cap - 1;
+        cells.assign(cap, Cell{0, -1, 0});
+        for (int64_t j = 0; j < u; ++j) {
+          uint64_t g = pair_hash(uniq_out[j], uslot_out[j]) & mask;
+          while (cells[g].pos >= 0) g = (g + 1) & mask;
+          cells[g] = Cell{uniq_out[j], static_cast<int32_t>(j), uslot_out[j]};
+        }
+      }
+    }
+    inv_out[i] = p;
   }
   return u;
 }

@@ -364,3 +364,30 @@ def dedup_first_seen_native(keys: np.ndarray):
         first.ctypes.data_as(ctypes.c_void_p),
         inv.ctypes.data_as(ctypes.c_void_p))
     return uniq[:u].copy(), first[:u].copy(), inv[:n].astype(np.int64)
+
+
+def dedup_slotted_first_seen_native(keys: np.ndarray, slots: np.ndarray):
+    """Native first-seen dedup of a (key, slot) stream
+    (kv_dedup_slotted_first_seen) — the fast route of
+    ps/table.dedup_slotted_first_seen. Returns (uniq keys, their slots,
+    inv int32), or None when the native library is unavailable."""
+    from paddlebox_tpu.native import load_native
+    lib = load_native()
+    if lib is None:
+        return None
+    keys = np.ascontiguousarray(keys, np.uint64)
+    slots = np.ascontiguousarray(slots, np.uint16)
+    n = len(keys)
+    if keys.shape != (n,) or slots.shape != (n,):
+        raise ValueError(f"one slot a key, both flat: keys {keys.shape}, "
+                         f"slots {slots.shape}")
+    uniq = np.empty(n, np.uint64)
+    uslot = np.empty(n, np.uint16)
+    inv = np.empty(n, np.int32)
+    u = lib.kv_dedup_slotted_first_seen(
+        keys.ctypes.data_as(ctypes.c_void_p),
+        slots.ctypes.data_as(ctypes.c_void_p), n,
+        uniq.ctypes.data_as(ctypes.c_void_p),
+        uslot.ctypes.data_as(ctypes.c_void_p),
+        inv.ctypes.data_as(ctypes.c_void_p))
+    return uniq[:u], uslot[:u], inv

@@ -538,6 +538,47 @@ def _dedup_first_seen_py(keys: np.ndarray
     return uniq_s[order], first_s[order], rank[inv_s]
 
 
+def dedup_slotted_first_seen(keys: np.ndarray, slots: np.ndarray
+                             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Dedup a pass's ``(key, slot)`` stream in FIRST-SEEN order →
+    (uniq keys, their slots as uint16, inv int32).
+
+    The compact-wire build's front half (train/device_pass.
+    ResidentPass._compact_tail): it runs OUTSIDE host_lock, and the
+    index is then walked with the distinct pairs only. A walk of the
+    first occurrences in stream order allocates new rows exactly as a
+    walk of the whole stream does (a repeat is a lookup), and a key seen
+    again under another slot stays a pair of its own, so the index
+    answers it as it would in the stream: its first row, local -1."""
+    from paddlebox_tpu.ps.kv import dedup_slotted_first_seen_native
+    out = dedup_slotted_first_seen_native(keys, slots)
+    if out is not None:
+        return out
+    return _dedup_slotted_first_seen_py(keys, slots)
+
+
+def _dedup_slotted_first_seen_py(keys: np.ndarray, slots: np.ndarray
+                                 ) -> Tuple[np.ndarray, np.ndarray,
+                                            np.ndarray]:
+    """The numpy formulation (no native library; the oracle the native
+    route is gated against): sort by (slot, key), rank the groups by
+    their first stream position."""
+    keys = np.asarray(keys, np.uint64)
+    slots = np.asarray(slots, np.uint16)
+    order = np.lexsort((keys, slots))  # stable: a group's head is its
+    ks, ss = keys[order], slots[order]  # first stream position
+    head = np.ones(len(order), bool)
+    head[1:] = (ks[1:] != ks[:-1]) | (ss[1:] != ss[:-1])
+    first = order[head]
+    by_first = np.argsort(first, kind="stable")
+    rank = np.empty(len(first), np.int32)
+    rank[by_first] = np.arange(len(first), dtype=np.int32)
+    inv = np.empty(len(order), np.int32)
+    inv[order] = rank[np.cumsum(head) - 1]
+    first = first[by_first]
+    return keys[first], slots[first], inv
+
+
 def fill_oob_pads(unique_rows: np.ndarray, u: int, capacity: int) -> None:
     """Fill positions [u:] with DISTINCT out-of-bounds row ids (> capacity).
 

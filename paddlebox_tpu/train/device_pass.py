@@ -41,7 +41,8 @@ from paddlebox_tpu.ops.bitpack import (pack_delta, pack_delta_auto,
                                        unpack_u12, unpack_u16m,
                                        unpack_u18, unpack_u24)
 from paddlebox_tpu.ops.device_unique import dedup_rows
-from paddlebox_tpu.ps.table import push_chunk, push_chunks
+from paddlebox_tpu.ps.table import (dedup_slotted_first_seen, push_chunk,
+                                    push_chunks)
 from paddlebox_tpu.train.step import (dequantize_floats, pack_floats,
                                       quantize_floats, unpack_floats)
 from paddlebox_tpu.utils.logging import get_logger
@@ -127,6 +128,10 @@ class ResidentPass:
         # rows and dedups in-trace (ops/device_unique.py).
         self.wire = "dedup"
         self.chunk_bits: Optional[int] = None
+        # the pass's DISTINCT table rows where the build knows them (the
+        # compact wire's bulk assign), what mark_trained_rows flags;
+        # None: the flags come from uniq
+        self.trained_rows: Optional[np.ndarray] = None
         # columnar side channels for the post-pass metric feed (or None)
         self.side = side
         # per-stage build seconds (front/dedup/index_host/index_dev/
@@ -452,27 +457,39 @@ class ResidentPass:
         meta = np.zeros((nb, 4), np.int32)
         segs = None if trivial else np.empty((nb, k_max), np.int32)
         t0 = time.perf_counter()
-        with trace.span("build.dedup", keys=_num_keys(per_batch)):
+        trained = None
+        with trace.span("build.dedup", keys=_num_keys(per_batch)) as sp:
             bulk = FLAGS.bulk_pass_assign
             if bulk:
-                # whole-pass bulk assign: ONE host_lock round-trip for the
-                # pass instead of nb (assign_slotted walks keys in order,
-                # so allocation is identical to the per-batch loop)
-                keys_all = np.concatenate([k for k, *_ in per_batch])
-                slots_all = np.concatenate([s for _, s, *_ in per_batch])
+                # whole-pass bulk assign over the pass's DISTINCT
+                # (key, slot) pairs: the first-seen dedup runs outside
+                # host_lock, then ONE lock round-trip walks the index
+                # with the distinct pairs only (a repeat is a lookup, so
+                # rows are allocated exactly as a walk of the whole
+                # stream allocates them) and the inverse expands rows
+                # and locals back to the stream
+                keys_u, slots_u, inv = dedup_slotted_first_seen(
+                    np.concatenate([k for k, *_ in per_batch]),
+                    np.concatenate([s for _, s, *_ in per_batch]
+                                   ).astype(np.uint16, copy=False))
+                sp.attrs["distinct"] = len(keys_u)
                 with table.host_lock:
-                    r_all, l_all = table.index.assign_slotted(
-                        keys_all, slots_all.astype(np.uint16, copy=False))
-                    table.slot_host[r_all] = slots_all
-                if (l_all < 0).any():
+                    r_u, l_u = table.index.assign_slotted(keys_u, slots_u)
+                    table.slot_host[r_u] = slots_u
+                if (l_u < 0).any():
                     return None
+                trained = r_u
                 bounds = np.cumsum([0] + [len(k) for k, *_ in per_batch])
             for i, (keys, slot_of_key, _, pad_seg, seg_arr) in \
                     enumerate(per_batch):
                 nk = len(keys)
                 if bulk:
-                    a = bounds[i]
-                    r, l = r_all[a:a + nk], l_all[a:a + nk]
+                    # straight into the pass's arrays ("clip": take
+                    # buffers its output under the default mode; inv is
+                    # in range by construction)
+                    inv_i = inv[bounds[i]:bounds[i] + nk]
+                    np.take(l_u, inv_i, out=locs[i, :nk], mode="clip")
+                    np.take(r_u, inv_i, out=rows_g[i, :nk], mode="clip")
                 else:
                     su = slot_of_key.astype(np.uint16, copy=False)
                     with table.host_lock:
@@ -480,8 +497,8 @@ class ResidentPass:
                         table.slot_host[r] = slot_of_key
                     if (l < 0).any():
                         return None
-                locs[i, :nk] = l
-                rows_g[i, :nk] = r
+                    locs[i, :nk] = l
+                    rows_g[i, :nk] = r
                 meta[i] = (nk, pad_seg, 0, 0)
                 if segs is not None:
                     segs[i, :nk] = seg_arr
@@ -521,6 +538,7 @@ class ResidentPass:
                      side=side)
             rp.wire = "compact"
             rp.chunk_bits = int(table.arena_chunk_bits)
+            rp.trained_rows = trained
             rp.dev = (loc_t, (jax.device_put(cmap),), floats_t,
                       jax.device_put(meta), segs_t, qm)
         if stats is not None:  # encode + transfer dispatch
@@ -1024,12 +1042,16 @@ class ResidentPass:
         """Flag this pass's rows as touched-since-last-save — called by
         the trainer AFTER the pass runs, so delta saves include them
         regardless of when a checkpoint landed relative to the preload.
-        Duplicate-tolerant boolean scatter after dropping the OOB pad
-        ids (save paths only read rows the index owns)."""
-        with trace.span("pass.mark_trained", pass_seq=self.pass_seq,
-                        rows=int(self.uniq.size)):
-            rows = self.uniq.ravel()
-            rows = rows[rows <= table.capacity]
+        The build's distinct rows where it kept them (no pad, no repeat);
+        else a duplicate-tolerant boolean scatter of uniq after dropping
+        the OOB pad ids (save paths only read rows the index owns).
+        ``rows`` on the span counts what is scattered."""
+        with trace.span("pass.mark_trained", pass_seq=self.pass_seq) as sp:
+            rows = self.trained_rows
+            if rows is None:
+                rows = self.uniq.ravel()
+                rows = rows[rows <= table.capacity]
+            sp.attrs["rows"] = len(rows)
             with table.host_lock:
                 table._touched[rows] = True
 

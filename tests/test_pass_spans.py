@@ -108,7 +108,9 @@ def test_resident_pass_leaves_its_boundary_in_the_ring(criteo_files,
             assert k.parent_id == train.span_id
         assert mine["pass.upload"][0].attrs == {"staged": True}
         assert mine["pass.dispatch"][0].attrs == {"chunks": 1}
-        assert mine["pass.mark_trained"][0].attrs["rows"] == rp.uniq.size
+        # the rows it flags: each batch's distinct rows, no pad
+        assert mine["pass.mark_trained"][0].attrs["rows"] == \
+            int(rp.meta[:, 2].sum())
         # the other lanes carry the same identifier
         (build,) = mine["pass.build"]
         (wait,) = mine["pass.wait"]
@@ -122,6 +124,44 @@ def test_resident_pass_leaves_its_boundary_in_the_ring(criteo_files,
             assert c.parent_id == build.span_id
             assert c.lane == trace.LANE_PRELOAD
         assert mine["build.front"][0].attrs["keys"] > 0
+
+
+@pytest.mark.parametrize("arena", [False, True],
+                         ids=["dedup_wire", "compact_wire"])
+def test_mark_trained_flags_the_rows_the_pass_trained(
+        criteo_files, no_sinks, tmp_path, arena):
+    """``mark_trained_rows`` flags every row of the pass that is no pad
+    (the rule before ISSUE 34, written out), from the build's distinct
+    rows on the compact wire and from ``uniq`` on the dedup wire; the
+    spans count what was deduplicated and what was flagged, and the next
+    delta save exports the pass's keys."""
+    tr, ds = _make(criteo_files, arena=arena)
+    table = tr.table
+    rp = ResidentPass.build_streamed(ds, table)
+    assert rp.wire == ("compact" if arena else "dedup")
+    assert (rp.trained_rows is not None) == arena
+    assert not table._touched.any()      # a built pass has not trained
+    tr.train_pass_resident(rp)
+    rows = rp.uniq.ravel()
+    want = np.zeros_like(table._touched)
+    want[rows[rows <= table.capacity]] = True
+    np.testing.assert_array_equal(table._touched, want)
+    assert not table._touched[table.capacity]    # pads never flagged
+    keys = np.unique(ds.columnar.keys)
+    assert want.sum() == len(keys)
+    by = _by_name(trace.recent_spans())
+    (mark,), (dedup,) = by["pass.mark_trained"], by["build.dedup"]
+    assert dedup.attrs["keys"] == ds.columnar.keys.size
+    if arena:    # one walk of the pass's distinct keys, one scatter
+        assert dedup.attrs["distinct"] == len(keys)
+        assert mark.attrs["rows"] == len(keys)
+    else:        # a batch's distinct rows, batch by batch
+        assert "distinct" not in dedup.attrs
+        assert mark.attrs["rows"] == int(rp.meta[:, 2].sum())
+    path = str(tmp_path / "delta.npz")
+    assert table.save_delta(path) == len(keys)
+    np.testing.assert_array_equal(np.sort(np.load(path)["keys"]), keys)
+    assert not table._touched.any()
 
 
 def test_inline_build_gets_one_pass_seq(criteo_files, no_sinks):
