@@ -6,6 +6,7 @@ from paddlebox_tpu.models.ads_rank import AdsRank
 from paddlebox_tpu.models.mmoe import MMoE, MMoESingle
 from paddlebox_tpu.models.nemotron_h import NemotronH
 from paddlebox_tpu.models.lfm2 import Lfm2Moe
+from paddlebox_tpu.models.mellum import MellumMoe
 
 MODEL_REGISTRY = {
     "ctr_dnn": CtrDnn,
@@ -17,4 +18,5 @@ MODEL_REGISTRY = {
 }
 
 __all__ = ["CtrDnn", "DeepFM", "WideDeep", "DCNv2", "AdsRank",
-           "MMoE", "MMoESingle", "NemotronH", "Lfm2Moe", "MODEL_REGISTRY"]
+           "MMoE", "MMoESingle", "NemotronH", "Lfm2Moe", "MellumMoe",
+           "MODEL_REGISTRY"]

@@ -39,12 +39,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from paddlebox_tpu.models.lm_parts import (ATTN_BLOCK, MOE_STEP_SCALARS,
-                                           head_loss, matmul,
-                                           moe_load_scalars, rms_norm)
+from paddlebox_tpu.models.lm_parts import (MOE_STEP_SCALARS, head_loss,
+                                           matmul, moe_load_scalars,
+                                           rms_norm, rotary_attention)
 from paddlebox_tpu.obs import trace
-from paddlebox_tpu.ops.causal_attention import (causal_gqa_attention,
-                                                rotary_embedding)
 from paddlebox_tpu.ops.short_conv import gated_short_conv
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts
 
@@ -158,18 +156,11 @@ class Lfm2Moe:
             return x + self._mm(y, lay["out_proj"])
 
     def _attention(self, lay, x):
-        s, t, _ = x.shape
         with _scope(trace.SCOPE_ATTN):
-            u = self._norm(x, lay["operator_norm"])
-            q = self._mm(u, lay["q"]).reshape(s, t, self.qh, self.hd)
-            k = self._mm(u, lay["k"]).reshape(s, t, self.kvh, self.hd)
-            v = self._mm(u, lay["v"]).reshape(s, t, self.kvh, self.hd)
-            q = rotary_embedding(self._norm(q, lay["q_norm"]), self.theta)
-            k = rotary_embedding(self._norm(k, lay["k_norm"]), self.theta)
-            o = causal_gqa_attention(q, k, v, block=ATTN_BLOCK,
-                                     mm_dtype=self.dtype)
-            return x + self._mm(o.reshape(s, t, self.qh * self.hd),
-                                lay["o"])
+            return x + rotary_attention(
+                self._norm(x, lay["operator_norm"]), lay,
+                (self.qh, self.kvh, self.hd), self.eps, self.dtype,
+                {"theta": self.theta})
 
     def _mlp(self, lay, x):
         """The dense SwiGLU feed-forward of ``MLP_ROWS`` positions

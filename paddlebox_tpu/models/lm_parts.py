@@ -1,16 +1,20 @@
-"""What the sequence models (``nemotron_h.py``, ``lfm2.py``) share beside
-the ops: the stated matrix product, RMSNorm, the head and the loss a slab
-of positions at a time, and the scalars an expert layer hands a step."""
+"""What the sequence models (``nemotron_h.py``, ``lfm2.py``,
+``mellum.py``) share beside the ops: the stated matrix product, RMSNorm,
+rotary attention with normed queries and keys, the head and the loss a
+slab of positions at a time, and the scalars an expert layer hands a
+step."""
 
 from __future__ import annotations
 
 import math
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from paddlebox_tpu.obs import trace
+from paddlebox_tpu.ops.causal_attention import (causal_gqa_attention,
+                                                rotary_embedding)
 
 _scope = jax.named_scope
 
@@ -40,6 +44,26 @@ def matmul(x, w, dtype):
 def rms_norm(x, weight, eps: float):
     return x * jax.lax.rsqrt(
         jnp.mean(x * x, -1, keepdims=True) + eps) * weight
+
+
+def rotary_attention(u, lay, heads, eps: float, dtype, rotary: Dict,
+                     window: Optional[int] = None):
+    """Grouped-query attention of the normed input ``u`` [S, T, hidden]
+    with ``heads`` = (query heads, key/value heads, head size): q, k, v
+    projections, queries and keys RMS-normed over the head and rotated
+    (``rotary``: what ``rotary_embedding`` is told), causal blockwise
+    attention over the whole sequence or a ``window``, the ``o``
+    projection; the caller names the scope and adds the residual."""
+    s, t, _ = u.shape
+    qh, kvh, hd = heads
+    q = matmul(u, lay["q"], dtype).reshape(s, t, qh, hd)
+    k = matmul(u, lay["k"], dtype).reshape(s, t, kvh, hd)
+    v = matmul(u, lay["v"], dtype).reshape(s, t, kvh, hd)
+    q = rotary_embedding(rms_norm(q, lay["q_norm"], eps), **rotary)
+    k = rotary_embedding(rms_norm(k, lay["k_norm"], eps), **rotary)
+    o = causal_gqa_attention(q, k, v, block=ATTN_BLOCK, mm_dtype=dtype,
+                             window=window)
+    return matmul(o.reshape(s, t, qh * hd), lay["o"], dtype)
 
 
 def head_loss(x, norm_weight, head, labels, valid, eps: float, dtype):

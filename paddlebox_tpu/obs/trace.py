@@ -136,6 +136,16 @@ CONV_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL,
                         SCOPE_CONV_PROJ, SCOPE_CONV_MIX, SCOPE_ATTN,
                         SCOPE_MLP, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS,
                         SCOPE_HEAD, SCOPE_LOSS, SCOPE_PUSH, SCOPE_DENSE_OPT)
+#: the same step over models/mellum.py: every layer attention then routed
+#: experts. A SLIDING layer's whole sublayer (norm, projections, head
+#: norms, rotary, windowed attention, ``o``) is under its own scope; a
+#: full layer stays under ``pbox.attn``, which so means "full causal
+#: attention" for every model
+SCOPE_ATTN_WINDOW = "pbox.attn_window"
+WINDOW_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_ATTN,
+                          SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE,
+                          SCOPE_MOE_EXPERTS, SCOPE_HEAD, SCOPE_LOSS,
+                          SCOPE_PUSH, SCOPE_DENSE_OPT)
 
 #: spans kept in memory (about 13 a resident pass: hundreds of passes)
 RING_SPANS = 8192

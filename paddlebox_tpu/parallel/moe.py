@@ -13,17 +13,19 @@ single ``jax.lax.all_to_all`` over the ``ep`` mesh axis inside
 Shapes are fully static: capacity drops overflow tokens exactly like the
 reference's capacity gates.
 
-``routed_experts`` is the other kind of expert layer: sigmoid top-k
-routing that DROPS NOTHING, over a layer that is told which experts it
-holds (one chip's share of an expert-parallel deployment): one sort of
+``routed_experts`` is the other kind of expert layer: top-k routing
+(``route_top_k``: sigmoid or softmax scores) that DROPS NOTHING, over a
+layer that is told which experts it holds (one chip's share of an
+expert-parallel deployment): one sort of
 the token-choices, then a grouped matrix product that is one loop an
 expert over the blocks of rows that hold its choices, the trip count
 read from the data, forward and (written by hand, ``jax.custom_vjp``)
 backward.
 
 Callers (ROADMAP D5 reads this): ``route_top_k`` and ``routed_experts``
-run in the benchmark (``models/nemotron_h.py``'s ``E`` layers, cell
-``nemotron3-nano-30b-a3b.train-packed-8k``). The capacity gates
+run in the benchmark (``models/nemotron_h.py``'s ``E`` layers,
+``models/lfm2.py``'s and ``models/mellum.py``'s feed-forwards: the three
+``train-packed-8k`` cells). The capacity gates
 (``top1_gating``, ``top2_gating``, ``naive_gating``),
 ``moe_forward_local`` and ``moe_forward_sharded`` (the expert
 ``all_to_all``) are test-only: no model of the benchmark calls them.
@@ -200,18 +202,23 @@ def moe_forward_sharded(mesh: Any, axis: str,
 EXPERT_BLOCK = 512
 
 
-def route_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
-                top_k: int, scale: float, sum_eps: float = 0.0
+def route_top_k(x: jax.Array, router: jax.Array,
+                bias: Optional[jax.Array], top_k: int, scale: float,
+                sum_eps: float = 0.0,
+                score: Callable[[jax.Array], jax.Array] = jax.nn.sigmoid
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid top-k routing (DeepSeek-V3 / Nemotron-H / LFM2 style),
-    float32 at the highest matmul precision: scores ``sigmoid(x W_r)``,
-    the ``top_k`` experts by score + ``bias`` (a correction that only
-    chooses, and gets no gradient), weights = the chosen scores over
+    """Top-k routing, float32 at the highest matmul precision: scores
+    ``score(x W_r)`` over ALL the router's outputs (``sigmoid``:
+    DeepSeek-V3 / Nemotron-H / LFM2 style; ``jax.nn.softmax``: the
+    Qwen3-MoE key family's, ``models/mellum.py``), the ``top_k`` experts
+    by score + ``bias`` (a correction that only chooses, and gets no
+    gradient; None: there is none), weights = the chosen scores over
     their sum (+ ``sum_eps`` where a model's equations add one), times
     ``scale``. x [N, D] -> (experts [N, k] int32, weights [N, k])."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
-                               precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+    s = score(jnp.dot(x.astype(jnp.float32), router,
+                      precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(
+        s if bias is None else s + jax.lax.stop_gradient(bias), top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     total = jnp.sum(w, -1, keepdims=True)
     if sum_eps:
