@@ -236,3 +236,108 @@ def test_dedup_slotted_first_seen_matches_numpy(case):
     first = np.full(len(uk), len(keys), np.int64)
     np.minimum.at(first, inv, np.arange(len(keys)))
     assert (np.diff(first) > 0).all()
+
+
+# ---- ISSUE 36: the chunk map's lookup without a gather ----
+
+_BIG_CAP, _BIG_SLOT0 = 1 << 23, 1050 * 4096 + 17
+
+
+@pytest.fixture(scope="module")
+def wide_slot_table():
+    """The build's view of a table whose slot 0 holds 1,051 chunks of
+    4,096 rows (``arena_chunk_bits`` 12, cell 1's), slot 1 three and
+    slot 2 one, registered slot by slot in turns so the slots' chunks
+    interleave: the index, its lock and the rows' slots, and no device
+    state (8.4M rows of it would serve nothing here)."""
+    import threading
+    import types
+    from paddlebox_tpu.ps.kv import NativeKV, make_kv
+    index = make_kv(_BIG_CAP)
+    if not isinstance(index, NativeKV):
+        pytest.skip("4.3M keys through the python index take minutes")
+    index.arena_enable(12, 3)
+    t = types.SimpleNamespace(
+        capacity=_BIG_CAP, arena_slots=3, arena_chunk_bits=12, index=index,
+        slot_host=np.zeros(_BIG_CAP + 1, np.int16),
+        host_lock=threading.Lock())
+    sizes, base = (_BIG_SLOT0, 9000, 40), (0, 1 << 40, 1 << 41)
+    done = [0, 0, 0]
+    while any(d < n for d, n in zip(done, sizes)):
+        for s in range(3):           # a turn: up to 1.5M keys a slot
+            n = min(1_500_000, sizes[s] - done[s])
+            if n:
+                keys = np.arange(done[s], done[s] + n, dtype=np.uint64)
+                _register(t, keys + np.uint64(base[s]), np.full(n, s))
+                done[s] += n
+    return t, base, sizes
+
+
+@pytest.mark.parametrize("path", ["select", "gather-ragged-K",
+                                  "gather-segments"])
+def test_decoded_rows_are_the_host_rows_on_both_sides_of_the_bound(
+        path, wide_slot_table):
+    """The device's decode of the compact wire gives the pass's host
+    ``rows_g`` whether the chunk map is read by ``cmap_select`` (trivial
+    segments and K a multiple of S: key p is slot p % S's) or by the
+    gather (every other shape); the select side lowers without one."""
+    import jax.numpy as jnp
+    from paddlebox_tpu.train.device_pass import ResidentPassRunner
+    table, base, sizes = wide_slot_table
+    rng = np.random.default_rng(36)
+    recs, s = 96, 3
+    ids = np.stack([rng.integers(0, n, recs) for n in sizes], axis=1)
+    ids[:8, 0] = sizes[0] - 1 - np.arange(8)     # the last chunk's rows
+    ids[8:12] = ids[:4]                          # repeats
+    keys = (ids + np.asarray(base)[None, :]).reshape(-1)
+    slots = np.tile(np.arange(s), recs)
+    trivial = path != "gather-segments"
+    k_max = recs * s // 2 + (4 if path == "gather-ragged-K" else 0)
+    per_batch = _batches(keys, slots, [recs * s // 2] * 2, k_max,
+                         segs=not trivial)
+    rp = _compact(per_batch, table, trivial)
+    assert rp is not None and rp.chunk_bits == 12
+    loc_t, (cmap,), floats, meta, segs_t, qm = rp.dev
+    assert len(loc_t) == 2 and loc_t[1].dtype == jnp.uint8  # u16m, m = 8
+    assert cmap.shape == (3, 2048)
+    runner = ResidentPassRunner(None, _BIG_CAP, trivial, wire="compact",
+                                num_slots=s, chunk_bits=12)
+
+    def decode(loc, floats, meta, segs):
+        v = runner._make_view(loc, (cmap,), floats, meta, segs, qm)
+        return v.unique_rows, v.gather_idx, v.num_unique
+
+    def args(i):
+        return (tuple(a[i] for a in loc_t), floats[i], meta[i],
+                tuple(a[i % a.shape[0]] for a in segs_t))
+
+    text = jax.jit(decode).lower(*args(0)).as_text()
+    assert ("gather" in text) == (path != "select")
+    for i in range(2):
+        uniq, gidx, n = jax.jit(decode)(*args(i))
+        want = np.where(rp.uniq[i] <= _BIG_CAP, rp.uniq[i], _BIG_CAP)
+        np.testing.assert_array_equal(np.asarray(uniq)[np.asarray(gidx)],
+                                      want)
+        assert int(n) == len(np.unique(want))
+    assert (rp.gidx >> 12).max() == 1050        # slot 0's last chunk
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 8, 64, 5), (26, 2048, 16, 8192), (3, 8193, 50, 8192),
+    (5, 100, 33, 1 << 22), (2, 64, 7, 70000)],
+    ids=["one-slot-8", "cell-1", "ragged-stride", "three-bytes-ragged",
+         "two-and-a-bit-bytes"])
+def test_cmap_select_is_the_gather(shape):
+    import jax.numpy as jnp
+    from paddlebox_tpu.ops.chunk_map import cmap_select
+    s, stride, b, max_chunk = shape
+    rng = np.random.default_rng(stride)
+    cmap = rng.integers(0, max_chunk + 1, (s, stride)).astype(np.int32)
+    cmap[:, -1] = max_chunk
+    c = rng.integers(0, stride, (b, s)).astype(np.int32)
+    c[0], c[-1] = 0, stride - 1
+    got = jax.jit(cmap_select, static_argnums=2)(
+        jnp.asarray(cmap), jnp.asarray(c), max_chunk)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got),
+                                  cmap[np.arange(s)[None, :], c])

@@ -64,6 +64,95 @@ def test_dedup_rows_count_includes_the_sentinel_entry(pad_keys, dups):
     assert (uniq[n - 1] == cap) == (pad_keys > 0)
 
 
+def _dedup_case(name):
+    """-> (rows int32 [K], capacity), K = 1,000: no power of two."""
+    rng = np.random.default_rng(36)
+    cap, k = 5000, 1000
+    if name == "all-sentinel":
+        rows = np.full(k, cap)
+    elif name == "no-repeat":
+        rows = rng.choice(cap, k, replace=False)
+    elif name == "heavy-repeat":
+        rows = rng.integers(0, 7, k) * 700
+    elif name == "padded":            # a ragged step: pads at the tail
+        rows = np.concatenate([rng.integers(0, 300, k - 137),
+                               np.full(137, cap)])
+    elif name == "sentinels-inside":  # invalid keys between real ones
+        rows = rng.integers(cap - 40, cap + 1, k)
+    else:
+        raise ValueError(name)
+    return rows.astype(np.int32), cap
+
+
+@pytest.mark.parametrize("name", ["all-sentinel", "no-repeat",
+                                  "heavy-repeat", "padded",
+                                  "sentinels-inside"])
+def test_dedup_rows_places_by_sorts_what_numpy_places(name):
+    """ISSUE 36: the results placed by sorts are numpy's, entry for
+    entry over the real prefix; the pads keep the docstring's contract
+    (distinct, out of bounds, never pointed at)."""
+    rows, cap = _dedup_case(name)
+    k = len(rows)
+    uniq, gidx, n = jax.jit(dedup_rows, static_argnums=1)(
+        jnp.asarray(rows), cap)
+    uniq, gidx, n = np.asarray(uniq), np.asarray(gidx), int(n)
+    ref, inv = np.unique(rows, return_inverse=True)
+    assert n == len(ref)
+    np.testing.assert_array_equal(uniq[:n], ref)
+    np.testing.assert_array_equal(gidx, inv)
+    assert uniq.dtype == np.int32 and gidx.dtype == np.int32
+    pads = uniq[n:]
+    assert (pads > cap).all() and (pads <= cap + k).all()
+    assert len(np.unique(pads)) == len(pads) and (np.diff(pads) > 0).all()
+
+
+def _lowered(fn, *args):
+    return jax.jit(fn).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("what", ["dedup_rows", "unpack_u16m-m8",
+                                  "unpack_u16m-m2",
+                                  "unpack_u16m-m2-batched"])
+def test_key_decode_and_dedup_lower_without_a_gather_or_scatter(what):
+    """A TPU gather or scatter is paid by the index (1.0-1.7 ms each at
+    cell 1's 212,992 keys: ledger, PR 35): the mechanism of ISSUE 36 is
+    that these two functions issue none, and this pins it."""
+    from paddlebox_tpu.ops.bitpack import unpack_u16m
+    k = 1040
+    if what == "dedup_rows":
+        text = _lowered(lambda r: dedup_rows(r, 5000),
+                        jnp.zeros(k, jnp.int32))
+        assert text.count("stablehlo.sort") == 3
+    else:
+        m = 8 if "m8" in what else 2
+        lead = (3,) if "batched" in what else ()
+        text = _lowered(lambda lo, hi: unpack_u16m(lo, hi, m),
+                        jnp.zeros(lead + (k,), jnp.uint16),
+                        jnp.zeros(lead + (k * m // 8,), jnp.uint8))
+    assert "gather" not in text and "scatter" not in text
+
+
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["flat", "leading-axis"])
+@pytest.mark.parametrize("m", [1, 2, 4, 8])
+def test_u16m_wire_round_trips(m, batched):
+    """16-bit lows + m-bit packed highs (ops/bitpack): every value of
+    the range's edges and a random fill come back, with and without a
+    leading batch axis; the packed highs are K * m / 8 bytes."""
+    from paddlebox_tpu.ops.bitpack import pack_u16m, unpack_u16m
+    rng = np.random.default_rng(m)
+    k, top = 1048, 1 << (16 + m)
+    v = rng.integers(0, top, (3, k) if batched else (k,)).astype(np.int32)
+    v[..., :4] = (0, top - 1, 0xFFFF, 0x10000)
+    lo, hi = pack_u16m(v, m)
+    assert lo.dtype == np.uint16 and hi.dtype == np.uint8
+    assert lo.shape == v.shape and hi.shape == v.shape[:-1] + (k * m // 8,)
+    got = jax.jit(unpack_u16m, static_argnums=2)(
+        jnp.asarray(lo), jnp.asarray(hi), m)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), v)
+
+
 @pytest.fixture(scope="module")
 def criteo_files(tmp_path_factory):
     d = tmp_path_factory.mktemp("criteo_dp")

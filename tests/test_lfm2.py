@@ -407,12 +407,15 @@ def test_pass_program_carries_every_scope():
 #: ``models/lm_parts.py``'s: the relu^2 body, the un-rotated attention
 #: call and the conv + silu lower as before. The op counts hold under any
 #: jax, the sha256 under the jax it was recorded with
-#: (tests/test_nemotron_h.py pins DeepFM's program the same way).
+#: (tests/test_nemotron_h.py pins DeepFM's program the same way). PR 36
+#: re-recorded it: the compact wire's decode and dedup_rows changed (the
+#: chunk map's gather and two scatters went, two sorts and cmap_select's
+#: product came); the model's ops are the parent's.
 NEMOTRON_PASS_JAX = "0.9.0"
 NEMOTRON_PASS_TEXT = \
-    "e4757a2824a6df03fe581435f30280d55470353da26814670622b69717a31c1c"
-NEMOTRON_PASS_OPS = {"while": 65, "gather": 172, "sort": 3, "scatter": 116,
-                     "dot_general": 276, "custom_call": 12}
+    "b60accf893235527c68d6a405a8df91ddd54aaa4894859b767a5aad91ca25b63"
+NEMOTRON_PASS_OPS = {"while": 65, "gather": 170, "sort": 5, "scatter": 112,
+                     "dot_general": 277, "custom_call": 12}
 
 
 def test_nemotron_pass_program_lowers_to_the_parents_text():
