@@ -13,6 +13,8 @@ context — the catalog:
 
     main            the training/driver thread
     preload.worker  the depth-N PassPreloader worker (build + stage)
+    preload.floats  the float half of a streamed build, beside the
+                    worker's key half (ResidentPass.build_streamed)
     epilogue.lane   the PassEpilogue single-lane write-back worker
     ssd.compact     SSD watermark demotion + segment compaction (rides
                     the epilogue worker, rendered as its own service row)
@@ -84,6 +86,7 @@ log = get_logger(__name__)
 #: names are legal; these are the rows the shipped pipeline uses.
 LANE_MAIN = "main"
 LANE_PRELOAD = "preload.worker"
+LANE_PRELOAD_FLOATS = "preload.floats"
 LANE_EPILOGUE = "epilogue.lane"
 LANE_SSD = "ssd.compact"
 LANE_READER = "stream.reader"
@@ -261,6 +264,15 @@ def current_span_id() -> int:
     end_pass links its submit span to the epilogue job it enqueues)."""
     stack = getattr(_TLS, "stack", None)
     return stack[-1][0] if stack else 0
+
+
+def current_pass_seq() -> Optional[int]:
+    """The ``pass_seq`` of the calling thread's innermost open span
+    (None when none is open or it has none): what a span opened on
+    ANOTHER thread for the same pass is given, since only a thread's own
+    spans inherit it."""
+    stack = getattr(_TLS, "stack", None)
+    return stack[-1][1] if stack else None
 
 
 def recent_spans() -> List[SpanRecord]:

@@ -25,7 +25,8 @@ from __future__ import annotations
 import collections
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import wait as futures_wait
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -84,6 +85,93 @@ def _num_keys(per_batch) -> int:
     return int(sum(len(b[0]) for b in per_batch))
 
 
+_FLOAT_LANE: Optional[ThreadPoolExecutor] = None
+_FLOAT_LANE_LOCK = threading.Lock()
+
+
+def _float_lane() -> ThreadPoolExecutor:
+    """The process's float lane: ONE persistent host thread (lane
+    ``preload.floats``), made at the first build that forks. Persistent,
+    not a thread a build: a new thread starts on a cold allocator arena,
+    so every temporary of the encode is mapped and page-faulted anew —
+    on the chip machine's host (no transparent hugepages) a thread a
+    build read cell 1's encode at 0.29-0.30 s where this thread reads
+    0.18 s, and held the training thread's pop for 5.8 ms where this
+    one costs 0.9 (PERF.md section 6, PR 39). Builds of several
+    preloaders share it: their float halves then queue, which costs
+    overlap and nothing else."""
+    global _FLOAT_LANE
+    with _FLOAT_LANE_LOCK:
+        if _FLOAT_LANE is None:
+            _FLOAT_LANE = ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="pbox-preload-floats",
+                initializer=trace.set_lane,
+                initargs=(trace.LANE_PRELOAD_FLOATS,))
+        return _FLOAT_LANE
+
+
+class _FloatHalf:
+    """The float half of a streamed build: the encoded block, its
+    ``qmeta`` and their two transfers. It shares nothing with the key
+    half but the block's shape, so ``fork`` makes it on the float lane
+    (``_float_lane``, span ``build.floats``) while the caller goes on
+    with the keys; ``make`` makes it on the caller's thread. The key half
+    asks ``join()`` for it only where it assembles the pass, so a forked
+    build's wall is the longer half, not the sum."""
+
+    def __init__(self) -> None:
+        self.batch_size = 0   # floats.shape[1]: known before any encoding
+        self.floats = self.qmeta = None
+        self.issued: List = []   # floats_t, qm, as far as they were put
+        self.sec = 0.0           # the half's own seconds
+        self.wait_sec = 0.0      # what join() blocked
+        self._fut: Optional[Future] = None
+
+    def make(self, batch_size: int, encode) -> None:
+        """``encode() -> (floats, qmeta)``, then the two puts."""
+        self.batch_size = batch_size
+        t0 = time.perf_counter()
+        self.floats, self.qmeta = encode()
+        self.issued.append(jax.device_put(self.floats))
+        self.issued.append(jax.device_put(
+            np.zeros((2, 0), np.float32) if self.qmeta is None
+            else self.qmeta))
+        self.sec = time.perf_counter() - t0
+
+    def fork(self, batch_size: int, encode) -> None:
+        """``make`` on the float lane. The span carries the caller's
+        ``pass_seq`` and links to the caller's open span (the
+        preloader's ``pass.build``); the lane does not poll for an
+        abort: the key half joins it."""
+        seq, parent = trace.current_pass_seq(), trace.current_span_id()
+
+        def run() -> None:
+            with trace.span("build.floats", pass_seq=seq,
+                            link_from=parent):
+                self.make(batch_size, encode)
+
+        self.batch_size = batch_size
+        self._fut = _float_lane().submit(run)
+
+    def settle(self) -> List:
+        """Wait the lane's work out without raising -> the transfers it
+        issued (every exit of a build passes here: none leaves the lane
+        at work on its pass)."""
+        if self._fut is not None:
+            futures_wait([self._fut])
+        return self.issued
+
+    def join(self):
+        """-> (floats, qmeta, floats_t, qm); the lane's error is
+        re-raised here, on the build's thread."""
+        t0 = time.perf_counter()
+        self.settle()
+        self.wait_sec += time.perf_counter() - t0
+        if self._fut is not None:
+            self._fut.result()
+        return (self.floats, self.qmeta) + tuple(self.issued)
+
+
 class ResidentPass:
     """One pass's batches, packed host-side then staged to HBM.
 
@@ -136,7 +224,8 @@ class ResidentPass:
         # columnar side channels for the post-pass metric feed (or None)
         self.side = side
         # per-stage build seconds (front/dedup/index_host/index_dev/
-        # pack/h2d), set by
+        # pack/h2d; floats/floats_wait where the float half ran on its
+        # own lane), set by
         # build_streamed — the preloader mirrors them into
         # pbox_preload_build_seconds_total{stage=...}
         self.build_stats: Optional[Dict[str, float]] = None
@@ -218,42 +307,70 @@ class ResidentPass:
         already-issued transfers (no orphan H2D competing with the
         emergency checkpoint) before raising PreloadBuildAborted.
 
+        TWO LANES: a columnar feed with a float block
+        (``desc.seq_len == 0``) encodes and puts that block on a second
+        host thread (``_FloatHalf.fork``: ``build.floats`` on the
+        process's float lane, ``preload.floats``) while this thread cuts
+        the key views, dedups, walks the index and packs; the halves meet
+        where the pass is assembled, so the build's wall is the longer
+        half. The
+        batch-walking fronts cut keys and floats in one walk, and a
+        sequence feed has no block to encode: both stay on this thread.
+
         Per-stage seconds land in ``rp.build_stats``
-        (front/dedup/index_host/index_dev/pack/h2d —
-        docs/PERFORMANCE.md telemetry)."""
+        (front/dedup/index_host/index_dev/pack/h2d; on two lanes
+        ``front`` times the key views alone, ``floats`` is the float
+        lane's own seconds and ``floats_wait`` what this thread waited
+        for it where they meet — docs/PERFORMANCE.md telemetry)."""
         stats: Dict[str, float] = {}
-        t0 = time.perf_counter()
-        with trace.span("build.front") as sp:
-            per_batch, floats, qmeta, trivial, nrec, side = cls._front(
-                dataset, floats_dtype)
-            sp.attrs["keys"] = _num_keys(per_batch)
-        stats["front"] = time.perf_counter() - t0
-        floats_t = jax.device_put(floats)
-        qm = jax.device_put(np.zeros((2, 0), np.float32)
-                            if qmeta is None else qmeta)
-        issued: List = [floats_t, qm]
+        col = getattr(dataset, "columnar", None)
+        forks = col is not None and not getattr(dataset.desc, "seq_len", 0)
+        half = _FloatHalf()
+        issued: List = []
         try:
+            t0 = time.perf_counter()
+            if forks:
+                desc = dataset.desc
+                nb = cls._columnar_batches(desc, col)
+                half.fork(desc.batch_size, lambda: cls._columnar_floats(
+                    desc, col, nb, floats_dtype))
+            with trace.span("build.front") as sp:
+                if forks:
+                    per_batch, trivial = cls._columnar_keys(desc, col, nb)
+                    nrec, side = cls._columnar_side(desc, col)
+                else:
+                    per_batch, floats, qmeta, trivial, nrec, side = \
+                        cls._front(dataset, floats_dtype)
+                sp.attrs["keys"] = _num_keys(per_batch)
+            stats["front"] = time.perf_counter() - t0
+            if not forks:
+                half.make(floats.shape[1], lambda: (floats, qmeta))
             rp = cls._build_streamed_tail(
-                per_batch, floats, qmeta, trivial, nrec, side, table,
-                floats_t, qm, threads, block, stats, issued)
+                per_batch, half, trivial, nrec, side, table, threads,
+                block, stats, issued)
         except PreloadBuildAborted:
-            # drain the transfers this build already issued: an orphan
-            # H2D in flight would contend with the emergency
-            # checkpoint's D2H during the grace window
-            jax.block_until_ready(list(jax.tree.leaves(issued)))
+            # drain the transfers this build already issued, the float
+            # lane's among them: an orphan H2D in flight would contend
+            # with the emergency checkpoint's D2H during the grace window
+            jax.block_until_ready(
+                list(jax.tree.leaves(issued + half.settle())))
             raise
+        finally:
+            half.settle()
+        if forks:
+            stats["floats"] = half.sec
+            stats["floats_wait"] = half.wait_sec
         rp.build_stats = stats
         return rp
 
     @classmethod
-    def _build_streamed_tail(cls, per_batch, floats, qmeta, trivial,
-                             nrec, side, table, floats_t, qm,
+    def _build_streamed_tail(cls, per_batch, half: _FloatHalf, trivial,
+                             nrec, side, table,
                              threads: int, block: bool,
                              stats: Dict[str, float],
                              issued: List) -> "ResidentPass":
         if getattr(table.index, "arena_enabled", False):
-            rp = cls._compact_tail(per_batch, floats, qmeta, trivial,
-                                   nrec, table, floats_t, qm,
+            rp = cls._compact_tail(per_batch, half, trivial, nrec, table,
                                    block=block, side=side, stats=stats)
             if rp is not None:
                 return rp
@@ -342,8 +459,10 @@ class ResidentPass:
                 t_h2d += time.perf_counter() - t0
             t0 = time.perf_counter()
             segs_enc = (None if segs is None else
-                        cls._encode_segs_or_fallback(segs, meta, floats))
+                        cls._encode_segs_or_fallback(segs, meta,
+                                                     half.batch_size))
             t_pack += time.perf_counter() - t0
+        floats, qmeta, floats_t, qm = half.join()
         with trace.span("build.upload"):
             t0 = time.perf_counter()
             segs_t = ((jax.device_put(np.zeros((1, 1), np.int32)),)
@@ -367,8 +486,8 @@ class ResidentPass:
         return rp
 
     @classmethod
-    def _encode_segs_or_fallback(cls, segs, meta, floats):
-        enc = cls._encode_segs_slotwire(segs, meta, floats.shape[1])
+    def _encode_segs_or_fallback(cls, segs, meta, batch_size: int):
+        enc = cls._encode_segs_slotwire(segs, meta, batch_size)
         return enc if enc is not None else cls._encode_gidx(segs)
 
     @classmethod
@@ -430,8 +549,8 @@ class ResidentPass:
         return pack_u18(gidx) if fmt == "u18" else (gidx,)
 
     @classmethod
-    def _compact_tail(cls, per_batch, floats, qmeta, trivial: bool,
-                      nrec: int, table, floats_t, qm,
+    def _compact_tail(cls, per_batch, half: _FloatHalf, trivial: bool,
+                      nrec: int, table,
                       block: bool = True,
                       side: Optional[Dict] = None,
                       stats: Optional[Dict[str, float]] = None
@@ -532,20 +651,22 @@ class ResidentPass:
             if segs is None:
                 segs_t = (jax.device_put(np.zeros((1, 1), np.int32)),)
             else:
-                enc = cls._encode_segs_slotwire(segs, meta, floats.shape[1])
+                enc = cls._encode_segs_slotwire(segs, meta,
+                                                half.batch_size)
                 segs_t = (tuple(jax.device_put(a) for a in enc)
                           if enc is not None else
                           tuple(jax.device_put(a)
                                 for a in cls._encode_gidx(segs)))
-            rp = cls(rows_g, locs, floats, meta, segs, nrec, qmeta=qmeta,
-                     side=side)
-            rp.wire = "compact"
-            rp.chunk_bits = int(table.arena_chunk_bits)
-            rp.trained_rows = trained
-            rp.dev = (loc_t, (jax.device_put(cmap),), floats_t,
-                      jax.device_put(meta), segs_t, qm)
+            cmap_t, meta_t = jax.device_put(cmap), jax.device_put(meta)
         if stats is not None:  # encode + transfer dispatch
             stats["pack"] = time.perf_counter() - t0
+        floats, qmeta, floats_t, qm = half.join()
+        rp = cls(rows_g, locs, floats, meta, segs, nrec, qmeta=qmeta,
+                 side=side)
+        rp.wire = "compact"
+        rp.chunk_bits = int(table.arena_chunk_bits)
+        rp.trained_rows = trained
+        rp.dev = (loc_t, (cmap_t,), floats_t, meta_t, segs_t, qm)
         if block:
             with trace.span("build.upload"):
                 jax.block_until_ready(list(jax.tree.leaves(rp.dev)))
@@ -700,14 +821,33 @@ class ResidentPass:
         """Vectorized whole-pass front for columnar datasets: array slices
         + bulk reshapes — no SlotBatch objects, no per-record python
         (build must stay under the device pass time for the preload to
-        fully overlap)."""
+        fully overlap). The key views, then the float block, on one
+        thread; ``build_streamed`` runs the same two halves side by
+        side."""
         desc = dataset.desc
-        bs = desc.batch_size
-        s = len(desc.sparse_slots)
+        nb = cls._columnar_batches(desc, col)
+        per_batch, trivial = cls._columnar_keys(desc, col, nb)
+        if getattr(desc, "seq_len", 0):
+            return cls._front_sequences(desc, col, per_batch, trivial, nb)
+        floats, qmeta = cls._columnar_floats(desc, col, nb, floats_dtype)
+        return (per_batch, floats, qmeta, trivial) \
+            + cls._columnar_side(desc, col)
+
+    @staticmethod
+    def _columnar_batches(desc, col) -> int:
+        """Batches of a columnar pass (the tail batch counts)."""
         r = col.num_records
         if r == 0:
             raise ValueError("empty pass")
-        nb = (r + bs - 1) // bs
+        return (r + desc.batch_size - 1) // desc.batch_size
+
+    @staticmethod
+    def _columnar_keys(desc, col, nb: int):
+        """The key half of the columnar front -> (per_batch, trivial):
+        reads ``col.keys``, ``col.key_slot`` and ``col.offsets`` only."""
+        bs = desc.batch_size
+        s = len(desc.sparse_slots)
+        r = col.num_records
         offsets = col.offsets
         bounds = offsets[np.minimum(np.arange(nb + 1) * bs, r)]
         nk_arr = np.diff(bounds)
@@ -735,25 +875,34 @@ class ResidentPass:
                 col.keys[a:b], col.key_slot[a:b].astype(np.int16),
                 k_max, pad_seg,
                 None if trivial else segs_global[a:b]))
-        if getattr(desc, "seq_len", 0):
-            return cls._front_sequences(desc, col, per_batch, trivial, nb)
-        # float block: pack the whole pass, zero-pad the tail batch
+        return per_batch, trivial
+
+    @classmethod
+    def _columnar_floats(cls, desc, col, nb: int, floats_dtype):
+        """The float half of the columnar front -> (floats, qmeta): pack
+        the whole pass, zero-pad the tail batch, apply the float wire.
+        Reads ``col.dense``, ``col.label``, ``col.show`` and ``col.clk``
+        only."""
+        bs, r = desc.batch_size, col.num_records
         floats_full = pack_floats(col.dense, col.label, col.show, col.clk)
         d3 = floats_full.shape[1]
         if nb * bs != r:
             padded = np.zeros((nb * bs, d3), np.float32)
             padded[:r] = floats_full
             floats_full = padded
-        floats = floats_full.reshape(nb, bs, d3)
-        floats, qmeta = cls._encode_floats(floats, floats_dtype)
-        front = (per_batch, floats, qmeta, trivial,
-                 int((col.show > 0).sum()))
-        # side channels for the post-pass metric registry feed (record j
-        # of batch i == columnar row i*bs + j); references, not copies
+        return cls._encode_floats(floats_full.reshape(nb, bs, d3),
+                                  floats_dtype)
+
+    @staticmethod
+    def _columnar_side(desc, col):
+        """-> (nrec, side): the pass's real records, and the side
+        channels for the post-pass metric registry feed (record j of
+        batch i == columnar row i*bs + j); references, not copies."""
         side = {"label": col.label, "show": col.show, "uid": col.uid,
                 "rank": col.rank, "cmatch": col.cmatch,
-                "batch_size": bs, "num_records": r}
-        return front + (side,)
+                "batch_size": desc.batch_size,
+                "num_records": col.num_records}
+        return int((col.show > 0).sum()), side
 
     @staticmethod
     def _front_sequences(desc, col, per_batch, trivial: bool, nb: int):
@@ -1487,6 +1636,12 @@ class PassPreloader:
                 seq = trace.next_pass_seq()
                 with trace.span("pass.build", pass_seq=seq) as _sp:
                     rp = self._build(ds)
+                    # a build on two lanes: what its key half waited
+                    # for the float half where they meet
+                    wait = (getattr(rp, "build_stats", None)
+                            or {}).get("floats_wait")
+                    if wait is not None:
+                        _sp.attrs["floats_wait_ms"] = wait * 1e3
                 try:
                     rp.pass_seq = seq
                     rp._trace_span_id = _sp.span_id

@@ -12,6 +12,7 @@ from paddlebox_tpu.ps.kv import PyKV
 from paddlebox_tpu.ps.table import (_dedup_slotted_first_seen_py,
                                     dedup_slotted_first_seen)
 from paddlebox_tpu.train import ResidentPass
+from paddlebox_tpu.train.device_pass import _FloatHalf
 
 CAP = 1 << 10
 
@@ -73,9 +74,9 @@ def _items(table):
 def _compact(per_batch, table, trivial):
     nrec = sum(len(k) for k, *_ in per_batch)
     floats = np.zeros((len(per_batch), 4, 5), np.float32)
-    return ResidentPass._compact_tail(
-        per_batch, floats, None, trivial, nrec, table,
-        jax.device_put(floats), jax.device_put(np.zeros((2, 0), np.float32)))
+    half = _FloatHalf()
+    half.make(floats.shape[1], lambda: (floats, None))
+    return ResidentPass._compact_tail(per_batch, half, trivial, nrec, table)
 
 
 def _zipf(rng, vocab, n):

@@ -1096,3 +1096,211 @@ def test_dedup_wire_reports_every_slot_pushed(criteo_files):
     full = rp.num_batches * rp.unique_capacity
     assert fin.attrs == {"push_slots": full, "push_slots_full": full}
     assert out["push_slots"] == out["push_slots_full"] == full
+
+
+# ---- the streamed build on two lanes (ISSUE 39) --------------------------
+# A columnar feed with a float block encodes and puts that block on a
+# thread of its own beside the key half; what it builds is what one thread
+# builds in a row (``_front`` then the tail), byte for byte.
+
+LANE_THREAD = "pbox-preload-floats_0"   # the process's one float lane
+
+
+def _ctr_feed(records: int, bs: int = 32, layout: str = "one-key",
+              bad_float: bool = False, seed: int = 39):
+    """A columnar CTR dataset of ``records`` (no files): 4 slots, 3 dense
+    columns; ``multi-key`` gives slot 2 one to three keys a record, so
+    the segments cross the wire."""
+    from paddlebox_tpu.data import InMemoryDataset, SlotDef
+    from paddlebox_tpu.data.columnar import ColumnarRecords
+    s = 4
+    rng = np.random.default_rng(seed)
+    slots = [SlotDef("label", "float", 1), SlotDef("dense", "float", 3)]
+    slots += [SlotDef(f"C{i}", "uint64") for i in range(s)]
+    desc = DataFeedDesc(slots=slots, batch_size=bs, label_slot="label",
+                        key_bucket_min=bs * s)
+    per_slot = np.ones((records, s), np.int64)
+    if layout == "multi-key":
+        per_slot[:, 2] = rng.integers(1, 4, records)
+    key_slot = np.repeat(np.tile(np.arange(s, dtype=np.int32), records),
+                         per_slot.reshape(-1))
+    keys = (rng.integers(0, 60, key_slot.size) + 1000 * key_slot
+            ).astype(np.uint64)
+    dense = rng.standard_normal((records, 3)).astype(np.float32)
+    if bad_float:
+        dense[5, 1] = np.inf    # no u8 range holds it: the bf16 wire
+    ds = InMemoryDataset(desc)
+    ds.columnar = ColumnarRecords(
+        keys=keys, key_slot=key_slot,
+        offsets=np.concatenate([[0], np.cumsum(per_slot.sum(axis=1))]
+                               ).astype(np.int64),
+        dense=dense, label=(rng.random(records) < 0.3).astype(np.float32),
+        show=np.ones(records, np.float32),
+        clk=np.zeros(records, np.float32))
+    return ds
+
+
+def _lane_table(arena: bool):
+    return EmbeddingTable(mf_dim=4, capacity=1 << 12, cfg=SparseSGDConfig(),
+                          unique_bucket_min=128,
+                          arena_slots=4 if arena else None,
+                          arena_chunk_bits=5)
+
+
+def _serial_build(ds, table, floats_dtype):
+    """The one-thread order: the whole front, the two puts, the tail."""
+    from paddlebox_tpu.train.device_pass import _FloatHalf
+    per_batch, floats, qmeta, trivial, nrec, side = ResidentPass._front(
+        ds, floats_dtype)
+    half = _FloatHalf()
+    half.make(floats.shape[1], lambda: (floats, qmeta))
+    return ResidentPass._build_streamed_tail(
+        per_batch, half, trivial, nrec, side, table, 4, True, {}, [])
+
+
+def _lane_is_idle() -> bool:
+    """The float lane takes new work at once: no build left it busy."""
+    from paddlebox_tpu.train.device_pass import _float_lane
+    return _float_lane().submit(lambda: True).result(timeout=10)
+
+
+@pytest.mark.parametrize("layout", ["one-key", "multi-key"])
+@pytest.mark.parametrize("records", [256, 230], ids=["even", "ragged"])
+@pytest.mark.parametrize("arena", [True, False], ids=["compact", "dedup"])
+@pytest.mark.parametrize("wire", ["q8", "q8-falls-back", "f32"])
+def test_two_lane_build_is_the_serial_build(wire, arena, records, layout):
+    from paddlebox_tpu.obs import trace
+    floats_dtype = np.float32 if wire == "f32" else "q8"
+    ds = _ctr_feed(records, layout=layout,
+                   bad_float=wire == "q8-falls-back")
+    t_a, t_b = _lane_table(arena), _lane_table(arena)
+    want = _serial_build(ds, t_a, floats_dtype)
+    trace.reset()
+    got = ResidentPass.build_streamed(ds, t_b, floats_dtype=floats_dtype)
+    # it did fork, and the build joined the lane
+    (lane,) = [r for r in trace.recent_spans() if r.name == "build.floats"]
+    assert lane.lane == trace.LANE_PRELOAD_FLOATS
+    assert _lane_is_idle()
+    assert got.build_stats["floats"] > 0
+    assert got.build_stats["floats_wait"] >= 0
+    # the same pass
+    assert got.wire == want.wire == ("compact" if arena else "dedup")
+    assert got.floats.dtype == {"q8": np.uint8, "f32": np.float32,
+                                "q8-falls-back": jnp.bfloat16}[wire]
+    assert (got.qmeta is not None) == (wire == "q8")
+    assert (got.segs is None) == (layout == "one-key")
+    assert got.floats.shape[0] == -(-records // 32)
+    assert (got.num_records, got.chunk_bits) == \
+        (want.num_records, want.chunk_bits)
+    for name in ("uniq", "gidx", "floats", "qmeta", "meta", "segs",
+                 "trained_rows"):
+        a, b = getattr(want, name), getattr(got, name)
+        assert (a is None) == (b is None), name
+        if a is not None:
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            np.testing.assert_array_equal(a.view(np.uint8), b.view(np.uint8))
+    la, lb = jax.tree.leaves(want.dev), jax.tree.leaves(got.dev)
+    assert jax.tree.structure(want.dev) == jax.tree.structure(got.dev)
+    for a, b in zip(la, lb):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a).view(np.uint8),
+                                      np.asarray(b).view(np.uint8))
+    assert got.side.keys() == want.side.keys()
+    assert got.side["label"] is want.side["label"]
+    # and the same index
+    for a, b in zip(t_a.index.items(), t_b.index.items()):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(t_a.slot_host, t_b.slot_host)
+
+
+def test_sequence_feed_builds_on_one_lane(monkeypatch):
+    """A sequence feed has an int32 label block and nothing to encode:
+    nothing goes to the float lane, no ``build.floats`` span, no lane
+    stages."""
+    from paddlebox_tpu.data import InMemoryDataset, SlotDef
+    from paddlebox_tpu.data.columnar import ColumnarRecords
+    from paddlebox_tpu.obs import trace
+    from paddlebox_tpu.train import device_pass
+    r, bs = 96, 32
+    desc = DataFeedDesc(
+        slots=[SlotDef("label", "float", 1), SlotDef("token", "uint64")],
+        batch_size=bs, label_slot="label", key_bucket_min=bs, seq_len=16,
+        bos_key=0)
+    rng = np.random.default_rng(0)
+    ds = InMemoryDataset(desc)
+    ds.columnar = ColumnarRecords(
+        keys=rng.integers(0, 50, r).astype(np.uint64),
+        key_slot=np.zeros(r, np.int32),
+        offsets=np.arange(r + 1, dtype=np.int64),
+        dense=np.zeros((r, 0), np.float32),
+        label=rng.integers(0, 50, r).astype(np.int32),
+        show=np.ones(r, np.float32), clk=np.zeros(r, np.float32))
+
+    def no_fork(*a, **k):
+        raise AssertionError("a sequence feed forked")
+    monkeypatch.setattr(device_pass._FloatHalf, "fork", no_fork)
+    table = EmbeddingTable(mf_dim=4, capacity=1 << 10, cfg=SparseSGDConfig(),
+                           unique_bucket_min=64, arena_slots=1)
+    trace.reset()
+    rp = ResidentPass.build_streamed(ds, table)
+    assert rp.floats.dtype == np.int32 and rp.floats.shape == (3, bs, 1)
+    names = {s.name for s in trace.recent_spans()}
+    assert "build.front" in names and "build.floats" not in names
+    assert set(rp.build_stats) == {"front", "dedup", "pack"}
+
+
+def test_float_lane_error_reaches_the_wait(monkeypatch):
+    """An error inside the float lane is re-raised by the build on the
+    preloader's thread, so ``wait()`` holds it as any build failure; the
+    worker is gone afterwards and the lane takes the next build's work."""
+    import threading
+    where = []
+
+    def boom(floats, floats_dtype):
+        where.append(threading.current_thread().name)
+        raise RuntimeError("boom in the float lane")
+    monkeypatch.setattr(ResidentPass, "_encode_floats", staticmethod(boom))
+    ds = _ctr_feed(128)
+    pre = PassPreloader(iter([ds, ds]), _lane_table(True))
+    pre.start_next()
+    with pytest.raises(RuntimeError, match="boom in the float lane"):
+        pre.wait()
+    assert where == [LANE_THREAD]      # raised there, once: no second build
+    assert pre.wait() is None
+    pre.drain(timeout=10)
+    assert not pre._worker.is_alive()
+    assert _lane_is_idle()
+
+
+def test_abort_joins_the_float_lane_and_drains_its_transfers(monkeypatch):
+    """A build aborted between stages waits the lane out and then every
+    transfer issued, the lane's two among them, before it re-raises."""
+    import time
+    from paddlebox_tpu.obs import trace
+    from paddlebox_tpu.train import device_pass
+    real_encode = ResidentPass._encode_floats
+
+    def slow(floats, floats_dtype):
+        time.sleep(0.3)          # the key half reaches its poll first
+        return real_encode(floats, floats_dtype)
+    monkeypatch.setattr(ResidentPass, "_encode_floats", staticmethod(slow))
+    drained = []
+    real_block = jax.block_until_ready
+    monkeypatch.setattr(
+        device_pass.jax, "block_until_ready",
+        lambda x: drained.append([(a.shape, a.dtype) for a in x])
+        or real_block(x))
+    ds = _ctr_feed(128)
+    table = _lane_table(False)      # the dedup tail polls before it dedups
+    trace.reset()
+    monkeypatch.setattr(device_pass._PRELOAD_TLS, "abort", lambda: True,
+                        raising=False)
+    with pytest.raises(device_pass.PreloadBuildAborted):
+        ResidentPass.build_streamed(ds, table, floats_dtype="q8")
+    # the lane's span is in the ring when the build raises: it ran to its
+    # end and was joined, not left at work
+    (lane,) = [r for r in trace.recent_spans() if r.name == "build.floats"]
+    assert lane.dur_ns >= 0.3e9 and _lane_is_idle()
+    assert drained == [[((4, 32, 6), np.dtype(np.uint8)),
+                        ((2, 3), np.dtype(np.float32))]]
+    assert table.feature_count == 0     # no key was assigned

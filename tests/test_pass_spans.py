@@ -128,6 +128,40 @@ def test_resident_pass_leaves_its_boundary_in_the_ring(criteo_files,
 
 @pytest.mark.parametrize("arena", [False, True],
                          ids=["dedup_wire", "compact_wire"])
+def test_float_lane_rides_beside_the_build(criteo_files, no_sinks, arena):
+    """A CTR pass's float block is encoded on a lane of its own (ISSUE
+    39): ``build.floats`` lies on ``preload.floats`` inside the build's
+    extent, carries the pass's ``pass_seq`` and links to ``pass.build``,
+    which says how long its key half waited where the two meet."""
+    tr, ds = _make(criteo_files, arena=arena)
+    pre = PassPreloader(iter([ds, ds]), tr.table, floats_dtype="q8")
+    pre.start_next()
+    passes = [pre.wait(), pre.wait()]
+    pre.drain()
+    spans = trace.recent_spans()
+    for rp in passes:
+        mine = _by_name([r for r in spans if r.pass_seq == rp.pass_seq])
+        (build,), (lane,) = mine["pass.build"], mine["build.floats"]
+        assert lane.lane == trace.LANE_PRELOAD_FLOATS
+        assert lane.parent_id == 0 and lane.link_from == build.span_id
+        assert build.t0_ns <= lane.t0_ns
+        assert lane.t0_ns + lane.dur_ns <= build.t0_ns + build.dur_ns
+        # the key half's stages stay the build's children on the
+        # worker's lane
+        for child in ("build.front", "build.dedup", "build.pack"):
+            assert mine[child][0].parent_id == build.span_id
+            assert mine[child][0].lane == trace.LANE_PRELOAD
+        assert build.attrs["floats_wait_ms"] == pytest.approx(
+            1e3 * rp.build_stats["floats_wait"])
+        assert build.attrs["floats_wait_ms"] >= 0
+        # the lane's own seconds: its span, less the span's bookkeeping
+        assert 0 < rp.build_stats["floats"] <= lane.dur_ns / 1e9
+    assert pre.build_stage_sec["floats"] == pytest.approx(
+        sum(rp.build_stats["floats"] for rp in passes))
+
+
+@pytest.mark.parametrize("arena", [False, True],
+                         ids=["dedup_wire", "compact_wire"])
 def test_mark_trained_flags_the_rows_the_pass_trained(
         criteo_files, no_sinks, tmp_path, arena):
     """``mark_trained_rows`` flags every row of the pass that is no pad
