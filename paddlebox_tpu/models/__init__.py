@@ -7,6 +7,7 @@ from paddlebox_tpu.models.mmoe import MMoE, MMoESingle
 from paddlebox_tpu.models.nemotron_h import NemotronH
 from paddlebox_tpu.models.lfm2 import Lfm2Moe
 from paddlebox_tpu.models.mellum import MellumMoe
+from paddlebox_tpu.models.ouro import OuroLoop
 
 MODEL_REGISTRY = {
     "ctr_dnn": CtrDnn,
@@ -19,4 +20,4 @@ MODEL_REGISTRY = {
 
 __all__ = ["CtrDnn", "DeepFM", "WideDeep", "DCNv2", "AdsRank",
            "MMoE", "MMoESingle", "NemotronH", "Lfm2Moe", "MellumMoe",
-           "MODEL_REGISTRY"]
+           "OuroLoop", "MODEL_REGISTRY"]

@@ -149,6 +149,17 @@ WINDOW_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_ATTN,
                           SCOPE_ATTN_WINDOW, SCOPE_MOE_ROUTE,
                           SCOPE_MOE_EXPERTS, SCOPE_HEAD, SCOPE_LOSS,
                           SCOPE_PUSH, SCOPE_DENSE_OPT)
+#: the same step over models/ouro.py: one stack of attention + dense
+#: feed-forward layers run several times with the same weights, the head
+#: read after every run. The exit gate (its product and sigmoid, the exit
+#: distribution, its entropy, the mixing of the exits' per-position
+#: losses) has a scope of its own; every run's attention is under
+#: ``pbox.attn``, feed-forward under ``pbox.mlp``, head read under
+#: ``pbox.head`` / ``pbox.loss``
+SCOPE_EXIT_GATE = "pbox.exit_gate"
+LOOP_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_ATTN,
+                        SCOPE_MLP, SCOPE_EXIT_GATE, SCOPE_HEAD, SCOPE_LOSS,
+                        SCOPE_PUSH, SCOPE_DENSE_OPT)
 
 #: spans kept in memory (about 13 a resident pass: hundreds of passes)
 RING_SPANS = 8192
