@@ -28,7 +28,9 @@ Precision: parameters, router, the convolution and its gates, norms,
 rotary embedding, softmax and loss float32; matrix products with
 ``compute_dtype`` (bfloat16) operands and float32 accumulation. Every op
 sits under one ``pbox.*`` scope of ``obs/trace``'s catalog; every sublayer
-is one ``jax.checkpoint`` (the dense MLP one a slab of positions).
+is one ``jax.checkpoint`` (the dense MLP one a slab of positions) that
+keeps its input, an attention operator's also its forward block loops'
+two results (``lm_parts.KEEP_ATTN_LOOPS``).
 """
 
 from __future__ import annotations
@@ -39,7 +41,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from paddlebox_tpu.models.lm_parts import (MOE_STEP_SCALARS, head_loss,
+from paddlebox_tpu.models.lm_parts import (KEEP_ATTN_LOOPS,
+                                           MOE_STEP_SCALARS, head_loss,
                                            matmul, moe_load_scalars,
                                            rms_norm, rotary_attention)
 from paddlebox_tpu.obs import trace
@@ -195,7 +198,7 @@ class Lfm2Moe:
         mlp_rows = math.gcd(x.shape[0] * x.shape[1], MLP_ROWS)
         for i, (kind, lay) in enumerate(zip(self.kinds, params["layers"])):
             operator = self._conv if kind == "conv" else self._attention
-            x = jax.checkpoint(operator)(lay, x)
+            x = jax.checkpoint(operator, policy=KEEP_ATTN_LOOPS)(lay, x)
             if i < self.n_dense:
                 # the hidden activation is the widest value of the step:
                 # a slab of positions at a time

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from paddlebox_tpu.obs import trace
-from paddlebox_tpu.ops.causal_attention import (causal_gqa_attention,
+from paddlebox_tpu.ops.causal_attention import (LOOP_RESIDUALS,
+                                                causal_gqa_attention,
                                                 rotary_embedding)
 
 _scope = jax.named_scope
@@ -24,6 +25,15 @@ _scope = jax.named_scope
 #: positions) that these allow
 ATTN_BLOCK = 512
 HEAD_ROWS = 4096
+
+#: what the ``jax.checkpoint`` around an attention (sub)layer keeps for
+#: its backward pass beside the layer's input: the two results that only
+#: the forward block loops can produce (the blocked output and each row's
+#: log-sum-exp, float32), so that the recomputed forward is projections,
+#: norms and rotations and the forward sweep runs once a step. Everything
+#: else inside the layer is recomputed
+KEEP_ATTN_LOOPS = jax.checkpoint_policies.save_only_these_names(
+    *LOOP_RESIDUALS)
 
 #: the scalars a model with routed experts hands out a step beside the
 #: loss, and how a pass folds each over its steps (``SeqTrainStep`` and

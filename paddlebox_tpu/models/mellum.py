@@ -29,7 +29,8 @@ float32; matrix products with ``compute_dtype`` (bfloat16) operands and
 float32 accumulation. Every op sits under one ``pbox.*`` scope of
 ``obs/trace``'s catalog (a sliding layer's attention under
 ``pbox.attn_window``, a full layer's under ``pbox.attn``); every sublayer
-is one ``jax.checkpoint``.
+is one ``jax.checkpoint`` that keeps its input, an attention sublayer's
+also its forward block loops' two results (``lm_parts.KEEP_ATTN_LOOPS``).
 """
 
 from __future__ import annotations
@@ -40,7 +41,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from paddlebox_tpu.models.lm_parts import (MOE_STEP_SCALARS, head_loss,
+from paddlebox_tpu.models.lm_parts import (KEEP_ATTN_LOOPS,
+                                           MOE_STEP_SCALARS, head_loss,
                                            matmul, moe_load_scalars,
                                            rms_norm, rotary_attention)
 from paddlebox_tpu.obs import trace
@@ -173,8 +175,8 @@ class MellumMoe:
         [layers])."""
         x, loads, rows = emb, [], []
         for kind, lay in zip(self.kinds, params["layers"]):
-            x = jax.checkpoint(self._attention, static_argnums=(0,))(
-                kind, lay, x)
+            x = jax.checkpoint(self._attention, static_argnums=(0,),
+                               policy=KEEP_ATTN_LOOPS)(kind, lay, x)
             x, load, computed = jax.checkpoint(self._moe)(lay, x)
             loads.append(load)
             rows.append(computed)

@@ -23,7 +23,9 @@ router chooses among, ``first_expert_held`` where this chip's run starts.
 Precision: parameters, router, scan state, norms, softmax and loss
 float32; matrix products with ``compute_dtype`` (bfloat16) operands and
 float32 accumulation. Every op sits under one ``pbox.*`` scope of
-``obs/trace``'s catalog; every layer is one ``jax.checkpoint``.
+``obs/trace``'s catalog; every layer is one ``jax.checkpoint`` that keeps
+its input, an attention layer's also its forward block loops' two results
+(``lm_parts.KEEP_ATTN_LOOPS``).
 """
 
 from __future__ import annotations
@@ -35,9 +37,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from paddlebox_tpu.models.lm_parts import (ATTN_BLOCK, MOE_STEP_SCALARS,
-                                           head_loss, matmul,
-                                           moe_load_scalars, rms_norm)
+from paddlebox_tpu.models.lm_parts import (ATTN_BLOCK, KEEP_ATTN_LOOPS,
+                                           MOE_STEP_SCALARS, head_loss,
+                                           matmul, moe_load_scalars,
+                                           rms_norm)
 from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.causal_attention import causal_gqa_attention
 from paddlebox_tpu.ops.short_conv import causal_depthwise_conv
@@ -216,7 +219,8 @@ class NemotronH:
                     lambda lay, xs: self._mamba(lay, xs[None])[0])
                 x = jax.lax.map(lambda xs, lay=lay: one(lay, xs), x)
             elif kind == "*":
-                x = jax.checkpoint(self._attention)(lay, x)
+                x = jax.checkpoint(self._attention,
+                                   policy=KEEP_ATTN_LOOPS)(lay, x)
             else:
                 x, load, computed = jax.checkpoint(self._moe)(lay, x)
                 loads.append(load)

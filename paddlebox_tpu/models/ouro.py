@@ -30,11 +30,15 @@ whose body is the layers, the weights closed over, so a weight's
 cotangent sums over the runs inside the scan's backward pass. **Kept**
 across the step, a run: each layer's input, the stack's output and its
 normed form ``h_r`` (layers + 2 values of [S, T, hidden] a run, and the
-gate's logits). **Recomputed** in the backward pass: everything
-inside a layer (a layer is one ``jax.checkpoint``: both norm pairs,
-projections, rotary, blockwise attention, the feed-forward's hidden
-activation), and every slab of logits (``lm_parts.head_nll``). A
-checkpoint a sublayer would keep twice as much for the same operations.
+gate's logits), and of every layer application the two results that
+only attention's forward block loops can produce (the blocked output and
+each row's log-sum-exp, ``lm_parts.KEEP_ATTN_LOOPS``: one more value of
+[S, T, hidden] a layer and a run, so that the forward sweep runs once a
+step). **Recomputed** in the backward pass: everything else inside a
+layer (a layer is one ``jax.checkpoint``: both norm pairs, projections,
+rotary, the feed-forward's hidden activation), and every slab of logits
+(``lm_parts.head_nll``). A checkpoint a sublayer would keep one value
+more a layer for the same operations.
 
 Precision: parameters, norms, rotary embedding, the gate (its product,
 sigmoid, the distribution, the entropy, the mixing), softmax and loss
@@ -50,7 +54,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from paddlebox_tpu.models.lm_parts import (head_nll, matmul, rms_norm,
+from paddlebox_tpu.models.lm_parts import (KEEP_ATTN_LOOPS, head_nll,
+                                           matmul, rms_norm,
                                            rotary_attention)
 from paddlebox_tpu.obs import trace
 
@@ -162,7 +167,7 @@ class OuroLoop:
         """Token vectors [S, T, hidden] -> (every run's normed output
         ``h_r`` [runs, S, T, hidden], the exit gate's logits [runs, S,
         T])."""
-        layer = jax.checkpoint(self._layer)
+        layer = jax.checkpoint(self._layer, policy=KEEP_ATTN_LOOPS)
 
         def run(x, _):
             for lay in params["layers"]:

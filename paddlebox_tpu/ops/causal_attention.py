@@ -4,7 +4,16 @@ One chip's whole sequence (``parallel/ring_attention`` is the form whose
 key/value blocks travel a mesh axis; its ``_flash_block`` is the step this
 grew from). The [T, T] scores exist for one pair of blocks at a time: the
 forward pass keeps the output and each row's log-sum-exp, the backward
-pass computes every block pair's probabilities again from them. Blocks
+pass computes every block pair's probabilities again from them. The
+backward pass reads five values: q, k and v, which a caller's
+``jax.checkpoint`` gets back from a projection and a rotation, and the
+blocked output and the log-sum-exp, which only the forward loops
+produce. ``_attention_fwd`` marks those two by name
+(``LOOP_RESIDUALS``): a checkpoint whose policy keeps them
+(``models/lm_parts.KEEP_ATTN_LOOPS``, every model's attention layer) runs
+the forward sweep once a step; under one that keeps nothing the sweep
+runs again before the backward sweep, and without a checkpoint a name is
+the identity. Blocks
 above the diagonal are never visited: query block i loops over key blocks
 0..i, a loop whose trip count is data, which is why the backward pass is
 written out (``jax.custom_vjp``) and not derived. With a ``window`` W a
@@ -34,6 +43,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+#: the names of the two residuals that only the forward block loops can
+#: produce, as ``_attention_fwd`` marks them for a ``jax.checkpoint``
+#: policy: the blocked output and each row's log-sum-exp
+LOOP_RESIDUALS = ("pbox_attn_out_blocks", "pbox_attn_row_lse")
 
 #: in place of -inf under the mask: exp() of it is 0 and no row is NaN
 _NEG = -1e30
@@ -132,6 +147,8 @@ def _scores(qi, kj, i, j, block, scale, window):
 
 
 def _forward(q, k, v, block, scale, mm_dtype, window):
+    """The forward sweep -> (the output by query block [nb,B,KV,G*blk,D],
+    each row's log-sum-exp [nb,B,KV,G*blk]), float32."""
     qb, kb, vb = _blocks(q.astype(mm_dtype), k.astype(mm_dtype),
                          v.astype(mm_dtype), block)
     nb, bsz, kv, m, d = qb.shape
@@ -158,18 +175,20 @@ def _forward(q, k, v, block, scale, mm_dtype, window):
              jnp.zeros((bsz, kv, m, d), f32)))
         return acc / den[..., None], top + jnp.log(den)
 
-    ob, lse = jax.lax.map(q_block, jnp.arange(nb))
-    return _unblock_q(ob, q.shape, block), (ob, lse)
+    return jax.lax.map(q_block, jnp.arange(nb))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _attention(q, k, v, block, scale, mm_dtype, window):
-    return _forward(q, k, v, block, scale, mm_dtype, window)[0]
+    ob, _ = _forward(q, k, v, block, scale, mm_dtype, window)
+    return _unblock_q(ob, q.shape, block)
 
 
 def _attention_fwd(q, k, v, block, scale, mm_dtype, window):
-    out, (ob, lse) = _forward(q, k, v, block, scale, mm_dtype, window)
-    return out, (q, k, v, ob, lse)
+    ob, lse = _forward(q, k, v, block, scale, mm_dtype, window)
+    ob = checkpoint_name(ob, LOOP_RESIDUALS[0])
+    lse = checkpoint_name(lse, LOOP_RESIDUALS[1])
+    return _unblock_q(ob, q.shape, block), (q, k, v, ob, lse)
 
 
 def _attention_bwd(block, scale, mm_dtype, window, res, dout):

@@ -30,6 +30,7 @@ from paddlebox_tpu.ops.causal_attention import (  # noqa: E402
 from paddlebox_tpu.ops.short_conv import gated_short_conv  # noqa: E402
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts  # noqa: E402
 from test_nemotron_h import (_pass_text,  # noqa: E402
+                             assert_the_forward_sweep_runs_once,
                              _toy_cell as nemotron_toy_cell, _trainer, f32,
                              highest_precision,  # noqa: F401
                              program_flags_restored, rel)  # noqa: F401
@@ -399,6 +400,18 @@ def test_pass_program_carries_every_scope():
         assert f"checkpoint/{s}/" in text, s
 
 
+@pytest.mark.parametrize("pattern,dense", [("f", 0), ("fcf", 1)])
+def test_the_attention_operators_forward_sweep_runs_once_a_step(
+        monkeypatch, pattern, dense):
+    """The conv operator's checkpoint is handed the same policy and has
+    nothing of that name to keep."""
+    from paddlebox_tpu.models import lfm2
+    cfg = cfg_of(pattern, dense)
+    assert_the_forward_sweep_runs_once(
+        monkeypatch, lfm2, program(cfg), ref.init(jax.random.PRNGKey(3), cfg),
+        attn_layers=pattern.count("f"))
+
+
 # ---- the first sequence model's pass program is what it was -------------------
 
 #: NemotronH's toy pass program (bfloat16 operands, the cell's own path)
@@ -410,12 +423,16 @@ def test_pass_program_carries_every_scope():
 #: (tests/test_nemotron_h.py pins DeepFM's program the same way). PR 36
 #: re-recorded it: the compact wire's decode and dedup_rows changed (the
 #: chunk map's gather and two scatters went, two sorts and cmap_select's
-#: product came); the model's ops are the parent's.
+#: product came); the model's ops are the parent's. PR 41 re-recorded it:
+#: the one attention layer's checkpoint keeps the forward block loops'
+#: two results, so the recomputed forward sweep went: its two ``while``
+#: ops (65 before) and its two products (277 ``dot_general`` before);
+#: no other count moved.
 NEMOTRON_PASS_JAX = "0.9.0"
 NEMOTRON_PASS_TEXT = \
-    "b60accf893235527c68d6a405a8df91ddd54aaa4894859b767a5aad91ca25b63"
-NEMOTRON_PASS_OPS = {"while": 65, "gather": 170, "sort": 5, "scatter": 112,
-                     "dot_general": 277, "custom_call": 12}
+    "b99aa5763551f8c6ee97b827bcc92eaff20de94f81a946d0ced0558550e3cd57"
+NEMOTRON_PASS_OPS = {"while": 63, "gather": 170, "sort": 5, "scatter": 112,
+                     "dot_general": 275, "custom_call": 12}
 
 
 def test_nemotron_pass_program_lowers_to_the_parents_text():

@@ -32,7 +32,8 @@ from paddlebox_tpu.ops.causal_attention import (  # noqa: E402
     yarn_inv_freq)
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts  # noqa: E402
 from test_lfm2 import _held_reference  # noqa: E402
-from test_nemotron_h import (_pass_text, _trainer, f32,  # noqa: E402
+from test_nemotron_h import (_pass_text, _trainer,  # noqa: E402
+                             assert_the_forward_sweep_runs_once, f32,
                              highest_precision,  # noqa: F401
                              program_flags_restored, rel)  # noqa: F401
 
@@ -332,6 +333,19 @@ def test_stack_matches_the_reference(pattern, t):
         == 2 * t * cfg["num_experts_per_tok"] * len(pattern)
     assert 0 < float(scalars["moe_choices_held"]) \
         <= float(scalars["moe_choices"])
+
+
+#: of ``SWEEP_T``'s three blocks of 512: a window inside one block, one
+#: that reaches into the block before and one that spans two blocks
+@pytest.mark.parametrize("pattern,window", [
+    ("F", 7), ("S", 7), ("S", 700), ("SF", 1100)])
+def test_the_attention_sublayers_forward_sweep_runs_once_a_step(
+        monkeypatch, pattern, window):
+    from paddlebox_tpu.models import mellum
+    cfg = cfg_of(pattern, sliding_window=window)
+    assert_the_forward_sweep_runs_once(
+        monkeypatch, mellum, program(cfg),
+        ref.init(jax.random.PRNGKey(3), cfg), attn_layers=len(pattern))
 
 
 @f32

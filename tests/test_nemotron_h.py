@@ -496,6 +496,61 @@ def test_sequence_pass_program_carries_every_scope():
         assert f"checkpoint/{s}/" in text, s
 
 
+# ---- what an attention layer's checkpoint keeps -------------------------------
+
+#: three blocks of ``lm_parts.ATTN_BLOCK`` positions in one sequence: the
+#: attention's two loops are loops in the compiled program too (a loop of
+#: one trip is not)
+SWEEP_T = 1536
+
+
+def assert_the_forward_sweep_runs_once(monkeypatch, module, model, params,
+                                       attn_layers: int):
+    """The compiled loss-and-gradient program of ``model`` over one
+    sequence of ``SWEEP_T`` positions, three ways: as ``module`` (the
+    model's own) writes it, with its checkpoints keeping nothing by name
+    (the program before the residuals had names: a name without a policy
+    is the identity), and with no ``jax.checkpoint`` at all. The model's
+    own holds as many ``while`` ops as the program without a checkpoint
+    and two fewer an attention layer copy than the one that keeps nothing
+    (the forward sweep's loop over query blocks and the loop over key
+    blocks inside it), and its loss and every gradient are the
+    keep-nothing program's bit for bit (tests/test_lfm2.py,
+    test_mellum.py and test_ouro.py call this too)."""
+    emb = jax.random.normal(jax.random.PRNGKey(1), (1, SWEEP_T, 64)) * 0.02
+    labels = jax.random.randint(jax.random.PRNGKey(2), (1, SWEEP_T), 0, 96)
+    valid = jnp.ones(labels.shape, bool)
+
+    def program_of():
+        compiled = jax.jit(jax.value_and_grad(
+            lambda p, e: model.loss(p, e, labels, valid)[0],
+            argnums=(0, 1))).lower(params, emb).compile()
+        return (len(re.findall(r" while\(", compiled.as_text())),
+                compiled(params, emb))
+
+    own_loops, own = program_of()
+    with monkeypatch.context() as m:
+        m.setattr(module, "KEEP_ATTN_LOOPS", None)
+        nothing_kept_loops, nothing_kept = program_of()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "checkpoint", lambda f, **kw: f)
+        no_checkpoint_loops, _ = program_of()
+    assert own_loops == no_checkpoint_loops
+    assert nothing_kept_loops == own_loops + 2 * attn_layers
+    assert float(own[0]) > 0
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(own)[0],
+                            jax.tree.leaves(nothing_kept)):
+        assert np.array_equal(a, b), jax.tree_util.keystr(path)
+
+
+def test_the_attention_layers_forward_sweep_runs_once_a_step(monkeypatch):
+    from paddlebox_tpu.models import nemotron_h
+    cfg = dict(CFG, hybrid_override_pattern="*E*")
+    assert_the_forward_sweep_runs_once(
+        monkeypatch, nemotron_h, program(cfg),
+        ref.init(jax.random.PRNGKey(3), cfg), attn_layers=2)
+
+
 # ---- the scan's kernels, as a chip is given them ------------------------------
 
 #: Mamba widths at which the scan's cells tile (a group of eight heads of

@@ -27,7 +27,8 @@ from benchmarks.tests.test_family_lm_ouro import (  # noqa: E402
 from paddlebox_tpu.models import OuroLoop, lm_parts  # noqa: E402
 from paddlebox_tpu.models.ouro import exit_distribution  # noqa: E402
 from paddlebox_tpu.obs import trace  # noqa: E402
-from test_nemotron_h import (_pass_text, _trainer, f32,  # noqa: E402
+from test_nemotron_h import (_pass_text, _trainer,  # noqa: E402
+                             assert_the_forward_sweep_runs_once, f32,
                              highest_precision,  # noqa: F401
                              program_flags_restored, rel)  # noqa: F401
 
@@ -329,6 +330,17 @@ def test_a_shared_weights_gradient_is_the_sum_over_four_untied_copies():
         part = g_copies[r][0]["down"]
         assert rel(part, summed[0]["down"]) > 0.3, r
     assert rel(g_copies[0][0]["down"], g_copies[3][0]["down"]) > 0.3
+
+
+@pytest.mark.parametrize("layers", [1, 2])
+def test_the_layers_forward_sweep_runs_once_a_step(monkeypatch, layers):
+    """The program holds one copy of the stack whatever the runs, so the
+    loops counted are a layer's, and the kept values stack over the runs
+    of the ``jax.lax.scan`` around it."""
+    from paddlebox_tpu.models import ouro
+    cfg = cfg_of(layers)
+    assert_the_forward_sweep_runs_once(
+        monkeypatch, ouro, program(cfg), seeded(cfg)[0], attn_layers=layers)
 
 
 @f32
