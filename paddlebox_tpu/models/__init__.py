@@ -8,6 +8,7 @@ from paddlebox_tpu.models.nemotron_h import NemotronH
 from paddlebox_tpu.models.lfm2 import Lfm2Moe
 from paddlebox_tpu.models.mellum import MellumMoe
 from paddlebox_tpu.models.ouro import OuroLoop
+from paddlebox_tpu.models.kimi_linear import KimiLinear
 
 MODEL_REGISTRY = {
     "ctr_dnn": CtrDnn,
@@ -20,4 +21,4 @@ MODEL_REGISTRY = {
 
 __all__ = ["CtrDnn", "DeepFM", "WideDeep", "DCNv2", "AdsRank",
            "MMoE", "MMoESingle", "NemotronH", "Lfm2Moe", "MellumMoe",
-           "OuroLoop", "MODEL_REGISTRY"]
+           "OuroLoop", "KimiLinear", "MODEL_REGISTRY"]

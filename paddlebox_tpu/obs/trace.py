@@ -160,6 +160,19 @@ SCOPE_EXIT_GATE = "pbox.exit_gate"
 LOOP_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL, SCOPE_ATTN,
                         SCOPE_MLP, SCOPE_EXIT_GATE, SCOPE_HEAD, SCOPE_LOSS,
                         SCOPE_PUSH, SCOPE_DENSE_OPT)
+#: the same step over models/kimi_linear.py: Kimi Delta Attention mixers
+#: beside latent attention ones (under ``pbox.attn``, full causal
+#: attention, with their latent projections and norm), a leading dense
+#: feed-forward (``pbox.mlp``), routed and shared experts after it
+SCOPE_KDA_PROJ = "pbox.kda_proj"    # norm, the seven projections, decay,
+#                                     beta, the gated output norm
+SCOPE_KDA_CONV = "pbox.kda_conv"    # three depthwise convs, SiLU, L2 norms
+SCOPE_KDA_SCAN = "pbox.kda_scan"    # the chunked delta rule (ops/kda.py)
+KDA_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL,
+                       SCOPE_KDA_PROJ, SCOPE_KDA_CONV, SCOPE_KDA_SCAN,
+                       SCOPE_ATTN, SCOPE_MLP, SCOPE_MOE_ROUTE,
+                       SCOPE_MOE_EXPERTS, SCOPE_MOE_SHARED, SCOPE_HEAD,
+                       SCOPE_LOSS, SCOPE_PUSH, SCOPE_DENSE_OPT)
 
 #: spans kept in memory (about 13 a resident pass: hundreds of passes)
 RING_SPANS = 8192

@@ -110,22 +110,25 @@ def _first_block(i, block: int, window: Optional[int]):
 
 
 def _blocks(q, k, v, block):
-    """q [B,T,KV,G,D] -> [nb,B,KV,G*blk,D]; k, v [B,T,KV,D] ->
-    [nb,B,KV,blk,D]."""
+    """q [B,T,KV,G,D] -> [nb,B,KV,G*blk,D]; k [B,T,KV,D], v [B,T,KV,Dv]
+    -> [nb,B,KV,blk,D], [nb,B,KV,blk,Dv]."""
     bsz, t, kv, g, d = q.shape
     nb = t // block
     qb = q.reshape(bsz, nb, block, kv, g, d).transpose(1, 0, 3, 4, 2, 5)
     qb = qb.reshape(nb, bsz, kv, g * block, d)
-    kb = k.reshape(bsz, nb, block, kv, d).transpose(1, 0, 3, 2, 4)
-    vb = v.reshape(bsz, nb, block, kv, d).transpose(1, 0, 3, 2, 4)
+    kb = k.reshape(bsz, nb, block, kv, k.shape[-1]).transpose(1, 0, 3, 2, 4)
+    vb = v.reshape(bsz, nb, block, kv, v.shape[-1]).transpose(1, 0, 3, 2, 4)
     return qb, kb, vb
 
 
 def _unblock_q(ob, shape, block):
-    bsz, t, kv, g, d = shape
-    nb = t // block
-    ob = ob.reshape(nb, bsz, kv, g, block, d).transpose(1, 0, 4, 2, 3, 5)
-    return ob.reshape(bsz, t, kv, g, d)
+    """A query-side block array [nb,B,KV,G*blk,W] back to [B,T,KV,G,W]:
+    ``shape`` gives B, T, KV and G, ``ob`` its own width W (the queries'
+    D, or the values' Dv for the output)."""
+    bsz, t, kv, g = shape[:4]
+    nb, w = t // block, ob.shape[-1]
+    ob = ob.reshape(nb, bsz, kv, g, block, w).transpose(1, 0, 4, 2, 3, 5)
+    return ob.reshape(bsz, t, kv, g, w)
 
 
 def _unblock_kv(xb, shape):
@@ -147,11 +150,11 @@ def _scores(qi, kj, i, j, block, scale, window):
 
 
 def _forward(q, k, v, block, scale, mm_dtype, window):
-    """The forward sweep -> (the output by query block [nb,B,KV,G*blk,D],
+    """The forward sweep -> (the output by query block [nb,B,KV,G*blk,Dv],
     each row's log-sum-exp [nb,B,KV,G*blk]), float32."""
     qb, kb, vb = _blocks(q.astype(mm_dtype), k.astype(mm_dtype),
                          v.astype(mm_dtype), block)
-    nb, bsz, kv, m, d = qb.shape
+    nb, bsz, kv, m, _ = qb.shape
     f32 = jnp.float32
 
     def q_block(i):
@@ -172,7 +175,7 @@ def _forward(q, k, v, block, scale, mm_dtype, window):
         top, den, acc = jax.lax.fori_loop(
             _first_block(i, block, window), i + 1, kv_block,
             (jnp.full((bsz, kv, m), _NEG, f32), jnp.zeros((bsz, kv, m), f32),
-             jnp.zeros((bsz, kv, m, d), f32)))
+             jnp.zeros((bsz, kv, m, vb.shape[-1]), f32)))
         return acc / den[..., None], top + jnp.log(den)
 
     return jax.lax.map(q_block, jnp.arange(nb))
@@ -196,7 +199,7 @@ def _attention_bwd(block, scale, mm_dtype, window, res, dout):
     f32 = jnp.float32
     qb, kb, vb = _blocks(q.astype(mm_dtype), k.astype(mm_dtype),
                          v.astype(mm_dtype), block)
-    dob = _blocks(dout, k, v, block)[0]
+    dob = _blocks(dout, k, v, block)[0]                    # [nb,B,KV,M,Dv]
     delta = jnp.sum(ob * dob, -1)                          # [nb,B,KV,M]
     dob = dob.astype(mm_dtype)
     nb = qb.shape[0]
@@ -241,11 +244,14 @@ def causal_gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          block: int = 512, sm_scale=None,
                          mm_dtype=jnp.bfloat16,
                          window: Optional[int] = None) -> jax.Array:
-    """q [B,T,H,D], k, v [B,T,KV,D] with H % KV == 0 (query head h reads
-    key/value head h // (H / KV)) -> [B,T,H,D] float32. The block is the
+    """q, k [B,T,H,D] / [B,T,KV,D], v [B,T,KV,Dv] with H % KV == 0 (query
+    head h reads key/value head h // (H / KV)) -> [B,T,H,Dv] float32;
+    values may be narrower or wider than queries and keys (latent
+    attention's 192 / 128, ``models/kimi_linear.py``). The block is the
     largest divisor of T that ``block`` allows. Position t reads the keys
     ``s <= t``, with a ``window`` those of ``t - window < s <= t`` (the
-    query itself counts; any positive size, whatever the block)."""
+    query itself counts; any positive size, whatever the block). The
+    scale is ``D^-0.5`` unless ``sm_scale`` says."""
     bsz, t, h, d = q.shape
     kv = k.shape[2]
     scale = float(sm_scale) if sm_scale is not None else d ** -0.5
@@ -254,4 +260,4 @@ def causal_gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"a window of {window} keys holds no query")
     out = _attention(q.reshape(bsz, t, kv, h // kv, d), k, v, blk, scale,
                      mm_dtype, window)
-    return out.reshape(bsz, t, h, d)
+    return out.reshape(bsz, t, h, v.shape[-1])
