@@ -38,20 +38,50 @@ element (the exponent masked to -inf above the diagonal before the exp).
 block and block substitution between them, in float32 at the highest
 precision.
 
-**Memory.** The pairwise decays ``[C, C, K]`` are never kept: the
-``SUB x SUB x K`` terms of the diagonal blocks exist for ``GROUP``
-chunks at a time inside a ``jax.checkpoint`` that the backward pass runs
-again (``_intra``). Kept for the backward pass are the state at every
-chunk boundary (``T / C`` of ``[K, V]`` float32 a head), ``W``, ``U~``,
-``exp(G) q``, ``exp(G_C - G) k`` and ``P``: a few times the size of
-``k``.
+Two forms of the one algorithm, chosen by what the shapes show
+(``_tiles``), booked as ``pbox_kernel_dispatch_total{kernel="kda_scan"}``:
 
-Precision: decays, cumulative sums, the pairwise terms of the diagonal
-blocks, ``T`` and the carried state are float32; every other matrix
-product (the scores across sub-chunks, ``W``, ``U~`` and the four state
-products a chunk) takes ``mm_dtype`` operands (bfloat16) and accumulates
-in float32, as ``ops/ssd.py`` does. No document reset: the state runs
-through a packed sequence (ROADMAP.md R8).
+* **The kernels** (``impl="pallas"``; Mosaic on a TPU, interpret mode
+  elsewhere) where K and V are whole 128-lane tiles and the chunk whole
+  8-row tiles. A grid cell is one (sequence, block of ``HEADS`` heads,
+  chunk); the chunk axis is sequential and each head's state lives in VMEM
+  scratch across it, turned (``S^T [V, K]``, float32). Inside a cell, all
+  in VMEM: ``G`` (a triangle product at the highest precision), the
+  scores across sub-chunks (MXU), the pairs inside them (element by
+  element, a column at a time), ``T`` (the diagonal blocks' substitutions
+  side by side, then block rows), ``W``, ``U~``, ``U``, ``O`` and the
+  state's update. HBM sees q, k, v, g and beta read and o written once.
+  The backward pass is written by hand (``jax.custom_vjp``): it walks the
+  chunks in reverse with the state's cotangent in scratch, recomputes
+  every tile of a chunk from its inputs and the state that entered it,
+  and writes dq, dk, dv, dg and dbeta once; ``dA = -T^T dT T^T``, and the
+  cotangent of ``G`` is summed to the chunk's end by the triangle turned.
+  **Kept for the backward pass**: the inputs, and the float32 state that
+  entered each chunk (``T / C`` of ``[V, K]`` a head, 268 MB a sequence of
+  the benchmark's cell: 8,192 steps, 32 heads of 128, chunks of 64),
+  written by the forward sweep that the rule's forward rule runs; a call
+  that is not differentiated, and the forward pass of a ``jax.checkpoint``
+  (``optimize_remat``), write none.
+* **The composition** (``impl="xla"``) at every other shape (the tests'
+  toy widths): ``GROUP`` chunks' intra-chunk terms at a time in a
+  ``lax.map`` under a ``jax.checkpoint`` (``_intra``; the pairwise decays
+  ``[C, C, K]`` are never kept), then a ``lax.scan`` over the chunks that
+  carries the state (``_states``), differentiated by JAX. Kept for the
+  backward pass: the state at every chunk boundary, ``W``, ``U~``,
+  ``exp(G) q``, ``exp(G_C - G) k`` and ``P``, a few times the size of
+  ``k``. It is the kernels' oracle.
+
+Precision, in both forms alike: decays, cumulative sums, the pairwise
+terms inside a sub-chunk, ``T`` and the carried state are float32. Rounded
+to ``mm_dtype`` (bfloat16), where they enter a product that accumulates in
+float32: the two sides of the scores across sub-chunks, ``T``, ``k exp(G)
+beta``, ``v beta``, ``W``, the state, ``U``, ``exp(G) q``, ``exp(G_C - G)
+k`` and ``P``. In the hand-written backward pass a cotangent is rounded
+where it enters a product (dO, the state's, U's, W's, and the pair terms'
+where they meet the scores' sides), and nowhere else; ``T``'s own
+cotangent is carried through ``-T^T dT T^T`` in float32 at the highest
+precision. No document reset: the state runs through a packed sequence
+(ROADMAP.md R8).
 """
 
 from __future__ import annotations
@@ -60,14 +90,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from paddlebox_tpu.obs import trace
+from paddlebox_tpu.ops import pallas_kernels as pk
 
 _F32 = jnp.float32
+_LANES = 128
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: rows of a sub-chunk: pairs inside one are decayed element by element
 SUB = 16
 #: chunks of every (sequence, head) whose intra-chunk terms one trip of
 #: the loop over chunks computes (and the backward pass recomputes)
 GROUP = 8
+#: the most heads one grid cell of the kernels works, side by side: their
+#: chains interleave (cell 6's three sweeps a sequence and layer, 45.8 ms
+#: on a v5e at 8 against 48.2 at 2: PERF.md, PR 43)
+HEADS = 8
 
 
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -77,10 +117,19 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     [B,T,H] -> o [B,T,H,V] float32, the state starting at 0. Any T: the
     tail is padded with steps of k = v = g = beta = 0, which leave the
     state as it is. ``chunk`` is a multiple of ``SUB``."""
-    bsz, t, h, _ = q.shape
+    bsz, t, h, kd = q.shape
     if chunk % SUB:
         raise ValueError(f"a chunk of {chunk} is no whole number of "
                          f"sub-chunks of {SUB}")
+    if _tiles(kd, v.shape[-1], chunk):
+        pk._book_dispatch("kda_scan", "pallas")
+        pad = -t % chunk
+        o = _rule_kernels(*(jnp.pad(x, ((0, 0), (0, pad))
+                                    + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, g, beta)),
+                          chunk, jnp.dtype(mm_dtype))
+        return o[:, :t]
+    pk._book_dispatch("kda_scan", "xla")
     n = -(-t // chunk)
     groups = -(-n // GROUP)
     pad = groups * GROUP * chunk - t
@@ -229,3 +278,373 @@ def _states(parts, vd: int, mm_dtype):
 
     _, o = jax.lax.scan(chunk, s0, parts)
     return o
+
+
+# ---- the kernels -------------------------------------------------------------
+#
+# A grid cell is one (sequence, block of ``_heads`` heads, chunk); the chunk
+# axis is sequential. q, k, g and v cross HBM as [B, T, H K] (a head is a
+# run of K lanes of a step's row: the model's own layout), beta as
+# [B, H / heads, T, heads] (a head is a column of its block). Inside a cell
+# every tile is [rows, lanes] with the chunk's steps in the rows; the state
+# is held turned, S^T [V, K], so that the decay left of a chunk scales it
+# as a row and every product is one of the MXU's three forms.
+
+
+def _tiles(kd: int, vd: int, chunk: int) -> bool:
+    """Whether a chunk's work is whole (8, 128) tiles: K and V whole
+    128-lane tiles, the chunk's steps whole 8-row tiles."""
+    return kd % _LANES == 0 and vd % _LANES == 0 and chunk % 8 == 0
+
+
+def _heads(h: int) -> int:
+    """Heads a grid cell works: the most, up to ``HEADS``, that divide H."""
+    return max(d for d in range(1, min(h, HEADS) + 1) if h % d == 0)
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _pairs(q, k, gam, mm):
+    """The chunk's pair terms, float32: P [C, C] (s <= r) and D [C, C]
+    (s < r, beta not yet applied), as ``_intra`` makes them, and for each
+    sub-chunk after the first what its pairs across sub-chunks were made
+    of (the backward pass's operands)."""
+    c, kd = q.shape
+    row, lane = _iota((SUB, c), 0), _iota((SUB, c), 1)
+    sub_row = _iota((SUB, kd), 0)
+    step = _iota((c, kd), 0)
+    ps, ds, across = [], [], [None]
+    for i in range(c // SUB):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        gi, qi, ki = gam[rows], q[rows], k[rows]
+        if i:
+            ref = gam[i * SUB - 1:i * SUB]                          # R_i
+            down = jnp.exp(gi - ref)
+            up = jnp.exp(jnp.where(step < i * SUB, ref - gam, -jnp.inf))
+            cols = (k * up).astype(mm)
+            rq, rk = (qi * down).astype(mm), (ki * down).astype(mm)
+            p, d = pk.mxu_dot(rq, cols, 1, 1), pk.mxu_dot(rk, cols, 1, 1)
+            across.append((down, up, cols, rq, rk))
+        else:
+            p = d = jnp.zeros((SUB, c), _F32)
+        # pairs inside the sub-chunk, a column s at a time
+        for j in range(SUB):
+            s = i * SUB + j
+            dec = jnp.exp(jnp.where(sub_row >= j, gi - gam[s:s + 1], -jnp.inf))
+            f = k[s:s + 1] * dec
+            at = lane == s
+            p = jnp.where(at, jnp.sum(qi * f, 1, keepdims=True), p)
+            d = jnp.where(at & (row > j), jnp.sum(ki * f, 1, keepdims=True), d)
+        ps.append(p)
+        ds.append(d)
+    return jnp.concatenate(ps, 0), jnp.concatenate(ds, 0), across
+
+
+def _inverse(a):
+    """``(I + a)^-1`` of a strictly lower a [C, C], float32, as
+    ``_block_inverse``: each diagonal block by forward substitution a row
+    at a time (row r is ``e_r - sum_s a_rs X_s``), the blocks' chains side
+    by side, then block row i as ``X_ii (E_i - A_i T)`` from the rows
+    above it, at the highest precision."""
+    c = a.shape[0]
+    ns = c // SUB
+    row = _iota((SUB, SUB), 0)
+    eye = (row == _iota((SUB, SUB), 1)).astype(_F32)
+    turned = [a[i * SUB:(i + 1) * SUB, i * SUB:(i + 1) * SUB].T
+              for i in range(ns)]                     # column r is a's row r
+    diag = [eye] * ns
+    for r in range(1, SUB):
+        for i in range(ns):
+            x = eye[r:r + 1] - jnp.sum(turned[i][:, r:r + 1] * diag[i], 0,
+                                       keepdims=True)
+            diag[i] = jnp.where(row == r, x, diag[i])
+    eye_c = (_iota((c, c), 0) == _iota((c, c), 1)).astype(_F32)
+    blocks = [pk.mxu_dot(diag[0], eye_c[:SUB], 1, 0)]
+    for i in range(1, ns):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        above = jnp.concatenate(blocks + [jnp.zeros((c - i * SUB, c), _F32)],
+                                0)
+        blocks.append(pk.mxu_dot(
+            diag[i], eye_c[rows] - pk.mxu_dot(a[rows], above, 1, 0), 1, 0))
+    return jnp.concatenate(blocks, 0)
+
+
+class _Chunk:
+    """A chunk's terms that need no state, as ``_intra`` computes them, for
+    one head: q, k, g [C, K], v [C, V], beta [C, 1], all float32."""
+
+    def __init__(self, q, k, v, g, beta, mm):
+        c = q.shape[0]
+        tri = (_iota((c, c), 0) >= _iota((c, c), 1)).astype(_F32)
+        self.q, self.k, self.mm = q, k, mm
+        self.gam = pk.mxu_dot(tri, g, 1, 0)
+        self.p, self.d, self.across = _pairs(q, k, self.gam, mm)
+        self.t = _inverse(beta * self.d)
+        self.tm = self.t.astype(mm)
+        self.eg = jnp.exp(self.gam)
+        self.kb = k * self.eg * beta
+        self.kbm, self.vbm = self.kb.astype(mm), (v * beta).astype(mm)
+        self.wm = pk.mxu_dot(self.tm, self.kbm, 1, 0).astype(mm)
+        self.ut = pk.mxu_dot(self.tm, self.vbm, 1, 0)
+        last = self.gam[c - 1:c]
+        self.dc = jnp.exp(last)                               # [1, K]
+        self.to_end = jnp.exp(last - self.gam)
+        self.qgm = (q * self.eg).astype(mm)
+        self.kcm = (k * self.to_end).astype(mm)
+        self.pm = self.p.astype(mm)
+
+    def written(self, sm):
+        """``U = U~ - W S`` [C, V] in ``mm``, from the state that entered
+        (turned, [V, K], in ``mm``)."""
+        return (self.ut - pk.mxu_dot(self.wm, sm, 1, 1)).astype(self.mm)
+
+    def step(self, st):
+        """The state that entered, turned ([V, K] float32) -> (O [C, V],
+        the state that leaves, turned)."""
+        sm = st.astype(self.mm)
+        um = self.written(sm)
+        o = pk.mxu_dot(self.qgm, sm, 1, 1) + pk.mxu_dot(self.pm, um, 1, 0)
+        return o, st * self.dc + pk.mxu_dot(um, self.kcm, 0, 0)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, mm,
+                save: bool):
+    entered_ref = rest[0] if save else None
+    state = rest[-1]                                       # [heads, V, K]
+    heads, vd, kd = state.shape
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    for j in range(heads):
+        kc, vc = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
+        st = state[j]
+        if save:
+            entered_ref[vc, :] = st
+        ch = _Chunk(*(r[:, kc].astype(_F32) for r in (q_ref, k_ref)),
+                    v_ref[:, vc].astype(_F32), g_ref[:, kc].astype(_F32),
+                    b_ref[:, j:j + 1].astype(_F32), mm)
+        o, state[j] = ch.step(st)
+        o_ref[:, vc] = o
+
+
+def _pairs_bwd(ch, dp, dd):
+    """The pair terms' cotangents (dp of P, dd of D, [C, C] float32) ->
+    their parts of dq, dk and d(gam), [C, K] each."""
+    q, k, gam, mm = ch.q, ch.k, ch.gam, ch.mm
+    c, kd = q.shape
+    sub_row = _iota((SUB, kd), 0)
+    dk_all = jnp.zeros((c, kd), _F32)
+    dgam_all = jnp.zeros((c, kd), _F32)
+    dqs, dks, dgams = [], [], []
+    for i in range(c // SUB):
+        rows = slice(i * SUB, (i + 1) * SUB)
+        gi, qi, ki = gam[rows], q[rows], k[rows]
+        dpi, ddi = dp[rows], dd[rows]
+        dqi = dki = dgi = jnp.zeros((SUB, kd), _F32)
+        if i:
+            down, up, cols, rq, rk = ch.across[i]
+            dpm, ddm = dpi.astype(mm), ddi.astype(mm)
+            drq, drk = pk.mxu_dot(dpm, cols, 1, 0), pk.mxu_dot(ddm, cols, 1, 0)
+            dcols = pk.mxu_dot(dpm, rq, 0, 0) + pk.mxu_dot(ddm, rk, 0, 0)
+            dqi, dki = drq * down, drk * down
+            t_down = (drq * qi + drk * ki) * down
+            t_up = dcols * k * up
+            dk_all = dk_all + dcols * up
+            dgam_all = dgam_all - t_up
+            dgi = t_down
+            # R_i is the decay accumulated up to the row before the block
+            dref = (jnp.sum(t_up, 0, keepdims=True)
+                    - jnp.sum(t_down, 0, keepdims=True))
+            dgams[i - 1] = dgams[i - 1] + jnp.where(sub_row == SUB - 1, dref,
+                                                    0.0)
+        dks_cols = jnp.zeros((SUB, kd), _F32)
+        for j in range(SUB):
+            s = i * SUB + j
+            dec = jnp.exp(jnp.where(sub_row >= j, gi - gam[s:s + 1], -jnp.inf))
+            ks = k[s:s + 1]
+            f = ks * dec
+            pc, dc = dpi[:, s:s + 1], ddi[:, s:s + 1]
+            dqi = dqi + pc * f
+            dki = dki + dc * f
+            h = dec * (pc * qi + dc * ki)
+            e = h * ks
+            dgi = dgi + e
+            # what the column's own step receives, into its row
+            dks_cols = jnp.where(sub_row == j, jnp.sum(h, 0, keepdims=True),
+                                 dks_cols)
+            dgi = dgi - jnp.where(sub_row == j, jnp.sum(e, 0, keepdims=True),
+                                  0.0)
+        dqs.append(dqi)
+        dks.append(dki + dks_cols)
+        dgams.append(dgi)
+    return (jnp.concatenate(dqs, 0), dk_all + jnp.concatenate(dks, 0),
+            dgam_all + jnp.concatenate(dgams, 0))
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entered_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, mm):
+    """One chunk of the reverse walk. ``dstate`` holds the cotangent of the
+    state (turned) that LEFT this chunk; it leaves holding that of the
+    state that entered. Every tile is recomputed from the inputs and the
+    state that entered the chunk."""
+    heads, vd, kd = dstate.shape
+    c = q_ref.shape[0]
+    causal = _iota((c, c), 0) >= _iota((c, c), 1)
+    strict = _iota((c, c), 0) > _iota((c, c), 1)
+    tri = causal.astype(_F32)
+    last_row = _iota((c, kd), 0) == c - 1
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    for j in range(heads):
+        kc, vc = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
+        q, k, g = (r[:, kc].astype(_F32) for r in (q_ref, k_ref, g_ref))
+        v = v_ref[:, vc].astype(_F32)
+        beta = b_ref[:, j:j + 1].astype(_F32)
+        ch = _Chunk(q, k, v, g, beta, mm)
+        st0 = entered_ref[vc, :]
+        sm = st0.astype(mm)
+        um = ch.written(sm)
+        left = dstate[j]                                   # [V, K] float32
+        dom, dsm = do_ref[:, vc].astype(mm), left.astype(mm)
+        # O = qg S + P U;  S' = exp(G_C) S + kc^T U;  U = U~ - W S
+        dun = pk.mxu_dot(ch.pm, dom, 0, 0) + pk.mxu_dot(ch.kcm, dsm, 1, 1)
+        dunm = dun.astype(mm)
+        dstate[j] = (pk.mxu_dot(dom, ch.qgm, 0, 0) + left * ch.dc
+                     - pk.mxu_dot(dunm, ch.wm, 0, 0))
+        dqg = pk.mxu_dot(dom, sm, 1, 0)
+        dwm = (-pk.mxu_dot(dunm, sm, 1, 0)).astype(mm)
+        dkc = pk.mxu_dot(um, dsm, 1, 0)
+        dp = jnp.where(causal, pk.mxu_dot(dom, um, 1, 1), 0.0)
+        # W = T kb, U~ = T vb, T = (I + A)^-1: dA = -T^T dT T^T
+        dt = pk.mxu_dot(dwm, ch.kbm, 1, 1) + pk.mxu_dot(dunm, ch.vbm, 1, 1)
+        dkb = pk.mxu_dot(ch.tm, dwm, 0, 0)
+        dvb = pk.mxu_dot(ch.tm, dunm, 0, 0)
+        da = jnp.where(strict, -pk.mxu_dot(pk.mxu_dot(ch.t, dt, 0, 0), ch.t,
+                                           1, 1), 0.0)
+        # A = beta D
+        dbeta = jnp.sum(da * ch.d, 1, keepdims=True)
+        dq, dk, dgam = _pairs_bwd(ch, dp, beta * da)
+        # qg = q exp(G), kb = k exp(G) beta, vb = v beta, kc = k exp(G_C - G)
+        dq = dq + dqg * ch.eg
+        dk = dk + dkb * ch.eg * beta + dkc * ch.to_end
+        t_kc = dkc * k * ch.to_end
+        dgam = dgam + dqg * q * ch.eg + dkb * ch.kb - t_kc
+        dbeta = dbeta + jnp.sum(dkb * k * ch.eg, 1, keepdims=True) \
+            + jnp.sum(dvb * v, 1, keepdims=True)
+        ddc = jnp.sum(st0 * left, 0, keepdims=True)
+        dlast = jnp.sum(t_kc, 0, keepdims=True) + ddc * ch.dc
+        dgam = dgam + jnp.where(last_row, dlast, 0.0)
+        # G = cumsum(g) within the chunk: its cotangent sums to the end
+        dq_ref[:, kc] = dq.astype(dq_ref.dtype)
+        dk_ref[:, kc] = dk.astype(dk_ref.dtype)
+        dg_ref[:, kc] = pk.mxu_dot(tri, dgam, 0, 0).astype(dg_ref.dtype)
+        dv_ref[:, vc] = (dvb * beta).astype(dv_ref.dtype)
+        db_ref[:, j:j + 1] = dbeta.astype(db_ref.dtype)
+
+
+def _specs(h, kd, vd, chunk, heads, step):
+    """Block specs of a (sequence, heads, chunk) cell; ``step`` maps the
+    grid's last index to the chunk (the reverse walk turns it)."""
+    wide_k = pl.BlockSpec((None, chunk, heads * kd),
+                          lambda s, j, i: (s, step(i), j))
+    wide_v = pl.BlockSpec((None, chunk, heads * vd),
+                          lambda s, j, i: (s, step(i), j))
+    col = pl.BlockSpec((None, None, chunk, heads),
+                       lambda s, j, i: (s, j, step(i), 0))
+    entered = pl.BlockSpec((None, None, heads * vd, kd),
+                           lambda s, j, i: (s, step(i), j, 0))
+    return wide_k, wide_v, col, entered
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=100 * 1024 * 1024)
+
+
+def _by_block(beta, heads: int):
+    """beta [B, T, H] -> [B, H / heads, T, heads]."""
+    bsz, t, h = beta.shape
+    return beta.reshape(bsz, t, h // heads, heads).transpose(0, 2, 1, 3)
+
+
+_sweep = pk.scoped_sweep(trace.SCOPE_KDA_SCAN)
+
+
+@_sweep
+def _forward(q, k, v, g, beta, *, chunk, mm, save: bool):
+    bsz, t, h, kd = q.shape
+    vd = v.shape[-1]
+    heads = _heads(h)
+    nc = t // chunk
+    wide_k, wide_v, col, entered = _specs(h, kd, vd, chunk, heads,
+                                          lambda i: i)
+    out_shape = [jax.ShapeDtypeStruct((bsz, t, h * vd), _F32)]
+    out_specs = [wide_v]
+    if save:
+        out_shape.append(jax.ShapeDtypeStruct((bsz, nc, h * vd, kd), _F32))
+        out_specs.append(entered)
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, mm=mm, save=save),
+        grid=(bsz, h // heads, nc),
+        in_specs=[wide_k, wide_k, wide_v, wide_k, col],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), _F32)],
+        compiler_params=_params(), interpret=pk._interpret(),
+    )(*(x.reshape(bsz, t, -1) for x in (q, k, v, g)), _by_block(beta, heads))
+    return out[0].reshape(bsz, t, h, vd), (out[1] if save else None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule_kernels(q, k, v, g, beta, chunk, mm):
+    return _forward(q, k, v, g, beta, chunk=chunk, mm=mm, save=False)[0]
+
+
+def _rule_kernels_fwd(q, k, v, g, beta, chunk, mm):
+    o, entered = _forward(q, k, v, g, beta, chunk=chunk, mm=mm, save=True)
+    return o, (q, k, v, g, beta, entered)
+
+
+def _rule_kernels_bwd(chunk, mm, res, do):
+    return _backward(*res, do, chunk=chunk, mm=mm)
+
+
+@_sweep
+def _backward(q, k, v, g, beta, entered, do, *, chunk, mm):
+    bsz, t, h, kd = q.shape
+    vd = v.shape[-1]
+    heads = _heads(h)
+    nc = t // chunk
+    wide_k, wide_v, col, entered_spec = _specs(h, kd, vd, chunk, heads,
+                                               lambda i: nc - 1 - i)
+    flat_k = jax.ShapeDtypeStruct((bsz, t, h * kd), _F32)
+    dq, dk, dv, dg, db = pl.pallas_call(
+        functools.partial(_bwd_kernel, mm=mm),
+        grid=(bsz, h // heads, nc),
+        in_specs=[wide_k, wide_k, wide_v, wide_k, col, entered_spec, wide_v],
+        out_specs=[wide_k, wide_k, wide_v, wide_k, col],
+        out_shape=[flat_k, flat_k,
+                   jax.ShapeDtypeStruct((bsz, t, h * vd), _F32), flat_k,
+                   jax.ShapeDtypeStruct((bsz, h // heads, t, heads), _F32)],
+        scratch_shapes=[pltpu.VMEM((heads, vd, kd), _F32)],
+        compiler_params=_params(), interpret=pk._interpret(),
+    )(*(x.reshape(bsz, t, -1) for x in (q, k, v, g)), _by_block(beta, heads),
+      entered, do.astype(_F32).reshape(bsz, t, h * vd))
+    return (dq.reshape(q.shape).astype(q.dtype),
+            dk.reshape(k.shape).astype(k.dtype),
+            dv.reshape(v.shape).astype(v.dtype),
+            dg.reshape(g.shape).astype(g.dtype),
+            db.transpose(0, 2, 1, 3).reshape(beta.shape).astype(beta.dtype))
+
+
+# optimize_remat: the forward pass of a ``jax.checkpoint`` around the rule
+# (the model's mixers) runs the sweep that writes no states
+_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd,
+                     optimize_remat=True)

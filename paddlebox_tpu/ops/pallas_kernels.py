@@ -76,6 +76,7 @@ between them and their XLA composition (PERF.md section 6, PR 32).
 from __future__ import annotations
 
 import functools
+import inspect
 from typing import Optional
 
 import jax
@@ -116,6 +117,40 @@ def _book_dispatch(kernel: str, impl: str) -> None:
             ).inc(kernel=kernel, impl=impl)
     except Exception:  # pragma: no cover - telemetry must never break math
         pass
+
+
+def mxu_dot(lhs, rhs, contract_l: int, contract_r: int):
+    """A kernel's float32-accumulated product of two 2-D tiles; float32
+    operands are multiplied as float32 (the tests' ``mm_dtype``), not in
+    one bfloat16 pass. (``ops/ssd.py`` keeps its own copy of this and of
+    ``scoped_sweep``: a Mosaic kernel's serialized body carries its ops'
+    source locations, so moving them would change cell 2's program.)"""
+    precision = (jax.lax.Precision.HIGHEST if lhs.dtype == jnp.float32
+                 else None)
+    return jax.lax.dot_general(
+        lhs, rhs, (((contract_l,), (contract_r,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32)
+
+
+def scoped_sweep(scope: str):
+    """A kernel sweep as a model's layers share it. Jitted, so that every
+    call at the same shapes (a layer's, the next layer's) is one trace of
+    the kernel's body and one function of the lowered module: tracing a
+    kernel is seconds of set-up in every process, cached programs or not.
+    And every op of it under ``scope``, whatever stack the caller's
+    transformation leaves it: a ``custom_vjp`` rule's ops carry the names
+    the rule gives them, and the primal that ``optimize_remat`` puts in a
+    checkpoint's forward pass carries none. The sweep's keyword-only
+    arguments are static."""
+    def wrap(rule):
+        @functools.wraps(rule)
+        def scoped(*args, **static):
+            with jax.named_scope(scope):
+                return rule(*args, **static)
+        return jax.jit(scoped, static_argnames=tuple(
+            name for name, par in inspect.signature(rule).parameters.items()
+            if par.kind is par.KEYWORD_ONLY))
+    return wrap
 
 
 def _require_interpret(name: str) -> None:

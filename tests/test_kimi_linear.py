@@ -30,7 +30,7 @@ from benchmarks.tests.test_family_lm_kimi_linear import (  # noqa: E402
 from paddlebox_tpu.models import KimiLinear, MellumMoe  # noqa: E402
 from paddlebox_tpu.models import kimi_linear  # noqa: E402
 from paddlebox_tpu.obs import trace  # noqa: E402
-from paddlebox_tpu.ops import causal_attention  # noqa: E402
+from paddlebox_tpu.ops import causal_attention, kda  # noqa: E402
 from paddlebox_tpu.ops.causal_attention import causal_gqa_attention  # noqa: E402
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts  # noqa: E402
 from test_mellum import dense_attention  # noqa: E402
@@ -171,10 +171,14 @@ def _published(pattern, **over):
 
 @f32
 @pytest.mark.parametrize("kind,t", [("K", 80), ("M", 48)])
-def test_a_mixer_at_the_published_widths(kind, t):
-    """A KDA mixer (32 heads of 128, over two chunks of 64) and a latent
-    attention mixer (32 heads of 128 + 64 over values of 128, rank 512)
-    at hidden 2304 against the reference's, values and every gradient."""
+def test_a_mixer_at_the_published_widths(monkeypatch, kind, t):
+    """A KDA mixer (32 heads of 128, over two chunks of 64: the rule's
+    kernels) and a latent attention mixer (32 heads of 128 + 64 over
+    values of 128, rank 512) at hidden 2304 against the reference's,
+    values and every gradient."""
+    # a kernel cell a head: the interpreter compiles a cell's body, in
+    # seconds a head (tests/test_kda_kernel.py covers cells of several)
+    monkeypatch.setattr(kda, "HEADS", 1)
     cfg = _published(kind)
     z = ref.dims(cfg)
     lay = _perturbed_norms(ref.init(jax.random.PRNGKey(4), cfg))["layers"][0]

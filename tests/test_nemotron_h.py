@@ -24,6 +24,7 @@ from benchmarks.reference import lm as ref_lm  # noqa: E402
 from benchmarks.reference.models import nemotron_h as ref  # noqa: E402
 from paddlebox_tpu.models import NemotronH  # noqa: E402
 from paddlebox_tpu.obs import trace  # noqa: E402
+from paddlebox_tpu.ops.kda import kda_chunked  # noqa: E402
 from paddlebox_tpu.ops.ssd import ssd_scan  # noqa: E402
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts  # noqa: E402
 
@@ -639,6 +640,61 @@ def test_the_scan_kernels_compile_for_a_v5e(what, one_v5e,
     compiled = jax.jit(f).trace(*args).lower(
         lowering_platforms=("tpu",)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= (
+        1 if what == "forward" else 2)
+
+
+def _delta_rule(*args):
+    return kda_chunked(*args, chunk=64, mm_dtype=jnp.bfloat16)
+
+
+def test_every_kernel_of_the_delta_rule_sits_under_its_scope(
+        kernels_for_the_chip):
+    """Two checkpointed calls of the delta rule at tile widths (two heads
+    of 128), each under another scope as a KDA mixer's projections are,
+    and their gradient, lowered for a TPU: three custom calls (the sweep
+    that writes no states, in the checkpoints' forward pass; the one that
+    writes them, in their recomputation; the backward sweep), each under
+    ``pbox.kda_scan`` by the rule's own naming, so that the trace's readers
+    count all three and ``other`` none of them."""
+    def args(seed):
+        return [jax.random.normal(jax.random.PRNGKey(seed + i), s)
+                for i, s in enumerate([(1, 128, 2, 128)] * 4 + [(1, 128, 2)])]
+
+    def layer(x, *rest):
+        with jax.named_scope(trace.SCOPE_KDA_PROJ):
+            return jax.checkpoint(_delta_rule)(x, *rest)
+
+    def loss(a, b):
+        return jnp.sum(layer(*a) * layer(*b))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
+        args(0), args(5)).lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    stacks = [locs[ref_] for ref_ in re.findall(
+        r"@tpu_custom_call\(.*loc\((#loc\d+)\)$", text, re.M)]
+    assert len(stacks) == 3, stacks
+    for stack in stacks:
+        assert trace.SCOPE_KDA_SCAN in stack.split("/"), stacks
+    calls = re.findall(r"call @(_forward|_backward)\w*\(", text)
+    assert calls.count("_backward") == 2 and calls.count("_forward") == 4
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_the_delta_rule_kernels_compile_for_a_v5e(what, one_v5e,
+                                                  kernels_for_the_chip):
+    """At cell 6's shapes (one sequence of 8,192 steps, 32 heads of 128,
+    chunks of 64, float32 in, bfloat16 operands): Mosaic takes the forward
+    and the backward sweep. It proves that they compile, not what they
+    compute (tests/test_kda_kernel.py) nor how fast (PERF.md)."""
+    t, h, d = 8192, 32, 128
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_v5e)
+            for s in [(1, t, h, d)] * 4 + [(1, t, h)]]
+    f = _delta_rule if what == "forward" else jax.grad(
+        lambda *v: jnp.sum(_delta_rule(*v)), argnums=(0, 1, 2, 3, 4))
+    compiled = jax.jit(f).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == (
         1 if what == "forward" else 2)
 
 
