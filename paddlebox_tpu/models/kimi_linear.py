@@ -46,9 +46,10 @@ its gates, norms, the delta rule's state and the softmax and loss
 float32; matrix products with ``compute_dtype`` (bfloat16) operands and
 float32 accumulation (the delta rule's own: ops/kda.py). Every op sits
 under one ``pbox.*`` scope of ``obs/trace``'s catalog
-(``KDA_SEQ_STEP_SCOPES``: a KDA mixer's projections and gated norm under
-``pbox.kda_proj``, its convs and L2 norms under ``pbox.kda_conv``, the
-rule under ``pbox.kda_scan``; a latent attention mixer, its latent
+(``KDA_SEQ_STEP_SCOPES``: a KDA mixer's projections under
+``pbox.kda_proj``, its convs and their SiLU under ``pbox.kda_conv``, the
+rule with the L2 norms of q and k and the output's gated norm under
+``pbox.kda_scan``; a latent attention mixer, its latent
 projections and norm with it, under ``pbox.attn``); every sublayer is one
 ``jax.checkpoint`` that keeps its input (the dense feed-forward one a
 slab of positions), a latent attention mixer's also its forward block
@@ -70,7 +71,7 @@ from paddlebox_tpu.models.lm_parts import (ATTN_BLOCK, KEEP_ATTN_LOOPS,
                                            rms_norm)
 from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.causal_attention import causal_gqa_attention
-from paddlebox_tpu.ops.kda import kda_chunked
+from paddlebox_tpu.ops.kda import kda_normed
 from paddlebox_tpu.ops.short_conv import causal_depthwise_conv
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts
 
@@ -81,8 +82,6 @@ _scope = jax.named_scope
 MLP_ROWS = 4096
 #: steps of a chunk of the delta rule
 KDA_CHUNK = 64
-#: what the L2 norm of queries and keys adds under its square root
-L2_EPS = 1e-6
 
 
 def layer_kinds(config: Dict[str, Any]) -> str:
@@ -226,37 +225,28 @@ class KimiLinear:
         return rms_norm(x, weight, self.eps)
 
     def _kda(self, lay, x):
-        s, t, _ = x.shape
-        h, kd = self.kh, self.kd
+        """Every array between the projections and ``o`` stays a flat row
+        [S, T, H K]: the rule's kernels take and give such rows and do the
+        per-head norms themselves (ops/kda.py)."""
+        kd = self.kd
         with _scope(trace.SCOPE_KDA_PROJ):
             u = self._norm(x, lay["attn_norm"])
             q, k, v = (self._mm(u, lay[n]) for n in ("q", "k", "v"))
             f = self._mm(self._mm(u, lay["f_a"]), lay["f_b"])
-            g = -jnp.exp(lay["A_log"])[:, None] * jax.nn.softplus(
-                (f + lay["dt_bias"]).reshape(s, t, h, kd))
+            g = -jnp.repeat(jnp.exp(lay["A_log"]), kd) * jax.nn.softplus(
+                f + lay["dt_bias"])
             beta = jax.nn.sigmoid(self._mm(u, lay["b"]))
             gate = self._mm(self._mm(u, lay["g_a"]), lay["g_b"]) \
                 + lay["g_bias"]
         with _scope(trace.SCOPE_KDA_CONV):
-
-            def conv(x, taps):
-                return jax.nn.silu(causal_depthwise_conv(x, taps)
-                                   ).reshape(s, t, h, kd)
-
-            def l2(x):
-                return x * jax.lax.rsqrt(
-                    jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
-
-            q = l2(conv(q, lay["q_conv"])) * kd ** -0.5
-            k = l2(conv(k, lay["k_conv"]))
-            v = conv(v, lay["v_conv"])
+            q, k, v = (jax.nn.silu(causal_depthwise_conv(y, lay[n]))
+                       for y, n in ((q, "q_conv"), (k, "k_conv"),
+                                    (v, "v_conv")))
         with _scope(trace.SCOPE_KDA_SCAN):
-            o = kda_chunked(q, k, v, g, beta, chunk=KDA_CHUNK,
-                            mm_dtype=self.dtype)
+            y = kda_normed(q, k, v, g, beta, gate, lay["o_norm"],
+                           eps=self.eps, chunk=KDA_CHUNK, mm_dtype=self.dtype)
         with _scope(trace.SCOPE_KDA_PROJ):
-            o = self._norm(o, lay["o_norm"]) \
-                * jax.nn.sigmoid(gate.reshape(s, t, h, kd))
-            return x + self._mm(o.reshape(s, t, h * kd), lay["o"])
+            return x + self._mm(y, lay["o"])
 
     def _mla(self, lay, x):
         s, t, _ = x.shape

@@ -38,47 +38,61 @@ element (the exponent masked to -inf above the diagonal before the exp).
 block and block substitution between them, in float32 at the highest
 precision.
 
-Two forms of the one algorithm, chosen by what the shapes show
-(``_tiles``), booked as ``pbox_kernel_dispatch_total{kernel="kda_scan"}``:
+A KDA mixer calls the rule between per-head norms (``kda_normed``):
+
+    y = rms_norm_V(rule(l2(q) K^-1/2, l2(k), v, g, beta)) o_norm sigmoid(gate)
+
+on flat rows ``[B, T, H K]``, the layout its projections and convs write
+(a head is a run of K lanes of a step's row). Two forms of it, chosen by
+what the shapes show (``_tiles``), booked as
+``pbox_kernel_dispatch_total{kernel="kda_scan"}``:
 
 * **The kernels** (``impl="pallas"``; Mosaic on a TPU, interpret mode
   elsewhere) where K and V are whole 128-lane tiles and the chunk whole
-  8-row tiles. A grid cell is one (sequence, block of ``HEADS`` heads,
+  8-row tiles. They take the rows as they are and do every per-head op on
+  a head's lane slice in VMEM: a norm is a lane reduction a row, so XLA
+  changes no array's layout between the mixer's producers and the
+  kernels. A grid cell is one (sequence, block of ``HEADS`` heads,
   chunk); the chunk axis is sequential and each head's state lives in VMEM
   scratch across it, turned (``S^T [V, K]``, float32). Inside a cell, all
-  in VMEM: ``G`` (a triangle product at the highest precision), the
-  scores across sub-chunks (MXU), the pairs inside them (element by
-  element, a column at a time), ``T`` (the diagonal blocks' substitutions
-  side by side, then block rows), ``W``, ``U~``, ``U``, ``O`` and the
-  state's update. HBM sees q, k, v, g and beta read and o written once.
-  The backward pass is written by hand (``jax.custom_vjp``): it walks the
-  chunks in reverse with the state's cotangent in scratch, recomputes
-  every tile of a chunk from its inputs and the state that entered it,
-  and writes dq, dk, dv, dg and dbeta once; ``dA = -T^T dT T^T``, and the
-  cotangent of ``G`` is summed to the chunk's end by the triangle turned.
-  **Kept for the backward pass**: the inputs, and the float32 state that
-  entered each chunk (``T / C`` of ``[V, K]`` a head, 268 MB a sequence of
-  the benchmark's cell: 8,192 steps, 32 heads of 128, chunks of 64),
-  written by the forward sweep that the rule's forward rule runs; a call
-  that is not differentiated, and the forward pass of a ``jax.checkpoint``
+  in VMEM: q's and k's L2 norms, ``G`` (a triangle product at the highest
+  precision), the scores across sub-chunks (MXU), the pairs inside them
+  (element by element, a column at a time), ``T`` (the diagonal blocks'
+  substitutions side by side, then block rows), ``W``, ``U~``, ``U``,
+  ``O``, the state's update and the gated norm of ``O``. HBM sees q, k,
+  v, g, beta and gate read and y written once. The backward pass is
+  written by hand (``jax.custom_vjp``): it walks the chunks in reverse
+  with the state's cotangent in scratch, recomputes every tile of a chunk
+  (``O`` too) from its inputs and the state that entered it, and writes
+  dq, dk, dv, dg, dbeta and dgate once, and each grid cell's part of
+  o_norm's gradient; ``dA = -T^T dT T^T``, and the cotangent of ``G`` is
+  summed to the chunk's end by the triangle turned. **Kept for the
+  backward pass**: the inputs, and the float32 state that entered each
+  chunk (``T / C`` of ``[V, K]`` a head, 268 MB a sequence of the
+  benchmark's cell: 8,192 steps, 32 heads of 128, chunks of 64), written
+  by the forward sweep that the rule's forward rule runs; a call that is
+  not differentiated, and the forward pass of a ``jax.checkpoint``
   (``optimize_remat``), write none.
 * **The composition** (``impl="xla"``) at every other shape (the tests'
-  toy widths): ``GROUP`` chunks' intra-chunk terms at a time in a
-  ``lax.map`` under a ``jax.checkpoint`` (``_intra``; the pairwise decays
-  ``[C, C, K]`` are never kept), then a ``lax.scan`` over the chunks that
-  carries the state (``_states``), differentiated by JAX. Kept for the
-  backward pass: the state at every chunk boundary, ``W``, ``U~``,
-  ``exp(G) q``, ``exp(G_C - G) k`` and ``P``, a few times the size of
-  ``k``. It is the kernels' oracle.
+  toy widths): the same norms in plain JAX around ``kda_chunked``, the
+  rule alone on ``[B, T, H, K]``: ``GROUP`` chunks' intra-chunk terms at
+  a time in a ``lax.map`` under a ``jax.checkpoint`` (``_intra``; the
+  pairwise decays ``[C, C, K]`` are never kept), then a ``lax.scan`` over
+  the chunks that carries the state (``_states``), differentiated by JAX.
+  Kept for the backward pass: the state at every chunk boundary, ``W``,
+  ``U~``, ``exp(G) q``, ``exp(G_C - G) k`` and ``P``, a few times the
+  size of ``k``. It is the kernels' oracle.
 
-Precision, in both forms alike: decays, cumulative sums, the pairwise
-terms inside a sub-chunk, ``T`` and the carried state are float32. Rounded
-to ``mm_dtype`` (bfloat16), where they enter a product that accumulates in
-float32: the two sides of the scores across sub-chunks, ``T``, ``k exp(G)
-beta``, ``v beta``, ``W``, the state, ``U``, ``exp(G) q``, ``exp(G_C - G)
-k`` and ``P``. In the hand-written backward pass a cotangent is rounded
-where it enters a product (dO, the state's, U's, W's, and the pair terms'
-where they meet the scores' sides), and nowhere else; ``T``'s own
+Precision, in both forms alike: the norms (q's and k's ``L2_EPS``, the
+output's ``eps``) and the gate's sigmoid, decays, cumulative sums, the
+pairwise terms inside a sub-chunk, ``T`` and the carried state are
+float32. Rounded to ``mm_dtype`` (bfloat16), where they enter a product
+that accumulates in float32: the two sides of the scores across
+sub-chunks, ``T``, ``k exp(G) beta``, ``v beta``, ``W``, the state, ``U``,
+``exp(G) q``, ``exp(G_C - G) k`` and ``P``. In the hand-written backward
+pass a cotangent is rounded where it enters a product (dO, which the
+gated norm's backward gives in float32, the state's, U's, W's, and the
+pair terms' where they meet the scores' sides), and nowhere else; ``T``'s own
 cotangent is carried through ``-T^T dT T^T`` in float32 at the highest
 precision. No document reset: the state runs through a packed sequence
 (ROADMAP.md R8).
@@ -108,28 +122,69 @@ GROUP = 8
 #: chains interleave (cell 6's three sweeps a sequence and layer, 45.8 ms
 #: on a v5e at 8 against 48.2 at 2: PERF.md, PR 43)
 HEADS = 8
+#: what the L2 norm of queries and keys adds under its square root
+L2_EPS = 1e-6
+
+
+def _check_chunk(chunk: int) -> None:
+    if chunk % SUB:
+        raise ValueError(f"a chunk of {chunk} is no whole number of "
+                         f"sub-chunks of {SUB}")
+
+
+def _l2(x):
+    """x over its last axis (a head's lanes) to unit length."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
+
+
+def _gated_norm(o, weight, gate, eps: float):
+    """o RMS-normed over its last axis (a head's lanes), times the norm's
+    weight and ``sigmoid(gate)``."""
+    return o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) \
+        * weight * jax.nn.sigmoid(gate)
+
+
+def kda_normed(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+               beta: jax.Array, gate: jax.Array, o_norm: jax.Array, *,
+               eps: float, chunk: int = 64, mm_dtype=jnp.bfloat16
+               ) -> jax.Array:
+    """The rule between a KDA mixer's per-head norms, on flat rows: q, k
+    (before their L2 norms) and g (log decay, <= 0) [B, T, H K], v and
+    gate [B, T, H V], beta [B, T, H], the output norm's weight o_norm [V]
+    -> ``rms_norm(rule(l2(q) K^-1/2, l2(k), v, g, beta), o_norm, eps)
+    sigmoid(gate)`` [B, T, H V] float32, the state starting at 0. Any T:
+    the kernels' tail is padded with steps of zeros, which leave the state
+    as it is. ``chunk`` is a multiple of ``SUB``."""
+    bsz, t, h = beta.shape
+    kd, vd = q.shape[-1] // h, v.shape[-1] // h
+    _check_chunk(chunk)
+    if _tiles(kd, vd, chunk):
+        pk._book_dispatch("kda_scan", "pallas")
+        pad = -t % chunk
+        y = _rule_kernels(*(jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                            for x in (q, k, v, g, beta, gate)),
+                          o_norm, chunk, jnp.dtype(mm_dtype), eps)
+        return y[:, :t]
+    pk._book_dispatch("kda_scan", "xla")
+
+    def heads(x):
+        return x.reshape(bsz, t, h, -1)
+
+    o = kda_chunked(_l2(heads(q)) * kd ** -0.5, _l2(heads(k)), heads(v),
+                    heads(g), beta, chunk, mm_dtype)
+    return _gated_norm(o, o_norm, heads(gate), eps).reshape(bsz, t, h * vd)
 
 
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, chunk: int = 64,
                 mm_dtype=jnp.bfloat16) -> jax.Array:
-    """q, k [B,T,H,K], v [B,T,H,V], g [B,T,H,K] (log decay, <= 0), beta
-    [B,T,H] -> o [B,T,H,V] float32, the state starting at 0. Any T: the
-    tail is padded with steps of k = v = g = beta = 0, which leave the
-    state as it is. ``chunk`` is a multiple of ``SUB``."""
+    """The rule alone, as the composition computes it at any shape: q, k
+    [B,T,H,K], v [B,T,H,V], g [B,T,H,K] (log decay, <= 0), beta [B,T,H]
+    -> o [B,T,H,V] float32, the state starting at 0. Any T: the tail is
+    padded with steps of k = v = g = beta = 0, which leave the state as
+    it is. ``chunk`` is a multiple of ``SUB``."""
     bsz, t, h, kd = q.shape
-    if chunk % SUB:
-        raise ValueError(f"a chunk of {chunk} is no whole number of "
-                         f"sub-chunks of {SUB}")
-    if _tiles(kd, v.shape[-1], chunk):
-        pk._book_dispatch("kda_scan", "pallas")
-        pad = -t % chunk
-        o = _rule_kernels(*(jnp.pad(x, ((0, 0), (0, pad))
-                                    + ((0, 0),) * (x.ndim - 2))
-                            for x in (q, k, v, g, beta)),
-                          chunk, jnp.dtype(mm_dtype))
-        return o[:, :t]
-    pk._book_dispatch("kda_scan", "xla")
+    _check_chunk(chunk)
     n = -(-t // chunk)
     groups = -(-n // GROUP)
     pad = groups * GROUP * chunk - t
@@ -283,8 +338,8 @@ def _states(parts, vd: int, mm_dtype):
 # ---- the kernels -------------------------------------------------------------
 #
 # A grid cell is one (sequence, block of ``_heads`` heads, chunk); the chunk
-# axis is sequential. q, k, g and v cross HBM as [B, T, H K] (a head is a
-# run of K lanes of a step's row: the model's own layout), beta as
+# axis is sequential. q, k, g, v, gate and y cross HBM as [B, T, H K] (a
+# head is a run of K lanes of a step's row: the model's own layout), beta as
 # [B, H / heads, T, heads] (a head is a column of its block). Inside a cell
 # every tile is [rows, lanes] with the chunk's steps in the rows; the state
 # is held turned, S^T [V, K], so that the decay left of a chunk scales it
@@ -400,20 +455,41 @@ class _Chunk:
         (turned, [V, K], in ``mm``)."""
         return (self.ut - pk.mxu_dot(self.wm, sm, 1, 1)).astype(self.mm)
 
+    def output(self, sm, um):
+        """``O = (exp(G) q) S_0 + P U`` [C, V] float32 from the state that
+        entered and U (both in ``mm``)."""
+        return pk.mxu_dot(self.qgm, sm, 1, 1) + pk.mxu_dot(self.pm, um, 1, 0)
+
     def step(self, st):
         """The state that entered, turned ([V, K] float32) -> (O [C, V],
         the state that leaves, turned)."""
         sm = st.astype(self.mm)
         um = self.written(sm)
-        o = pk.mxu_dot(self.qgm, sm, 1, 1) + pk.mxu_dot(self.pm, um, 1, 0)
-        return o, st * self.dc + pk.mxu_dot(um, self.kcm, 0, 0)
+        return (self.output(sm, um),
+                st * self.dc + pk.mxu_dot(um, self.kcm, 0, 0))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, mm,
-                save: bool):
+def _normed_qk(q_ref, k_ref, kc):
+    """A head's q and k [C, K] as the rule takes them: L2-normed, q times
+    K^-1/2."""
+    kd = kc.stop - kc.start
+    return (_l2(q_ref[:, kc].astype(_F32)) * kd ** -0.5,
+            _l2(k_ref[:, kc].astype(_F32)))
+
+
+def _l2_bwd(x, dz):
+    """The cotangent of x [C, K] from that of ``_l2(x)``."""
+    r = jax.lax.rsqrt(jnp.sum(x * x, 1, keepdims=True) + L2_EPS)
+    z = x * r
+    return r * (dz - z * jnp.sum(dz * z, 1, keepdims=True))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, gate_ref, w_ref, y_ref,
+                *rest, mm, eps: float, save: bool):
     entered_ref = rest[0] if save else None
     state = rest[-1]                                       # [heads, V, K]
     heads, vd, kd = state.shape
+    weight = w_ref[...]                                    # [1, V]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -424,11 +500,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, mm,
         st = state[j]
         if save:
             entered_ref[vc, :] = st
-        ch = _Chunk(*(r[:, kc].astype(_F32) for r in (q_ref, k_ref)),
+        ch = _Chunk(*_normed_qk(q_ref, k_ref, kc),
                     v_ref[:, vc].astype(_F32), g_ref[:, kc].astype(_F32),
                     b_ref[:, j:j + 1].astype(_F32), mm)
         o, state[j] = ch.step(st)
-        o_ref[:, vc] = o
+        y_ref[:, vc] = _gated_norm(o, weight, gate_ref[:, vc].astype(_F32),
+                                   eps)
 
 
 def _pairs_bwd(ch, dp, dd):
@@ -485,34 +562,51 @@ def _pairs_bwd(ch, dp, dd):
             dgam_all + jnp.concatenate(dgams, 0))
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entered_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, mm):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, gate_ref, w_ref,
+                entered_ref, dy_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                dgate_ref, dw_ref, dstate, *, mm, eps: float):
     """One chunk of the reverse walk. ``dstate`` holds the cotangent of the
     state (turned) that LEFT this chunk; it leaves holding that of the
     state that entered. Every tile is recomputed from the inputs and the
-    state that entered the chunk."""
+    state that entered the chunk. ``dw_ref`` sums the norm weight's
+    cotangent over the cell's heads and chunks."""
     heads, vd, kd = dstate.shape
     c = q_ref.shape[0]
     causal = _iota((c, c), 0) >= _iota((c, c), 1)
     strict = _iota((c, c), 0) > _iota((c, c), 1)
     tri = causal.astype(_F32)
     last_row = _iota((c, kd), 0) == c - 1
+    weight = w_ref[...]                                    # [1, V]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
 
+    dw = jnp.zeros((1, vd), _F32)
     for j in range(heads):
         kc, vc = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
-        q, k, g = (r[:, kc].astype(_F32) for r in (q_ref, k_ref, g_ref))
+        q, k = _normed_qk(q_ref, k_ref, kc)
+        g = g_ref[:, kc].astype(_F32)
         v = v_ref[:, vc].astype(_F32)
         beta = b_ref[:, j:j + 1].astype(_F32)
         ch = _Chunk(q, k, v, g, beta, mm)
         st0 = entered_ref[vc, :]
         sm = st0.astype(mm)
         um = ch.written(sm)
+        # y = n weight sigmoid(gate), n = O r, r = (mean O^2 + eps)^-1/2
+        o = ch.output(sm, um)
+        r = jax.lax.rsqrt(jnp.mean(o * o, 1, keepdims=True) + eps)
+        n = o * r
+        sg = jax.nn.sigmoid(gate_ref[:, vc].astype(_F32))
+        dy = dy_ref[:, vc].astype(_F32)
+        dgate_ref[:, vc] = (dy * n * weight * sg * (1.0 - sg)).astype(
+            dgate_ref.dtype)
+        dw = dw + jnp.sum(dy * n * sg, 0, keepdims=True)
+        dn = dy * weight * sg
+        do = r * (dn - n * jnp.mean(dn * n, 1, keepdims=True))
         left = dstate[j]                                   # [V, K] float32
-        dom, dsm = do_ref[:, vc].astype(mm), left.astype(mm)
+        dom, dsm = do.astype(mm), left.astype(mm)
         # O = qg S + P U;  S' = exp(G_C) S + kc^T U;  U = U~ - W S
         dun = pk.mxu_dot(ch.pm, dom, 0, 0) + pk.mxu_dot(ch.kcm, dsm, 1, 1)
         dunm = dun.astype(mm)
@@ -541,26 +635,35 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entered_ref, do_ref,
         ddc = jnp.sum(st0 * left, 0, keepdims=True)
         dlast = jnp.sum(t_kc, 0, keepdims=True) + ddc * ch.dc
         dgam = dgam + jnp.where(last_row, dlast, 0.0)
-        # G = cumsum(g) within the chunk: its cotangent sums to the end
-        dq_ref[:, kc] = dq.astype(dq_ref.dtype)
-        dk_ref[:, kc] = dk.astype(dk_ref.dtype)
+        # G = cumsum(g) within the chunk: its cotangent sums to the end;
+        # q and k through their L2 norms (q's K^-1/2 first)
+        dq_ref[:, kc] = _l2_bwd(q_ref[:, kc].astype(_F32),
+                                dq * kd ** -0.5).astype(dq_ref.dtype)
+        dk_ref[:, kc] = _l2_bwd(k_ref[:, kc].astype(_F32), dk).astype(
+            dk_ref.dtype)
         dg_ref[:, kc] = pk.mxu_dot(tri, dgam, 0, 0).astype(dg_ref.dtype)
         dv_ref[:, vc] = (dvb * beta).astype(dv_ref.dtype)
         db_ref[:, j:j + 1] = dbeta.astype(db_ref.dtype)
+    dw_ref[...] += dw
 
 
-def _specs(h, kd, vd, chunk, heads, step):
+def _specs(kd, vd, chunk, heads, step):
     """Block specs of a (sequence, heads, chunk) cell; ``step`` maps the
-    grid's last index to the chunk (the reverse walk turns it)."""
+    grid's last index to the chunk (the reverse walk turns it). ``norm``
+    is the output norm's weight [1, V], whole in every cell; ``per_cell``
+    one row [1, V] a (sequence, heads) pair, the same block down the
+    chunks."""
     wide_k = pl.BlockSpec((None, chunk, heads * kd),
                           lambda s, j, i: (s, step(i), j))
     wide_v = pl.BlockSpec((None, chunk, heads * vd),
                           lambda s, j, i: (s, step(i), j))
     col = pl.BlockSpec((None, None, chunk, heads),
                        lambda s, j, i: (s, j, step(i), 0))
+    norm = pl.BlockSpec((1, vd), lambda s, j, i: (0, 0))
+    per_cell = pl.BlockSpec((None, None, 1, vd), lambda s, j, i: (s, j, 0, 0))
     entered = pl.BlockSpec((None, None, heads * vd, kd),
                            lambda s, j, i: (s, step(i), j, 0))
-    return wide_k, wide_v, col, entered
+    return wide_k, wide_v, col, norm, per_cell, entered
 
 
 def _params():
@@ -579,69 +682,75 @@ _sweep = pk.scoped_sweep(trace.SCOPE_KDA_SCAN)
 
 
 @_sweep
-def _forward(q, k, v, g, beta, *, chunk, mm, save: bool):
-    bsz, t, h, kd = q.shape
-    vd = v.shape[-1]
+def _forward(q, k, v, g, beta, gate, o_norm, *, chunk, mm, eps, save: bool):
+    bsz, t, h = beta.shape
+    kd, vd = q.shape[-1] // h, v.shape[-1] // h
     heads = _heads(h)
     nc = t // chunk
-    wide_k, wide_v, col, entered = _specs(h, kd, vd, chunk, heads,
-                                          lambda i: i)
+    wide_k, wide_v, col, norm, _, entered = _specs(kd, vd, chunk, heads,
+                                                   lambda i: i)
     out_shape = [jax.ShapeDtypeStruct((bsz, t, h * vd), _F32)]
     out_specs = [wide_v]
     if save:
         out_shape.append(jax.ShapeDtypeStruct((bsz, nc, h * vd, kd), _F32))
         out_specs.append(entered)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, mm=mm, save=save),
+        functools.partial(_fwd_kernel, mm=mm, eps=eps, save=save),
         grid=(bsz, h // heads, nc),
-        in_specs=[wide_k, wide_k, wide_v, wide_k, col],
+        in_specs=[wide_k, wide_k, wide_v, wide_k, col, wide_v, norm],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((heads, vd, kd), _F32)],
         compiler_params=_params(), interpret=pk._interpret(),
-    )(*(x.reshape(bsz, t, -1) for x in (q, k, v, g)), _by_block(beta, heads))
-    return out[0].reshape(bsz, t, h, vd), (out[1] if save else None)
+    )(q, k, v, g, _by_block(beta, heads), gate, o_norm.reshape(1, vd))
+    return out[0], (out[1] if save else None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _rule_kernels(q, k, v, g, beta, chunk, mm):
-    return _forward(q, k, v, g, beta, chunk=chunk, mm=mm, save=False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _rule_kernels(q, k, v, g, beta, gate, o_norm, chunk, mm, eps):
+    return _forward(q, k, v, g, beta, gate, o_norm, chunk=chunk, mm=mm,
+                    eps=eps, save=False)[0]
 
 
-def _rule_kernels_fwd(q, k, v, g, beta, chunk, mm):
-    o, entered = _forward(q, k, v, g, beta, chunk=chunk, mm=mm, save=True)
-    return o, (q, k, v, g, beta, entered)
+def _rule_kernels_fwd(q, k, v, g, beta, gate, o_norm, chunk, mm, eps):
+    y, entered = _forward(q, k, v, g, beta, gate, o_norm, chunk=chunk, mm=mm,
+                          eps=eps, save=True)
+    return y, (q, k, v, g, beta, gate, o_norm, entered)
 
 
-def _rule_kernels_bwd(chunk, mm, res, do):
-    return _backward(*res, do, chunk=chunk, mm=mm)
+def _rule_kernels_bwd(chunk, mm, eps, res, dy):
+    return _backward(*res, dy, chunk=chunk, mm=mm, eps=eps)
 
 
 @_sweep
-def _backward(q, k, v, g, beta, entered, do, *, chunk, mm):
-    bsz, t, h, kd = q.shape
-    vd = v.shape[-1]
+def _backward(q, k, v, g, beta, gate, o_norm, entered, dy, *, chunk, mm,
+              eps):
+    bsz, t, h = beta.shape
+    kd, vd = q.shape[-1] // h, v.shape[-1] // h
     heads = _heads(h)
     nc = t // chunk
-    wide_k, wide_v, col, entered_spec = _specs(h, kd, vd, chunk, heads,
-                                               lambda i: nc - 1 - i)
+    wide_k, wide_v, col, norm, per_cell, entered_spec = _specs(
+        kd, vd, chunk, heads, lambda i: nc - 1 - i)
     flat_k = jax.ShapeDtypeStruct((bsz, t, h * kd), _F32)
-    dq, dk, dv, dg, db = pl.pallas_call(
-        functools.partial(_bwd_kernel, mm=mm),
+    flat_v = jax.ShapeDtypeStruct((bsz, t, h * vd), _F32)
+    dq, dk, dv, dg, db, dgate, dw = pl.pallas_call(
+        functools.partial(_bwd_kernel, mm=mm, eps=eps),
         grid=(bsz, h // heads, nc),
-        in_specs=[wide_k, wide_k, wide_v, wide_k, col, entered_spec, wide_v],
-        out_specs=[wide_k, wide_k, wide_v, wide_k, col],
-        out_shape=[flat_k, flat_k,
-                   jax.ShapeDtypeStruct((bsz, t, h * vd), _F32), flat_k,
-                   jax.ShapeDtypeStruct((bsz, h // heads, t, heads), _F32)],
+        in_specs=[wide_k, wide_k, wide_v, wide_k, col, wide_v, norm,
+                  entered_spec, wide_v],
+        out_specs=[wide_k, wide_k, wide_v, wide_k, col, wide_v, per_cell],
+        out_shape=[flat_k, flat_k, flat_v, flat_k,
+                   jax.ShapeDtypeStruct((bsz, h // heads, t, heads), _F32),
+                   flat_v,
+                   jax.ShapeDtypeStruct((bsz, h // heads, 1, vd), _F32)],
         scratch_shapes=[pltpu.VMEM((heads, vd, kd), _F32)],
         compiler_params=_params(), interpret=pk._interpret(),
-    )(*(x.reshape(bsz, t, -1) for x in (q, k, v, g)), _by_block(beta, heads),
-      entered, do.astype(_F32).reshape(bsz, t, h * vd))
-    return (dq.reshape(q.shape).astype(q.dtype),
-            dk.reshape(k.shape).astype(k.dtype),
-            dv.reshape(v.shape).astype(v.dtype),
-            dg.reshape(g.shape).astype(g.dtype),
-            db.transpose(0, 2, 1, 3).reshape(beta.shape).astype(beta.dtype))
+    )(q, k, v, g, _by_block(beta, heads), gate, o_norm.reshape(1, vd),
+      entered, dy.astype(_F32))
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            dg.astype(g.dtype),
+            db.transpose(0, 2, 1, 3).reshape(beta.shape).astype(beta.dtype),
+            dgate.astype(gate.dtype),
+            jnp.sum(dw, (0, 1, 2)).astype(o_norm.dtype))
 
 
 # optimize_remat: the forward pass of a ``jax.checkpoint`` around the rule

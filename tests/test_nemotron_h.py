@@ -24,7 +24,7 @@ from benchmarks.reference import lm as ref_lm  # noqa: E402
 from benchmarks.reference.models import nemotron_h as ref  # noqa: E402
 from paddlebox_tpu.models import NemotronH  # noqa: E402
 from paddlebox_tpu.obs import trace  # noqa: E402
-from paddlebox_tpu.ops.kda import kda_chunked  # noqa: E402
+from paddlebox_tpu.ops.kda import kda_normed  # noqa: E402
 from paddlebox_tpu.ops.ssd import ssd_scan  # noqa: E402
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts  # noqa: E402
 
@@ -644,7 +644,13 @@ def test_the_scan_kernels_compile_for_a_v5e(what, one_v5e,
 
 
 def _delta_rule(*args):
-    return kda_chunked(*args, chunk=64, mm_dtype=jnp.bfloat16)
+    return kda_normed(*args, eps=1e-5, chunk=64, mm_dtype=jnp.bfloat16)
+
+
+def _flat_rule_shapes(t, h, d):
+    """q, k, v, g [1, T, H d], beta [1, T, H], gate [1, T, H d], the output
+    norm's weight [d]: what a KDA mixer hands the rule."""
+    return [(1, t, h * d)] * 4 + [(1, t, h), (1, t, h * d), (d,)]
 
 
 def test_every_kernel_of_the_delta_rule_sits_under_its_scope(
@@ -658,7 +664,7 @@ def test_every_kernel_of_the_delta_rule_sits_under_its_scope(
     count all three and ``other`` none of them."""
     def args(seed):
         return [jax.random.normal(jax.random.PRNGKey(seed + i), s)
-                for i, s in enumerate([(1, 128, 2, 128)] * 4 + [(1, 128, 2)])]
+                for i, s in enumerate(_flat_rule_shapes(128, 2, 128))]
 
     def layer(x, *rest):
         with jax.named_scope(trace.SCOPE_KDA_PROJ):
@@ -668,7 +674,7 @@ def test_every_kernel_of_the_delta_rule_sits_under_its_scope(
         return jnp.sum(layer(*a) * layer(*b))
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
-        args(0), args(5)).lower(lowering_platforms=("tpu",)).as_text(
+        args(0), args(9)).lower(lowering_platforms=("tpu",)).as_text(
             debug_info=True)
     locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
     stacks = [locs[ref_] for ref_ in re.findall(
@@ -683,19 +689,53 @@ def test_every_kernel_of_the_delta_rule_sits_under_its_scope(
 @pytest.mark.parametrize("what", ["forward", "gradient"])
 def test_the_delta_rule_kernels_compile_for_a_v5e(what, one_v5e,
                                                   kernels_for_the_chip):
-    """At cell 6's shapes (one sequence of 8,192 steps, 32 heads of 128,
-    chunks of 64, float32 in, bfloat16 operands): Mosaic takes the forward
-    and the backward sweep. It proves that they compile, not what they
-    compute (tests/test_kda_kernel.py) nor how fast (PERF.md)."""
-    t, h, d = 8192, 32, 128
+    """At cell 6's shapes (one sequence of 8,192 steps, 32 heads of 128 as
+    flat rows, chunks of 64, float32 in, bfloat16 operands, the norms
+    inside): Mosaic takes the forward and the backward sweep. It proves
+    that they compile, not what they compute (tests/test_kda_kernel.py)
+    nor how fast (PERF.md)."""
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_v5e)
-            for s in [(1, t, h, d)] * 4 + [(1, t, h)]]
+            for s in _flat_rule_shapes(8192, 32, 128)]
     f = _delta_rule if what == "forward" else jax.grad(
-        lambda *v: jnp.sum(_delta_rule(*v)), argnums=(0, 1, 2, 3, 4))
+        lambda *v: jnp.sum(_delta_rule(*v)), argnums=tuple(range(7)))
     compiled = jax.jit(f).trace(*args).lower(
         lowering_platforms=("tpu",)).compile()
     assert compiled.as_text().count("tpu_custom_call") == (
         1 if what == "forward" else 2)
+
+
+def test_a_kda_mixer_changes_no_layout_of_its_rows(one_v5e,
+                                                   kernels_for_the_chip):
+    """One KDA mixer's loss and gradient at cell 6's widths (hidden 2304,
+    32 heads of 128; 512 steps) compiled for a v5e: no ``copy``,
+    ``reshape`` or ``transpose`` that is not a bitcast is left on a
+    float32 array of a flat row's or a head's shape (``[.., 4096]``,
+    ``[.., 32, 128]``), labelled or not. Where per-head ops sat between
+    the flat producers and the rule's flat operands, XLA changed the
+    tiling of each such array on both sides (20 such ops here, 25 a
+    sequence and mixer of cell 6's pass program)."""
+    from benchmarks.tests.test_family_lm_kimi_linear import (toy_cell,
+                                                              toy_config)
+    from paddlebox_tpu.models import KimiLinear
+    cfg = dict(toy_cell()["config"], **toy_config("K"))
+    cfg.update(hidden_size=2304, linear_attn_config=dict(
+        cfg["linear_attn_config"], num_heads=32, head_dim=128))
+    model = KimiLinear(cfg)
+    lay = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][0]
+    lay = {k: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e)
+           for k, a in lay.items() if k not in ("w1", "w2", "w3", "ffn_norm")}
+    x = jax.ShapeDtypeStruct((1, 512, 2304), jnp.float32, sharding=one_v5e)
+
+    def loss(lay, x):
+        return jnp.sum(jax.checkpoint(model._kda)(lay, x) ** 2)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
+        lay, x).lower(lowering_platforms=("tpu",)).compile().as_text()
+    moved = re.findall(
+        r"= f32\[([\d,]*(?:4096|32,128))\]\{[^}]*\} "
+        r"(copy|reshape|transpose)\(", text)
+    assert not moved, moved
+    assert text.count("tpu_custom_call") == 3
 
 
 # ---- the click models' pass program is what it was --------------------------
