@@ -816,9 +816,6 @@ def phase_kernels(watch: CompileWatch, ds_uniform, desc_uniform,
     get_hub().add_sink(MemorySink())
     ds_ragged, desc_ragged = ragged_dataset(
         w.batch_size * w.batches_per_pass, w, seed=5)
-    # what a chip runs under use_pallas_index is the XLA while_loop
-    # formulation (ops/pallas_index module docstring) — booked as xla
-    index_impl = "pallas" if _interpret() else "xla"
     # seqpool: MXU one-hot pooling sums in another order → tolerance
     rag_off = resident_kernel_run(ph, None, {}, ds_ragged, desc_ragged, w,
                                   exact=False)
@@ -828,17 +825,16 @@ def phase_kernels(watch: CompileWatch, ds_uniform, desc_uniform,
         ds_ragged, desc_ragged, w, exact=False)
     check_state_close("use_pallas_seqpool", rag_off, rag_on)
     del rag_off, rag_on
-    # gather and index move the same bits → the digest must not change
+    # gather moves the same bits → the digest must not change
     uni_off = resident_kernel_run(ph, None, {}, ds_uniform, desc_uniform,
                                   w, exact=True)
-    for flag, expect in (("use_pallas_gather", {"gather_rows": "pallas"}),
-                         ("use_pallas_index", {"index.assign": index_impl})):
-        got = resident_kernel_run(ph, flag, expect, ds_uniform,
-                                  desc_uniform, w, exact=True)
-        ph.check(got == uni_off, f"{flag}: state digest differs from "
-                 f"flag-off ({got[:12]} vs {uni_off[:12]})")
-        print(f"[kernels] {flag}: state digest bit-identical to flag-off",
-              flush=True)
+    flag = "use_pallas_gather"
+    got = resident_kernel_run(ph, flag, {"gather_rows": "pallas"},
+                              ds_uniform, desc_uniform, w, exact=True)
+    ph.check(got == uni_off, f"{flag}: state digest differs from "
+             f"flag-off ({got[:12]} vs {uni_off[:12]})")
+    print(f"[kernels] {flag}: state digest bit-identical to flag-off",
+          flush=True)
 
     # the CTR family over one AdsRank-PV pass at the Widths' pv_* sizes
     job = PvJob(w)

@@ -112,24 +112,6 @@ class Flags:
     # the data_norm mean/scale applied in the same residency (summary
     # update and the sharded sync_stats psum stay outside, unchanged)
     use_pallas_cross_norm: bool = False
-    # device-resident key assignment (ops/pallas_index.py — ISSUE 19):
-    # route bulk row assignment (EmbeddingTable.bulk_assign_unique, the
-    # resident-pass build front) and the sharded plan's per-shard
-    # assign/lookup (ps/sharded.prepare_global) through an
-    # open-addressing hash index living in device HBM — first-seen
-    # dedup of raw 64-bit feature ids (ops/device_unique.
-    # dedup_keys_first_seen) + a Pallas linear-probe insert/lookup over
-    # a bucket array, with the host kv mirrored only for NEW keys (one
-    # O(new) append instead of the O(all keys) per-pass round trip).
-    # Row allocation is first-seen sequential, bit-identical to the
-    # host index when its free list is empty; any state the device
-    # index cannot mirror exactly (free-list holes after shrink,
-    # arena-slotted tables, probe/capacity overflow) degrades LOUDLY
-    # to the host path (warning + pbox_kernel_dispatch_total booking).
-    # Off (default) = the host index path, byte-for-byte today's
-    # program; parity + digest gates in tier-1
-    # (tests/test_pallas_index.py, tests/test_pallas_train_gate.py).
-    use_pallas_index: bool = False
 
     # --- fused computation-collective sharded step (ISSUE 11;
     # docs/PERFORMANCE.md §Sharded-step overlap) ---
@@ -198,10 +180,6 @@ class Flags:
     # start their H2D transfer as each chunk completes instead of after
     # the full pack (<= 0 = whole pass, the pre-pipeline behavior)
     preload_pack_chunk_batches: int = 8
-    # whole-pass bulk key assignment: one assign round-trip under
-    # host_lock per pass instead of one per batch (False = the serial
-    # per-batch path, bit-compatible reference)
-    bulk_pass_assign: bool = True
     # q8 float wire on NON-columnar re-iterable datasets: True streams
     # per-column min/max batch-by-batch and casts on a second walk —
     # no full-pass f32 staging, but heavy-tailed columns lose

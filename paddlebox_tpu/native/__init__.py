@@ -171,10 +171,6 @@ def load_native() -> ctypes.CDLL | None:
         lib.kv_assign_slotted.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
                                           ctypes.c_void_p, ctypes.c_int64,
                                           ctypes.c_void_p, ctypes.c_void_p]
-        lib.kv_assign_unique_slotted.restype = ctypes.c_int64
-        lib.kv_assign_unique_slotted.argtypes = [
-            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-            ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p]
         lib.kv_dedup_first_seen.restype = ctypes.c_int64
         lib.kv_dedup_first_seen.argtypes = [ctypes.c_void_p, ctypes.c_int64,
                                             ctypes.c_void_p, ctypes.c_void_p,

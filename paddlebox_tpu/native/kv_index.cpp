@@ -397,35 +397,6 @@ int64_t kv_assign_slotted(void* p, const uint64_t* in, const uint16_t* slots,
   return n;
 }
 
-// Slotted variant of kv_assign_unique (same dedup contract): new keys
-// allocate in their slot's arena.
-int64_t kv_assign_unique_slotted(void* p, const uint64_t* in,
-                                 const uint16_t* slots, int64_t n,
-                                 int32_t* uniq_rows_out,
-                                 int32_t* inverse_out) {
-  KvIndex* kv = static_cast<KvIndex*>(p);
-  uint32_t epoch = kv->next_epoch();
-  int64_t u = 0;
-  constexpr int64_t PF = 16;
-  for (int64_t i = 0; i < n; ++i) {
-    if (i + PF < n) {
-      uint64_t h = mix(in[i + PF]) & kv->mask;
-      __builtin_prefetch(&kv->state[h]);
-      __builtin_prefetch(&kv->keys[h]);
-    }
-    int32_t row = kv->assign_one(in[i], static_cast<int32_t>(slots[i]));
-    if (row == -2) return -1;
-    if (kv->seen_epoch[row] != epoch) {
-      kv->seen_epoch[row] = epoch;
-      kv->seen_pos[row] = static_cast<int32_t>(u);
-      uniq_rows_out[u] = row;
-      ++u;
-    }
-    inverse_out[i] = kv->seen_pos[row];
-  }
-  return u;
-}
-
 // Export the chunk ownership map: chunk_slot_out/chunk_rank_out sized
 // kv_arena_chunk_count(); returns the number of allocated chunks.
 // chunk_map[slot, rank] = chunk id reconstructs vectorized host-side.

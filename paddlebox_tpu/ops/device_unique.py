@@ -74,68 +74,3 @@ def dedup_rows(rows: jax.Array, capacity: int
         jnp.where(is_first, sr, capacity + 1 + pos))
     return unique_rows, gather_idx, uid_sorted[-1] + 1
 
-
-def dedup_keys_first_seen(
-        key_hi: jax.Array, key_lo: jax.Array, num_valid: jax.Array
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """First-seen dedup of 64-bit FEATURE IDS (not row ids) on device —
-    the bitwise generalization of ``ps/table.dedup_first_seen``
-    (ISSUE 19 stage a): raw ids ride as (hi, lo) int32 halves so the
-    whole pipeline stays x64-free.
-
-    Args:
-      key_hi, key_lo: int32 [K_pad] — the key's upper/lower 32 bits
-        (any bit pattern; keys are compared for EQUALITY only, so
-        signedness never matters). Positions ≥ num_valid are padding
-        and may hold anything.
-      num_valid: int32 scalar — number of real keys.
-
-    Returns ``(uniq_hi, uniq_lo, first_pos, inv, num_unique)``, all
-    padded to K_pad:
-      - uniq_hi/uniq_lo [K_pad]: the distinct keys in FIRST-SEEN order
-        (positions ≥ num_unique hold pad-key garbage — callers slice
-        by num_unique).
-      - first_pos [K_pad] int32: each unique's first occurrence
-        position in the input stream (ascending by construction; pads
-        hold K_pad).
-      - inv [K_pad] int32: per input position, the unique's first-seen
-        rank (``uniq[inv[i]] == key[i]``); pad positions point past
-        num_unique.
-      - num_unique: int32 scalar count of real uniques.
-
-    Matches the host oracle bit for bit: ``uniq`` equals
-    ``dedup_first_seen(keys)[0]``, ``first_pos[:U]`` its first-index
-    array and ``inv[:nv]`` its inverse — gated in tier-1
-    (tests/test_pallas_index.py)."""
-    k = key_hi.shape[0]
-    pos = jnp.arange(k, dtype=jnp.int32)
-    valid = pos < num_valid
-    # validity is the LEADING sort key: pads group after every real key
-    # and never merge into a real run even when their stale bits match
-    # a real id; (hi, lo) only need to group equal keys, so the signed
-    # int32 sort order is fine
-    vkey = (~valid).astype(jnp.int32)
-    _, sh, sl, perm = jax.lax.sort(
-        (vkey, key_hi.astype(jnp.int32), key_lo.astype(jnp.int32), pos),
-        num_keys=3)
-    sv = perm < num_valid
-    is_first = jnp.concatenate(
-        [jnp.ones(1, bool),
-         (sh[1:] != sh[:-1]) | (sl[1:] != sl[:-1])
-         | (sv[1:] != sv[:-1])])
-    uid_sorted = jnp.cumsum(is_first.astype(jnp.int32)) - 1
-    # each run's first stream position: the sort is stable on pos (it
-    # rides as the last key), so a segment-min over the run recovers it
-    first_pos = jnp.full(k, k, jnp.int32).at[uid_sorted].min(perm)
-    # first-seen rank = order of runs by first position; the pad run
-    # (first pad position == num_valid) sorts after every real run and
-    # unused slots (first_pos == K_pad) sort last
-    order = jnp.argsort(first_pos)
-    rank = jnp.zeros(k, jnp.int32).at[order].set(pos)
-    inv = jnp.zeros(k, jnp.int32).at[perm].set(rank[uid_sorted],
-                                               unique_indices=True)
-    fp = first_pos[order]
-    gather_at = jnp.minimum(fp, k - 1)
-    return (key_hi[gather_at], key_lo[gather_at],
-            jnp.where(fp < num_valid, fp, k).astype(jnp.int32), inv,
-            jnp.sum((is_first & sv).astype(jnp.int32)))

@@ -6,9 +6,9 @@ Reference hot kernels being replaced (SURVEY.md §2.1-2.2, §2.4):
   gather where the Pallas pipeline double-buffers eight aligned (8, D)
   tile DMAs per grid step (HBM→VMEM), overlapping fetches across steps.
 - ``PushMergeCopy`` scatter (box_wrapper.cu:417) + in-kernel optimizer write
-  (heter_ps/optimizer.cuh.h) → ``scatter_rows``: aliased in-place row
-  scatter (the optimizer math itself stays in jnp where XLA fuses it against
-  the gathered rows; only the irregular-access scatter needs a kernel).
+  (heter_ps/optimizer.cuh.h) → no kernel: ``table.apply_push`` is XLA's
+  masked scatter-add over packed table lines, with the optimizer math in
+  jnp where XLA fuses it (a per-row scatter kernel is ruled out below).
 - ``FusedSeqpoolKernelNormal`` (fused/fused_seqpool_cvm_op.cu:36) →
   ``segment_sum_mxu``: the ragged per-slot sum-pool recast as a blocked
   one-hot × values matmul so it runs on the MXU systolic array instead of
@@ -36,8 +36,8 @@ Mosaic status on the current installation (jax 0.9.0 / libtpu 0.0.34,
 TPU v5e — chip_smoke.py kernels phase): ``gather_rows``,
 ``fused_pool_cvm_forward``, ``segment_gather_mxu``/``segment_sum_mxu``
 compile and agree with the XLA compositions. Every block's last two
-dims must be (8, 128)-aligned or span the array: ``scatter_rows`` and
-the DMA references below keep (1, D) blocks and are interpret-only.
+dims must be (8, 128)-aligned or span the array: a (1, D) row block is
+refused, which is why ``gather_rows`` moves whole 8-row tiles.
 
 Measured verdict of an EARLIER installation (post ISSUE 12; one TPU
 chip, DeepFM/criteo bench, AoS table [8M+1, 16] f32, 213k rows/batch) —
@@ -50,10 +50,10 @@ rationale, not today's rates:
   laid out with a 128-lane minor tile, so a 16-wide row slice is
   "unaligned" regardless of memory space; (b) at D=128 the scalar-core
   loop issues DMAs at ~320 µs each (2048 rows = 656 ms), ~1000x off.
-  ``gather_rows_dma``/``scatter_rows_dma`` are therefore DEMOTED to
-  interpret-only reference implementations — they raise loudly when
-  invoked on a real TPU backend. Revisit only if Mosaic grows a
-  batched gather/scatter DMA primitive or SparseCore access.
+  So no per-row DMA kernel exists, not even as an interpret-mode
+  reference. Revisit only if Mosaic grows a batched gather/scatter DMA
+  primitive or SparseCore access, and measure any such kernel against
+  the MXU family below.
 - The viable TPU formulation of the irregular hot path is the MXU
   one-hot matmul family below: ``segment_sum_mxu`` (pool forward),
   ``segment_gather_mxu`` (pool backward / ragged gather by
@@ -153,18 +153,6 @@ def scoped_sweep(scope: str):
     return wrap
 
 
-def _require_interpret(name: str) -> None:
-    """DMA reference paths are interpret-only (see module docstring):
-    invoking them on a real TPU backend is a ~1000x perf bug, not a
-    fallback — fail loudly instead."""
-    if not _interpret():
-        raise RuntimeError(
-            f"{name} is an interpret-mode reference implementation only "
-            "(per-row DMA measured ~320 µs/row on Mosaic — see "
-            "ops/pallas_kernels.py status); use gather_rows / "
-            "segment_sum_mxu / fused_pool_cvm_forward on TPU")
-
-
 # ---------------------------------------------------------------------------
 # Row gather (pull_sparse hot path)
 # ---------------------------------------------------------------------------
@@ -216,160 +204,6 @@ def gather_rows(table: jax.Array, rows: jax.Array) -> jax.Array:
         interpret=_interpret(),
     )(rows_p, *([table] * _GR))
     return out[:u]
-
-
-# ---------------------------------------------------------------------------
-# Row scatter (push_sparse write-back)
-# ---------------------------------------------------------------------------
-
-def scatter_rows(table: jax.Array, rows: jax.Array,
-                 values: jax.Array) -> jax.Array:
-    """REFERENCE-ONLY (interpret mode; no production consumer since the
-    packed-line layout made apply_push a masked line scatter-ADD — see
-    TableState/DESIGN_NOTES §2): write values[i] into table[rows[i]] in
-    place (buffer aliased).
-
-    In-bounds rows must be duplicate-free (the unique-scatter contract);
-    out-of-bounds pad rows clamp to the sentinel row C-1, whose racy
-    last-write-wins content the callers reset (table.apply_push).
-    """
-    c, d = table.shape
-    u = rows.shape[0]
-
-    def kernel(rows_ref, tbl_ref, val_ref, out_ref):
-        del rows_ref, tbl_ref
-        out_ref[...] = val_ref[...]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(u,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # aliased table, untouched
-            pl.BlockSpec((1, d), lambda i, rows_ref: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, d), lambda i, rows_ref: (jnp.minimum(rows_ref[i], c - 1), 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((c, d), table.dtype),
-        input_output_aliases={1: 0},  # tensor input 0 (table) → output 0
-        interpret=_interpret(),
-    )(rows, table, values)
-
-
-# ---------------------------------------------------------------------------
-# Manual-DMA row gather/scatter — per-row 64B copies, semaphore ring
-# ---------------------------------------------------------------------------
-#
-# XLA lowers irregular gather/scatter to per-ELEMENT access on TPU; these
-# kernels issue one DMA per ROW instead. Rows stream through VMEM in blocks
-# of _TR (the pallas pipeline double-buffers the block transfer), and inside
-# each block a scalar loop issues per-row DMAs, keeping _NSEM in flight.
-# Out-of-bounds row ids (the OOB padding contract of table._build_index /
-# device_unique.dedup_rows) are clamped to the sentinel row C — reads there
-# return zeros, racy pad writes land on C which apply_push resets.
-
-_TR = 2048    # rows per grid block (VMEM: _TR * D * 4B)
-_NSEM = 16    # DMAs in flight
-
-
-def _dma_body(rows_ref, tbl_ref, io_ref, sem, base, scatter: bool) -> None:
-    """Issue one 64B-row DMA per index with a _NSEM-deep in-flight ring.
-    rows_ref: SMEM [tr] block-local row ids; io_ref: the full [K, d]
-    values/out array in HBM (row base+r ↔ table row); tbl_ref: the whole
-    table in HBM. DMAs are HBM→HBM (row slices are contiguous, so no VMEM
-    tiling constraint applies)."""
-    tr = rows_ref.shape[0]
-    c = tbl_ref.shape[0] - 1
-
-    def issue(r):
-        row = jnp.minimum(rows_ref[r], c)  # OOB pads clamp to sentinel
-        if scatter:
-            return pltpu.make_async_copy(
-                io_ref.at[base + r], tbl_ref.at[row], sem.at[r % _NSEM])
-        return pltpu.make_async_copy(
-            tbl_ref.at[row], io_ref.at[base + r], sem.at[r % _NSEM])
-
-    def body(r, carry):
-        # reuse slot r%_NSEM: drain the DMA issued _NSEM rows ago
-        @pl.when(r >= _NSEM)
-        def _():
-            issue(r - _NSEM).wait()
-        issue(r).start()
-        return carry
-
-    jax.lax.fori_loop(0, tr, body, 0)
-    start = max(0, tr - _NSEM)
-
-    def drain(i, carry):
-        issue(start + i).wait()
-        return carry
-
-    jax.lax.fori_loop(0, tr - start, drain, 0)
-
-
-def scatter_rows_dma(table: jax.Array, rows: jax.Array,
-                     values: jax.Array) -> jax.Array:
-    """table[rows[i]] = values[i] via per-row DMAs, table aliased in place.
-
-    rows must be duplicate-free among in-bounds ids (the unique-scatter
-    contract of table._build_index / device_unique.dedup_rows); OOB pads
-    clamp to the sentinel row — racy pad writes land there and the caller
-    resets it (apply_push)."""
-    _require_interpret("scatter_rows_dma")
-    c1, d = table.shape
-    k = rows.shape[0]
-    tr = min(_TR, k)
-    assert k % tr == 0, f"pad rows to a multiple of {tr}"
-
-    def kernel(rows_ref, tbl_ref, val_ref, out_ref, sem):
-        del tbl_ref  # out_ref is its alias — write through the output
-        _dma_body(rows_ref, out_ref, val_ref, sem,
-                  pl.program_id(0) * tr, scatter=True)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(k // tr,),
-        in_specs=[
-            pl.BlockSpec((tr,), lambda b: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.HBM),  # table (aliased)
-            pl.BlockSpec(memory_space=pltpu.HBM),  # values, stays in HBM
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((_NSEM,))],
-        out_shape=jax.ShapeDtypeStruct((c1, d), table.dtype),
-        input_output_aliases={1: 0},  # table input → output
-        interpret=_interpret(),
-    )(rows, table, values)
-
-
-def gather_rows_dma(table: jax.Array, rows: jax.Array) -> jax.Array:
-    """out[i] = table[min(rows[i], C)] via per-row DMAs (OOB ids clamp to
-    the zero sentinel row — same semantics as XLA's clamped gather)."""
-    _require_interpret("gather_rows_dma")
-    c1, d = table.shape
-    k = rows.shape[0]
-    tr = min(_TR, k)
-    assert k % tr == 0, f"pad rows to a multiple of {tr}"
-
-    def kernel(rows_ref, tbl_ref, out_ref, sem):
-        _dma_body(rows_ref, tbl_ref, out_ref, sem,
-                  pl.program_id(0) * tr, scatter=False)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(k // tr,),
-        in_specs=[
-            pl.BlockSpec((tr,), lambda b: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.HBM),  # table
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),  # written via DMA
-        scratch_shapes=[pltpu.SemaphoreType.DMA((_NSEM,))],
-        out_shape=jax.ShapeDtypeStruct((k, d), table.dtype),
-        interpret=_interpret(),
-    )(rows, table)
 
 
 # ---------------------------------------------------------------------------

@@ -139,22 +139,6 @@ class PyKV:
             locs[i] = self._arena.local_of(r, s)
         return rows, locs
 
-    def assign_unique_slotted(self, keys: np.ndarray, slots: np.ndarray
-                              ) -> Tuple[np.ndarray, np.ndarray]:
-        """Slotted assign_unique: dedup in first-occurrence order, new
-        keys allocate in their slot's arena."""
-        uniq, first_idx, inv = np.unique(keys, return_index=True,
-                                         return_inverse=True)
-        rows = np.empty(len(uniq), dtype=np.int32)
-        m = self._map
-        for j, k in enumerate(uniq.tolist()):
-            r = m.get(k)
-            if r is None:
-                r = self._alloc(int(slots[first_idx[j]]))
-                m[k] = r
-            rows[j] = r
-        return rows, inv.astype(np.int32, copy=False)
-
     def arena_export(self) -> Tuple[np.ndarray, np.ndarray]:
         a = self._arena
         assert a is not None
@@ -243,20 +227,6 @@ class NativeKV:
         if done != n:
             raise _full_error(self.capacity)
         return rows, locs
-
-    def assign_unique_slotted(self, keys: np.ndarray, slots: np.ndarray
-                              ) -> Tuple[np.ndarray, np.ndarray]:
-        keys = np.ascontiguousarray(keys, dtype=np.uint64)
-        slots = np.ascontiguousarray(slots, dtype=np.uint16)
-        n = len(keys)
-        uniq_rows = np.empty(n, dtype=np.int32)
-        inv = np.empty(n, dtype=np.int32)
-        u = self._lib.kv_assign_unique_slotted(
-            self._h, self._buf(keys), self._buf(slots), n,
-            self._buf(uniq_rows), self._buf(inv))
-        if u < 0:
-            raise _full_error(self.capacity)
-        return uniq_rows[:u].copy(), inv
 
     def arena_export(self) -> Tuple[np.ndarray, np.ndarray]:
         n = int(self._lib.kv_arena_chunk_count(self._h))

@@ -233,9 +233,6 @@ class PassScopedTable(EmbeddingTable):
                     slot_override=self.slot_host[rs].astype(np.float32)),
                 on_freed=lambda freed:
                     self.slot_host.__setitem__(freed, 0))
-            # window promote assigns/releases kv rows behind the device
-            # index's back — re-seed (or degrade) on the next bulk assign
-            self._reset_dev_index()
             ins_vals = {f: v[still] for f, v in st.values.items()}
             self.slot_host[rows_new] = ins_vals["slot"].astype(np.int16)
             if len(rows_new):

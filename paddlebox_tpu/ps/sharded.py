@@ -166,82 +166,14 @@ class ShardedEmbeddingTable:
     def _plan_depth(self) -> int:
         return getattr(self._plan_tls, "depth", 0)
 
-    # ------------------------------------------------------------------
-    # device-resident key assignment (FLAGS.use_pallas_index): lazy
-    # per-shard Pallas open-addressing mirrors of the host kvs — same
-    # contract as EmbeddingTable._bulk_assign_device: the host kv stays
-    # AUTHORITATIVE, any state the mirror cannot reproduce exactly
-    # degrades that shard loudly and stickily back to the host path.
-    def _dev_index_for(self, s: int):
-        """Per-shard DeviceKeyIndex, lazily seeded from the shard's
-        host kv (call under host_lock)."""
-        if getattr(self, "_dev_indexes", None) is None:
-            self._dev_indexes = [None] * self.n
-        dev = self._dev_indexes[s]
-        if dev is None:
-            from paddlebox_tpu.ops.pallas_index import DeviceKeyIndex
-            dev = DeviceKeyIndex(self.capacity)
-            if not dev.seed_from_kv(self.indexes[s]):
-                dev.degrade(f"shard {s}: host kv rows are not dense "
-                            "(free-list holes) — cannot mirror")
-            self._dev_indexes[s] = dev
-        return dev
-
-    def _reset_dev_indexes(self) -> None:
-        """Forget every shard's device mirror after a host-side kv
-        lifecycle mutation (load/shrink/merge/release/promote): the
-        next flag-on prepare re-seeds from the kv, or degrades loudly
-        if the allocation is no longer dense."""
-        self._dev_indexes = None
-
-    def _shard_rows_device(self, s: int, keys_s: np.ndarray,
-                           assign: bool) -> Optional[np.ndarray]:
-        """Device route for one owner-shard request list: probe the
-        shard's device hash index instead of the host kv. Returns
-        int32 rows (assign) or rows with miss→C (lookup), or None to
-        fall back to the host kv."""
-        dev = self._dev_index_for(s)
-        if dev.degraded:
-            return None
-        if len(self.indexes[s]) != dev.next_row:
-            dev.degrade(f"shard {s}: host kv diverged "
-                        f"({len(self.indexes[s])} keys vs "
-                        f"{dev.next_row} mirrored)")
-            return None
-        if not assign:
-            rows = dev.lookup_rows(keys_s)
-            return np.where(rows < 0, self.capacity,
-                            rows).astype(np.int32)
-        out = dev.assign_unique(keys_s)
-        if out is None:
-            dev.degrade(f"shard {s}: probe/capacity overflow "
-                        f"({len(keys_s)} keys at {dev.next_row} rows, "
-                        f"capacity {self.capacity})")
-            return None
-        rows_u, new_mask = out
-        if new_mask.any():
-            # mirror ONLY the new keys into the host kv; kv.assign
-            # allocates in stream order, so a dense kv must reproduce
-            # the device rows exactly — anything else means holes
-            krows = self.indexes[s].assign(keys_s[new_mask])
-            if not np.array_equal(
-                    krows, rows_u[new_mask].astype(krows.dtype)):
-                dev.degrade(f"shard {s}: host kv allocated different "
-                            "rows than the device index (free-list "
-                            "holes)")
-                return None
-        return rows_u.astype(np.int32, copy=False)
-
     def _shard_rows(self, s: int, keys_s: np.ndarray,
                     assign: bool) -> np.ndarray:
         """Resolve owner-local rows for one (dst, owner) request list
         (call under host_lock; ``keys_s`` sorted unique keys owned by
         shard ``s``). The single seam shared by the monolithic and
-        grouped plans: plan-depth assigns stay host-side (plan rows
-        need the pre-lookup miss mask and roll back on abort), the
-        streaming assign / read-only lookup paths route through the
-        per-shard device probe table behind FLAGS.use_pallas_index,
-        with both decisions booked in pbox_kernel_dispatch_total."""
+        grouped plans: plan-depth assigns note their new keys (plan
+        rows need the pre-lookup miss mask and roll back on abort);
+        lookups read a miss as the sentinel ``C``."""
         C = self.capacity
         if assign and self._plan_depth:
             pre = self.indexes[s].lookup(keys_s)
@@ -250,21 +182,7 @@ class ShardedEmbeddingTable:
                 self._note_plan_assigned(s, keys_s[pre < 0])
             # touched stays clear: plan rows train only after their
             # pass opens; mark_trained_rows flags them post-training
-            if getattr(self, "_dev_indexes", None) is not None:
-                # the mirror missed these assigns — re-seed on next use
-                self._dev_indexes[s] = None
             return rows_s
-        if FLAGS.use_pallas_index:
-            from paddlebox_tpu.ops.pallas_index import (
-                book_index_dispatch, device_impl)
-            op = "assign" if assign else "lookup"
-            rows_s = self._shard_rows_device(s, keys_s, assign)
-            if rows_s is not None:
-                if assign:
-                    self._touched[s][rows_s] = True
-                book_index_dispatch(op, device_impl())
-                return rows_s
-            book_index_dispatch(op, "host")
         if assign:
             rows_s = self.indexes[s].assign(keys_s)
             self._touched[s][rows_s] = True
@@ -749,7 +667,6 @@ class ShardedEmbeddingTable:
             total += len(keys)
         self.state = TableState.from_logical(data, self.capacity,
                                              ext=self.opt_ext)
-        self._reset_dev_indexes()
         return total
 
     # ---- lifecycle: shrink / merge (box_wrapper.h:638-640,801-815) ----
@@ -778,7 +695,6 @@ class ShardedEmbeddingTable:
                 data[s][freed] = 0.0
                 self._touched[s][freed] = False
                 freed_total += len(freed)
-            self._reset_dev_indexes()
             self.state = TableState.from_logical(data, self.capacity,
                                                  ext=self.opt_ext)
         log.info("sharded shrink: freed %d rows across %d shards",
@@ -848,7 +764,6 @@ class ShardedEmbeddingTable:
                 rows_all = self.indexes[s].lookup(keys)
                 self._touched[s][rows_all] = True
                 total += len(keys)
-            self._reset_dev_indexes()
             self.state = TableState.from_logical(data, self.capacity,
                                                  ext=self.opt_ext)
         log.info("sharded merge_model: %d rows from %s", total, path)

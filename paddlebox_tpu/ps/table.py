@@ -517,7 +517,7 @@ def dedup_first_seen(keys: np.ndarray
     dedup_first_seen_native) when the library is available — the
     python formulation below walks the stream three times (unique +
     argsort + rank scatter); both produce bitwise-identical outputs
-    (tests/test_pallas_index.py gates it), and the cut shows up in
+    (tests/test_native_kv.py gates it), and the cut shows up in
     ``pbox_preload_build_seconds_total{stage=dedup}``."""
     from paddlebox_tpu.ps.kv import dedup_first_seen_native
     out = dedup_first_seen_native(keys)
@@ -529,7 +529,7 @@ def dedup_first_seen(keys: np.ndarray
 def _dedup_first_seen_py(keys: np.ndarray
                          ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """The pure-python three-pass formulation (the oracle the native
-    and device paths are gated against)."""
+    path is gated against)."""
     uniq_s, first_s, inv_s = np.unique(keys, return_index=True,
                                        return_inverse=True)
     order = np.argsort(first_s, kind="stable")
@@ -1221,15 +1221,10 @@ class EmbeddingTable:
         # serializes host-side index/touched mutation across threads
         # (prefetch prepare, ResidentPass.build preload, shrink/save/load)
         self.host_lock = threading.Lock()
-        # device-resident key index (FLAGS.use_pallas_index seam):
-        # created lazily on first flag-on bulk assign, dropped whenever
-        # the host kv's allocation may stop being dense (load/merge/
-        # shrink) — see _device_index
-        self._dev_index = None
-        # last bulk_assign_unique timing split, host-lock mirror work vs
-        # device insert work — surfaced as the preloader's `index` build
-        # stage (train/device_pass._dedup_phase)
-        self.last_assign_seconds = {"index_host": 0.0, "index_device": 0.0}
+        # last bulk_assign_unique's seconds under host_lock — surfaced
+        # as the preloader's `index_host` build stage
+        # (train/device_pass._dedup_phase)
+        self.last_assign_seconds = {"index_host": 0.0}
 
     # ---- per-batch host prep (dedup + row assignment) ----
     def _build_index(self, batch: SlotBatch, rows: np.ndarray,
@@ -1279,33 +1274,15 @@ class EmbeddingTable:
         ``host_lock`` (first-seen order — see dedup_first_seen), then
         ONE index round-trip under the lock instead of one per batch.
         Returns (rows of the first-seen uniques, inverse). Slot
-        metadata records the key's PASS-level first-occurrence slot;
-        the serial per-batch path nets out to the last batch's
-        first occurrence instead — identical under the one-slot-per-key
-        contract (CTR feasigns are slot-qualified,
-        Dataset.pass_key_slots), which is the only input either path
-        supports.
+        metadata records the key's PASS-level first-occurrence slot,
+        the key's one slot under the one-slot-per-key contract (CTR
+        feasigns are slot-qualified, Dataset.pass_key_slots), which is
+        the only input the build supports.
 
         Arena tables assign slotted so first-seen keys land in their
-        slot's arena (same rationale as the per-batch dedup path:
-        slotless assigns would poison the compact wire forever).
-
-        ``FLAGS.use_pallas_index`` routes this through the device hash
-        index (_bulk_assign_device): raw ids go to the chip, dedup and
-        row assignment happen there, and the host kv is mirrored with
-        ONLY the new keys — one O(new) append instead of the O(all)
-        round trip. Any call the device route cannot serve exactly
-        (probe/capacity overflow, kv divergence) falls back here,
-        loudly, and books ``index.assign/host``."""
+        slot's arena: slotless assigns would poison the compact wire
+        forever."""
         keys = np.ascontiguousarray(keys, np.uint64)
-        if FLAGS.use_pallas_index:
-            dev = self._device_index()
-            if not dev.degraded:
-                out = self._bulk_assign_device(keys, slot_of_key, dev)
-                if out is not None:
-                    return out
-            from paddlebox_tpu.ops.pallas_index import book_index_dispatch
-            book_index_dispatch("assign", "host")
         uniq, first_idx, inv = dedup_first_seen(keys)
         slots_first = slot_of_key[first_idx]
         t1 = time.perf_counter()
@@ -1318,77 +1295,8 @@ class EmbeddingTable:
             self.slot_host[rows] = slots_first.astype(np.int16,
                                                       copy=False)
         self.last_assign_seconds = {
-            "index_host": time.perf_counter() - t1, "index_device": 0.0}
+            "index_host": time.perf_counter() - t1}
         return rows, inv
-
-    # ---- device-resident key index (FLAGS.use_pallas_index) ----
-    def _device_index(self):
-        """Lazy DeviceKeyIndex for this table. On creation it seeds from
-        the host kv (possible only while kv allocation is dense) and
-        marks itself degraded — sticky, loud — when it can't mirror
-        (arena-slotted allocation, free-list holes)."""
-        dev = self._dev_index
-        if dev is None:
-            from paddlebox_tpu.ops.pallas_index import DeviceKeyIndex
-            dev = DeviceKeyIndex(self.capacity)
-            with self.host_lock:
-                if getattr(self.index, "arena_enabled", False):
-                    dev.degrade("arena-slotted row allocation has no "
-                                "dense device mirror")
-                elif not dev.seed_from_kv(self.index):
-                    dev.degrade("host kv rows are not dense "
-                                "(free-list holes) — cannot seed")
-            self._dev_index = dev
-        return dev
-
-    def _reset_dev_index(self) -> None:
-        """Drop the device index after a host-kv lifecycle mutation
-        (load/merge/shrink/window eviction); the next flag-on bulk
-        assign re-seeds from the kv, or degrades loudly if it can't."""
-        self._dev_index = None
-
-    def _bulk_assign_device(self, keys: np.ndarray,
-                            slot_of_key: np.ndarray, dev
-                            ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-        """Device route of bulk_assign_unique: on-device first-seen
-        dedup + hash insert, host kv mirrored with the NEW keys only.
-        Returns None (after degrading ``dev``) whenever the result
-        cannot be trusted bit-for-bit — the caller redoes the call on
-        the host path, which is always authoritative."""
-        from paddlebox_tpu.ops.pallas_index import (book_index_dispatch,
-                                                    device_impl)
-        t0 = time.perf_counter()
-        pre_rows = dev.next_row
-        out = dev.assign_raw(keys)
-        t_dev = time.perf_counter() - t0
-        if out is None:
-            dev.degrade("probe/capacity overflow "
-                        f"({len(keys)} keys at {pre_rows} rows, "
-                        f"capacity {self.capacity})")
-            return None
-        uniq, first_idx, inv, rows_u, new_mask = out
-        t1 = time.perf_counter()
-        slots_first = slot_of_key[first_idx]
-        with self.host_lock:
-            if len(self.index) != pre_rows:
-                dev.degrade(f"host kv diverged ({len(self.index)} keys "
-                            f"vs {pre_rows} mirrored)")
-                return None
-            if new_mask.any():
-                krows = self.index.assign(uniq[new_mask])
-                if not np.array_equal(
-                        krows, rows_u[new_mask].astype(np.int32)):
-                    dev.degrade("host kv allocated different rows than "
-                                "the device index (free-list holes)")
-                    return None
-            self.slot_host[rows_u] = slots_first.astype(np.int16,
-                                                        copy=False)
-        self.last_assign_seconds = {
-            "index_host": time.perf_counter() - t1,
-            "index_device": t_dev}
-        book_index_dispatch("assign", device_impl())
-        return (rows_u.astype(np.int32, copy=False),
-                inv.astype(np.int64, copy=False))
 
     def prepare(self, batch: SlotBatch) -> PullIndex:
         valid = batch.keys[:batch.num_keys]
@@ -1576,7 +1484,6 @@ class EmbeddingTable:
                 self.slot_host[:] = 0
             rows = self._assign_file_rows(keys,
                                           blob["slot"].astype(np.int16))
-            self._reset_dev_index()
         data = np.asarray(jax.device_get(self.state.data)).copy()
         self._insert_file_rows(data, rows, blob)
         self.state = TableState.from_logical(data, self.capacity,
@@ -1616,7 +1523,6 @@ class EmbeddingTable:
             self.state = TableState.from_logical(data, self.capacity,
                                                  ext=self.opt_ext)
             self._touched[rows_all] = True
-            self._reset_dev_index()
         log.info("merge_model: %d rows (%d new, %d stat-merged) from %s",
                  len(keys), len(rows_new), int(existing.sum()), path)
         return len(keys)
@@ -1670,7 +1576,6 @@ class EmbeddingTable:
                                                  ext=self.opt_ext)
             self._touched[freed_rows] = False
             self.slot_host[freed_rows] = 0
-            self._reset_dev_index()
         log.info("shrink: freed %d/%d rows", len(freed_rows), len(keys))
         return int(len(freed_rows))
 

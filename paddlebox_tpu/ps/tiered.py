@@ -433,8 +433,6 @@ class TieredShardedEmbeddingTable(ShardedEmbeddingTable):
                 freed = self.indexes[s].release(ck)
                 self._touched[s][freed] = False
                 freed_total += len(ck)
-            if freed_total:
-                self._reset_dev_indexes()
             self._evict_async_rows += freed_total
             self._evict_async_sec += time.perf_counter() - t0
         if freed_total:
@@ -474,7 +472,6 @@ class TieredShardedEmbeddingTable(ShardedEmbeddingTable):
                     untouched = ~self._touched[s][rows_ok]
                     if untouched.any():
                         self.indexes[s].release(ks_ok[untouched])
-                        self._reset_dev_indexes()
                     self._unpin_pending(s, ks)
             self._stage_q.clear()
             self._stage_gen += 1   # reject straddling in-flight fetches
@@ -561,7 +558,6 @@ class TieredShardedEmbeddingTable(ShardedEmbeddingTable):
                 untouched = ~self._touched[s][rows]
                 if untouched.any():
                     self.indexes[s].release(ks[untouched])
-                    self._reset_dev_indexes()
         filtered: List[Optional[np.ndarray]] = [None] * self.n
         for s in range(self.n):
             p = snap[s]
@@ -855,9 +851,6 @@ class TieredShardedEmbeddingTable(ShardedEmbeddingTable):
                 for k in st_s:
                     stats[k] = stats.get(k, 0) + st_s[k]
                 total += len(st.keys[s])
-            # promote assigned/released kv rows behind the device
-            # mirrors' back — re-seed (or degrade) on next prepare
-            self._reset_dev_indexes()
             rows = np.concatenate(row_l) if row_l else np.empty(0, np.int32)
             if len(rows):
                 self.state = scatter_logical_rows(

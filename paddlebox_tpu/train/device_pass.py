@@ -223,9 +223,9 @@ class ResidentPass:
         self.trained_rows: Optional[np.ndarray] = None
         # columnar side channels for the post-pass metric feed (or None)
         self.side = side
-        # per-stage build seconds (front/dedup/index_host/index_dev/
-        # pack/h2d; floats/floats_wait where the float half ran on its
-        # own lane), set by
+        # per-stage build seconds (front/dedup/index_host/pack/h2d;
+        # floats/floats_wait where the float half ran on its own lane),
+        # set by
         # build_streamed — the preloader mirrors them into
         # pbox_preload_build_seconds_total{stage=...}
         self.build_stats: Optional[Dict[str, float]] = None
@@ -318,7 +318,7 @@ class ResidentPass:
         sequence feed has no block to encode: both stay on this thread.
 
         Per-stage seconds land in ``rp.build_stats``
-        (front/dedup/index_host/index_dev/pack/h2d; on two lanes
+        (front/dedup/index_host/pack/h2d; on two lanes
         ``front`` times the key views alone, ``floats`` is the float
         lane's own seconds and ``floats_wait`` what this thread waited
         for it where they meet — docs/PERFORMANCE.md telemetry)."""
@@ -383,12 +383,10 @@ class ResidentPass:
             dedup, u_pad, k_max = cls._dedup_phase(
                 per_batch, table, threads, stats=stats)
         t_dedup = time.perf_counter() - t0
-        # the index stage (key→row assignment inside the dedup phase,
-        # host kv or device probe table) reports separately so the
-        # stall breakdown names the actual bottleneck; keep the stages
-        # a partition of the build wall
-        stats["dedup"] = max(0.0, t_dedup - stats.get("index_host", 0.0)
-                             - stats.get("index_dev", 0.0))
+        # the index stage (key→row assignment inside the dedup phase)
+        # reports separately so the stall breakdown names the actual
+        # bottleneck; keep the stages a partition of the build wall
+        stats["dedup"] = max(0.0, t_dedup - stats.get("index_host", 0.0))
         poll_preload_abort()
         # wire formats decided ONCE from the dedup results — the exact
         # choice _encode_uniq/_encode_gidx make on the whole pass, so
@@ -579,48 +577,32 @@ class ResidentPass:
         meta = np.zeros((nb, 4), np.int32)
         segs = None if trivial else np.empty((nb, k_max), np.int32)
         t0 = time.perf_counter()
-        trained = None
         with trace.span("build.dedup", keys=_num_keys(per_batch)) as sp:
-            bulk = FLAGS.bulk_pass_assign
-            if bulk:
-                # whole-pass bulk assign over the pass's DISTINCT
-                # (key, slot) pairs: the first-seen dedup runs outside
-                # host_lock, then ONE lock round-trip walks the index
-                # with the distinct pairs only (a repeat is a lookup, so
-                # rows are allocated exactly as a walk of the whole
-                # stream allocates them) and the inverse expands rows
-                # and locals back to the stream
-                keys_u, slots_u, inv = dedup_slotted_first_seen(
-                    np.concatenate([k for k, *_ in per_batch]),
-                    np.concatenate([s for _, s, *_ in per_batch]
-                                   ).astype(np.uint16, copy=False))
-                sp.attrs["distinct"] = len(keys_u)
-                with table.host_lock:
-                    r_u, l_u = table.index.assign_slotted(keys_u, slots_u)
-                    table.slot_host[r_u] = slots_u
-                if (l_u < 0).any():
-                    return None
-                trained = r_u
-                bounds = np.cumsum([0] + [len(k) for k, *_ in per_batch])
-            for i, (keys, slot_of_key, _, pad_seg, seg_arr) in \
-                    enumerate(per_batch):
+            # whole-pass bulk assign over the pass's DISTINCT (key, slot)
+            # pairs: the first-seen dedup runs outside host_lock, then
+            # ONE lock round-trip walks the index with the distinct pairs
+            # only (a repeat is a lookup, so rows are allocated exactly as
+            # a walk of the whole stream allocates them) and the inverse
+            # expands rows and locals back to the stream
+            keys_u, slots_u, inv = dedup_slotted_first_seen(
+                np.concatenate([k for k, *_ in per_batch]),
+                np.concatenate([s for _, s, *_ in per_batch]
+                               ).astype(np.uint16, copy=False))
+            sp.attrs["distinct"] = len(keys_u)
+            with table.host_lock:
+                r_u, l_u = table.index.assign_slotted(keys_u, slots_u)
+                table.slot_host[r_u] = slots_u
+            if (l_u < 0).any():
+                return None
+            bounds = np.cumsum([0] + [len(k) for k, *_ in per_batch])
+            for i, (keys, _, _, pad_seg, seg_arr) in enumerate(per_batch):
                 nk = len(keys)
-                if bulk:
-                    # straight into the pass's arrays ("clip": take
-                    # buffers its output under the default mode; inv is
-                    # in range by construction)
-                    inv_i = inv[bounds[i]:bounds[i] + nk]
-                    np.take(l_u, inv_i, out=locs[i, :nk], mode="clip")
-                    np.take(r_u, inv_i, out=rows_g[i, :nk], mode="clip")
-                else:
-                    su = slot_of_key.astype(np.uint16, copy=False)
-                    with table.host_lock:
-                        r, l = table.index.assign_slotted(keys, su)
-                        table.slot_host[r] = slot_of_key
-                    if (l < 0).any():
-                        return None
-                    locs[i, :nk] = l
-                    rows_g[i, :nk] = r
+                # straight into the pass's arrays ("clip": take buffers
+                # its output under the default mode; inv is in range by
+                # construction)
+                inv_i = inv[bounds[i]:bounds[i] + nk]
+                np.take(l_u, inv_i, out=locs[i, :nk], mode="clip")
+                np.take(r_u, inv_i, out=rows_g[i, :nk], mode="clip")
                 meta[i] = (nk, pad_seg, 0, 0)
                 if segs is not None:
                     segs[i, :nk] = seg_arr
@@ -665,7 +647,7 @@ class ResidentPass:
                  side=side)
         rp.wire = "compact"
         rp.chunk_bits = int(table.arena_chunk_bits)
-        rp.trained_rows = trained
+        rp.trained_rows = r_u
         rp.dev = (loc_t, (cmap_t,), floats_t, meta_t, segs_t, qm)
         if block:
             with trace.span("build.upload"):
@@ -952,86 +934,38 @@ class ResidentPass:
         """Pass-level dedup + row assignment (the FeedPass registration +
         DedupKeysAndFillIdx steps). Returns
         ([(uniq_sorted, gidx)] per batch, u_pad, k_max). When ``stats``
-        is given and the bulk path runs, the assignment time the table
-        measured (host kv vs device probe table — see
-        EmbeddingTable.last_assign_seconds) lands in ``stats["index"]``.
+        is given, the seconds the table spent under host_lock
+        (EmbeddingTable.last_assign_seconds) land in
+        ``stats["index_host"]``.
 
-        BULK path (FLAGS.bulk_pass_assign, default): concatenate every
-        batch's keys, ONE first-seen dedup + assign round-trip under
-        host_lock (EmbeddingTable.bulk_assign_unique — the dedup itself
-        runs outside the lock), then the per-batch sort/rank splits fan
-        out over a thread pool (numpy releases the GIL). The old path
-        acquired host_lock once PER BATCH with the index assign inside
-        — nb serialized lock round-trips on the preloader thread, the
-        dominant prologue stall. New-row
-        allocation order is first-seen over the pass, matching a serial
-        batch walk of the native (first-occurrence) index row for row.
+        Concatenate every batch's keys, ONE first-seen dedup + assign
+        round-trip under host_lock (EmbeddingTable.bulk_assign_unique —
+        the dedup itself runs outside the lock), then the per-batch
+        sort/rank splits fan out over a thread pool (numpy releases the
+        GIL). New-row allocation order is first-seen over the pass,
+        matching a serial batch walk of the native (first-occurrence)
+        index row for row."""
+        keys_all = np.concatenate([k for k, *_ in per_batch])
+        slots_all = np.concatenate([s for _, s, *_ in per_batch])
+        rows_u, inv = table.bulk_assign_unique(keys_all, slots_all)
+        if stats is not None:
+            stats["index_host"] = table.last_assign_seconds["index_host"]
+        rows_of_key = rows_u[inv]
+        bounds = np.cumsum([0] + [len(k) for k, *_ in per_batch])
+        poll_preload_abort()
 
-        SERIAL fallback (flag off, or tables without bulk_assign_unique):
-        the per-batch assign loop, unchanged."""
-        bulk = getattr(table, "bulk_assign_unique", None)
-        if FLAGS.bulk_pass_assign and bulk is not None:
-            keys_all = np.concatenate([k for k, *_ in per_batch])
-            slots_all = np.concatenate([s for _, s, *_ in per_batch])
-            rows_u, inv = bulk(keys_all, slots_all)
-            if stats is not None:
-                las = getattr(table, "last_assign_seconds", None)
-                if las:
-                    # split, not a single stage: a starved pipeline
-                    # must name WHICH half of assignment is slow (the
-                    # host kv walk vs the device probe-table insert)
-                    stats["index_host"] = las.get("index_host", 0.0)
-                    stats["index_dev"] = las.get("index_device", 0.0)
-            rows_of_key = rows_u[inv]
-            bounds = np.cumsum([0] + [len(k) for k, *_ in per_batch])
-            poll_preload_abort()
+        def batch_dedup(a, b):
+            u, g = np.unique(rows_of_key[a:b], return_inverse=True)
+            return (u.astype(np.int32, copy=False),
+                    g.astype(np.int32, copy=False))
 
-            def batch_dedup(a, b):
-                u, g = np.unique(rows_of_key[a:b], return_inverse=True)
-                return (u.astype(np.int32, copy=False),
-                        g.astype(np.int32, copy=False))
-
-            with ThreadPoolExecutor(max_workers=threads) as pool:
-                dedup = list(pool.map(
-                    batch_dedup, bounds[:-1], bounds[1:]))
-        else:
-            dedup = cls._dedup_serial(per_batch, table, threads)
+        with ThreadPoolExecutor(max_workers=threads) as pool:
+            dedup = list(pool.map(batch_dedup, bounds[:-1], bounds[1:]))
         u_max = max(len(u) + 1 for u, _ in dedup)
         from paddlebox_tpu.ps.table import next_bucket_fine
         u_pad = next_bucket_fine(table.unique_bucket_min, u_max)
         k_max = max(kc for _, _, kc, _, _ in per_batch)
         return dedup, u_pad, k_max
-
-    @classmethod
-    def _dedup_serial(cls, per_batch, table, threads: int = 4):
-        """The per-batch assign loop (pre-bulk reference): one
-        host_lock acquisition + index round-trip per batch."""
-
-        def sort_rank(rows_u, inv):
-            u = len(rows_u)
-            order = np.argsort(rows_u, kind="stable")
-            rank = np.empty(u, np.int32)
-            rank[order] = np.arange(u, dtype=np.int32)
-            return rows_u[order], rank[inv]
-
-        # arena tables assign slotted even on the dedup wire, so keys
-        # seen here first don't land in the default arena and poison the
-        # compact wire for every later pass
-        slotted = getattr(table.index, "arena_enabled", False)
-        futs = []
-        with ThreadPoolExecutor(max_workers=threads) as pool:
-            for keys, slot_of_key, *_ in per_batch:
-                with table.host_lock:  # vs shrink/save on the main thread
-                    if slotted:
-                        rows_u, inv = table.index.assign_unique_slotted(
-                            keys, slot_of_key.astype(np.uint16,
-                                                     copy=False))
-                    else:
-                        rows_u, inv = table.index.assign_unique(keys)
-                    # slot = host metadata (slot_host), not wire bytes
-                    table.record_slots(rows_u, inv, slot_of_key)
-                futs.append(pool.submit(sort_rank, rows_u, inv))
-            return [f.result() for f in futs]
 
     @classmethod
     def _pack_chunk(cls, per_batch, dedup, u_pad: int, k_max: int,

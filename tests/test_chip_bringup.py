@@ -155,38 +155,6 @@ print("STATUS", nat.native_status())
     assert r.stdout.split("STATUS")[2].strip() == "verified"
 
 
-# ---- (e) the dispatch counter books what ran ----------------------------
-
-def test_index_seam_books_xla_when_it_runs_the_while_loop(monkeypatch):
-    """On a chip use_pallas_index runs the XLA while_loop formulation;
-    the counter must say impl="xla", never "pallas"."""
-    from paddlebox_tpu.config import flags_scope
-    from paddlebox_tpu.obs import MemorySink
-    from paddlebox_tpu.obs.hub import get_hub, reset_hub
-    from paddlebox_tpu.ops import pallas_index
-    from paddlebox_tpu.ps.sharded import ShardedEmbeddingTable
-    monkeypatch.setattr(pallas_index, "_interpret", lambda: False)
-    reset_hub()
-    hub = get_hub()
-    hub.add_sink(MemorySink())
-    try:
-        st = ShardedEmbeddingTable(2, mf_dim=4, capacity_per_shard=64,
-                                   req_bucket_min=8, serve_bucket_min=8)
-        keys0 = np.arange(2, 20, 2, dtype=np.uint64)  # shard-0-owned
-        with flags_scope(use_pallas_index=True):
-            rows = st._shard_rows(0, keys0, assign=True)
-            again = st._shard_rows(0, keys0, assign=False)
-        np.testing.assert_array_equal(rows, np.arange(len(keys0)))
-        np.testing.assert_array_equal(again, rows)
-        c = hub.counter("pbox_kernel_dispatch_total")
-        for op in ("index.assign", "index.lookup"):
-            assert c.value(kernel=op, impl="xla") == 1
-            assert c.value(kernel=op, impl="pallas") == 0
-            assert c.value(kernel=op, impl="host") == 0
-    finally:
-        reset_hub()
-
-
 # ---- one process per chip ----------------------------------------------
 
 def test_launcher_refuses_local_gang_unless_cpu_emulation(monkeypatch):

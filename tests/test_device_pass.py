@@ -429,40 +429,37 @@ def test_preloader_hbm_budget_clamps(criteo_files):
 
 
 def test_bulk_assign_matches_serial(criteo_files):
-    """Whole-pass bulk key assignment (one host_lock round-trip)
-    produces the same per-batch index as the serial per-batch path:
-    key→row decode agrees with the index either way, and on the
-    native (first-occurrence) index the builds are row-for-row
-    identical."""
-    from paddlebox_tpu.config import flags_scope
-    from paddlebox_tpu.native import load_native
+    """Whole-pass bulk key assignment (one host_lock round-trip) builds
+    the per-batch index a serial walk of every key of the pass gives:
+    the same rows in the same first-seen allocation order, each batch's
+    sorted unique rows and inverse, and each key's first-seen slot."""
     tr_a, ds = _make(criteo_files)
     tr_b, _ = _make(criteo_files)
-    with flags_scope(bulk_pass_assign=True):
-        rp_a = ResidentPass.build(ds, tr_a.table)
-    with flags_scope(bulk_pass_assign=False):
-        rp_b = ResidentPass.build(ds, tr_b.table)
-    assert rp_a.num_batches == rp_b.num_batches
-    np.testing.assert_array_equal(rp_a.meta[:, (0, 1, 2)],
-                                  rp_b.meta[:, (0, 1, 2)])
-    # both builds registered the same key set, and each build's wire
-    # decodes every key to the row its own index assigned
+    rp = ResidentPass.build(ds, tr_a.table)
+    per_batch = ResidentPass._front(ds, np.float32)[0]
+    # the oracle: every key of the pass through the index, in stream
+    # order, one batch after another
+    walk = tr_b.table.index
+    slot_of = {}
+    assert rp.num_batches == len(per_batch)
+    for i, (keys, slots, *_) in enumerate(per_batch):
+        rows = walk.assign(keys)
+        for k, s in zip(keys.tolist(), slots.tolist()):
+            slot_of.setdefault(k, s)
+        uniq = np.unique(rows)
+        nk, u = len(keys), len(uniq)
+        assert tuple(rp.meta[i, (0, 2)]) == (nk, u)
+        np.testing.assert_array_equal(rp.uniq[i, :u], uniq)
+        np.testing.assert_array_equal(rp.gidx[i, :nk],
+                                      np.searchsorted(uniq, rows))
     keys_a, rows_a = tr_a.table.index.items()
-    keys_b, _ = tr_b.table.index.items()
-    np.testing.assert_array_equal(np.sort(keys_a), np.sort(keys_b))
-    for rp, tr in ((rp_a, tr_a), (rp_b, tr_b)):
-        batches = list(ds.batches())
-        for i, b in enumerate(batches):
-            nk = b.num_keys
-            rows_wire = rp.uniq[i][rp.gidx[i][:nk]]
-            rows_idx = tr.table.index.lookup(b.keys[:nk])
-            np.testing.assert_array_equal(rows_wire, rows_idx)
-    if load_native() is not None:
-        # native assign_unique is first-occurrence — bulk first-seen
-        # allocation reproduces the serial walk row for row
-        np.testing.assert_array_equal(rp_a.uniq, rp_b.uniq)
-        np.testing.assert_array_equal(rp_a.gidx, rp_b.gidx)
-        np.testing.assert_array_equal(rp_a.meta, rp_b.meta)
+    keys_b, rows_b = walk.items()
+    oa, ob = np.argsort(keys_a), np.argsort(keys_b)
+    np.testing.assert_array_equal(keys_a[oa], keys_b[ob])
+    np.testing.assert_array_equal(rows_a[oa], rows_b[ob])
+    np.testing.assert_array_equal(
+        tr_a.table.slot_host[rows_a],
+        [slot_of[k] for k in keys_a.tolist()])
 
 
 def test_preloader_error_mid_queue(criteo_files):

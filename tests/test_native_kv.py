@@ -4,7 +4,9 @@ import numpy as np
 import pytest
 
 from paddlebox_tpu.native import load_native
-from paddlebox_tpu.ps.kv import NativeKV, PyKV, TableFullError
+from paddlebox_tpu.ps.kv import (NativeKV, PyKV, TableFullError,
+                                 dedup_first_seen_native)
+from paddlebox_tpu.ps.table import _dedup_first_seen_py, dedup_first_seen
 
 
 requires_native = pytest.mark.skipif(load_native() is None,
@@ -229,25 +231,6 @@ def test_arena_release_reuses_within_slot(impl):
     assert (nlocs >= 0).all()
 
 
-@pytest.mark.parametrize("impl", ["native", "py"])
-def test_arena_assign_unique_slotted(impl):
-    if impl == "native" and load_native() is None:
-        pytest.skip("native lib unavailable")
-    kv = (NativeKV(1 << 10, load_native()) if impl == "native"
-          else PyKV(1 << 10))
-    kv.arena_enable(4, 4)
-    keys = np.array([5, 9, 5, 13, 9, 5], np.uint64)
-    slots = np.array([1, 2, 1, 3, 2, 1], np.uint16)
-    uniq_rows, inv = kv.assign_unique_slotted(keys, slots)
-    assert len(uniq_rows) == 3
-    np.testing.assert_array_equal(uniq_rows[inv],
-                                  kv.assign_slotted(keys, slots)[0])
-    # rows landed in their slots' arenas
-    cs_map, _ = kv.arena_export()
-    _, locs = kv.assign_slotted(keys, slots)
-    assert (locs >= 0).all()
-
-
 def test_arena_enable_after_assign_raises():
     kv = PyKV(64)
     kv.assign(np.array([1], np.uint64))
@@ -276,3 +259,45 @@ def test_arena_out_of_range_slot_clamps_to_default(impl):
     r2, l2 = kv.assign_slotted(np.array([3], np.uint64),
                                np.array([1], np.uint16))
     assert l2[0] >= 0 and len(kv) == 3
+
+
+def _make_streams():
+    rng = np.random.default_rng(7)
+    base = rng.integers(1, 2 ** 31, size=200).astype(np.uint64)
+    return {
+        # small vocab -> heavy duplicate + hash-collision pressure
+        "collision_heavy": rng.integers(1, 40, size=400).astype(np.uint64),
+        "zipf": np.minimum(rng.zipf(1.3, size=500), 4000).astype(np.uint64),
+        "uniform_distinct": rng.choice(
+            np.arange(1, 1 << 20, dtype=np.uint64), 300, replace=False),
+        # ids identical mod 2^32 — a 32-bit-truncating hash or compare
+        # would alias every pair
+        "hi64_collide_mod32": np.concatenate(
+            [base, base | (np.uint64(1) << np.uint64(33))]),
+    }
+
+
+#: adversarial key streams, shared with tests/test_host_key_index.py
+STREAMS = _make_streams()
+
+
+def test_native_dedup_matches_oracle():
+    if dedup_first_seen_native(STREAMS["zipf"]) is None:
+        pytest.skip("native kv library unavailable")
+    for name, keys in STREAMS.items():
+        uniq_o, first_o, inv_o = _dedup_first_seen_py(keys)
+        uniq, first, inv = dedup_first_seen_native(keys)
+        np.testing.assert_array_equal(uniq, uniq_o, err_msg=name)
+        np.testing.assert_array_equal(first, first_o, err_msg=name)
+        np.testing.assert_array_equal(inv, inv_o, err_msg=name)
+
+
+def test_dedup_first_seen_public_route_matches_oracle():
+    """The seam everyone calls (native when buildable, python
+    otherwise) is bitwise against the oracle either way."""
+    for name, keys in STREAMS.items():
+        uniq_o, first_o, inv_o = _dedup_first_seen_py(keys)
+        uniq, first, inv = dedup_first_seen(keys)
+        np.testing.assert_array_equal(uniq, uniq_o, err_msg=name)
+        np.testing.assert_array_equal(first, first_o, err_msg=name)
+        np.testing.assert_array_equal(inv, inv_o, err_msg=name)

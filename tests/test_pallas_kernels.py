@@ -13,8 +13,7 @@ REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__),
 from paddlebox_tpu.config import flags_scope
 from paddlebox_tpu.ops.pallas_kernels import (
     CVM_CONV, CVM_FULL, CVM_NONE, CVM_SHOW, fused_embed_pool_cvm,
-    fused_pool_cvm_forward, gather_rows, scatter_rows, segment_gather_mxu,
-    segment_sum_mxu,
+    fused_pool_cvm_forward, gather_rows, segment_gather_mxu, segment_sum_mxu,
 )
 
 
@@ -32,30 +31,6 @@ def test_gather_rows_wide():
     rows = jnp.asarray(rng.integers(0, 256, size=500).astype(np.int32))
     out = gather_rows(table, rows)
     np.testing.assert_allclose(np.asarray(out), np.asarray(table)[rows])
-
-
-def test_scatter_rows_matches_set():
-    rng = np.random.default_rng(2)
-    table = rng.normal(size=(64, 16)).astype(np.float32)
-    rows = rng.permutation(64)[:20].astype(np.int32)
-    vals = rng.normal(size=(20, 16)).astype(np.float32)
-    out = scatter_rows(jnp.asarray(table), jnp.asarray(rows),
-                       jnp.asarray(vals))
-    want = table.copy()
-    want[rows] = vals
-    np.testing.assert_allclose(np.asarray(out), want)
-
-
-def test_scatter_rows_under_jit():
-    rng = np.random.default_rng(3)
-    table = rng.normal(size=(32, 8)).astype(np.float32)
-    rows = np.array([5, 9, 31], np.int32)
-    vals = rng.normal(size=(3, 8)).astype(np.float32)
-    f = jax.jit(scatter_rows)
-    out = f(jnp.asarray(table), jnp.asarray(rows), jnp.asarray(vals))
-    want = table.copy()
-    want[rows] = vals
-    np.testing.assert_allclose(np.asarray(out), want)
 
 
 @pytest.mark.parametrize("k,s", [(100, 40), (700, 200), (7, 3), (1500, 3000)])
@@ -374,26 +349,10 @@ def test_use_pallas_flags_referenced_outside_config():
     assert not unread, f"flags never read outside config.py: {unread}"
 
 
-def test_dma_reference_paths_refuse_real_tpu(monkeypatch):
-    """gather_rows_dma / scatter_rows_dma are demoted to interpret-only
-    reference code: on a real TPU backend they must raise, not run the
-    measured-1000x-off per-row DMA loop."""
-    import paddlebox_tpu.ops.pallas_kernels as pk
-    monkeypatch.setattr(pk, "_interpret", lambda: False)
-    t = jnp.zeros((65, 16), jnp.float32)
-    rows = jnp.zeros((32,), jnp.int32)
-    vals = jnp.zeros((32, 16), jnp.float32)
-    with pytest.raises(RuntimeError, match="interpret-mode reference"):
-        pk.gather_rows_dma(t, rows)
-    with pytest.raises(RuntimeError, match="interpret-mode reference"):
-        pk.scatter_rows_dma(t, rows, vals)
-
-
 def test_kernel_dispatch_counter_books():
     """EVERY dispatch seam books pbox_kernel_dispatch_total{kernel,impl}
-    for both implementations — the seqpool seam (ISSUE 12), the three
-    CTR-family seams (ISSUE 13), and the device key-index seam
-    (ISSUE 19: index.assign/index.lookup with impls pallas|host)."""
+    for both implementations — the seqpool seam and the three CTR-family
+    seams."""
     from paddlebox_tpu.obs import MemorySink
     from paddlebox_tpu.obs.hub import get_hub, reset_hub
     from paddlebox_tpu.ops import (batch_fc, cross_norm_hadamard,
@@ -431,52 +390,11 @@ def test_kernel_dispatch_counter_books():
             run_all()
         with flags_scope(**{k: False for k in flags_on}):
             run_all()
-        # the ISSUE 19 device key-index seam: impls are pallas/host —
-        # the fallback is the authoritative host kv, not an XLA
-        # formulation, and BOTH routing decisions must book
-        from paddlebox_tpu.ps.sharded import ShardedEmbeddingTable
-        st = ShardedEmbeddingTable(2, mf_dim=4, capacity_per_shard=64,
-                                   req_bucket_min=8, serve_bucket_min=8)
-        keys0 = np.arange(2, 20, 2, dtype=np.uint64)  # shard-0-owned
-        with flags_scope(use_pallas_index=True):
-            st._shard_rows(0, keys0, assign=True)    # index.assign/pallas
-            st._shard_rows(0, keys0, assign=False)   # index.lookup/pallas
-            st._dev_index_for(0).degrade("test: force host fallback")
-            st._shard_rows(0, keys0, assign=True)    # index.assign/host
-            st._shard_rows(0, keys0, assign=False)   # index.lookup/host
         c = hub.counter("pbox_kernel_dispatch_total")
         for kernel in ("fused_embed_pool_cvm", "rank_attention",
                        "batch_fc", "cross_norm"):
             for impl in ("pallas", "xla"):
                 assert c.value(kernel=kernel, impl=impl) >= 1, \
                     f"seam {kernel!r} never booked impl={impl!r}"
-        for kernel in ("index.assign", "index.lookup"):
-            for impl in ("pallas", "host"):
-                assert c.value(kernel=kernel, impl=impl) >= 1, \
-                    f"seam {kernel!r} never booked impl={impl!r}"
     finally:
         reset_hub()
-
-
-def test_dma_kernels_interpret_semantics():
-    """gather_rows_dma / scatter_rows_dma (interpret mode off-TPU):
-    OOB rows clamp to the sentinel; scatter is in-place on unique rows."""
-    import jax.numpy as jnp
-    from paddlebox_tpu.ops.pallas_kernels import (gather_rows_dma,
-                                                  scatter_rows_dma)
-    C, D, K = 64, 16, 32
-    rng = np.random.default_rng(0)
-    table = jnp.zeros((C + 1, D), jnp.float32)
-    uq = np.unique(rng.integers(0, C, size=K).astype(np.int32))
-    rows = np.concatenate([uq, C + 1 + np.arange(K - len(uq),
-                                                 dtype=np.int32)])
-    vals = rng.normal(size=(K, D)).astype(np.float32)
-    out = np.asarray(scatter_rows_dma(table, jnp.asarray(rows),
-                                      jnp.asarray(vals)))
-    ref = np.zeros((C + 1, D), np.float32)
-    ref[uq] = vals[:len(uq)]
-    np.testing.assert_allclose(out[:C], ref[:C])  # row C is the racy pad bin
-    got = np.asarray(gather_rows_dma(jnp.asarray(out).at[C].set(0.0),
-                                     jnp.asarray(rows)))
-    np.testing.assert_allclose(got[:len(uq)], vals[:len(uq)])
-    np.testing.assert_allclose(got[len(uq):], 0.0)  # OOB → sentinel zeros

@@ -286,134 +286,31 @@ def test_resident_digest_immune_to_ctr_flags(criteo_files):
     assert d0 == d1
 
 
-# ---- ISSUE 19: device-resident key index (use_pallas_index) ------------
-
-def test_index_depth2_preloader_digest_matches_flag_off(criteo_files):
-    """The ISSUE 19 acceptance digest gate, resident half: a depth-2
-    preloaded multi-pass run with use_pallas_index=1 (device dedup +
-    hash-insert row assignment, host kv mirrored with new keys only)
-    reproduces the depth-0 flag-off state_digest EXACTLY."""
-    with flags_scope(use_pallas_index=False):
-        tr0, ds = _trainer_uniform(criteo_files)
-        tr0.train_passes_resident([ds] * 4, depth=0)
-        d0 = state_digest(tr0)
-    with flags_scope(use_pallas_index=True):
-        tr1, ds = _trainer_uniform(criteo_files)
-        tr1.train_passes_resident([ds] * 4, depth=2)
-        d1 = state_digest(tr1)
-    assert d0 == d1
-    # the device route actually served (not a silent host fallback)
-    dev = tr1.table._dev_index
-    assert dev is not None and not dev.degraded, dev and dev.degrade_reason
-
-
-def test_index_sharded_digest_matches_flag_off(criteo_files):
-    """The ISSUE 19 acceptance digest gate, sharded half: streaming +
-    resident passes on a 2-device mesh with use_pallas_index=1 (per-
-    shard device mirrors behind _shard_rows) reproduce the flag-off
-    sharded_state_digest EXACTLY."""
-    from paddlebox_tpu.parallel import make_mesh
-    from paddlebox_tpu.ps.sharded import ShardedEmbeddingTable
-    from paddlebox_tpu.train.checkpoint import sharded_state_digest
-    from paddlebox_tpu.train.sharded import ShardedTrainer
-    mesh = make_mesh(2)
-    desc = DataFeedDesc.criteo(batch_size=32)
-    desc.key_bucket_min = 512
-    ds = DatasetFactory().create_dataset("InMemoryDataset", desc)
-    ds.set_filelist(criteo_files)
-    ds.load_into_memory()
-
-    def run(flag):
-        cfg = SparseSGDConfig(mf_create_thresholds=0.0,
-                              mf_initial_range=0.0,
-                              learning_rate=0.1, mf_learning_rate=0.1)
-        table = ShardedEmbeddingTable(2, mf_dim=4,
-                                      capacity_per_shard=4096, cfg=cfg,
-                                      req_bucket_min=256,
-                                      serve_bucket_min=256)
-        with flags_scope(use_pallas_index=flag,
-                         log_period_steps=10 ** 6):
-            tr = ShardedTrainer(DeepFM(hidden=(16, 16)), table, desc,
-                                mesh, tx=optax.adam(2e-3))
-            tr.train_pass(ds)
-            tr.train_pass_resident(ds)
-        return sharded_state_digest(tr)
-
-    assert run(True) == run(False)
-
-
-def test_index_overflow_degrades_without_digest_drift(criteo_files):
-    """Capacity/probe-pressure overflow mid-run flips the mirror to the
-    host path LOUDLY (warning + index.assign/host booked) and the final
-    state_digest still matches flag-off exactly — degraded never means
-    wrong."""
-    import logging
-    from paddlebox_tpu.obs import MemorySink
-    from paddlebox_tpu.obs.hub import get_hub, reset_hub
-    from paddlebox_tpu.ops.pallas_index import DeviceKeyIndex
-    with flags_scope(use_pallas_index=False):
-        tr0, ds = _trainer_uniform(criteo_files)
-        tr0.train_passes_resident([ds] * 2, depth=0)
-        d0 = state_digest(tr0)
-    reset_hub()
-    hub = get_hub()
-    hub.add_sink(MemorySink())
-    records = []
-    handler = logging.Handler()
-    handler.emit = records.append
-    logging.getLogger("paddlebox_tpu").addHandler(handler)
-    try:
-        with flags_scope(use_pallas_index=True):
-            tr1, ds = _trainer_uniform(criteo_files)
-            # plant a crippled mirror: 512 buckets cannot hold criteo's
-            # ~1k pass uniques -> probe overflow on the first bulk
-            # assign, sticky degrade, host path from then on
-            tr1.table._dev_index = DeviceKeyIndex(tr1.table.capacity,
-                                                  n_buckets=512)
-            tr1.train_passes_resident([ds] * 2, depth=2)
-            d1 = state_digest(tr1)
-        c = hub.counter("pbox_kernel_dispatch_total")
-        assert c.value(kernel="index.assign", impl="host") >= 1
-    finally:
-        logging.getLogger("paddlebox_tpu").removeHandler(handler)
-        reset_hub()
-    assert d1 == d0
-    dev = tr1.table._dev_index
-    assert dev.degraded and "overflow" in dev.degrade_reason
-    assert any("degraded" in r.getMessage() for r in records), \
-        "overflow degrade was silent — must warn"
-
-
-def test_index_abort_polled_build_rolls_back(criteo_files):
-    """A stop-polled (aborted) flag-on preloader build leaves the host
-    kv authoritative and the device mirror either exactly in sync or
-    degraded — and the pipeline restarts cleanly after clear_stop."""
+def test_stop_polled_build_leaves_host_kv_consistent(criteo_files):
+    """A stop-polled (aborted) preloader build leaves the host kv
+    consistent — every key resolves to its own distinct row — and the
+    pipeline restarts cleanly after clear_stop: a fresh run from this
+    table digests identically to a run from scratch."""
     from paddlebox_tpu.resilience import preemption
     from paddlebox_tpu.train.device_pass import PassPreloader
-    with flags_scope(use_pallas_index=True):
-        tr, ds = _trainer_uniform(criteo_files)
-        pre = PassPreloader(iter([ds] * 6), tr.table, depth=1)
-        try:
-            pre.start_next()
-            assert pre.wait() is not None
-            preemption.request_stop("test")
-            while pre.wait() is not None:   # drain staged passes
-                pass
-            pre.drain(timeout=30)
-        finally:
-            preemption.clear_stop()
-            pre.drain()
-        dev = tr.table._dev_index
-        if dev is not None and not dev.degraded:
-            with tr.table.host_lock:
-                keys, rows = tr.table.index.items()
-            assert len(keys) == dev.next_row
-            np.testing.assert_array_equal(dev.lookup_rows(keys),
-                                          rows.astype(np.int64))
-        # aborted build rolled back cleanly: a fresh flag-on run from
-        # this table still digests identically to flag-off from scratch
-        tr.train_passes_resident([ds], depth=1)
-    with flags_scope(use_pallas_index=False):
-        tr0, ds0 = _trainer_uniform(criteo_files)
-        tr0.train_passes_resident([ds0], depth=0)
+    tr, ds = _trainer_uniform(criteo_files)
+    pre = PassPreloader(iter([ds] * 6), tr.table, depth=1)
+    try:
+        pre.start_next()
+        assert pre.wait() is not None
+        preemption.request_stop("test")
+        while pre.wait() is not None:   # drain staged passes
+            pass
+        pre.drain(timeout=30)
+    finally:
+        preemption.clear_stop()
+        pre.drain()
+    with tr.table.host_lock:
+        keys, rows = tr.table.index.items()
+        np.testing.assert_array_equal(tr.table.index.lookup(keys), rows)
+    assert len(np.unique(rows)) == len(rows) == len(np.unique(keys))
+    assert (rows >= 0).all() and (rows < tr.table.capacity).all()
+    tr.train_passes_resident([ds], depth=1)
+    tr0, ds0 = _trainer_uniform(criteo_files)
+    tr0.train_passes_resident([ds0], depth=0)
     assert state_digest(tr) == state_digest(tr0)
