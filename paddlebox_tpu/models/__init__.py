@@ -9,6 +9,7 @@ from paddlebox_tpu.models.lfm2 import Lfm2Moe
 from paddlebox_tpu.models.mellum import MellumMoe
 from paddlebox_tpu.models.ouro import OuroLoop
 from paddlebox_tpu.models.kimi_linear import KimiLinear
+from paddlebox_tpu.models.laguna import LagunaMoe
 
 MODEL_REGISTRY = {
     "ctr_dnn": CtrDnn,
@@ -21,4 +22,4 @@ MODEL_REGISTRY = {
 
 __all__ = ["CtrDnn", "DeepFM", "WideDeep", "DCNv2", "AdsRank",
            "MMoE", "MMoESingle", "NemotronH", "Lfm2Moe", "MellumMoe",
-           "OuroLoop", "KimiLinear", "MODEL_REGISTRY"]
+           "OuroLoop", "KimiLinear", "LagunaMoe", "MODEL_REGISTRY"]

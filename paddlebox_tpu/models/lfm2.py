@@ -42,18 +42,15 @@ import jax
 import jax.numpy as jnp
 
 from paddlebox_tpu.models.lm_parts import (KEEP_ATTN_LOOPS,
-                                           MOE_STEP_SCALARS, head_loss,
+                                           MOE_STEP_SCALARS, dense_ffn,
+                                           gated_experts, head_loss,
                                            matmul, moe_load_scalars,
                                            rms_norm, rotary_attention)
 from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.short_conv import gated_short_conv
-from paddlebox_tpu.parallel.moe import route_top_k, routed_experts
 
 _scope = jax.named_scope
 
-#: positions the dense MLP's hidden activation exists for at a time: the
-#: largest divisor of the step's positions that this allows
-MLP_ROWS = 4096
 #: what the published router adds to the chosen scores' sum
 ROUTE_SUM_EPS = 1e-6
 
@@ -165,27 +162,10 @@ class Lfm2Moe:
                 (self.qh, self.kvh, self.hd), self.eps, self.dtype,
                 {"theta": self.theta})
 
-    def _mlp(self, lay, x):
-        """The dense SwiGLU feed-forward of ``MLP_ROWS`` positions
-        [rows, hidden]."""
-        with _scope(trace.SCOPE_MLP):
-            u = self._norm(x, lay["ffn_norm"])
-            hid = jax.nn.silu(self._mm(u, lay["w1"])) \
-                * self._mm(u, lay["w3"])
-            return x + self._mm(hid, lay["w2"])
-
     def _moe(self, lay, x) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        s, t, d = x.shape
-        with _scope(trace.SCOPE_MOE_ROUTE):
-            u = self._norm(x, lay["ffn_norm"]).reshape(s * t, d)
-            idx, w = route_top_k(u, lay["router"], lay["expert_bias"],
-                                 self.top_k, self.route_scale,
-                                 sum_eps=ROUTE_SUM_EPS)
-        with _scope(trace.SCOPE_MOE_EXPERTS):
-            y, stats = routed_experts(u, idx, w, lay["up"], lay["down"],
-                                      self.held, mm_dtype=self.dtype,
-                                      gate=lay["gate"])
-            return x + y.reshape(s, t, d), stats["load"], stats["rows"]
+        return gated_experts(lay, x, self.eps, self.dtype, self.held,
+                             self.top_k, self.route_scale,
+                             bias=lay["expert_bias"], sum_eps=ROUTE_SUM_EPS)
 
     # ---- the stack, the head and the loss ----
     def hidden(self, params, emb: jax.Array):
@@ -194,18 +174,11 @@ class Lfm2Moe:
         [expert layers, held], the rows each expert layer's loops
         computed, int32 [expert layers])."""
         x, loads, rows = emb, [], []
-        shape = x.shape
-        mlp_rows = math.gcd(x.shape[0] * x.shape[1], MLP_ROWS)
         for i, (kind, lay) in enumerate(zip(self.kinds, params["layers"])):
             operator = self._conv if kind == "conv" else self._attention
             x = jax.checkpoint(operator, policy=KEEP_ATTN_LOOPS)(lay, x)
             if i < self.n_dense:
-                # the hidden activation is the widest value of the step:
-                # a slab of positions at a time
-                one = jax.checkpoint(self._mlp)
-                x = jax.lax.map(lambda xs, lay=lay: one(lay, xs),
-                                x.reshape(-1, mlp_rows, self.d)
-                                ).reshape(shape)
+                x = dense_ffn(lay, x, self.eps, self.dtype)
             else:
                 x, load, computed = jax.checkpoint(self._moe)(lay, x)
                 loads.append(load)

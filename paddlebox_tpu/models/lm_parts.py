@@ -1,14 +1,17 @@
 """What the sequence models (``nemotron_h.py``, ``lfm2.py``,
-``mellum.py``, ``ouro.py``) share beside the ops: the stated matrix
-product, RMSNorm, rotary attention (queries and keys normed over the head
-where the layer has such norms), the head read a slab of positions at a
-time for one hidden state or several, and the scalars an expert layer
-hands a step."""
+``mellum.py``, ``ouro.py``, ``kimi_linear.py``, ``laguna.py``) share
+beside the ops: the stated matrix product, RMSNorm, rotary attention
+(queries and keys normed over the head where the layer has such norms,
+the heads gated where it has a gate), the SwiGLU of a dense feed-forward
+or a shared expert, the dense feed-forward a slab of positions at a time,
+the gated-expert feed-forward (with a shared expert or none), the head
+read a slab of positions at a time for one hidden state or
+several, and the scalars an expert layer hands a step."""
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +20,7 @@ from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.causal_attention import (LOOP_RESIDUALS,
                                                 causal_gqa_attention,
                                                 rotary_embedding)
+from paddlebox_tpu.parallel.moe import route_top_k, routed_experts
 
 _scope = jax.named_scope
 
@@ -25,6 +29,9 @@ _scope = jax.named_scope
 #: positions) that these allow
 ATTN_BLOCK = 512
 HEAD_ROWS = 4096
+#: positions the dense feed-forward's hidden activation exists for at a
+#: time: the largest divisor of the step's positions that this allows
+MLP_ROWS = 4096
 
 #: what the ``jax.checkpoint`` around an attention (sub)layer keeps for
 #: its backward pass beside the layer's input: the two results that only
@@ -64,8 +71,11 @@ def rotary_attention(u, lay, heads, eps: float, dtype, rotary: Dict,
     projections, queries and keys RMS-normed over the head where the
     layer has ``q_norm`` and ``k_norm``, then rotated (``rotary``: what
     ``rotary_embedding`` is told), causal blockwise attention over the
-    whole sequence or a ``window``, the ``o`` projection; the caller
-    names the scope and adds the residual."""
+    whole sequence or a ``window``, each head's output times its gate
+    ``sigmoid(u W_g)`` where the layer has ``attn_gate`` W_g [hidden,
+    query heads] (one scalar a head and position, under
+    ``pbox.attn_gate``), the ``o`` projection; the caller names the
+    scope and adds the residual."""
     s, t, _ = u.shape
     qh, kvh, hd = heads
     q = matmul(u, lay["q"], dtype).reshape(s, t, qh, hd)
@@ -80,7 +90,66 @@ def rotary_attention(u, lay, heads, eps: float, dtype, rotary: Dict,
     q, k = turned(q, "q_norm"), turned(k, "k_norm")
     o = causal_gqa_attention(q, k, v, block=ATTN_BLOCK, mm_dtype=dtype,
                              window=window)
+    if "attn_gate" in lay:
+        with _scope(trace.SCOPE_ATTN_GATE):
+            o = o * jax.nn.sigmoid(matmul(u, lay["attn_gate"], dtype)
+                                   )[..., None]
     return matmul(o.reshape(s, t, qh * hd), lay["o"], dtype)
+
+
+def swiglu(u, w_gate, w_up, w_down, dtype):
+    """``(silu(u W_gate) * (u W_up)) W_down``: a dense feed-forward's or a
+    shared expert's body (the routed experts' is
+    ``parallel/moe.routed_experts``')."""
+    hid = jax.nn.silu(matmul(u, w_gate, dtype)) * matmul(u, w_up, dtype)
+    return matmul(hid, w_down, dtype)
+
+
+def _dense_slab(lay, x, eps: float, dtype):
+    with _scope(trace.SCOPE_MLP):
+        u = rms_norm(x, lay["ffn_norm"], eps)
+        return x + swiglu(u, lay["w1"], lay["w3"], lay["w2"], dtype)
+
+
+def dense_ffn(lay, x, eps: float, dtype):
+    """``x + SwiGLU(RMSNorm(x))`` of a dense feed-forward layer (``w1``
+    gate, ``w3`` up, ``w2`` down) under ``pbox.mlp``, over ``x`` [S, T,
+    hidden]: its hidden activation is the widest value of a step, so it
+    exists for ``MLP_ROWS`` positions at a time, each slab one
+    ``jax.checkpoint`` that keeps its input."""
+    shape = x.shape
+    rows = math.gcd(shape[0] * shape[1], MLP_ROWS)
+    one = jax.checkpoint(_dense_slab, static_argnums=(2, 3))
+    return jax.lax.map(lambda xs: one(lay, xs, eps, dtype),
+                       x.reshape(-1, rows, shape[-1])).reshape(shape)
+
+
+def gated_experts(lay, x, eps: float, dtype, held: Tuple[int, int],
+                  top_k: int, scale: float, bias=None, sum_eps: float = 0.0,
+                  score=jax.nn.sigmoid):
+    """``x + FFN(RMSNorm(x))`` of an expert layer over ``x`` [S, T,
+    hidden] -> (that, the token-choices each held expert took [held], the
+    rows its loops computed): the router over all its outputs
+    (``route_top_k``'s ``bias``, ``scale``, ``sum_eps`` and ``score``)
+    under ``pbox.moe_route``, the held gated experts' share under
+    ``pbox.moe_experts``, and where the layer has one
+    (``shared_gate``, ``shared_up``, ``shared_down``) a shared SwiGLU
+    expert every token passes through under ``pbox.moe_shared``; the
+    residual add closes the last of the two."""
+    s, t, d = x.shape
+    with _scope(trace.SCOPE_MOE_ROUTE):
+        u = rms_norm(x, lay["ffn_norm"], eps).reshape(s * t, d)
+        idx, w = route_top_k(u, lay["router"], bias, top_k, scale,
+                             sum_eps=sum_eps, score=score)
+    with _scope(trace.SCOPE_MOE_EXPERTS):
+        y, stats = routed_experts(u, idx, w, lay["up"], lay["down"], held,
+                                  mm_dtype=dtype, gate=lay["gate"])
+        if "shared_up" not in lay:
+            return x + y.reshape(s, t, d), stats["load"], stats["rows"]
+    with _scope(trace.SCOPE_MOE_SHARED):
+        y = y + swiglu(u, lay["shared_gate"], lay["shared_up"],
+                       lay["shared_down"], dtype)
+        return x + y.reshape(s, t, d), stats["load"], stats["rows"]
 
 
 def _head_slabs(fold, carry, x, lab, beside, norm_weight, head, eps: float,

@@ -42,12 +42,12 @@ import jax
 import jax.numpy as jnp
 
 from paddlebox_tpu.models.lm_parts import (KEEP_ATTN_LOOPS,
-                                           MOE_STEP_SCALARS, head_loss,
-                                           matmul, moe_load_scalars,
-                                           rms_norm, rotary_attention)
+                                           MOE_STEP_SCALARS, gated_experts,
+                                           head_loss, matmul,
+                                           moe_load_scalars, rms_norm,
+                                           rotary_attention)
 from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.causal_attention import yarn_inv_freq
-from paddlebox_tpu.parallel.moe import route_top_k, routed_experts
 
 _scope = jax.named_scope
 
@@ -56,21 +56,36 @@ KINDS = ("full_attention", "sliding_attention")
 
 def rotary_table(dim: int, rope: Dict[str, Any]) -> Dict[str, Any]:
     """What ``rotary_embedding`` is told for one group of the published
-    ``rope_parameters``: the base alone (``default``), or YaRN's inverse
-    frequencies and the amplitude on cos and sin (``yarn``)."""
+    ``rope_parameters`` over heads of ``dim``: the base alone
+    (``default``), or YaRN's inverse frequencies and the amplitude on cos
+    and sin (``yarn``); with a ``partial_rotary_factor`` under 1 also
+    the ``rotary_dim`` it leaves, ``int(dim * factor)`` as the
+    published modelling code takes it, and the table is of that width."""
+    factor = float(rope.get("partial_rotary_factor", 1.0))
+    if not 0 < factor <= 1:
+        raise ValueError(f"partial_rotary_factor {factor} is not in (0, 1]")
+    rot = int(dim * factor)
+    if rot < 2 or rot % 2:
+        raise ValueError(f"partial_rotary_factor {factor} leaves a rotary "
+                         f"width of {rot} in a head of {dim}: no whole "
+                         f"pairs")
     base = float(rope["rope_theta"])
     if rope["rope_type"] == "default":
-        return {"theta": base}
-    if rope["rope_type"] != "yarn":
+        table = {"theta": base}
+    elif rope["rope_type"] == "yarn":
+        scale = float(rope["factor"])
+        table = {"inv_freq": yarn_inv_freq(
+                     rot, base, scale,
+                     int(rope["original_max_position_embeddings"]),
+                     float(rope["beta_fast"]), float(rope["beta_slow"])),
+                 "amplitude": float(rope.get("attention_factor")
+                                    or 0.1 * math.log(scale) + 1)}
+    else:
         raise ValueError(f"rope_type {rope['rope_type']!r} is not default "
                          f"or yarn")
-    factor = float(rope["factor"])
-    return {"inv_freq": yarn_inv_freq(
-                dim, base, factor,
-                int(rope["original_max_position_embeddings"]),
-                float(rope["beta_fast"]), float(rope["beta_slow"])),
-            "amplitude": float(rope.get("attention_factor")
-                               or 0.1 * math.log(factor) + 1)}
+    if rot < dim:
+        table["rotary_dim"] = rot
+    return table
 
 
 class MellumMoe:
@@ -156,16 +171,8 @@ class MellumMoe:
                 self.rotary[kind], window=self.window if sliding else None)
 
     def _moe(self, lay, x) -> Tuple[jax.Array, jax.Array, jax.Array]:
-        s, t, d = x.shape
-        with _scope(trace.SCOPE_MOE_ROUTE):
-            u = self._norm(x, lay["ffn_norm"]).reshape(s * t, d)
-            idx, w = route_top_k(u, lay["router"], None, self.top_k, 1.0,
-                                 score=jax.nn.softmax)
-        with _scope(trace.SCOPE_MOE_EXPERTS):
-            y, stats = routed_experts(u, idx, w, lay["up"], lay["down"],
-                                      self.held, mm_dtype=self.dtype,
-                                      gate=lay["gate"])
-            return x + y.reshape(s, t, d), stats["load"], stats["rows"]
+        return gated_experts(lay, x, self.eps, self.dtype, self.held,
+                             self.top_k, 1.0, score=jax.nn.softmax)
 
     # ---- the stack, the head and the loss ----
     def hidden(self, params, emb: jax.Array):

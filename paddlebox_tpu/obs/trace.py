@@ -173,6 +173,9 @@ KDA_SEQ_STEP_SCOPES = (SCOPE_DECODE, SCOPE_DEDUP, SCOPE_PULL,
                        SCOPE_ATTN, SCOPE_MLP, SCOPE_MOE_ROUTE,
                        SCOPE_MOE_EXPERTS, SCOPE_MOE_SHARED, SCOPE_HEAD,
                        SCOPE_LOSS, SCOPE_PUSH, SCOPE_DENSE_OPT)
+#: a head-wise output gate's projection, sigmoid and product with the
+#: heads (models/laguna.py), inside its attention layer's scope
+SCOPE_ATTN_GATE = "pbox.attn_gate"
 
 #: spans kept in memory (about 13 a resident pass: hundreds of passes)
 RING_SPANS = 8192

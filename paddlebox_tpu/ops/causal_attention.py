@@ -29,7 +29,8 @@ float32; the softmax is float32.
 
 ``rotary_embedding`` is the position embedding a model applies to its
 queries and keys before the call (``models/lfm2.py``; ``models/mellum.py``
-hands it a table a layer kind, ``yarn_inv_freq``'s on its full layers); a
+hands it a table a layer kind, ``yarn_inv_freq``'s on its full layers,
+and ``models/laguna.py`` turns only half of a full layer's head); a
 model without one (``models/nemotron_h.py``) calls the attention as it
 is.
 """
@@ -55,16 +56,24 @@ _NEG = -1e30
 
 
 def rotary_embedding(x: jax.Array, theta: Optional[float] = None, *,
-                     inv_freq=None, amplitude: float = 1.0) -> jax.Array:
+                     inv_freq=None, amplitude: float = 1.0,
+                     rotary_dim: Optional[int] = None) -> jax.Array:
     """Rotate-half position embedding, float32: x [B, T, heads, D], the
     pair (x_i, x_{i + D/2}) of position t turned by the angle
     ``t * inv_freq_i``; a position is the index in the sequence. The
     inverse frequencies are ``theta^(-2i / D)`` unless a model gives its
     own [D / 2] (``yarn_inv_freq``), and cos and sin are multiplied by
-    ``amplitude`` where it is not 1."""
+    ``amplitude`` where it is not 1. With a ``rotary_dim`` R under D only
+    a head's first R dimensions turn, as a head of R would (the pair
+    (x_i, x_{i + R/2}), the table's D read as R), and the other D - R
+    pass as they are: a configuration's ``partial_rotary_factor``."""
     if (theta is None) == (inv_freq is None):
         raise ValueError("give the base theta or the inverse frequencies, "
                          "one and not both")
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = rotary_embedding(x[..., :rotary_dim], theta,
+                                  inv_freq=inv_freq, amplitude=amplitude)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], -1)
     t, d = x.shape[1], x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
         if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
