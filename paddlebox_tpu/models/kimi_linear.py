@@ -54,7 +54,8 @@ projections and norm with it, under ``pbox.attn``); every sublayer is one
 ``jax.checkpoint`` that keeps its input (the dense feed-forward one a
 slab of positions), a latent attention mixer's also its forward block
 loops' two results (``lm_parts.KEEP_ATTN_LOOPS``), a KDA mixer one a
-sequence (``nemotron_h.py``'s Mamba mixer's form).
+sequence that also keeps the states its rule's forward sweep wrote and the
+rule's output (``KEEP_KDA_RULE``), so that sweep runs once a step.
 """
 
 from __future__ import annotations
@@ -64,6 +65,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from paddlebox_tpu.models.lm_parts import (ATTN_BLOCK, KEEP_ATTN_LOOPS,
                                            MOE_STEP_SCALARS, dense_ffn,
@@ -72,13 +74,24 @@ from paddlebox_tpu.models.lm_parts import (ATTN_BLOCK, KEEP_ATTN_LOOPS,
                                            rms_norm)
 from paddlebox_tpu.obs import trace
 from paddlebox_tpu.ops.causal_attention import causal_gqa_attention
-from paddlebox_tpu.ops.kda import kda_normed
+from paddlebox_tpu.ops.kda import ENTERED, kda_normed
 from paddlebox_tpu.ops.short_conv import causal_depthwise_conv
 
 _scope = jax.named_scope
 
 #: steps of a chunk of the delta rule
 KDA_CHUNK = 64
+#: the name of a KDA mixer's rule output as the out-projection takes it
+#: (``compute_dtype``)
+KDA_OUT = "kda_out"
+#: what the ``jax.checkpoint`` around a KDA mixer keeps for its backward
+#: pass beside its input: the states the rule's forward sweep wrote at
+#: the entry of every ``STRIDE``-th chunk (ops/kda.py) and the rule's
+#: output as the out-projection's operand, so that the forward sweep runs
+#: once a step. Everything else inside the mixer (the norm, the
+#: projections, the decay, the convs and SiLU) is recomputed
+KEEP_KDA_RULE = jax.checkpoint_policies.save_only_these_names(ENTERED,
+                                                              KDA_OUT)
 
 
 def layer_kinds(config: Dict[str, Any]) -> str:
@@ -243,6 +256,7 @@ class KimiLinear:
             y = kda_normed(q, k, v, g, beta, gate, lay["o_norm"],
                            eps=self.eps, chunk=KDA_CHUNK, mm_dtype=self.dtype)
         with _scope(trace.SCOPE_KDA_PROJ):
+            y = checkpoint_name(y.astype(self.dtype), KDA_OUT)
             return x + self._mm(y, lay["o"])
 
     def _mla(self, lay, x):
@@ -276,12 +290,15 @@ class KimiLinear:
         x, loads, rows = emb, [], []
         for i, (kind, lay) in enumerate(zip(self.kinds, params["layers"])):
             if kind == "K":
-                # sequences are independent in a mixer over time: one at
-                # a time, so that the mixer's temporaries (the step's
-                # widest) are one sequence's
+                # sequences are independent in a mixer over time: one
+                # checkpoint a sequence, so that the mixer's temporaries
+                # (the step's widest) are one sequence's. Unrolled: what a
+                # ``lax.map``'s trips keep, stacked in the loop's output,
+                # the compiler reserves twice over (PERF.md)
                 one = jax.checkpoint(
-                    lambda lay, xs: self._kda(lay, xs[None])[0])
-                x = jax.lax.map(lambda xs, lay=lay: one(lay, xs), x)
+                    lambda lay, xs: self._kda(lay, xs[None])[0],
+                    policy=KEEP_KDA_RULE)
+                x = jnp.stack([one(lay, x[s]) for s in range(x.shape[0])])
             else:
                 x = jax.checkpoint(self._mla, policy=KEEP_ATTN_LOOPS)(lay, x)
             if i < self.n_dense:

@@ -67,12 +67,18 @@ what the shapes show (``_tiles``), booked as
   dq, dk, dv, dg, dbeta and dgate once, and each grid cell's part of
   o_norm's gradient; ``dA = -T^T dT T^T``, and the cotangent of ``G`` is
   summed to the chunk's end by the triangle turned. **Kept for the
-  backward pass**: the inputs, and the float32 state that entered each
-  chunk (``T / C`` of ``[V, K]`` a head, 268 MB a sequence of the
-  benchmark's cell: 8,192 steps, 32 heads of 128, chunks of 64), written
-  by the forward sweep that the rule's forward rule runs; a call that is
-  not differentiated, and the forward pass of a ``jax.checkpoint``
-  (``optimize_remat``), write none.
+  backward pass**: the inputs, and the float32 state that entered every
+  ``STRIDE``-th chunk (``T / (STRIDE C)`` of ``[V, K]`` a head, 134 MB a
+  sequence of the benchmark's cell: 8,192 steps, 32 heads of 128, chunks
+  of 64), written by the forward sweep that the rule's forward rule runs
+  and named ``ENTERED``; a grid cell of the backward sweep is ``STRIDE``
+  chunks: it rebuilds their terms from the inputs, advances the kept
+  state through them as the forward sweep does, then walks them back,
+  each chunk's terms built once. Under a KDA mixer's ``jax.checkpoint``
+  (``models/kimi_linear.py::KEEP_KDA_RULE``) the forward pass runs the
+  state-writing sweep once and the checkpoint keeps the states (and the
+  rule's output), so its recomputation runs no sweep; a call that is not
+  differentiated runs the sweep that writes none.
 * **The composition** (``impl="xla"``) at every other shape (the tests'
   toy widths): the same norms in plain JAX around ``kda_chunked``, the
   rule alone on ``[B, T, H, K]``: ``GROUP`` chunks' intra-chunk terms at
@@ -104,6 +110,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -118,12 +125,25 @@ SUB = 16
 #: chunks of every (sequence, head) whose intra-chunk terms one trip of
 #: the loop over chunks computes (and the backward pass recomputes)
 GROUP = 8
-#: the most heads one grid cell of the kernels works, side by side: their
-#: chains interleave (cell 6's three sweeps a sequence and layer, 45.8 ms
-#: on a v5e at 8 against 48.2 at 2: PERF.md, PR 43)
+#: the most heads one grid cell of the forward sweep works, side by side:
+#: their chains interleave (cell 6's then three sweeps a sequence and
+#: layer, 45.8 ms on a v5e at 8 against 48.2 at 2). A cell of the
+#: backward sweep holds ``STRIDE`` chunks' terms a head and works
+#: ``HEADS / STRIDE`` (its sweep 20.8 ms a sequence and layer at 4, 22.4 at
+#: 8: PERF.md)
 HEADS = 8
 #: what the L2 norm of queries and keys adds under its square root
 L2_EPS = 1e-6
+#: chunks from one state the forward sweep keeps for the backward pass to
+#: the next: it writes the state that enters every ``STRIDE``-th chunk, and
+#: a cell of the backward sweep walks ``STRIDE`` chunks from it (cell 6's
+#: pass program keeps every state in 15.35 GiB of the chip's 15.75, every
+#: second one in 14.91: PERF.md)
+STRIDE = 2
+#: the name the states that the forward sweep writes carry out of the
+#: rule's forward rule: a ``jax.checkpoint`` whose policy saves it keeps
+#: them for the backward sweep, and its recomputation runs no sweep
+ENTERED = "kda_entered"
 
 
 def _check_chunk(chunk: int) -> None:
@@ -153,14 +173,15 @@ def kda_normed(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     gate [B, T, H V], beta [B, T, H], the output norm's weight o_norm [V]
     -> ``rms_norm(rule(l2(q) K^-1/2, l2(k), v, g, beta), o_norm, eps)
     sigmoid(gate)`` [B, T, H V] float32, the state starting at 0. Any T:
-    the kernels' tail is padded with steps of zeros, which leave the state
-    as it is. ``chunk`` is a multiple of ``SUB``."""
+    the kernels' tail is padded to whole runs of ``STRIDE`` chunks with
+    steps of zeros, which leave the state as it is. ``chunk`` is a multiple
+    of ``SUB``."""
     bsz, t, h = beta.shape
     kd, vd = q.shape[-1] // h, v.shape[-1] // h
     _check_chunk(chunk)
     if _tiles(kd, vd, chunk):
         pk._book_dispatch("kda_scan", "pallas")
-        pad = -t % chunk
+        pad = -t % (chunk * STRIDE)
         y = _rule_kernels(*(jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
                             for x in (q, k, v, g, beta, gate)),
                           o_norm, chunk, jnp.dtype(mm_dtype), eps)
@@ -352,9 +373,9 @@ def _tiles(kd: int, vd: int, chunk: int) -> bool:
     return kd % _LANES == 0 and vd % _LANES == 0 and chunk % 8 == 0
 
 
-def _heads(h: int) -> int:
-    """Heads a grid cell works: the most, up to ``HEADS``, that divide H."""
-    return max(d for d in range(1, min(h, HEADS) + 1) if h % d == 0)
+def _heads(h: int, most: int) -> int:
+    """Heads a grid cell works: the most, up to ``most``, that divide H."""
+    return max(d for d in range(1, min(h, most) + 1) if h % d == 0)
 
 
 def _iota(shape, dim):
@@ -460,21 +481,25 @@ class _Chunk:
         entered and U (both in ``mm``)."""
         return pk.mxu_dot(self.qgm, sm, 1, 1) + pk.mxu_dot(self.pm, um, 1, 0)
 
+    def leaving(self, st, um):
+        """The state that leaves (turned, float32) from the one that
+        entered and U (in ``mm``)."""
+        return st * self.dc + pk.mxu_dot(um, self.kcm, 0, 0)
+
     def step(self, st):
         """The state that entered, turned ([V, K] float32) -> (O [C, V],
         the state that leaves, turned)."""
         sm = st.astype(self.mm)
         um = self.written(sm)
-        return (self.output(sm, um),
-                st * self.dc + pk.mxu_dot(um, self.kcm, 0, 0))
+        return self.output(sm, um), self.leaving(st, um)
 
 
-def _normed_qk(q_ref, k_ref, kc):
-    """A head's q and k [C, K] as the rule takes them: L2-normed, q times
-    K^-1/2."""
+def _normed_qk(q_ref, k_ref, rows, kc):
+    """A head's q and k [C, K] in ``rows`` as the rule takes them:
+    L2-normed, q times K^-1/2."""
     kd = kc.stop - kc.start
-    return (_l2(q_ref[:, kc].astype(_F32)) * kd ** -0.5,
-            _l2(k_ref[:, kc].astype(_F32)))
+    return (_l2(q_ref[rows, kc].astype(_F32)) * kd ** -0.5,
+            _l2(k_ref[rows, kc].astype(_F32)))
 
 
 def _l2_bwd(x, dz):
@@ -495,12 +520,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, gate_ref, w_ref, y_ref,
     def _():
         state[...] = jnp.zeros_like(state)
 
+    if save:
+        @pl.when(pl.program_id(2) % STRIDE == 0)
+        def _():
+            for j in range(heads):
+                entered_ref[j * vd:(j + 1) * vd, :] = state[j]
+
     for j in range(heads):
         kc, vc = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
         st = state[j]
-        if save:
-            entered_ref[vc, :] = st
-        ch = _Chunk(*_normed_qk(q_ref, k_ref, kc),
+        ch = _Chunk(*_normed_qk(q_ref, k_ref, slice(None), kc),
                     v_ref[:, vc].astype(_F32), g_ref[:, kc].astype(_F32),
                     b_ref[:, j:j + 1].astype(_F32), mm)
         o, state[j] = ch.step(st)
@@ -565,13 +594,15 @@ def _pairs_bwd(ch, dp, dd):
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, gate_ref, w_ref,
                 entered_ref, dy_ref, dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
                 dgate_ref, dw_ref, dstate, *, mm, eps: float):
-    """One chunk of the reverse walk. ``dstate`` holds the cotangent of the
-    state (turned) that LEFT this chunk; it leaves holding that of the
-    state that entered. Every tile is recomputed from the inputs and the
-    state that entered the chunk. ``dw_ref`` sums the norm weight's
-    cotangent over the cell's heads and chunks."""
+    """``STRIDE`` chunks of the reverse walk. ``dstate`` holds the cotangent
+    of the state (turned) that LEFT the cell's last chunk; it leaves holding
+    that of the state that entered its first. Every tile is recomputed from
+    the inputs and the kept state that entered the cell: forward through
+    its chunks, the state advanced as the forward sweep advances it, then
+    back through them, each chunk's terms built once. ``dw_ref`` sums the
+    norm weight's cotangent over the cell's heads and chunks."""
     heads, vd, kd = dstate.shape
-    c = q_ref.shape[0]
+    c = q_ref.shape[0] // STRIDE
     causal = _iota((c, c), 0) >= _iota((c, c), 1)
     strict = _iota((c, c), 0) > _iota((c, c), 1)
     tri = causal.astype(_F32)
@@ -586,83 +617,92 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, gate_ref, w_ref,
     dw = jnp.zeros((1, vd), _F32)
     for j in range(heads):
         kc, vc = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
-        q, k = _normed_qk(q_ref, k_ref, kc)
-        g = g_ref[:, kc].astype(_F32)
-        v = v_ref[:, vc].astype(_F32)
-        beta = b_ref[:, j:j + 1].astype(_F32)
-        ch = _Chunk(q, k, v, g, beta, mm)
-        st0 = entered_ref[vc, :]
-        sm = st0.astype(mm)
-        um = ch.written(sm)
-        # y = n weight sigmoid(gate), n = O r, r = (mean O^2 + eps)^-1/2
-        o = ch.output(sm, um)
-        r = jax.lax.rsqrt(jnp.mean(o * o, 1, keepdims=True) + eps)
-        n = o * r
-        sg = jax.nn.sigmoid(gate_ref[:, vc].astype(_F32))
-        dy = dy_ref[:, vc].astype(_F32)
-        dgate_ref[:, vc] = (dy * n * weight * sg * (1.0 - sg)).astype(
-            dgate_ref.dtype)
-        dw = dw + jnp.sum(dy * n * sg, 0, keepdims=True)
-        dn = dy * weight * sg
-        do = r * (dn - n * jnp.mean(dn * n, 1, keepdims=True))
-        left = dstate[j]                                   # [V, K] float32
-        dom, dsm = do.astype(mm), left.astype(mm)
-        # O = qg S + P U;  S' = exp(G_C) S + kc^T U;  U = U~ - W S
-        dun = pk.mxu_dot(ch.pm, dom, 0, 0) + pk.mxu_dot(ch.kcm, dsm, 1, 1)
-        dunm = dun.astype(mm)
-        dstate[j] = (pk.mxu_dot(dom, ch.qgm, 0, 0) + left * ch.dc
-                     - pk.mxu_dot(dunm, ch.wm, 0, 0))
-        dqg = pk.mxu_dot(dom, sm, 1, 0)
-        dwm = (-pk.mxu_dot(dunm, sm, 1, 0)).astype(mm)
-        dkc = pk.mxu_dot(um, dsm, 1, 0)
-        dp = jnp.where(causal, pk.mxu_dot(dom, um, 1, 1), 0.0)
-        # W = T kb, U~ = T vb, T = (I + A)^-1: dA = -T^T dT T^T
-        dt = pk.mxu_dot(dwm, ch.kbm, 1, 1) + pk.mxu_dot(dunm, ch.vbm, 1, 1)
-        dkb = pk.mxu_dot(ch.tm, dwm, 0, 0)
-        dvb = pk.mxu_dot(ch.tm, dunm, 0, 0)
-        da = jnp.where(strict, -pk.mxu_dot(pk.mxu_dot(ch.t, dt, 0, 0), ch.t,
-                                           1, 1), 0.0)
-        # A = beta D
-        dbeta = jnp.sum(da * ch.d, 1, keepdims=True)
-        dq, dk, dgam = _pairs_bwd(ch, dp, beta * da)
-        # qg = q exp(G), kb = k exp(G) beta, vb = v beta, kc = k exp(G_C - G)
-        dq = dq + dqg * ch.eg
-        dk = dk + dkb * ch.eg * beta + dkc * ch.to_end
-        t_kc = dkc * k * ch.to_end
-        dgam = dgam + dqg * q * ch.eg + dkb * ch.kb - t_kc
-        dbeta = dbeta + jnp.sum(dkb * k * ch.eg, 1, keepdims=True) \
-            + jnp.sum(dvb * v, 1, keepdims=True)
-        ddc = jnp.sum(st0 * left, 0, keepdims=True)
-        dlast = jnp.sum(t_kc, 0, keepdims=True) + ddc * ch.dc
-        dgam = dgam + jnp.where(last_row, dlast, 0.0)
-        # G = cumsum(g) within the chunk: its cotangent sums to the end;
-        # q and k through their L2 norms (q's K^-1/2 first)
-        dq_ref[:, kc] = _l2_bwd(q_ref[:, kc].astype(_F32),
-                                dq * kd ** -0.5).astype(dq_ref.dtype)
-        dk_ref[:, kc] = _l2_bwd(k_ref[:, kc].astype(_F32), dk).astype(
-            dk_ref.dtype)
-        dg_ref[:, kc] = pk.mxu_dot(tri, dgam, 0, 0).astype(dg_ref.dtype)
-        dv_ref[:, vc] = (dvb * beta).astype(dv_ref.dtype)
-        db_ref[:, j:j + 1] = dbeta.astype(db_ref.dtype)
+        st = entered_ref[vc, :]
+        walk = []
+        for m in range(STRIDE):
+            rows = slice(m * c, (m + 1) * c)
+            q, k = _normed_qk(q_ref, k_ref, rows, kc)
+            v = v_ref[rows, vc].astype(_F32)
+            beta = b_ref[rows, j:j + 1].astype(_F32)
+            ch = _Chunk(q, k, v, g_ref[rows, kc].astype(_F32), beta, mm)
+            sm = st.astype(mm)
+            um = ch.written(sm)
+            walk.append((rows, ch, v, beta, st, sm, um))
+            if m + 1 < STRIDE:
+                st = ch.leaving(st, um)
+        for rows, ch, v, beta, st0, sm, um in reversed(walk):
+            q, k = ch.q, ch.k
+            # y = n weight sigmoid(gate), n = O r, r = (mean O^2 + eps)^-1/2
+            o = ch.output(sm, um)
+            r = jax.lax.rsqrt(jnp.mean(o * o, 1, keepdims=True) + eps)
+            n = o * r
+            sg = jax.nn.sigmoid(gate_ref[rows, vc].astype(_F32))
+            dy = dy_ref[rows, vc].astype(_F32)
+            dgate_ref[rows, vc] = (dy * n * weight * sg * (1.0 - sg)).astype(
+                dgate_ref.dtype)
+            dw = dw + jnp.sum(dy * n * sg, 0, keepdims=True)
+            dn = dy * weight * sg
+            do = r * (dn - n * jnp.mean(dn * n, 1, keepdims=True))
+            left = dstate[j]                               # [V, K] float32
+            dom, dsm = do.astype(mm), left.astype(mm)
+            # O = qg S + P U;  S' = exp(G_C) S + kc^T U;  U = U~ - W S
+            dun = pk.mxu_dot(ch.pm, dom, 0, 0) + pk.mxu_dot(ch.kcm, dsm, 1, 1)
+            dunm = dun.astype(mm)
+            dstate[j] = (pk.mxu_dot(dom, ch.qgm, 0, 0) + left * ch.dc
+                         - pk.mxu_dot(dunm, ch.wm, 0, 0))
+            dqg = pk.mxu_dot(dom, sm, 1, 0)
+            dwm = (-pk.mxu_dot(dunm, sm, 1, 0)).astype(mm)
+            dkc = pk.mxu_dot(um, dsm, 1, 0)
+            dp = jnp.where(causal, pk.mxu_dot(dom, um, 1, 1), 0.0)
+            # W = T kb, U~ = T vb, T = (I + A)^-1: dA = -T^T dT T^T
+            dt = pk.mxu_dot(dwm, ch.kbm, 1, 1) + pk.mxu_dot(dunm, ch.vbm, 1, 1)
+            dkb = pk.mxu_dot(ch.tm, dwm, 0, 0)
+            dvb = pk.mxu_dot(ch.tm, dunm, 0, 0)
+            da = jnp.where(strict, -pk.mxu_dot(pk.mxu_dot(ch.t, dt, 0, 0),
+                                               ch.t, 1, 1), 0.0)
+            # A = beta D
+            dbeta = jnp.sum(da * ch.d, 1, keepdims=True)
+            dq, dk, dgam = _pairs_bwd(ch, dp, beta * da)
+            # qg = q exp(G), kb = k exp(G) beta, vb = v beta,
+            # kc = k exp(G_C - G)
+            dq = dq + dqg * ch.eg
+            dk = dk + dkb * ch.eg * beta + dkc * ch.to_end
+            t_kc = dkc * k * ch.to_end
+            dgam = dgam + dqg * q * ch.eg + dkb * ch.kb - t_kc
+            dbeta = dbeta + jnp.sum(dkb * k * ch.eg, 1, keepdims=True) \
+                + jnp.sum(dvb * v, 1, keepdims=True)
+            ddc = jnp.sum(st0 * left, 0, keepdims=True)
+            dlast = jnp.sum(t_kc, 0, keepdims=True) + ddc * ch.dc
+            dgam = dgam + jnp.where(last_row, dlast, 0.0)
+            # G = cumsum(g) within the chunk: its cotangent sums to the end;
+            # q and k through their L2 norms (q's K^-1/2 first)
+            dq_ref[rows, kc] = _l2_bwd(q_ref[rows, kc].astype(_F32),
+                                       dq * kd ** -0.5).astype(dq_ref.dtype)
+            dk_ref[rows, kc] = _l2_bwd(k_ref[rows, kc].astype(_F32),
+                                       dk).astype(dk_ref.dtype)
+            dg_ref[rows, kc] = pk.mxu_dot(tri, dgam, 0, 0).astype(
+                dg_ref.dtype)
+            dv_ref[rows, vc] = (dvb * beta).astype(dv_ref.dtype)
+            db_ref[rows, j:j + 1] = dbeta.astype(db_ref.dtype)
     dw_ref[...] += dw
 
 
-def _specs(kd, vd, chunk, heads, step):
-    """Block specs of a (sequence, heads, chunk) cell; ``step`` maps the
-    grid's last index to the chunk (the reverse walk turns it). ``norm``
-    is the output norm's weight [1, V], whole in every cell; ``per_cell``
-    one row [1, V] a (sequence, heads) pair, the same block down the
-    chunks."""
-    wide_k = pl.BlockSpec((None, chunk, heads * kd),
+def _specs(kd, vd, rows, heads, step, kept):
+    """Block specs of a (sequence, heads, ``rows`` steps) cell; ``step``
+    maps the grid's last index to the block of steps (the reverse walk
+    turns it), ``kept`` to the kept state. ``norm`` is the output norm's
+    weight [1, V], whole in every cell; ``per_cell`` one row [1, V] a
+    (sequence, heads) pair, the same block down the chunks."""
+    wide_k = pl.BlockSpec((None, rows, heads * kd),
                           lambda s, j, i: (s, step(i), j))
-    wide_v = pl.BlockSpec((None, chunk, heads * vd),
+    wide_v = pl.BlockSpec((None, rows, heads * vd),
                           lambda s, j, i: (s, step(i), j))
-    col = pl.BlockSpec((None, None, chunk, heads),
+    col = pl.BlockSpec((None, None, rows, heads),
                        lambda s, j, i: (s, j, step(i), 0))
     norm = pl.BlockSpec((1, vd), lambda s, j, i: (0, 0))
     per_cell = pl.BlockSpec((None, None, 1, vd), lambda s, j, i: (s, j, 0, 0))
     entered = pl.BlockSpec((None, None, heads * vd, kd),
-                           lambda s, j, i: (s, step(i), j, 0))
+                           lambda s, j, i: (s, kept(i), j, 0))
     return wide_k, wide_v, col, norm, per_cell, entered
 
 
@@ -685,14 +725,15 @@ _sweep = pk.scoped_sweep(trace.SCOPE_KDA_SCAN)
 def _forward(q, k, v, g, beta, gate, o_norm, *, chunk, mm, eps, save: bool):
     bsz, t, h = beta.shape
     kd, vd = q.shape[-1] // h, v.shape[-1] // h
-    heads = _heads(h)
+    heads = _heads(h, HEADS)
     nc = t // chunk
-    wide_k, wide_v, col, norm, _, entered = _specs(kd, vd, chunk, heads,
-                                                   lambda i: i)
+    wide_k, wide_v, col, norm, _, entered = _specs(
+        kd, vd, chunk, heads, lambda i: i, lambda i: i // STRIDE)
     out_shape = [jax.ShapeDtypeStruct((bsz, t, h * vd), _F32)]
     out_specs = [wide_v]
     if save:
-        out_shape.append(jax.ShapeDtypeStruct((bsz, nc, h * vd, kd), _F32))
+        out_shape.append(jax.ShapeDtypeStruct(
+            (bsz, nc // STRIDE, h * vd, kd), _F32))
         out_specs.append(entered)
     out = pl.pallas_call(
         functools.partial(_fwd_kernel, mm=mm, eps=eps, save=save),
@@ -714,7 +755,8 @@ def _rule_kernels(q, k, v, g, beta, gate, o_norm, chunk, mm, eps):
 def _rule_kernels_fwd(q, k, v, g, beta, gate, o_norm, chunk, mm, eps):
     y, entered = _forward(q, k, v, g, beta, gate, o_norm, chunk=chunk, mm=mm,
                           eps=eps, save=True)
-    return y, (q, k, v, g, beta, gate, o_norm, entered)
+    return y, (q, k, v, g, beta, gate, o_norm,
+               checkpoint_name(entered, ENTERED))
 
 
 def _rule_kernels_bwd(chunk, mm, eps, res, dy):
@@ -726,15 +768,16 @@ def _backward(q, k, v, g, beta, gate, o_norm, entered, dy, *, chunk, mm,
               eps):
     bsz, t, h = beta.shape
     kd, vd = q.shape[-1] // h, v.shape[-1] // h
-    heads = _heads(h)
-    nc = t // chunk
+    heads = _heads(h, max(1, HEADS // STRIDE))
+    cells = t // (chunk * STRIDE)
     wide_k, wide_v, col, norm, per_cell, entered_spec = _specs(
-        kd, vd, chunk, heads, lambda i: nc - 1 - i)
+        kd, vd, chunk * STRIDE, heads, lambda i: cells - 1 - i,
+        lambda i: cells - 1 - i)
     flat_k = jax.ShapeDtypeStruct((bsz, t, h * kd), _F32)
     flat_v = jax.ShapeDtypeStruct((bsz, t, h * vd), _F32)
     dq, dk, dv, dg, db, dgate, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, mm=mm, eps=eps),
-        grid=(bsz, h // heads, nc),
+        grid=(bsz, h // heads, cells),
         in_specs=[wide_k, wide_k, wide_v, wide_k, col, wide_v, norm,
                   entered_spec, wide_v],
         out_specs=[wide_k, wide_k, wide_v, wide_k, col, wide_v, per_cell],
@@ -753,7 +796,4 @@ def _backward(q, k, v, g, beta, gate, o_norm, entered, dy, *, chunk, mm,
             jnp.sum(dw, (0, 1, 2)).astype(o_norm.dtype))
 
 
-# optimize_remat: the forward pass of a ``jax.checkpoint`` around the rule
-# (the model's mixers) runs the sweep that writes no states
-_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd,
-                     optimize_remat=True)
+_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)

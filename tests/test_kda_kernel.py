@@ -4,8 +4,9 @@ against the composition with the same norms in plain JAX and against the
 token recurrence the benchmark's plain reference runs
 (benchmarks/reference/models/kimi_linear.py) between the reference's own
 norms, at tile widths: K = V = 128, chunks of 64, two heads; the value and
-the gradients of q, k, v, g, beta, gate and the output norm's weight. And
-the shape test that chooses between the two forms."""
+the gradients of q, k, v, g, beta, gate and the output norm's weight, also
+under a KDA mixer's checkpoint, which keeps the states the forward sweep
+wrote. And the shape test that chooses between the two forms."""
 
 import functools
 import os
@@ -14,10 +15,13 @@ import sys
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.reference.models.kimi_linear import recurrence  # noqa: E402
+from paddlebox_tpu.models.kimi_linear import (KDA_OUT,  # noqa: E402
+                                              KEEP_KDA_RULE)
 from paddlebox_tpu.ops import kda  # noqa: E402
 from paddlebox_tpu.ops.kda import kda_normed  # noqa: E402
 from test_kda import inputs, rel  # noqa: E402
@@ -28,9 +32,9 @@ CHUNK = 64
 EPS = 1e-5
 NAMES = ("y", "dq", "dk", "dv", "dg", "dbeta", "dgate", "do_norm")
 
-#: (T, a decay down to -30 a step, heads a grid cell works, rows of q and
-#: k whose norm is under the L2 norm's eps)
-CASES = {"one-chunk": (64, False, 2, False),
+#: (T, a decay down to -30 a step, ``kda.HEADS``, rows of q and k whose
+#: norm is under the L2 norm's eps)
+CASES = {"one-chunk": (64, False, 4, False),
          "two-chunks-and-a-tail": (150, False, 1, False),
          "decay-down-to-30-a-step": (150, True, 1, False),
          "a-row-of-a-tiny-norm": (64, False, 1, True)}
@@ -106,8 +110,10 @@ def reference(q, k, v, g, beta, gate, o_norm):
 def readings(t, strong, mm, heads, tiny=False):
     """{name: (the kernels', the composition's, the reference's)} of the
     value and the seven gradients, computed once and read by every case.
-    ``heads``: a cell of two heads side by side, or a cell a head (the
-    interpreter compiles a cell's body once, in seconds a head)."""
+    ``heads``: ``kda.HEADS``; 4 puts both heads side by side in a cell of
+    either sweep (the backward's works ``HEADS / STRIDE``), 1 one head in
+    each (the interpreter compiles a cell's body once, in seconds a
+    head)."""
     args, cot = flat_inputs(t, seed=t, strong=strong, tiny=tiny)
     mm = jnp.dtype(mm)
     before, kda.HEADS = kda.HEADS, heads
@@ -161,9 +167,8 @@ def test_bfloat16_operands_stay_within_a_rounding(name):
 
 
 def test_the_sweep_that_writes_no_states_computes_the_same():
-    """A call that is not differentiated, and the forward pass of a
-    ``jax.checkpoint``, run the sweep without its states: the same
-    value."""
+    """A call that is not differentiated runs the sweep without its
+    states: the same value."""
     args, _ = flat_inputs(150, seed=150)
     before, kda.HEADS = kda.HEADS, 1
     try:
@@ -172,6 +177,80 @@ def test_the_sweep_that_writes_no_states_computes_the_same():
     finally:
         kda.HEADS = before
     assert rel(got, readings(150, False, "float32", 1)["y"][1]) < 1e-5
+
+
+def mixer_rule(*args, mm):
+    """The rule as a KDA mixer runs it: under the mixer's checkpoint, whose
+    policy keeps the states that the forward sweep wrote and the rule's
+    output, so that the backward pass reads the kept states and runs no
+    forward sweep of its own."""
+    def rule(*a):
+        return checkpoint_name(fused(*a, mm=mm), KDA_OUT)
+
+    return jax.checkpoint(rule, policy=KEEP_KDA_RULE)(*args)
+
+
+#: (T, heads a grid cell works, rows of a tiny norm) of the rule under
+#: the mixer's checkpoint: one chunk, two, and three with a tail (a count
+#: of chunks that ``kda.STRIDE`` does not divide), each beside the readings
+#: of the rule without a checkpoint at the same inputs
+KEPT = {"one-chunk": (64, 1, True), "two-chunks": (128, 1, False),
+        "three-chunks-and-a-tail": (150, 1, False)}
+
+
+def kept_values(t, heads, tiny):
+    """{name: the value or gradient} of the rule under the mixer's
+    checkpoint, at ``readings(t, False, "float32", heads, tiny)``'s
+    inputs."""
+    args, cot = flat_inputs(t, seed=t, tiny=tiny)
+    before, kda.HEADS = kda.HEADS, heads
+    try:
+        with jax.default_matmul_precision("highest"):
+            return value_and_grads(
+                functools.partial(mixer_rule, mm=jnp.dtype("float32")),
+                args, cot)
+    finally:
+        kda.HEADS = before
+
+
+kept_readings = functools.lru_cache(maxsize=None)(kept_values)
+
+
+@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("case", list(KEPT))
+def test_the_mixers_checkpoint_gives_what_the_rule_gives(case, name):
+    """The value and the seven gradients under the mixer's checkpoint are
+    the rule's without one (the same sweeps at the same inputs, the
+    states read back from where the forward pass kept them) and the
+    composition's."""
+    t, heads, tiny = KEPT[case]
+    got = kept_readings(t, heads, tiny)[name]
+    plain, want, _ = readings(t, False, "float32", heads, tiny)[name]
+    assert got.shape == want.shape
+    assert float(jnp.linalg.norm(want)) > 0
+    assert rel(got, plain) < 1e-6
+    assert rel(got, want) < 1e-5
+
+
+def test_a_wrong_kept_state_leaves_the_composition(monkeypatch):
+    """The backward sweep handed the state kept for the next run of
+    ``kda.STRIDE`` chunks in place of its own (the last run the first's,
+    zero): the value is the forward pass's and stays right, and every
+    gradient leaves the composition's by far more than the limit the
+    comparison holds it to. What the checkpoint keeps is what the backward
+    sweep's result rests on."""
+    backward = kda._backward
+
+    def shifted(q, k, v, g, beta, gate, o_norm, entered, dy, **static):
+        return backward(q, k, v, g, beta, gate, o_norm,
+                        jnp.roll(entered, -1, axis=1), dy, **static)
+
+    monkeypatch.setattr(kda, "_backward", shifted)
+    got = kept_values(150, 1, False)
+    want = {n: r[1] for n, r in readings(150, False, "float32", 1).items()}
+    assert rel(got["y"], want["y"]) < 1e-5
+    for name in NAMES[1:]:
+        assert rel(got[name], want[name]) > 1e-3, name
 
 
 SHAPES = {

@@ -17,13 +17,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.reference import lm as ref_lm  # noqa: E402
 from benchmarks.reference.models import nemotron_h as ref  # noqa: E402
 from paddlebox_tpu.models import NemotronH  # noqa: E402
+from paddlebox_tpu.models.kimi_linear import (  # noqa: E402
+    KDA_CHUNK, KDA_OUT, KEEP_KDA_RULE)
 from paddlebox_tpu.obs import trace  # noqa: E402
+from paddlebox_tpu.ops import kda  # noqa: E402
 from paddlebox_tpu.ops.kda import kda_normed  # noqa: E402
 from paddlebox_tpu.ops.ssd import ssd_scan  # noqa: E402
 from paddlebox_tpu.parallel.moe import route_top_k, routed_experts  # noqa: E402
@@ -653,22 +657,34 @@ def _flat_rule_shapes(t, h, d):
     return [(1, t, h * d)] * 4 + [(1, t, h), (1, t, h * d), (d,)]
 
 
+def _kept_rule(*args):
+    """The delta rule under a KDA mixer's checkpoint: its policy keeps the
+    states that the forward sweep writes and the rule's output
+    (``test_kda_kernel.mixer_rule`` in bfloat16; that module imports this
+    one through ``test_kda``)."""
+    def rule(*a):
+        return checkpoint_name(_delta_rule(*a), KDA_OUT)
+
+    return jax.checkpoint(rule, policy=KEEP_KDA_RULE)(*args)
+
+
 def test_every_kernel_of_the_delta_rule_sits_under_its_scope(
         kernels_for_the_chip):
-    """Two checkpointed calls of the delta rule at tile widths (two heads
-    of 128), each under another scope as a KDA mixer's projections are,
-    and their gradient, lowered for a TPU: three custom calls (the sweep
-    that writes no states, in the checkpoints' forward pass; the one that
-    writes them, in their recomputation; the backward sweep), each under
-    ``pbox.kda_scan`` by the rule's own naming, so that the trace's readers
-    count all three and ``other`` none of them."""
+    """Two calls of the delta rule at tile widths (two heads of 128), each
+    under a KDA mixer's checkpoint and under another scope as a KDA
+    mixer's projections are, and their gradient, lowered for a TPU: two
+    custom calls, the sweep that writes the states (in the checkpoints'
+    forward pass) and the backward sweep, which reads the states the
+    checkpoints kept. No forward sweep is left in the checkpoints'
+    recomputation. Each under ``pbox.kda_scan`` by the rule's own naming,
+    so that the trace's readers count both and ``other`` neither."""
     def args(seed):
         return [jax.random.normal(jax.random.PRNGKey(seed + i), s)
                 for i, s in enumerate(_flat_rule_shapes(128, 2, 128))]
 
     def layer(x, *rest):
         with jax.named_scope(trace.SCOPE_KDA_PROJ):
-            return jax.checkpoint(_delta_rule)(x, *rest)
+            return _kept_rule(x, *rest)
 
     def loss(a, b):
         return jnp.sum(layer(*a) * layer(*b))
@@ -679,11 +695,11 @@ def test_every_kernel_of_the_delta_rule_sits_under_its_scope(
     locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
     stacks = [locs[ref_] for ref_ in re.findall(
         r"@tpu_custom_call\(.*loc\((#loc\d+)\)$", text, re.M)]
-    assert len(stacks) == 3, stacks
+    assert len(stacks) == 2, stacks
     for stack in stacks:
         assert trace.SCOPE_KDA_SCAN in stack.split("/"), stacks
     calls = re.findall(r"call @(_forward|_backward)\w*\(", text)
-    assert calls.count("_backward") == 2 and calls.count("_forward") == 4
+    assert calls.count("_backward") == 2 and calls.count("_forward") == 2
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
@@ -704,30 +720,37 @@ def test_the_delta_rule_kernels_compile_for_a_v5e(what, one_v5e,
         1 if what == "forward" else 2)
 
 
-def test_a_kda_mixer_changes_no_layout_of_its_rows(one_v5e,
-                                                   kernels_for_the_chip):
-    """One KDA mixer's loss and gradient at cell 6's widths (hidden 2304,
-    32 heads of 128; 512 steps) compiled for a v5e: no ``copy``,
-    ``reshape`` or ``transpose`` that is not a bitcast is left on a
-    float32 array of a flat row's or a head's shape (``[.., 4096]``,
-    ``[.., 32, 128]``), labelled or not. Where per-head ops sat between
-    the flat producers and the rule's flat operands, XLA changed the
-    tiling of each such array on both sides (20 such ops here, 25 a
-    sequence and mixer of cell 6's pass program)."""
+def _kda_model_at_the_cells_widths():
+    """One KDA layer of cell 6's widths (hidden 2304, 32 heads of 128)
+    over the toy family's other numbers (a dense feed-forward of 80)."""
     from benchmarks.tests.test_family_lm_kimi_linear import (toy_cell,
                                                               toy_config)
     from paddlebox_tpu.models import KimiLinear
     cfg = dict(toy_cell()["config"], **toy_config("K"))
     cfg.update(hidden_size=2304, linear_attn_config=dict(
         cfg["linear_attn_config"], num_heads=32, head_dim=128))
-    model = KimiLinear(cfg)
+    return KimiLinear(cfg)
+
+
+def test_a_kda_mixer_changes_no_layout_of_its_rows(one_v5e,
+                                                   kernels_for_the_chip):
+    """One KDA mixer's loss and gradient at cell 6's widths (hidden 2304,
+    32 heads of 128; 512 steps) under its checkpoint, compiled for a v5e:
+    no ``copy``, ``reshape`` or ``transpose`` that is not a bitcast is left
+    on a float32 array of a flat row's or a head's shape (``[.., 4096]``,
+    ``[.., 32, 128]``), labelled or not. Where per-head ops sat between
+    the flat producers and the rule's flat operands, XLA changed the
+    tiling of each such array on both sides (20 such ops here, 25 a
+    sequence and mixer of cell 6's pass program)."""
+    model = _kda_model_at_the_cells_widths()
     lay = jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"][0]
     lay = {k: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e)
            for k, a in lay.items() if k not in ("w1", "w2", "w3", "ffn_norm")}
     x = jax.ShapeDtypeStruct((1, 512, 2304), jnp.float32, sharding=one_v5e)
 
     def loss(lay, x):
-        return jnp.sum(jax.checkpoint(model._kda)(lay, x) ** 2)
+        return jnp.sum(jax.checkpoint(model._kda, policy=KEEP_KDA_RULE)(
+            lay, x) ** 2)
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).trace(
         lay, x).lower(lowering_platforms=("tpu",)).compile().as_text()
@@ -735,7 +758,45 @@ def test_a_kda_mixer_changes_no_layout_of_its_rows(one_v5e,
         r"= f32\[([\d,]*(?:4096|32,128))\]\{[^}]*\} "
         r"(copy|reshape|transpose)\(", text)
     assert not moved, moved
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
+
+
+#: the temporaries (bytes) of the program that the next test compiles for
+#: a described v5e, as it compiled while the mixer's checkpoint kept no
+#: state and ran the forward sweep again in the backward pass
+KDA_LAYER_TEMP_RECOMPUTED = 3_421_032_960
+
+
+def test_a_kda_layer_keeps_what_its_backward_sweep_reads(
+        one_v5e, kernels_for_the_chip):
+    """A KDA layer's loss and gradient at cell 6's widths and steps (two
+    sequences of 8,192 through ``KimiLinear.hidden``, chunks of 64)
+    compiled for a v5e. Two custom calls a sequence (the mixer's
+    checkpoint is one a sequence): the forward sweep that writes the
+    states and the backward sweep, none in the mixer's recomputation. The
+    temporaries grow by what the checkpoint keeps, a sequence the float32
+    state at the entry of every ``kda.STRIDE``-th chunk and the rule's
+    bfloat16 output, and by no more than a tenth of it over the program
+    that ran the sweep again: kept values that the compiler reserves twice
+    over (as it does for a ``lax.map``'s stacked residuals) fail this."""
+    model = _kda_model_at_the_cells_widths()
+    seqs, t, h, d = 2, 8192, 32, 128
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((seqs, t, model.d), jnp.float32,
+                             sharding=one_v5e)
+
+    def loss(params, x):
+        return jnp.sum(model.hidden(params, x)[0] ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(
+        params, x).lower(lowering_platforms=("tpu",)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2 * seqs
+    states = t // (KDA_CHUNK * kda.STRIDE) * h * d * d * 4
+    kept = seqs * (states + t * h * d * 2)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < KDA_LAYER_TEMP_RECOMPUTED + 1.1 * kept, (temp, kept)
 
 
 # ---- the click models' pass program is what it was --------------------------
