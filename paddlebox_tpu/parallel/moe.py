@@ -201,6 +201,9 @@ def moe_forward_sharded(mesh: Any, axis: str,
 #: largest divisor of the token count that this allows
 EXPERT_BLOCK = 512
 
+#: the TPU's lane width: the loops see a token's row as whole rows of it
+LANES = 128
+
 
 def route_top_k(x: jax.Array, router: jax.Array,
                 bias: Optional[jax.Array], top_k: int, scale: float,
@@ -255,9 +258,11 @@ def routed_experts(x: jax.Array, idx: jax.Array, w: jax.Array,
     gather of its tokens' rows, the products with the expert's matrices
     read in place, and a scatter-add; the backward pass is written by
     hand as the same loop (reverse mode cannot differentiate a trip count
-    that is data). Nothing is sized by, or walks, the ``N * k`` bound but
-    the sort and its integers: time follows the choices that are there,
-    and an expert without a choice runs no trip."""
+    that is data). The loops gather and scatter-add token rows of
+    ``_token_rows``' view, each row whole tiles of the chip's memory.
+    Nothing is sized by, or walks, the ``N * k`` bound but the sort and
+    its integers: time follows the choices that are there, and an expert
+    without a choice runs no trip."""
     n, k = x.shape[0], idx.shape[1]
     lo, hi = held
     n_held = hi - lo
@@ -293,6 +298,23 @@ def _block_rows(tok, starts, load, e: int, i, blk: int):
     return t, i * blk + jnp.arange(blk, dtype=jnp.int32) < load[e]
 
 
+def _token_rows(a):
+    """``a`` [N, D] viewed as [N, ceil(D / LANES), LANES], zero past D: a
+    token's row is whole lane-rows, so under the TPU's (8, 128) tiling a
+    gather or scatter-add of a token moves whole tiles of its own, where
+    in [N, D] it is one sublane of each of ``D / 128`` tiles shared with
+    seven other tokens."""
+    pad = -a.shape[1] % LANES
+    if pad:
+        a = jnp.pad(a, ((0, 0), (0, pad)))
+    return a.reshape(a.shape[0], -1, LANES)
+
+
+def _flat_rows(a, d: int):
+    """``_token_rows``' view [rows, lanes, LANES] back to [rows, d]."""
+    return a.reshape(a.shape[0], -1)[:, :d]
+
+
 def _dot(a, b, axis_a: int, axis_b: int):
     """``a`` and ``b`` contracted over one axis each, float32 out."""
     return jax.lax.dot_general(a, b, (((axis_a,), (axis_b,)), ((), ())),
@@ -325,23 +347,25 @@ def _grouped_product(x, wt, ups, down, tok, starts, load, blk, mm_dtype):
     over the tokens of each expert's run (``routed_experts`` says what
     ``tok``, ``starts`` and ``load`` are, ``_activation`` what ``act``
     is). Products take ``mm_dtype`` operands and accumulate in float32;
-    the activation is float32."""
-    xm = x.astype(mm_dtype)
+    the activation is float32. The loops gather ``x``'s and scatter-add
+    ``y``'s token rows in ``_token_rows``' view."""
+    d = x.shape[1]
+    xm = _token_rows(x.astype(mm_dtype))
     upms = [up.astype(mm_dtype) for up in ups]
     downm = down.astype(mm_dtype)
-    y = jnp.zeros(x.shape, jnp.float32)
+    y = jnp.zeros(xm.shape, jnp.float32)
     for e in range(down.shape[0]):
 
         def one_block(i, y, e=e):
             t, live = _block_rows(tok, starts, load, e, i, blk)
-            xb = xm[t]
+            xb = _flat_rows(xm[t], d)
             act, _ = _activation([_dot(xb, upm[e], 1, 0) for upm in upms])
             out = _dot(act.astype(mm_dtype), downm[e], 1, 0)
             wb = jnp.where(live, wt[:, e][t], 0)
-            return y.at[t].add(out * wb[:, None])
+            return y.at[t].add(_token_rows(out * wb[:, None]))
 
         y = jax.lax.fori_loop(0, -(-load[e] // blk), one_block, y)
-    return y
+    return _flat_rows(y, d)
 
 
 def _grouped_product_fwd(x, wt, ups, down, tok, starts, load, blk, mm_dtype):
@@ -357,10 +381,12 @@ def _grouped_product_bwd(blk, mm_dtype, res, dy):
     float32 through its loop and are written once."""
     x, wt, ups, down, tok, starts, load = res
     f32 = jnp.float32
-    xm = x.astype(mm_dtype)
+    d = x.shape[1]
+    xm = _token_rows(x.astype(mm_dtype))
+    dy = _token_rows(dy)
     upms = [up.astype(mm_dtype) for up in ups]
     downm = down.astype(mm_dtype)
-    dx = jnp.zeros(x.shape, f32)
+    dx = jnp.zeros(xm.shape, f32)
     dwt, dups, ddown = [], [], []
     for e in range(down.shape[0]):
 
@@ -368,11 +394,11 @@ def _grouped_product_bwd(blk, mm_dtype, res, dy):
             dx, dwt_e, dups_e, ddown_e = carry
             t, live = _block_rows(tok, starts, load, e, i, blk)
             wb = jnp.where(live, wt[:, e][t], 0)
-            xb = xm[t]
+            xb = _flat_rows(xm[t], d)
             act, kept = _activation([_dot(xb, upm[e], 1, 0)
                                      for upm in upms])
             act = act.astype(mm_dtype)
-            dyb = dy[t]
+            dyb = _flat_rows(dy[t], d)
             # d out / d act, before the row's weight: the weight's own
             # gradient is sum(out * dy) = sum(act * (dy down^T))
             dact = _dot(dyb.astype(mm_dtype), downm[e], 1, 1)
@@ -382,8 +408,8 @@ def _grouped_product_bwd(blk, mm_dtype, res, dy):
                 act, (dyb * wb[:, None]).astype(mm_dtype), 0, 0)
             dhids = [dhid.astype(mm_dtype) for dhid in
                      _activation_bwd(kept, dact * wb[:, None])]
-            dx = dx.at[t].add(functools.reduce(operator.add, [
-                _dot(dhid, upm[e], 1, 1) for dhid, upm in zip(dhids, upms)]))
+            dx = dx.at[t].add(_token_rows(functools.reduce(operator.add, [
+                _dot(dhid, upm[e], 1, 1) for dhid, upm in zip(dhids, upms)])))
             dups_e = [dup_e + _dot(xb, dhid, 0, 0)
                       for dup_e, dhid in zip(dups_e, dhids)]
             return dx, dwt_e, dups_e, ddown_e
@@ -396,9 +422,10 @@ def _grouped_product_bwd(blk, mm_dtype, res, dy):
         dwt.append(dwt_e)
         dups.append(dups_e)
         ddown.append(ddown_e)
-    return (dx.astype(x.dtype), jnp.stack(dwt, 1).astype(wt.dtype),
-            tuple(jnp.stack(d).astype(up.dtype)
-                  for d, up in zip(zip(*dups), ups)),
+    return (_flat_rows(dx, d).astype(x.dtype),
+            jnp.stack(dwt, 1).astype(wt.dtype),
+            tuple(jnp.stack(g).astype(up.dtype)
+                  for g, up in zip(zip(*dups), ups)),
             jnp.stack(ddown).astype(down.dtype), None, None, None)
 
 

@@ -427,10 +427,15 @@ def test_the_attention_operators_forward_sweep_runs_once_a_step(
 #: the one attention layer's checkpoint keeps the forward block loops'
 #: two results, so the recomputed forward sweep went: its two ``while``
 #: ops (65 before) and its two products (277 ``dot_general`` before);
-#: no other count moved.
+#: no other count moved. Re-recorded when the expert loops took
+#: ``parallel/moe._token_rows``' view: their gathers and scatter-adds take
+#: [N, 1, 128] token rows where they took [N, 64] (the toy's 64 zero-padded
+#: to one lane-row), with the pads, reshapes and slices around them; every
+#: counted op is the parent's, and with ``_token_rows`` made the identity
+#: the program lowers to the parent's text (sha256 b99aa576...).
 NEMOTRON_PASS_JAX = "0.9.0"
 NEMOTRON_PASS_TEXT = \
-    "b99aa5763551f8c6ee97b827bcc92eaff20de94f81a946d0ced0558550e3cd57"
+    "07d2a6427953c2e083dace9e5e2d6fc5e0d51559c4897abaf0412250112a7cbb"
 NEMOTRON_PASS_OPS = {"while": 63, "gather": 170, "sort": 5, "scatter": 112,
                      "dot_general": 275, "custom_call": 12}
 

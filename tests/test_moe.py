@@ -84,3 +84,55 @@ def test_moe_sharded_matches_local():
     np.testing.assert_allclose(np.asarray(y_sh), np.asarray(y_local),
                                rtol=1e-4, atol=1e-5)
     assert np.isfinite(float(aux_sh))
+
+
+# ---- routed_experts: a token's row moves as whole lane-rows -------------------
+
+def _rows_case(d, gated, empty):
+    """96 tokens (blocks of 32, so runs of several blocks), 3 choices of 8
+    experts, experts 2..5 held; ``empty``: no token chooses expert 3."""
+    from paddlebox_tpu.parallel.moe import route_top_k
+    n, f, experts = 96, 48, 8
+    ks = jax.random.split(jax.random.PRNGKey(d + 2 * gated + 4 * empty), 7)
+    x = jax.random.normal(ks[0], (n, d))
+    bias = jnp.zeros(experts).at[3].set(-1e9 if empty else 0.0)
+    idx, w = route_top_k(x, jax.random.normal(ks[1], (d, experts)), bias,
+                         3, 1.0)
+    held = (2, 6)
+    up, gate, down = (jax.random.normal(k, s) * 0.1 for k, s in zip(
+        ks[2:5], ((4, d, f), (4, d, f), (4, f, d))))
+    return x, idx, w, up, gate if gated else None, down, held, ks[5]
+
+
+@pytest.mark.parametrize("d,gated,empty", [
+    (256, False, False), (256, True, False), (200, False, False),
+    (200, True, False), (256, True, True)],
+    ids=["lanes-2-relu2", "lanes-2-swiglu", "d200-relu2", "d200-swiglu",
+         "an-expert-empty"])
+def test_tile_rows_give_the_flat_forms_bits(monkeypatch, d, gated, empty):
+    """``routed_experts`` moves token rows in ``_token_rows``' view
+    [N, ceil(D / 128), 128]; with the view taken away (the flat [N, D]
+    form the loops had before) ``y`` and every gradient are the same to
+    the bit: the same adds in the same order, in float32."""
+    from paddlebox_tpu.parallel import moe
+    x, idx, w, up, gate, down, held, key = _rows_case(d, gated, empty)
+    load = np.asarray(jnp.sum(idx[:, :, None] == jnp.arange(*held), (0, 1)))
+    assert (load[1] == 0) == empty and load.max() > 32
+    cot = jax.random.normal(key, x.shape)
+
+    def run():
+        def total(x, w, up, gate, down):
+            y, _ = moe.routed_experts(x, idx, w, up, down, held,
+                                      mm_dtype=jnp.bfloat16, gate=gate)
+            return jnp.sum(y * cot), y
+        return jax.grad(total, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+            x, w, up, gate, down)
+
+    tiled = run()
+    monkeypatch.setattr(moe, "_token_rows", lambda a: a)
+    flat = run()
+    leaves = jax.tree.leaves(tiled)
+    assert len(leaves) == (6 if gated else 5)
+    for a, b in zip(leaves, jax.tree.leaves(flat)):
+        assert a.shape == b.shape and np.asarray(a).any()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

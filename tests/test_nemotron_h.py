@@ -799,6 +799,42 @@ def test_a_kda_layer_keeps_what_its_backward_sweep_reads(
     assert temp < KDA_LAYER_TEMP_RECOMPUTED + 1.1 * kept, (temp, kept)
 
 
+def test_the_expert_loops_move_whole_tiles_a_token_on_a_v5e(one_v5e):
+    """``routed_experts``' loss and gradient at cell 4's shapes (16,384
+    tokens of 2,304, 8 of 64 gated experts of 896 held, 8 choices a
+    token) compiled for a v5e: every gather and scatter of token rows, in
+    the forward and the backward loops, takes the rows as [16384, 18, 128]
+    (a token 18 whole lane-rows: its own tiles under the (8, 128) tiling),
+    and no scatter into [16384, 2304] is left, where a token's row is one
+    sublane of 18 tiles shared with seven other tokens."""
+    n, d, f, k, held = 16384, 2304, 896, 8, 8
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    def loss(x, w, up, gate, down, idx):
+        y, _ = routed_experts(x, idx, w, up, down, (0, held), gate=gate)
+        return jnp.sum(y * y)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).trace(
+        arg((n, d)), arg((n, k)), arg((held, d, f)), arg((held, d, f)),
+        arg((held, f, d)), arg((n, k), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    rows = collections.Counter()
+    for shape, op, rest in re.findall(
+            r"= \w+\[([\d,]*)\]\S* (gather|scatter)\((.*)", text):
+        sizes = re.search(r"slice_sizes=\{([\d,]*)\}", rest)
+        token_row = (sizes.group(1).split(",")[1:] if op == "gather"
+                     else shape.split(",")[1:])
+        if token_row:
+            rows[op, ",".join(token_row)] += 1
+    # a forward and a backward loop an expert: x's rows gathered in both,
+    # dy's in the backward, y's and dx's rows scatter-added
+    assert rows == {("gather", "18,128"): 3 * held,
+                    ("scatter", "18,128"): 2 * held}, rows
+    assert not re.search(r"f32\[16384,2304\]\S* scatter\(", text)
+
+
 # ---- the click models' pass program is what it was --------------------------
 
 #: DeepFM's resident pass program (the shapes below) as lowered at the
